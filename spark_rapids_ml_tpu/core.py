@@ -43,6 +43,7 @@ from .data.dataframe import DataFrame, _is_sparse
 from .params import Params, _TpuParams, HasLabelCol, HasPredictionCol, HasWeightCol
 from .runtime import autotune, envspec, telemetry
 from .parallel.mesh import (
+    device_bytes_limit,
     global_row_count,
     make_mesh,
     resolve_mesh_mp,
@@ -117,16 +118,8 @@ _GANG_PARTITION_LOGGED = False
 
 def _default_gang_budget() -> float:
     """Default HBM budget for gang-fit lane residents: a quarter of the
-    device memory limit (4 GB when the backend reports none, e.g. the CPU
-    test mesh)."""
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = float(stats.get("bytes_limit", 0.0))
-    except Exception:
-        limit = 0.0
-    if limit <= 0.0:
-        limit = float(16 << 30)
-    return limit / 4.0
+    device memory limit (of a nominal 16 GB on the CPU test mesh)."""
+    return device_bytes_limit(16 << 30) / 4.0
 
 
 def _gang_env_on() -> bool:
@@ -237,18 +230,14 @@ def _default_stream_threshold_bytes() -> int:
 
     Overridable via ``TPUML_STREAM_THRESHOLD_BYTES``. Default: 60% of one
     device's reported memory (the design matrix must leave room for Gram
-    temporaries), or 8 GiB when the backend doesn't report memory (CPU)."""
+    temporaries) times the local device count; 8 GiB on the CPU backend,
+    which reports no memory limit."""
     env = envspec.get("TPUML_STREAM_THRESHOLD_BYTES")
     if env is not None:
         return int(env)
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        limit = int(stats.get("bytes_limit", 0)) if stats else 0
-        if limit > 0:
-            return int(0.6 * limit * len(jax.local_devices()))
-    except Exception:
-        pass
-    return 8 << 30
+    if jax.local_devices()[0].platform == "cpu":
+        return 8 << 30
+    return int(0.6 * device_bytes_limit(0) * len(jax.local_devices()))
 
 
 class _TpuEstimator(Params, _TpuParams):

@@ -41,7 +41,7 @@ from typing import Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 
 from ..parallel.layout import LAYOUT

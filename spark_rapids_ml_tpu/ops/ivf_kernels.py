@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding
 
-from ._compat import shard_map
+from jax import shard_map
 from ..parallel.layout import LAYOUT
 from ..parallel.mesh import DP_AXIS, MP_AXIS
 from .kmeans_kernels import kmeans_lloyd, pairwise_sq_dists

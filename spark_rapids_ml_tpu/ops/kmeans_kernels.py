@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
-from ._compat import shard_map
+from jax import shard_map
 
 from ..parallel.layout import LAYOUT
 from ..parallel.mesh import DP_AXIS
@@ -36,6 +36,7 @@ def pairwise_sq_dists(
     c_sq: jax.Array | None = None,
     *,
     matmul_dtype=None,
+    precision=None,
 ) -> jax.Array:
     """(rows, k) squared euclidean distances: ||x||² - 2 x·c + ||c||², ≥ 0.
 
@@ -44,6 +45,13 @@ def pairwise_sq_dists(
     ``matmul_dtype=bfloat16`` runs that contraction with bf16 operands and
     f32 accumulation (~2x MXU rate; ||x||²/||c||² stay f32): assignment
     flips only on near-ties, which Lloyd's local search absorbs.
+
+    ``precision``: on a TPU an f32 contraction at the default precision is
+    NOT an f32 product (measured on v5e, PR 22: the expansion then loses
+    ~1e-3 of a distance where ||x||² ≫ d²). Callers whose RESULT is the
+    distance — exact kNN, the reported KMeans cost — pass
+    ``lax.Precision.HIGHEST``; callers that only take an argmin over
+    well-separated centers keep the default. No effect on the CPU.
     """
     if c_sq is None:
         c_sq = (centers * centers).sum(axis=1)
@@ -55,7 +63,7 @@ def pairwise_sq_dists(
             preferred_element_type=x.dtype,
         )
     else:
-        xc = x @ centers.T
+        xc = jnp.dot(x, centers.T, precision=precision)
     d2 = x_sq[:, None] - 2.0 * xc + c_sq[None, :]
     return jnp.maximum(d2, 0.0)
 
@@ -73,7 +81,8 @@ def stats_dot(onehot: jax.Array, x: jax.Array, matmul_dtype=None) -> jax.Array:
     )
 
 
-def _chunk_stats(X_local, mask_local, centers, csize: int, matmul_dtype=None):
+def _chunk_stats(X_local, mask_local, centers, csize: int, matmul_dtype=None,
+                 exact: bool = False):
     """Chunked pass over local rows; returns (sums (k,d), counts int32 (k,),
     cost).
 
@@ -87,22 +96,29 @@ def _chunk_stats(X_local, mask_local, centers, csize: int, matmul_dtype=None):
     a reshaped X — see its docstring for the layout-repack hazard).
     ``matmul_dtype=bfloat16`` also runs the one-hot stats contraction with
     bf16 operands (one-hots are exact; x rounds at ~1e-3 relative, washed
-    out by the per-cluster mean)."""
+    out by the per-cluster mean). ``exact`` runs the distance contraction
+    at ``Precision.HIGHEST`` — for the pass whose cost is reported."""
     from .kmeans_pallas import kmeans_pallas_ok, lloyd_step_pallas
 
     k = centers.shape[0]
     d = X_local.shape[1]
-    if kmeans_pallas_ok(X_local.shape[0], d, k, X_local.dtype, matmul_dtype):
+    if kmeans_pallas_ok(
+        X_local.shape[0], d, k, X_local.dtype, matmul_dtype, exact
+    ):
         return lloyd_step_pallas(
-            X_local, mask_local, centers, matmul_dtype=matmul_dtype
+            X_local, mask_local, centers, matmul_dtype=matmul_dtype,
+            exact=exact,
         )
+    prec = lax.Precision.HIGHEST if exact else None
     n_chunks = check_row_chunking(X_local.shape[0], csize)
     c_sq = (centers * centers).sum(axis=1)  # (k,)
 
     def body(i, carry):
         sums, counts, cost = carry
         x, m = row_chunk(i, csize, X_local, mask_local)
-        d2 = pairwise_sq_dists(x, centers, c_sq, matmul_dtype=matmul_dtype)
+        d2 = pairwise_sq_dists(
+            x, centers, c_sq, matmul_dtype=matmul_dtype, precision=prec
+        )
         assign = jnp.argmin(d2, axis=1)
         onehot = jax.nn.one_hot(assign, k, dtype=x.dtype) * m[:, None]
         sums = sums + stats_dot(onehot, x, matmul_dtype)
@@ -228,12 +244,17 @@ def _kmeans_lloyd_1d(
         # straight-line form is kept; the unaligned-d memory note lives in
         # COVERAGE.md.
         #
-        # The final cost pass ALWAYS runs f32: the ||x||²-2x·c+||c||²
-        # expansion cancels catastrophically at bf16 precision when rows
-        # sit near their centroid (intra-cluster distance² ~ |x|²·2⁻⁸
-        # rounding), which corrupts the reported cost even though
-        # iteration ARGMIN assignments only need inter-center contrast.
-        _, _, cost = _chunk_stats(X_local, mask_local, centers, csize)
+        # The final cost pass ALWAYS runs f32 operands at HIGHEST
+        # precision: the ||x||²-2x·c+||c||² expansion cancels
+        # catastrophically at bf16 precision when rows sit near their
+        # centroid (intra-cluster distance² ~ |x|²·2⁻⁸ rounding), which
+        # corrupts the reported cost even though iteration ARGMIN
+        # assignments only need inter-center contrast. On the MXU an f32
+        # dot at DEFAULT precision is such a reduced product (measured on
+        # v5e, PR 22: reported cost 1.7e-3 off a plain f32 Lloyd).
+        _, _, cost = _chunk_stats(
+            X_local, mask_local, centers, csize, exact=True
+        )
         cost = lax.psum(cost, DP_AXIS)
         return centers, cost, it
 

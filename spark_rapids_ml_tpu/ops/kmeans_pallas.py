@@ -33,7 +33,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ._compat import pallas_tpu_compiler_params
 
 # Test hook (mirrors ops.linalg.FORCE_INTERPRET): run the kernel through
 # the Pallas interpreter on CPU so tests cover the real kernel body.
@@ -52,10 +51,13 @@ _TILE = 2048
 _LOWERING_OK: dict = {}
 
 
-def _probe_lowering(d: int, k: int, matmul_dtype) -> bool:
+def _probe_lowering(d: int, k: int, matmul_dtype, exact: bool = False) -> bool:
     from .linalg import probe_pallas_lowering
 
-    key = (d, -(-k // 128) * 128, jnp.dtype(matmul_dtype).name if matmul_dtype else None)
+    key = (
+        d, -(-k // 128) * 128,
+        jnp.dtype(matmul_dtype).name if matmul_dtype else None, exact,
+    )
 
     def compile_fn():
         # avals only — the probe may run while an outer fit is tracing,
@@ -63,12 +65,16 @@ def _probe_lowering(d: int, k: int, matmul_dtype) -> bool:
         x = jax.ShapeDtypeStruct((_TILE, d), jnp.float32)
         m = jax.ShapeDtypeStruct((_TILE,), jnp.float32)
         c = jax.ShapeDtypeStruct((k, d), jnp.float32)
-        lloyd_step_pallas.lower(x, m, c, matmul_dtype=matmul_dtype).compile()
+        lloyd_step_pallas.lower(
+            x, m, c, matmul_dtype=matmul_dtype, exact=exact
+        ).compile()
 
     return probe_pallas_lowering(_LOWERING_OK, key, compile_fn, "fused Lloyd")
 
 
-def kmeans_pallas_ok(n_local: int, d: int, k: int, dtype, matmul_dtype=None) -> bool:
+def kmeans_pallas_ok(
+    n_local: int, d: int, k: int, dtype, matmul_dtype=None, exact: bool = False
+) -> bool:
     """Trace-time gate: TPU, f32 input, lane-aligned d (KMeans ingestion
     pads features to 128, so the reference d=3000 shape qualifies), local
     rows divisible by the tile (the shard_rows csize invariant makes the
@@ -91,24 +97,29 @@ def kmeans_pallas_ok(n_local: int, d: int, k: int, dtype, matmul_dtype=None) -> 
         and vmem < 90 * 1024 * 1024
     )
     if ok and not FORCE_INTERPRET:
-        ok = _probe_lowering(d, k, matmul_dtype)
+        ok = _probe_lowering(d, k, matmul_dtype, exact)
     return ok
 
 
-@functools.partial(jax.jit, static_argnames=("matmul_dtype", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("matmul_dtype", "exact", "interpret")
+)
 def lloyd_step_pallas(
     Xl: jax.Array,       # (n_local, d) f32 — padded rows carry mask 0
     ml: jax.Array,       # (n_local,) f32 row validity
     centers: jax.Array,  # (k, d) f32
     *,
     matmul_dtype=None,
+    exact: bool = False,
     interpret: bool | None = None,
 ):
     """One Lloyd accumulation pass over local rows.
 
     Returns (sums (k, d) f32, counts (k,) int32, cost () f32) — the same
     triple as ``kmeans_kernels._chunk_stats``, before the cross-device
-    psum."""
+    psum. ``exact`` runs the distance contraction at
+    ``Precision.HIGHEST`` (Mosaic's default for f32 operands is a reduced
+    product): the pass whose cost is reported asks for it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -150,6 +161,7 @@ def lloyd_step_pallas(
         xd = x.astype(cd.dtype)
         xc = jax.lax.dot_general(
             xd, c_ref[:], (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST if exact else None,
             preferred_element_type=jnp.float32,
         )                                  # (tile, k_pad)
         # x_sq is row-constant: it joins for the cost only, never the argmin
@@ -190,8 +202,7 @@ def lloyd_step_pallas(
             jax.ShapeDtypeStruct((1, k_pad), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

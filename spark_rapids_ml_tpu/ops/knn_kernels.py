@@ -22,8 +22,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ._compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh
 
 from ..parallel.layout import LAYOUT
@@ -182,7 +181,9 @@ def ring_knn(
                 def iblock(carry, blk):
                     bd_c, bi_c = carry
                     xi, mi_b, idi_b = blk
-                    d2 = pairwise_sq_dists(xq, xi)
+                    d2 = pairwise_sq_dists(
+                        xq, xi, precision=lax.Precision.HIGHEST
+                    )
                     d2 = jnp.where(mi_b[None, :] > 0, d2, jnp.inf)
                     # top-k the raw tile, THEN merge with the carry at
                     # width 2k. Concatenating the (qc, ic) tile with the

@@ -18,7 +18,10 @@ replaces the sort with a tau-gated extraction loop:
 * Exactness: each iteration inserts the globally best remaining candidate
   of the block; k iterations bound the loop because a block's (k+1)-th
   best can never enter the top-k alongside its k better neighbours.
-  Verified on-chip bit-for-bit against ``lax.top_k`` (ids and distances).
+  The distance product itself runs at ``Precision.HIGHEST``: Mosaic's
+  default for f32 operands is a reduced product, under which the
+  selection is exact over inexact scores (``chip_smoke.py`` holds the
+  result against an f64 brute force).
 
 Reference role: replaces the fused distance+select kernels cuML's
 ``NearestNeighborsMG.kneighbors`` runs per partition pair
@@ -32,7 +35,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ._compat import pallas_tpu_compiler_params
 from jax import lax
 
 # Test hook (mirrors ops.kmeans_pallas.FORCE_INTERPRET).
@@ -119,8 +121,11 @@ def knn_pallas_pass(
 
         xq = xq_ref[:]                    # (QB, d)
         xi = xi_ref[:]                    # (IB, d)
+        # HIGHEST: Mosaic's default for f32 operands is a reduced product,
+        # which on v5e put 11% of queries on a wrong neighbour set (PR 22)
         xc = jax.lax.dot_general(
             xq, xi, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )                                 # (QB, IB)
         score0 = csq_ref[:] - 2.0 * xc    # (1, IB) broadcasts; +inf = masked
@@ -184,8 +189,7 @@ def knn_pallas_pass(
             jax.ShapeDtypeStruct((nq, k), jnp.float32),
             jax.ShapeDtypeStruct((nq, k), jnp.int32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

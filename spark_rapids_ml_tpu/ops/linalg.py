@@ -15,8 +15,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ._compat import pallas_tpu_compiler_params, shard_map
+from jax import lax, shard_map
 
 from ..parallel.layout import LAYOUT
 from ..parallel.mesh import DP_AXIS, MP_AXIS
@@ -154,8 +153,7 @@ def _shifted_gram_pallas(
             jax.ShapeDtypeStruct((d, d), jnp.float32),
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # 16 MB double-buffered row tiles + centering temporaries + the
             # d×d accumulator (16 MB at d=2048) need headroom past the
@@ -355,31 +353,45 @@ def standardize_moments(
     var = (d * d).sum(axis=0) / n
     return mean, jnp.sqrt(var), n
 
+
+class PallasLoweringError(RuntimeError):
+    """A Pallas kernel whose static gate said yes was refused by the TPU
+    compiler. Carries the kernel's name and the compiler's message."""
+
+
+class _naming_refusal:
+    """Context manager: an exception leaving the block is re-raised as
+    :class:`PallasLoweringError` with the kernel's name in front of it."""
+
+    def __init__(self, name: str, key) -> None:
+        self.name, self.key = name, key
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if isinstance(exc, Exception):
+            raise PallasLoweringError(
+                f"{self.name} Pallas kernel failed to lower for config "
+                f"{self.key}: {exc_type.__name__}: {exc}"
+            ) from exc
+        return False
+
+
 def probe_pallas_lowering(cache: dict, key, compile_fn, name: str) -> bool:
     """Shared hardware-lowering probe for Pallas kernels.
 
-    Interpret-mode tests exercise kernel bodies but not Mosaic lowering
-    (round 3: a scalar VMEM store traced and interpreted fine yet failed
-    only on the real chip, dropping KMeans from the bench capture). Before
-    first real use of a config, ``compile_fn`` AOT-compiles a tiny
-    instance; a rejection routes every caller to its XLA fallback instead
-    of crashing the fit. Only genuine Mosaic rejections are negative-cached
-    — a transient backend failure (RPC hiccup, HBM pressure) must not pin
-    the process to the slower path forever.
+    Interpret-mode tests exercise kernel bodies but not Mosaic lowering (a
+    scalar VMEM store traced and interpreted fine yet failed only on the
+    real chip). Before first real use of a config, ``compile_fn``
+    AOT-compiles a tiny instance. The static shape gates decide which path
+    a shape takes; a kernel they admitted and the compiler then refuses is
+    a defect, so the refusal **raises** :class:`PallasLoweringError` naming
+    the kernel — a fit never carries on in silence on the XLA path.
+    ``cache`` memoises successes only. Always returns True.
     """
     if key not in cache:
-        try:
+        with _naming_refusal(name, key):
             compile_fn()
-            cache[key] = True
-        except Exception as e:
-            import logging
-
-            logging.getLogger(name).warning(
-                "%s Pallas kernel failed to lower for config %s; "
-                "falling back to the XLA path: %s", name, key, e
-            )
-            msg = str(e)
-            if "Mosaic" in msg or "Not implemented" in msg:
-                cache[key] = False
-            return False
-    return cache[key]
+        cache[key] = True
+    return True

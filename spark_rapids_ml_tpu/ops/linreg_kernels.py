@@ -116,7 +116,7 @@ def linreg_suffstats_chunked(
     covariance. The d-vector statistics (Xy, sums, variance) stay
     replicated: they are O(d), not O(d²).
     """
-    from ._compat import shard_map
+    from jax import shard_map
     from ..parallel.layout import LAYOUT
     from ..parallel.mesh import DP_AXIS, MP_AXIS
     from .linalg import check_row_chunking, row_chunk

@@ -24,8 +24,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ._compat import pallas_tpu_compiler_params, shard_map
+from jax import lax, shard_map
 from ..parallel.layout import LAYOUT
 from ..parallel.mesh import DP_AXIS
 
@@ -167,8 +166,7 @@ def _loss_grad_pallas(Xl, yl, ml, A, b_row, *, multinomial: bool,
             jax.ShapeDtypeStruct((Kp, d), jnp.float32),
             jax.ShapeDtypeStruct((1, _LANES), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

@@ -42,8 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ._compat import pallas_tpu_compiler_params
-
 # Test hook (mirrors ops.linalg.FORCE_INTERPRET): run the kernel through
 # the Pallas interpreter on CPU so tests cover the real kernel body.
 FORCE_INTERPRET = False
@@ -198,8 +196,7 @@ def subblock_hist(
             (L * S, W), lambda i: (i, 0), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((n_blocks * L * S, W), jnp.float32),
-        compiler_params=pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
@@ -323,8 +320,7 @@ def subblock_hist_sel(
             (L * S, W), lambda i: (i, 0), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((n_blocks * L * S, W), jnp.float32),
-        compiler_params=pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
@@ -692,8 +688,7 @@ def packed_traverse(
         ],
         out_specs=pl.BlockSpec((B, T_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, T_pad), jnp.int32),
-        compiler_params=pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

@@ -27,7 +27,9 @@ Randomness has two modes:
   order, shared via ``umap_kernels.epoch_rng_keys``) and streamed into
   the kernel. Same-seed outputs match ``optimize_embedding_rows`` to
   float associativity — this is the parity-testable mode, and the only
-  mode under interpret (jax 0.4.x has no interpreter for the TPU PRNG).
+  mode under interpret (``interpret=True`` has no CPU rule for
+  ``prng_seed`` on the installed jax 0.9.0 — checked; the TPU
+  ``InterpretParams`` mode accepts it but returns all-zero bits).
 * ``rng="onchip"`` — the kernel draws the slot mask from the TPU
   hardware PRNG (``pltpu.prng_seed``/``prng_random_bits``), removing the
   (R, K) uniform stream from HBM entirely. Statistically equivalent
@@ -38,12 +40,11 @@ semantics exactly: tn[r, k, s] = src[perm[(((r - offs[s]) mod R)·K + k)
 mod n_tab]], materialized per epoch as cheap contiguous tiles/rolls of
 the (n_tab,) permutation — integer copies, never an embedding gather.
 
-Hardware gating follows the rf_pallas convention: a trace-time shape
-gate plus ``ops.linalg.probe_pallas_lowering`` on a two-block instance
-of the real config; any Mosaic rejection (e.g. of the sublane
-``dynamic_gather`` or non-integer ``pow``) routes the caller to the XLA
-loop. Engine selection is ``TPUML_UMAP_OPT`` = auto | pallas | xla,
-mirroring ``TPUML_RF_APPLY``.
+Hardware gating: the compiled kernel is ruled out for now (see
+``umap_sgd_pallas_ok``: jax 0.9.0 refuses its row gather), so the engine
+runs in interpret mode only and TPU fits take the XLA loop. Engine
+selection is ``TPUML_UMAP_OPT`` = auto | pallas | xla, mirroring
+``TPUML_RF_APPLY``.
 """
 
 from __future__ import annotations
@@ -54,18 +55,12 @@ import logging
 import jax
 import jax.numpy as jnp
 
-from ._compat import pallas_tpu_compiler_params, pallas_tpu_prng
 from ..runtime import envspec
 from .umap_kernels import epoch_alpha, epoch_rng_keys
 
 # Test hook (mirrors ops.rf_pallas.FORCE_INTERPRET): run the kernel
 # through the Pallas interpreter on CPU so tests cover the real body.
 FORCE_INTERPRET = False
-
-# Hardware-lowering probe results keyed by (n_tab, K, C, neg, rng);
-# policy in ops.linalg.probe_pallas_lowering. n_tab is in the key because
-# the table's whole-array VMEM residency is the config being probed.
-_LOWERING_OK: dict = {}
 
 # CSR rows per grid block. 256 divides both row buckets the fit uses
 # (4096 and 256); transform batches are padded up to it with inert rows.
@@ -78,21 +73,29 @@ def resolve_umap_opt() -> str:
 
 def default_rng_mode() -> str:
     """On-chip PRNG on real TPU hardware; the XLA stream everywhere else
-    (the interpreter has no PRNG lowering on jax 0.4.x)."""
+    (the Pallas interpreter has no rule for ``pltpu.prng_seed``)."""
     if FORCE_INTERPRET or jax.default_backend() != "tpu":
         return "xla"
-    from jax.experimental.pallas import tpu as pltpu
-
-    return "onchip" if pallas_tpu_prng(pltpu) is not None else "xla"
+    return "onchip"
 
 
 def umap_sgd_pallas_ok(
     n_tab: int, K: int, C: int, neg: int, rng: str = "xla"
 ) -> bool:
-    """Trace-time gate: TPU (or interpret), slot widths in range, and the
-    lane-padded table inside the VMEM budget — then a probed lowering."""
+    """Trace-time gate: slot widths in range and the lane-padded table
+    inside the VMEM budget.
+
+    Interpret mode only, by rule: the installed Pallas TPU lowering
+    (jax 0.9.0, ``pallas/mosaic/lowering.py`` gather rule) takes
+    ``take_along_axis`` only where indices and table have the same shape
+    and refuses this kernel's (B·K, C)-from-(n_tab, C) row gather at any
+    size (``tests/test_chip_compile.py`` keeps the refusal as a strict
+    xfail). Until the gather is rewritten every TPU fit takes the XLA
+    epoch loop by this rule, not by a caught compile error; whoever
+    repairs it re-opens the gate to ``jax.default_backend() == "tpu"``
+    behind ``ops.linalg.probe_pallas_lowering``, as ``rf_pallas`` does."""
     ok = (
-        (jax.default_backend() == "tpu" or FORCE_INTERPRET)
+        FORCE_INTERPRET
         and 1 <= C <= 8
         and 1 <= K <= 128
         and 1 <= neg <= 16
@@ -102,55 +105,16 @@ def umap_sgd_pallas_ok(
         # buffers fit the 100 MB vmem budget (65536 rows -> 33.5 MB).
         and n_tab * 512 <= 64 * 1024 * 1024
     )
-    if ok and rng == "onchip":
-        if FORCE_INTERPRET:
-            return False
-        from jax.experimental.pallas import tpu as pltpu
-
-        ok = pallas_tpu_prng(pltpu) is not None
-    if ok and not FORCE_INTERPRET:
-        ok = _probe_lowering(n_tab, K, C, neg, rng)
-    return ok
-
-
-def _probe_lowering(n_tab: int, K: int, C: int, neg: int, rng: str) -> bool:
-    from .linalg import probe_pallas_lowering
-
-    key = (n_tab, K, C, neg, rng)
-    B = BLOCK_ROWS
-
-    def compile_fn():
-        # two grid blocks (rf_pallas rationale: single-block probes mask
-        # multi-block rejections) at the REAL table shape — residency is
-        # part of the config
-        src = jax.ShapeDtypeStruct((n_tab, C), jnp.float32)
-        h = jax.ShapeDtypeStruct((2 * B, C), jnp.float32)
-        tails = jax.ShapeDtypeStruct((2 * B, K), jnp.int32)
-        p = jax.ShapeDtypeStruct((2 * B, K), jnp.float32)
-        nids = jax.ShapeDtypeStruct((2 * B, neg * K), jnp.int32)
-        u = (
-            jax.ShapeDtypeStruct((2 * B, K), jnp.float32)
-            if rng == "xla"
-            else None
-        )
-        seed = jax.ShapeDtypeStruct((1, 1), jnp.int32)
-        sgd_epoch_rows.lower(
-            src, h, tails, p, nids, u, seed,
-            a=1.577, b=0.895, gamma=1.0, attract_scale=2.0, rng=rng,
-        ).compile()
-
-    return probe_pallas_lowering(
-        _LOWERING_OK, key, compile_fn, "UMAP VMEM-resident SGD"
-    )
+    # the interpreter has no rule for the on-chip PRNG
+    return ok and rng != "onchip"
 
 
 def select_sgd_engine(
     n_tab: int, K: int, C: int, neg: int, *, rng: str | None = None
 ) -> str:
     """Resolve ``TPUML_UMAP_OPT`` against the gate/probe: returns
-    ``"pallas"`` or ``"xla"``. An explicit ``pallas`` that the gate
-    rejects warns and falls back — the fit must not crash on a config
-    Mosaic refuses (same clean-fallback contract as the probe itself)."""
+    ``"pallas"`` or ``"xla"``. An explicit ``pallas`` at a config the
+    static gate rules out warns and runs the XLA loop."""
     mode = resolve_umap_opt()
     if mode == "xla":
         return "xla"
@@ -216,10 +180,9 @@ def sgd_epoch_rows(
         if rng == "xla":
             unif = u_ref[...]
         else:
-            prng_seed, prng_bits = pallas_tpu_prng(pltpu)
             # decorrelate grid blocks off the per-epoch seed
-            prng_seed(seed_ref[0, 0] + pl.program_id(0))
-            bits = prng_bits((B, K))
+            pltpu.prng_seed(seed_ref[0, 0] + pl.program_id(0))
+            bits = pltpu.prng_random_bits((B, K))
             unif = (bits >> jnp.uint32(8)).astype(jnp.float32) * (
                 1.0 / (1 << 24)
             )
@@ -280,8 +243,7 @@ def sgd_epoch_rows(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((B, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.float32),
-        compiler_params=pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

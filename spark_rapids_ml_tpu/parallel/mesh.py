@@ -37,19 +37,30 @@ def _cached_mesh(n_dp: int, n_mp: int) -> Mesh:
     return Mesh(devices, (DP_AXIS, MP_AXIS))
 
 
+def device_bytes_limit(cpu_default: float) -> float:
+    """Memory limit of one local device, from ``memory_stats()``.
+
+    The CPU backend reports none: it gets ``cpu_default`` (the virtual test
+    mesh). On an accelerator a missing ``bytes_limit`` is an error — every
+    budget derived from a made-up limit would be wrong in silence."""
+    dev = jax.local_devices()[0]
+    limit = float((dev.memory_stats() or {}).get("bytes_limit", 0.0))
+    if limit > 0.0:
+        return limit
+    if dev.platform == "cpu":
+        return float(cpu_default)
+    raise RuntimeError(
+        f"{dev.platform} device {dev.device_kind!r} reports no bytes_limit "
+        "in memory_stats(); cannot size HBM budgets"
+    )
+
+
 def _default_mp_budget() -> float:
     """Default HBM budget for one device's model-axis shard under
-    ``TPUML_MESH_MP=auto``: a quarter of the device memory limit (4 GB
-    when the backend reports none, e.g. the CPU test mesh) — the same
-    convention as the gang-fit and tree-batch resolvers."""
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = float(stats.get("bytes_limit", 0.0))
-    except Exception:
-        limit = 0.0
-    if limit <= 0.0:
-        limit = float(16 << 30)
-    return limit / 4.0
+    ``TPUML_MESH_MP=auto``: a quarter of the device memory limit (of a
+    nominal 16 GB on the CPU test mesh) — the same convention as the
+    gang-fit and tree-batch resolvers."""
+    return device_bytes_limit(16 << 30) / 4.0
 
 
 def resolve_mesh_mp(model_bytes: float = 0.0) -> int:

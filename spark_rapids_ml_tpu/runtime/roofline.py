@@ -39,13 +39,11 @@ Semantics worth knowing before reading numbers:
 
 from __future__ import annotations
 
-import logging
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 from . import envspec, lockwitness
 
-_LOGGER = logging.getLogger("spark_rapids_ml_tpu")
 
 __all__ = [
     "install",
@@ -54,6 +52,7 @@ __all__ = [
     "aggregate",
     "site_costs",
     "peak_specs",
+    "chip_peaks",
     "reset_roofline",
 ]
 
@@ -61,31 +60,23 @@ __all__ = [
 # per-platform peak specs
 # --------------------------------------------------------------------------
 
-# bf16 peak FLOP/s per chip by device kind (mirrors bench.py's MFU
-# denominator so measured and derived MFU share a scale).
-_PEAK_FLOPS_BY_KIND: Tuple[Tuple[str, float], ...] = (
-    ("v6", 918e12),
-    ("v5p", 459e12),
-    ("v5 lite", 197e12),
-    ("v5e", 197e12),
-    ("v5", 459e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
+# The one peak table, keyed by ``device_kind`` substring: (bf16 peak FLOP/s,
+# HBM GB/s) per chip, datasheet figures (v5e: Google Cloud "TPU v5e" —
+# 197 TFLOP/s bf16, 819 GB/s). ``bench.py`` reads it through
+# :func:`chip_peaks` so measured and derived MFU share a denominator.
+_PEAKS_BY_KIND: Tuple[Tuple[str, float, float], ...] = (
+    ("v6", 918e12, 1640.0),
+    ("v5p", 459e12, 2765.0),
+    ("v5 lite", 197e12, 819.0),
+    ("v5e", 197e12, 819.0),
+    ("v5", 459e12, 2765.0),
+    ("v4", 275e12, 1228.0),
+    ("v3", 123e12, 900.0),
+    ("v2", 45e12, 700.0),
 )
-# HBM bandwidth GB/s per chip by device kind (datasheet figures).
-_PEAK_HBM_GBPS_BY_KIND: Tuple[Tuple[str, float], ...] = (
-    ("v6", 1640.0),
-    ("v5p", 2765.0),
-    ("v5 lite", 819.0),
-    ("v5e", 819.0),
-    ("v5", 2765.0),
-    ("v4", 1228.0),
-    ("v3", 900.0),
-    ("v2", 700.0),
-)
-# nominal CPU-fallback figures: keep ratios finite without pretending a
-# host is an accelerator (same convention as bench.py's _CPU_PEAK)
+# nominal figures for the CPU backend only: they keep ratios finite in
+# host-only runs, whose results are flagged ``host_only`` and are never a
+# device metric
 _CPU_PEAK_FLOPS = 1e12
 _CPU_PEAK_HBM_GBPS = 100.0
 
@@ -93,45 +84,50 @@ _PEAK_LOCK = lockwitness.make_lock("roofline.peaks")
 _PEAK_CACHE: Optional[Tuple[float, float, int]] = None
 
 
-def _kind_lookup(kind: str, table: Tuple[Tuple[str, float], ...],
-                 fallback: float) -> float:
-    kind = kind.lower()
-    for key, peak in table:
+def chip_peaks(device_kind: str, platform: str) -> Tuple[float, float]:
+    """``(peak_flops, peak_hbm_gbps)`` per chip for a device.
+
+    The CPU backend gets the nominal host figures. An accelerator whose
+    ``device_kind`` is not in the table is an error, not a default: a
+    utilization against a made-up peak is worse than none.
+    """
+    if platform == "cpu":
+        return _CPU_PEAK_FLOPS, _CPU_PEAK_HBM_GBPS
+    kind = device_kind.lower()
+    for key, flops, gbps in _PEAKS_BY_KIND:
         if key in kind:
-            return peak
-    return fallback
+            return flops, gbps
+    raise ValueError(
+        f"no peak figures for device_kind {device_kind!r} (platform "
+        f"{platform!r}); add it to runtime/roofline._PEAKS_BY_KIND with "
+        "its source"
+    )
 
 
 def peak_specs() -> Tuple[float, float, int]:
     """``(peak_flops_per_chip, peak_hbm_gbps_per_chip, device_count)``.
 
-    Env overrides win; otherwise the device-kind tables (CPU nominal
-    fallback). Cached after first resolution — by the time a compile has
-    been attributed the backend is necessarily up, so the device probe
-    cannot initialize anything the program was not already using.
+    Env overrides win; otherwise :func:`chip_peaks` of the first device.
+    Cached after first resolution — by the time a compile has been
+    attributed the backend is necessarily up, so the device probe cannot
+    initialize anything the program was not already using.
     """
     global _PEAK_CACHE
     with _PEAK_LOCK:
         if _PEAK_CACHE is not None:
             return _PEAK_CACHE
-        kind, n_dev = "cpu", 1
-        try:
-            import jax
+        import jax
 
-            devices = jax.devices()
-            n_dev = len(devices)
-            kind = getattr(devices[0], "device_kind", "cpu")
-        except Exception:  # no backend: nominal single-host figures
-            pass
+        devices = jax.devices()
         flops = envspec.get("TPUML_PEAK_FLOPS")
-        if flops is None:
-            flops = _kind_lookup(kind, _PEAK_FLOPS_BY_KIND, _CPU_PEAK_FLOPS)
         gbps = envspec.get("TPUML_PEAK_HBM_GBPS")
-        if gbps is None:
-            gbps = _kind_lookup(
-                kind, _PEAK_HBM_GBPS_BY_KIND, _CPU_PEAK_HBM_GBPS
+        if flops is None or gbps is None:
+            t_flops, t_gbps = chip_peaks(
+                devices[0].device_kind, devices[0].platform
             )
-        _PEAK_CACHE = (float(flops), float(gbps), n_dev)
+            flops = t_flops if flops is None else flops
+            gbps = t_gbps if gbps is None else gbps
+        _PEAK_CACHE = (float(flops), float(gbps), len(devices))
         return _PEAK_CACHE
 
 
@@ -204,27 +200,28 @@ def _consume_pending(site: str) -> None:
 def install() -> bool:
     """Wrap the backend compile entry point so executables surface their
     cost analysis, and make sure the shared ``jax.monitoring`` listener
-    is registered (idempotent). Returns True when the hook is active.
+    is registered (idempotent). Returns True once the hook is active.
 
-    The wrap targets a jax-internal symbol; when the internals have
-    moved this degrades to "roofline attributes absent" rather than an
-    import error — the cost-analysis-fallback contract.
+    The wrap targets ``jax._src.compiler.backend_compile_and_load``, the
+    function every ``jit`` compile of the installed jax (0.9.0) goes
+    through. It is a jax-internal symbol: when it is absent this
+    **raises** — a hook that installs and then attributes nothing is the
+    defect this replaces.
     """
     global _INSTALLED, _ORIG_BACKEND_COMPILE
     with _LOCK:
         if _INSTALLED:
             return True
-        try:
-            from jax._src import compiler as _jax_compiler
+        from jax._src import compiler as _jax_compiler
 
-            _ORIG_BACKEND_COMPILE = _jax_compiler.backend_compile
-            _jax_compiler.backend_compile = _wrapped_backend_compile
-        except Exception:
-            _LOGGER.debug(
-                "roofline: jax compile hook unavailable; "
-                "cost-model attribution disabled"
+        if not hasattr(_jax_compiler, "backend_compile_and_load"):
+            raise RuntimeError(
+                "roofline: jax._src.compiler.backend_compile_and_load is "
+                "absent in this jax; the cost-analysis hook has to be moved "
+                "to the function jit compiles through"
             )
-            return False
+        _ORIG_BACKEND_COMPILE = _jax_compiler.backend_compile_and_load
+        _jax_compiler.backend_compile_and_load = _wrapped_backend_compile
         _INSTALLED = True
     # the compile-event listener is the attribution path (telemetry owns
     # it; it calls back into _consume_pending) — register outside _LOCK,

@@ -41,7 +41,8 @@ def _cv_failfast() -> bool:
 
 # Serializes per-fold device work under parallel CV (see run_fold in
 # CrossValidator.fit): concurrent first-compiles of one jitted fit from
-# multiple threads deadlock on jax 0.4.x.
+# multiple threads were seen to deadlock on an earlier jax; not re-tested on
+# the installed 0.9.0, so the lock stays.
 _FOLD_DEVICE_LOCK = threading.Lock()
 
 
@@ -204,8 +205,8 @@ class CrossValidator(_CrossValidatorParams):
         def _run_fold(
             i: int,
         ) -> Tuple[np.ndarray, Optional[List[_TpuModel]]]:
-            # Device passes are serialized across fold threads: jax 0.4.x
-            # can deadlock (futex wedge inside the dispatch lock) when
+            # Device passes are serialized across fold threads: an earlier
+            # jax could deadlock (futex wedge inside the dispatch lock) when
             # several threads race the *first* compile of the same jitted
             # fit. The lock covers ONLY device work — fold selection,
             # host-side _combine stacking, and metric aggregation run

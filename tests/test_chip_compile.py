@@ -1,0 +1,226 @@
+"""Compile the Pallas kernels for a TPU v5e that is described, not attached.
+
+The suite runs kernel bodies in interpret mode; that cannot show what the
+Mosaic compiler refuses (a slice off the tiling, too much VMEM, a program
+that does not fit HBM). These tests hand the kernel functions themselves
+(``interpret=False``; the ``*_ok`` gates ask ``jax.default_backend()`` and
+would take the CPU branch here) to the installed TPU compiler at the widths
+``chip_smoke.py`` runs, plus — compile only, they are off the smoke path —
+the RF and UMAP kernels at ``bench.py``'s shapes. A compile that passes is
+not a chip run.
+
+Rules (on-chip-measurement guide, section 2): the topology is described
+inside a module-scoped fixture that skips when it cannot be; nothing touches
+it at import time; the persistent compilation cache is off around these
+tests (a topology compile is written to it but can never be read back
+without a chip); every compile happens in this process; all cases live in
+this one file.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+ROWS, D = 4_194_304, 256          # chip_smoke.py's X
+F32, I32 = jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_compile_cache):
+    """``S(shape, dtype)``: a shape placed on the first described chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    sh = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype=F32: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo, no_compile_cache):
+    """(mesh, rows(shape, dtype)): a shape row-sharded over the 2x2 host."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1), ("dp", "mp"))
+    sh = NamedSharding(mesh, P("dp"))
+    return mesh, lambda shape, dtype=F32: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# ---- the smoke path, one chip -------------------------------------------
+
+
+def test_gram_kernel_compiles(one_chip):
+    from spark_rapids_ml_tpu.ops.linalg import _shifted_gram_pallas
+
+    fn = jax.jit(lambda X, m, mu: _shifted_gram_pallas(X, m, mu, interpret=False))
+    c = fn.lower(one_chip((ROWS, D)), one_chip((ROWS,)), one_chip((D,))).compile()
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize(
+    "k,exact",
+    [(1024, False), (1024, True), (4097, False)],
+    ids=["lloyd_k1024", "cost_pass_k1024_highest", "kmeans_seeding_candidates_k4097"],
+)
+def test_lloyd_kernel_compiles(one_chip, k, exact):
+    """k=1024 is the Lloyd loop and (exact) its final cost pass; ~4,100 is
+    what k-means|| seeding asks of the same kernel (count_closest over
+    1 + 2·2k candidates), inside the gate's VMEM arithmetic — all at the
+    estimator's default matmul_dtype (None = f32 operands)."""
+    from spark_rapids_ml_tpu.ops.kmeans_pallas import lloyd_step_pallas
+
+    c = lloyd_step_pallas.lower(
+        one_chip((ROWS, D)), one_chip((ROWS,)), one_chip((k, D)),
+        matmul_dtype=None, exact=exact, interpret=False,
+    ).compile()
+    assert _has_kernel(c)
+
+
+def test_logreg_loss_grad_kernel_compiles(one_chip):
+    from spark_rapids_ml_tpu.ops.logreg_pallas import _loss_grad_pallas, _row_tile
+
+    Kp = 8  # binary: one class row, sublane-padded
+    fn = jax.jit(
+        lambda X, y, m, A, b: _loss_grad_pallas(
+            X, y, m, A, b, multinomial=False, n_valid_classes=1,
+            tile=_row_tile(D, Kp), interpret=False,
+        )
+    )
+    c = fn.lower(
+        one_chip((ROWS, D)), one_chip((ROWS,)), one_chip((ROWS,)),
+        one_chip((Kp, D)), one_chip((1, 128)),
+    ).compile()
+    assert _has_kernel(c)
+
+
+def test_knn_pass_kernel_compiles(one_chip):
+    from spark_rapids_ml_tpu.ops.knn_pallas import _IB, _QB, knn_pallas_pass
+
+    nq, ni, k = 65_536, 1_048_576, 16
+    assert nq % _QB == 0 and ni % _IB == 0
+    c = knn_pallas_pass.lower(
+        one_chip((nq, D)), one_chip((ni, D)), one_chip((1, ni)),
+        one_chip((1, ni), I32), one_chip((nq, k)), one_chip((nq, k), I32),
+        interpret=False,
+    ).compile()
+    assert _has_kernel(c)
+
+
+# ---- the cross-chip path: whole programs over the described 2x2 mesh ----
+
+
+def test_sharded_gram_step_compiles_for_four_chips(four_chips, monkeypatch):
+    """The PCA fit program at num_workers=4: the Gram kernel on every shard
+    and a psum of the partials, a quarter of X per device."""
+    from spark_rapids_ml_tpu.models.feature import _pca_fit_kernel
+
+    mesh, rows = four_chips
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the gate
+    c = _pca_fit_kernel.lower(
+        rows((ROWS, D)), rows((ROWS,)), k=3, mesh=mesh, csize=65_536
+    ).compile()
+    txt = c.as_text()
+    assert "tpu_custom_call" in txt and "all-reduce" in txt
+    per_device = c.memory_analysis().argument_size_in_bytes
+    assert per_device < 0.3 * ROWS * D * 4
+
+
+def test_knn_ring_compiles_for_four_chips(four_chips, monkeypatch):
+    from spark_rapids_ml_tpu.ops import knn_pallas
+    from spark_rapids_ml_tpu.ops.knn_kernels import ring_knn
+
+    mesh, rows = four_chips
+    nq, ni, k = 65_536, 1_048_576, 16
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the gate's probe compiles for the process's own backend (the CPU here);
+    # the kernel's lowering for the chip is test_knn_pass_kernel_compiles
+    monkeypatch.setitem(knn_pallas._LOWERING_OK, (D, k), True)
+    c = ring_knn.lower(
+        rows((nq, D)), rows((ni, D)), rows((ni,)), rows((ni,), I32),
+        mesh=mesh, k=k, topk_impl="auto",
+    ).compile()
+    txt = c.as_text()
+    assert "tpu_custom_call" in txt and "collective-permute" in txt
+
+
+# ---- off the smoke path: compile only, bench.py's RF / UMAP shapes -------
+
+
+def test_rf_subblock_hist_kernel_compiles(one_chip):
+    """131,072 rows x 16 sampled features x 128 bins, 2 stats (bench_rf)."""
+    from spark_rapids_ml_tpu.ops.rf_pallas import subblock_hist
+
+    n, k, S = 131_072, 16, 2
+    c = subblock_hist.lower(
+        one_chip((n, k), I32), one_chip((S, n)), n_bins=128, r_sub=64,
+        variance=False, transposed_sw=True, interpret=False,
+    ).compile()
+    assert _has_kernel(c)
+
+
+def test_rf_packed_traverse_kernel_compiles(one_chip):
+    """Depth 13 (k1=7, k2=6), d=256 (64 packed words), 131,072 rows. The
+    kernel unrolls a static loop over every tree and its compile time grows
+    faster than the tree count (here: 4 s at 1 tree, 7 s at 2, 80 s at 8 —
+    the smallest forest the gate admits — and minutes at bench.py's 56; see
+    CHANGES.md PR 22). Two trees run the lockstep loop twice and keep this
+    case inside tier-1's clock."""
+    from spark_rapids_ml_tpu.ops.rf_pallas import packed_traverse
+
+    n, t_pad, k1, k2, words = 131_072, 2, 7, 6, 64
+    c = packed_traverse.lower(
+        one_chip((n, words), I32), one_chip((n, t_pad), I32),
+        one_chip((t_pad << k1, 64), I32), one_chip((t_pad << k1, 64), I32),
+        k1=k1, k2=k2, d_pad=4 * words, interpret=False,
+    ).compile()
+    assert _has_kernel(c)
+
+
+@pytest.mark.xfail(
+    strict=True,
+    raises=AssertionError,
+    reason="jax 0.9.0 pallas/mosaic/lowering.py gather rule: 'assert "
+    "indices_aval.shape == in_aval.shape + (1,)' — take_along_axis lowers only "
+    "where indices and table have the same shape; the kernel gathers (B*K, C) "
+    "rows from an (n_tab, C) table. umap_sgd_pallas_ok rules the compiled "
+    "kernel out until the gather is rewritten (CHANGES.md PR 22).",
+)
+@pytest.mark.parametrize("rng", ["onchip", "xla"])
+def test_umap_sgd_kernel_compiles(one_chip, rng):
+    """65,536 points (the table VMEM-resident), 2 components, K=24 slots,
+    5 negatives: the fit's epoch loop with either randomness source."""
+    from spark_rapids_ml_tpu.ops.umap_pallas import umap_sgd_pallas
+
+    n, C, K, R = 65_536, 2, 24, 98_304
+    c = umap_sgd_pallas.lower(
+        one_chip((n, C)), one_chip((n, C)), one_chip((R,), I32),
+        one_chip((R, K), I32), one_chip((R, K)), one_chip((2,), jnp.uint32),
+        n_epochs=200, a=1.577, b=0.895, self_table=True, rng=rng,
+        interpret=False,
+    ).compile()
+    assert _has_kernel(c)

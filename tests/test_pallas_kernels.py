@@ -417,3 +417,35 @@ class TestKnnPallas:
         d2 = ((np.asarray(Xq)[:, None, :] - np.asarray(Xi)[None, :, :]) ** 2).sum(-1)
         oracle = np.sort(d2, axis=1)[:, :k]
         np.testing.assert_allclose(np.asarray(d_s), oracle, rtol=1e-4, atol=1e-4)
+
+
+def test_probe_pallas_lowering_raises_with_kernel_name_and_memoises_success():
+    """A kernel the static gate admitted and the compiler refuses is loud:
+    the probe raises with the kernel's name and the compiler's message, and
+    a refusal is never cached. A success is memoised (compiled once)."""
+    from spark_rapids_ml_tpu.ops.linalg import (
+        PallasLoweringError,
+        probe_pallas_lowering,
+    )
+
+    cache: dict = {}
+    calls = []
+
+    def refused():
+        calls.append("refused")
+        raise ValueError("Mosaic failed to compile TPU kernel: bad slice")
+
+    for _ in range(2):  # not negative-cached: the second ask compiles again
+        with pytest.raises(PallasLoweringError) as ei:
+            probe_pallas_lowering(cache, (256, 1024), refused, "fused Lloyd")
+        assert "fused Lloyd" in str(ei.value)
+        assert "Mosaic failed to compile TPU kernel: bad slice" in str(ei.value)
+        assert isinstance(ei.value.__cause__, ValueError)
+    assert calls == ["refused", "refused"] and cache == {}
+
+    def accepted():
+        calls.append("accepted")
+
+    assert probe_pallas_lowering(cache, (256, 1024), accepted, "fused Lloyd")
+    assert probe_pallas_lowering(cache, (256, 1024), accepted, "fused Lloyd")
+    assert calls.count("accepted") == 1 and cache == {(256, 1024): True}

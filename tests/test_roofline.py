@@ -375,21 +375,21 @@ def test_bench_regress_rules():
     br = _load_by_path("bench_regress")
     base = {
         "pca": _entry(1.0, 2.0, 0.2),
-        "tunnel": _entry(10.0, 1.0, 0.1, tunnel_bound=True),
+        "hostonly": _entry(10.0, 1.0, 0.1, host_only=True),
         "nomfu": _entry(1.0, 1.0, 0.0),
         "dropped": _entry(1.0, 1.0, 0.1),
     }
     # within noise everywhere: pass
     cur_ok = {
         "pca": _entry(1.1, 1.9, 0.19),
-        "tunnel": _entry(20.0, 1.0, 0.1, tunnel_bound=True),
+        "hostonly": _entry(20.0, 1.0, 0.1, host_only=True),
         "nomfu": _entry(1.05, 1.05, 0.0),
         "new": _entry(9.0, 0.5, 0.0),
     }
     rows, failed = br.compare(base, cur_ok, 0.15)
     assert not failed
     status = {(n, f): s for n, f, _b, _c, _d, s in rows}
-    assert status[("tunnel", "fit_seconds")] == "skip:tunnel-bound"
+    assert status[("hostonly", "fit_seconds")] == "skip:host-only"
     assert status[("nomfu", "mfu")] == "skip:zero-baseline"
     assert status[("new", "-")] == "skip:new-entry"
     assert status[("dropped", "-")] == "skip:entry-dropped"
@@ -441,8 +441,8 @@ def test_bench_regress_serving_p99_gate(tmp_path):
 def test_bench_regress_tuned_vs_default_gate():
     """The autotuner ratio gates two ways: trajectory (shrink past
     -threshold vs the prior run) and an absolute floor at 1.0-threshold
-    that bites even on new and tunnel_bound entries — the ratio is
-    measured back-to-back in one run, so link weather cancels out and
+    that bites even on new and host_only entries — the ratio is
+    measured back-to-back in one run, so the machine cancels out and
     'no prior run' is no excuse for losing to the default."""
     br = _load_by_path("bench_regress")
     good = _entry(1.0, 1.1, 0.0, tuned_vs_default=1.2)
@@ -466,21 +466,21 @@ def test_bench_regress_tuned_vs_default_gate():
         {}, {"autotune": _entry(1.0, 1.0, 0.0, tuned_vs_default=0.7)}, 0.15
     )
     assert failed, rows
-    # ...and tunnel_bound does not shelter it (same-run ratio)
+    # ...and host_only does not shelter it (same-run ratio)
     rows, failed = br.compare(
         {"autotune": good},
         {"autotune": _entry(
-            1.0, 1.1, 0.0, tuned_vs_default=0.7, tunnel_bound=True
+            1.0, 1.1, 0.0, tuned_vs_default=0.7, host_only=True
         )},
         0.15,
     )
     assert failed, rows
     assert any("tuned_vs_default>=floor" in r[1] for r in rows)
-    # just above the floor, trajectory skipped by tunnel_bound: pass
+    # just above the floor, trajectory skipped by host_only: pass
     rows, failed = br.compare(
         {"autotune": good},
         {"autotune": _entry(
-            1.0, 1.1, 0.0, tuned_vs_default=0.9, tunnel_bound=True
+            1.0, 1.1, 0.0, tuned_vs_default=0.9, host_only=True
         )},
         0.15,
     )

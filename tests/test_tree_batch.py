@@ -40,15 +40,24 @@ def _cfg(**kw):
     return tk.ForestConfig(**base)
 
 
-def _assert_batched_bit_identical(bins, valid, stats, cfg, n_trees=4, seed=7):
+def _assert_batched_bit_identical(
+    bins, valid, stats, cfg, n_trees=4, seed=7, gain_ulp=0
+):
+    """Every field of the batched build equals the per-tree build bit for
+    bit. ``gain_ulp`` relaxes ONLY the reported ``gain`` values to that many
+    units in the last place; structure, thresholds and leaf payloads stay
+    exact."""
     keys = jax.random.split(jax.random.PRNGKey(seed), n_trees)
     seqs = [tk._build_tree(bins, stats, valid, k, cfg) for k in keys]
     bat = tk._build_trees_batched(bins, stats, valid, keys, cfg)
     for i, s in enumerate(seqs):
         for field in s:
+            a, b = np.asarray(s[field]), np.asarray(bat[field][i])
+            if field == "gain" and gain_ulp:
+                np.testing.assert_array_max_ulp(a, b, maxulp=gain_ulp)
+                continue
             np.testing.assert_array_equal(
-                np.asarray(s[field]), np.asarray(bat[field][i]),
-                err_msg=f"tree {i} field {field}",
+                a, b, err_msg=f"tree {i} field {field}"
             )
 
 
@@ -65,13 +74,23 @@ def test_bit_identity_classification(strategy, k_features):
 def test_bit_identity_regression(strategy, k_features):
     """Variance stats are the hard case: f32 accumulation order must be
     preserved exactly (the fused tall-skinny matmul is NOT used there —
-    see _hist_matmul_b)."""
+    see _hist_matmul_b).
+
+    Numerics contract: the histograms, and with them every split (feature,
+    threshold) and leaf payload, are bit-identical. The reported variance
+    ``gain`` is a difference of three f32 terms (parent and child sums of
+    squares over counts, each up to ~2x the gain) whose multiply-add
+    contraction XLA's CPU backend (jax 0.9.0) chooses per compiled program,
+    so the batched and per-tree programs may round each term differently:
+    up to 3 x half an ulp at twice the gain's magnitude = 3 ulp of the gain.
+    Seen: only the root's gain differs, by 1-3 ulp (6e-8..1.8e-7 at ~0.68).
+    Gain is held to 4 ulp, everything else to equality."""
     bins, valid, _, reg_stats = _data()
     cfg = _cfg(
         hist_strategy=strategy, k_features=k_features,
         n_stats=3, impurity="variance",
     )
-    _assert_batched_bit_identical(bins, valid, reg_stats, cfg)
+    _assert_batched_bit_identical(bins, valid, reg_stats, cfg, gain_ulp=4)
 
 
 @pytest.mark.parametrize("impurity", ["gini", "variance"])
