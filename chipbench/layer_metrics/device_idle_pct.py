@@ -1,0 +1,9 @@
+"""Device: 100·(1 − union of device-op intervals / traced interval) of the
+traced job."""
+
+
+def read(ctx):
+    trace = ctx["trace"]
+    if not trace or trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
