@@ -327,7 +327,7 @@ class _TpuEstimator(Params, _TpuParams):
         if not groups:
             return {}, {}, {}
         from .runtime import counters as _res_counters
-        from .utils.profiling import annotate, timed
+        from .utils.profiling import annotate
 
         lane_bytes = float(self._gang_lane_bytes(inputs))
         min_chunk = 1 if allow_singletons else 2
@@ -362,15 +362,12 @@ class _TpuEstimator(Params, _TpuParams):
                     lane_fold=np.asarray([lane_folds[i] for i in chunk], np.int32),
                     n_folds=n_folds,
                 )
-            with annotate(f"{cls_name}.gang_fit"), timed(
-                self.logger, "gang_fit"
-            ), telemetry.span(
+            with annotate(f"{cls_name}.gang_fit"), telemetry.span(
                 f"{cls_name}.gang_fit",
                 lanes=len(chunk),
                 bucket=str(key),
-            ) as g_span:
+            ):
                 outs = gang_fit(inputs, group_ps, **kw)
-                g_span.fence(outs)
             res_delta = _res_counters.delta_since(res_base)
             _res_counters.bump("gang_dispatches")
             _res_counters.bump("gang_lanes_total", len(chunk))
@@ -424,13 +421,13 @@ class _TpuEstimator(Params, _TpuParams):
         gang_fit: Callable[..., List[Dict[str, Any]]],
     ) -> Optional[List[List["_TpuModel"]]]:
         from .data.dataframe import kfold_ids
-        from .utils.profiling import annotate, timed
+        from .utils.profiling import annotate
 
         self._apply_verbosity()
         cls_name = type(self).__name__
-        with annotate(f"{cls_name}.preprocess"), timed(
-            self.logger, "preprocess"
-        ), telemetry.span("preprocess", gang_cv=True):
+        with annotate(f"{cls_name}.preprocess"), telemetry.span(
+            "preprocess", gang_cv=True
+        ):
             inputs = self._pre_process_data(dataset)
         # the SAME seeded draw kfold() makes, so masked lanes see exactly
         # the rows the sequential per-fold path trains on
@@ -804,7 +801,7 @@ class _TpuEstimator(Params, _TpuParams):
     ) -> List["_TpuModel"]:
         # phase annotations land as named ranges on the profiler timeline
         # (the reference's NVTX ranges, ``RapidsRowMatrix.scala:62,70``)
-        from .utils.profiling import annotate, timed
+        from .utils.profiling import annotate
 
         self._apply_verbosity()
         cls_name = type(self).__name__
@@ -814,15 +811,15 @@ class _TpuEstimator(Params, _TpuParams):
             self.logger.info(
                 "Streaming fit engaged (out-of-core chunked ingestion)."
             )
-            with annotate(f"{cls_name}.preprocess"), timed(
-                self.logger, "preprocess"
-            ), telemetry.span("preprocess", streaming=True):
+            with annotate(f"{cls_name}.preprocess"), telemetry.span(
+                "preprocess", streaming=True
+            ):
                 inputs: Any = self._pre_process_stream(dataset)
             fit_func: Any = stream_func
         else:
-            with annotate(f"{cls_name}.preprocess"), timed(
-                self.logger, "preprocess"
-            ), telemetry.span("preprocess", streaming=False):
+            with annotate(f"{cls_name}.preprocess"), telemetry.span(
+                "preprocess", streaming=False
+            ):
                 inputs = self._pre_process_data(dataset)
             fit_func = self._get_tpu_fit_func(dataset)
         models: List[_TpuModel] = []
@@ -883,15 +880,12 @@ class _TpuEstimator(Params, _TpuParams):
                 models.append(model)
                 continue
             res_base = _res_counters.snapshot()
+            # no fence here (nor on the gang dispatch): a fit function returns
+            # its results on the host, so no device array is left to wait on
             with autotune.collect() as tuned, annotate(
                 f"{cls_name}.fit"
-            ), timed(
-                self.logger, "fit"
-            ), telemetry.span(
-                "fit.dispatch", lane=lane, streaming=streaming
-            ) as d_span:
+            ), telemetry.span("fit.dispatch", lane=lane, streaming=streaming):
                 result = fit_func(inputs, ps)
-                d_span.fence(result)
             # fit provenance (model-axis degree, per-shard bytes, ...) rides
             # out of the kernel beside the model arrays; strip it before the
             # estimator unpacks result into model constructor kwargs. Absent
@@ -1046,9 +1040,16 @@ class _TpuModel(Params, _TpuParams):
         Embarrassingly parallel: rows are processed in device-sized batches;
         no collectives (matching the reference, which builds no communicator
         for transform)."""
-        from .data.dataframe import AugmentedScanFrame, ParquetScanFrame
-        from .utils.profiling import annotate, timed
+        # root telemetry span: the wall a caller times around transform(),
+        # feature extraction before the batches and the output frame after
+        with telemetry.span(f"{type(self).__name__}.transform.call"):
+            return self._transform_in_span(dataset)
 
+    def _transform_in_span(self, dataset: DataFrame) -> DataFrame:
+        from .data.dataframe import AugmentedScanFrame, ParquetScanFrame
+        from .utils.profiling import annotate
+
+        name = type(self).__name__
         self._apply_verbosity()
         if isinstance(dataset, ParquetScanFrame) and not dataset.is_materialized():
             # out-of-core transform (the reference transforms per Arrow
@@ -1065,10 +1066,8 @@ class _TpuModel(Params, _TpuParams):
                 )
                 with _x64_ctx(np_dtype):
                     fn = self._get_tpu_transform_func(dataset)
-                    with annotate(f"{type(self).__name__}.transform"), timed(
-                        self.logger, "transform(streamed)"
-                    ), telemetry.span(
-                        f"{type(self).__name__}.transform", streamed=True
+                    with annotate(f"{name}.transform"), telemetry.span(
+                        f"{name}.transform", streamed=True
                     ):
                         out_columns = self._apply_streamed(fn, dataset, input_col)
                     self._log_transform_stages()
@@ -1076,16 +1075,19 @@ class _TpuModel(Params, _TpuParams):
         X = self._extract_features_for_transform(dataset)
         with _x64_ctx(X.dtype):
             fn = self._get_tpu_transform_func(dataset)
-            with annotate(f"{type(self).__name__}.transform"), timed(
-                self.logger, "transform"
-            ), telemetry.span(
-                f"{type(self).__name__}.transform", streamed=False
+            with annotate(f"{name}.transform"), telemetry.span(
+                f"{name}.transform", streamed=False
             ):
                 out_columns = self._apply_batched(fn, X)
             self._log_transform_stages()
-        out = dataset
-        for name, col in out_columns.items():
-            out = out.withColumn(name, col)
+        with telemetry.span(
+            "transform.assemble",
+            columns=len(out_columns),
+            bytes=sum(int(c.nbytes) for c in out_columns.values()),
+        ):
+            out = dataset
+            for col_name, col in out_columns.items():
+                out = out.withColumn(col_name, col)
         return out
 
     def _log_transform_stages(self) -> None:
@@ -1113,11 +1115,14 @@ class _TpuModel(Params, _TpuParams):
         return {k: np.concatenate(v, axis=0) for k, v in chunks.items()}
 
     def _extract_features_for_transform(self, dataset: DataFrame) -> np.ndarray:
-        X, X_sparse = _resolve_feature_matrix(self, dataset)
-        if X is None:
-            X = np.asarray(X_sparse.todense())
-        dtype = np.float32 if self._float32_inputs else X.dtype
-        return np.ascontiguousarray(X, dtype=dtype)
+        with telemetry.span("transform.extract") as e_span:
+            X, X_sparse = _resolve_feature_matrix(self, dataset)
+            if X is None:
+                X = np.asarray(X_sparse.todense())
+            dtype = np.float32 if self._float32_inputs else X.dtype
+            out = np.ascontiguousarray(X, dtype=dtype)
+            e_span.set_attr(bytes=int(out.nbytes), copied=out is not X)
+            return out
 
     def _transform_batch_rows(self) -> int:
         return 1 << 17  # 131072 rows/batch keeps HBM use bounded
@@ -1136,26 +1141,36 @@ class _TpuModel(Params, _TpuParams):
         staging = self._transform_device_staging
         n = X.shape[0]
         bs = self._transform_batch_rows()
-        if n <= bs:
-            Xb = jax.device_put(X) if staging else X
-            return {k: np.asarray(v)[:n] for k, v in fn(Xb).items()}
+
+        def stage(lo: int, batch: int) -> Any:
+            with telemetry.span(
+                "transform.stage", rows=min(bs, n - lo), batch=batch
+            ):
+                Xb = X[lo : lo + bs]
+                return jax.device_put(Xb) if staging else Xb
+
         chunks: Dict[str, List[np.ndarray]] = {}
-        nxt = jax.device_put(X[:bs]) if staging else X[:bs]
-        for lo in range(0, n, bs):
+        nxt = stage(0, 0)
+        for batch, lo in enumerate(range(0, max(n, 1), bs)):
             cur = nxt
             hi = min(lo + bs, n)
             if hi < n:
                 # double-buffer: stage the NEXT batch before materializing
                 # this batch's outputs (np.asarray below blocks on device)
-                nxt = (
-                    jax.device_put(X[hi : hi + bs])
-                    if staging
-                    else X[hi : hi + bs]
-                )
-            part = fn(cur)
-            for k, v in part.items():
-                chunks.setdefault(k, []).append(np.asarray(v)[: hi - lo])
-        return {k: np.concatenate(v, axis=0) for k, v in chunks.items()}
+                nxt = stage(hi, batch + 1)
+            with telemetry.span("transform.apply", rows=hi - lo, batch=batch):
+                part = fn(cur)
+            with telemetry.span("transform.fetch", rows=hi - lo, batch=batch):
+                for k, v in part.items():
+                    chunks.setdefault(k, []).append(np.asarray(v)[: hi - lo])
+        if n <= bs:
+            return {k: v[0] for k, v in chunks.items()}
+        with telemetry.span(
+            "transform.assemble",
+            columns=len(chunks),
+            bytes=sum(int(c.nbytes) for v in chunks.values() for c in v),
+        ):
+            return {k: np.concatenate(v, axis=0) for k, v in chunks.items()}
 
     # ---- multi-model support (CV single-pass) ----------------------------
     @classmethod

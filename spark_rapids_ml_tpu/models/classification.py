@@ -44,7 +44,7 @@ from ..params import (
     _mk,
 )
 from ..ops.logreg_kernels import logreg_fit, logreg_fit_batched, logreg_predict
-from ..runtime import envspec
+from ..runtime import envspec, telemetry
 from ..utils.logging import get_logger
 
 
@@ -265,34 +265,56 @@ class LogisticRegression(
             c = float(params["C"])
             reg = 1.0 / c if c > 0.0 else 0.0
             l1_ratio = float(params["l1_ratio"])
-            out = logreg_fit(
-                inputs.X,
-                inputs.mask,
-                inputs.y,
-                n_classes=n_classes,
-                multinomial=multinomial,
-                fit_intercept=fit_intercept,
-                standardization=bool(params["standardization"]),
-                l1=jnp.asarray(reg * l1_ratio, inputs.dtype),
-                l2=jnp.asarray(reg * (1.0 - l1_ratio), inputs.dtype),
-                use_l1=reg * l1_ratio > 0.0,
-                max_iter=int(params["max_iter"]),
-                tol=jnp.asarray(float(params["tol"]), inputs.dtype),
-                # rows are dp-sharded by _pre_process_data: lets the TPU
-                # path use the fused Pallas loss+grad pass
-                mesh=inputs.mesh,
-                # bf16 objective reads (f32 accumulation) via framework
-                # kwarg or env; default full f32
-                objective_dtype=_resolve_objective_dtype(params),
+            # the gate logreg_fit takes at trace time, asked again on the
+            # host so the span says which loss+gradient the program runs
+            from ..ops.logreg_pallas import logreg_pallas_declined
+
+            declined = logreg_pallas_declined(
+                inputs.X.shape[1], n_classes if multinomial else 1, inputs.X.dtype
             )
-            return {
-                "coef_": np.asarray(out["coef_"]),
-                "intercept_": np.asarray(out["intercept_"]),
-                "n_classes": n_classes,
-                "multinomial": multinomial,
-                "n_iter": int(out["n_iter"]),
-                "objective": float(out["objective"]),
-            }
+            with telemetry.span(
+                "solver.launch",
+                program=logreg_fit.__name__,
+                loss_grad="xla_autodiff" if declined else "pallas_fused",
+                **({"declined": declined} if declined else {}),
+            ):
+                out = logreg_fit(
+                    inputs.X,
+                    inputs.mask,
+                    inputs.y,
+                    n_classes=n_classes,
+                    multinomial=multinomial,
+                    fit_intercept=fit_intercept,
+                    standardization=bool(params["standardization"]),
+                    l1=jnp.asarray(reg * l1_ratio, inputs.dtype),
+                    l2=jnp.asarray(reg * (1.0 - l1_ratio), inputs.dtype),
+                    use_l1=reg * l1_ratio > 0.0,
+                    max_iter=int(params["max_iter"]),
+                    tol=jnp.asarray(float(params["tol"]), inputs.dtype),
+                    # rows are dp-sharded by _pre_process_data: lets the TPU
+                    # path use the fused Pallas loss+grad pass
+                    mesh=inputs.mesh,
+                    # bf16 objective reads (f32 accumulation) via framework
+                    # kwarg or env; default full f32
+                    objective_dtype=_resolve_objective_dtype(params),
+                )
+            # the first fetch blocks until the frame is on the device and
+            # the solver's program has run
+            with telemetry.span("solver.fetch") as f_span:
+                result = {
+                    "coef_": np.asarray(out["coef_"]),
+                    "intercept_": np.asarray(out["intercept_"]),
+                    "n_classes": n_classes,
+                    "multinomial": multinomial,
+                    "n_iter": int(out["n_iter"]),
+                    "objective": float(out["objective"]),
+                }
+                n_evals = int(out["n_evals"])
+                f_span.set_attr(n_iter=result["n_iter"], n_evals=n_evals)
+            # provenance, not a model attribute: core strips it before the
+            # constructor, so nothing persisted changes
+            result["_fit_report"] = {"n_evals": n_evals}
+            return result
 
         return _fit
 
@@ -680,11 +702,11 @@ class LogisticRegressionModel(
                 jnp.asarray(b_np, dtype=Xb.dtype),
                 multinomial=multinomial,
             )
-            return {
-                pred_col: np.asarray(pred),
-                prob_col: np.asarray(prob),
-                raw_col: np.asarray(raw),
-            }
+            # device arrays: every caller materializes the columns itself
+            # (core._apply_batched under its transform.fetch span, the
+            # serving dispatcher after its transfer fault site), so the wait
+            # for the batch lands where it is named
+            return {pred_col: pred, prob_col: prob, raw_col: raw}
 
         return _fn
 

@@ -20,7 +20,7 @@ models for single-pass CV evaluation (reference ``regression.py:750-773``).
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -52,6 +52,7 @@ from ..ops.linreg_kernels import (
     solve_elasticnet_batched,
     solve_normal,
 )
+from ..runtime import telemetry
 
 
 class LinearRegressionClass:
@@ -173,11 +174,12 @@ class LinearRegression(
         return isinstance(evaluator, RegressionEvaluator)
 
     @staticmethod
-    def _solve_from_stats(
+    def _launch_solver(
         stats: Dict[str, jax.Array], params: Dict[str, Any], dtype: Any
-    ) -> Dict[str, Any]:
-        """Solver dispatch on precomputed sufficient statistics — shared by
-        the resident and streaming fits so the two paths cannot diverge."""
+    ) -> Tuple[str, Tuple[Any, Any, Any]]:
+        """Solver dispatch on precomputed sufficient statistics: the jitted
+        solver's name and its (coefficients, intercept, iterations), still
+        on the device."""
         alpha = float(params["alpha"])
         l1_ratio = float(params["l1_ratio"])
         standardization = bool(params["standardization"])
@@ -187,22 +189,37 @@ class LinearRegression(
             beta, intercept = solve_normal(
                 stats, jnp.asarray(l2, dtype), standardization=standardization
             )
-            n_iter = 1
-        else:
-            beta, intercept, it = solve_elasticnet(
-                stats,
-                jnp.asarray(l1, dtype),
-                jnp.asarray(l2, dtype),
-                standardization=standardization,
-                max_iter=int(params["max_iter"]),
-                tol=float(params["tol"]),
-            )
-            n_iter = int(it)
-        return {
-            "coefficients": np.asarray(beta),
-            "intercept": float(intercept),
-            "n_iter": n_iter,
-        }
+            return solve_normal.__name__, (beta, intercept, 1)
+        return solve_elasticnet.__name__, solve_elasticnet(
+            stats,
+            jnp.asarray(l1, dtype),
+            jnp.asarray(l2, dtype),
+            standardization=standardization,
+            max_iter=int(params["max_iter"]),
+            tol=float(params["tol"]),
+        )
+
+    @staticmethod
+    def _fetch_solution(beta: Any, intercept: Any, it: Any) -> Dict[str, Any]:
+        """The solver's results on the host; the first conversion blocks
+        until the programs before it have run."""
+        with telemetry.span("solver.fetch") as f_span:
+            result = {
+                "coefficients": np.asarray(beta),
+                "intercept": float(intercept),
+                "n_iter": int(it),
+            }
+            f_span.set_attr(n_iter=result["n_iter"])
+        return result
+
+    @staticmethod
+    def _solve_from_stats(
+        stats: Dict[str, jax.Array], params: Dict[str, Any], dtype: Any
+    ) -> Dict[str, Any]:
+        """Launch, then fetch — shared by the resident and streaming fits
+        so the two paths cannot diverge."""
+        _, out = LinearRegression._launch_solver(stats, params, dtype)
+        return LinearRegression._fetch_solution(*out)
 
     def _chunk_rows(self, n_rows: int, n_dp: int) -> int:
         # route resident fits through the chunked suffstats scan: bounds
@@ -295,28 +312,36 @@ class LinearRegression(
 
         def _fit(inputs: FitInputs, params: Dict[str, Any]) -> Dict[str, Any]:
             fit_intercept = bool(params["fit_intercept"])
-            if fit_intercept not in stats_cache:
-                # the single data pass — shared by every param map
-                csize = inputs.csize
-                mp = mp_gram_blocks(inputs.mesh, inputs.X.shape[1])
-                if self.rows_chunkable(inputs.X.shape[0], inputs.mesh, csize):
-                    stats_cache[fit_intercept] = linreg_suffstats_chunked(
-                        inputs.X, inputs.mask, inputs.y, inputs.weight,
-                        mesh=inputs.mesh, csize=csize,
-                        fit_intercept=fit_intercept,
-                        weighted=inputs.weight is not None,
-                        mp_blocks=mp > 1,
-                    )
-                    blocked_mp[fit_intercept] = mp
-                else:
-                    stats_cache[fit_intercept] = linreg_suffstats(
-                        inputs.X, inputs.mask, inputs.y, inputs.weight,
-                        fit_intercept=fit_intercept,
-                    )
-                    blocked_mp[fit_intercept] = 1
-            result = self._solve_from_stats(
-                stats_cache[fit_intercept], params, inputs.dtype
-            )
+            with telemetry.span("solver.launch") as l_span:
+                programs = []
+                if fit_intercept not in stats_cache:
+                    # the single data pass — shared by every param map
+                    csize = inputs.csize
+                    mp = mp_gram_blocks(inputs.mesh, inputs.X.shape[1])
+                    if self.rows_chunkable(inputs.X.shape[0], inputs.mesh, csize):
+                        stats_cache[fit_intercept] = linreg_suffstats_chunked(
+                            inputs.X, inputs.mask, inputs.y, inputs.weight,
+                            mesh=inputs.mesh, csize=csize,
+                            fit_intercept=fit_intercept,
+                            weighted=inputs.weight is not None,
+                            mp_blocks=mp > 1,
+                        )
+                        blocked_mp[fit_intercept] = mp
+                        programs.append(linreg_suffstats_chunked.__name__)
+                    else:
+                        stats_cache[fit_intercept] = linreg_suffstats(
+                            inputs.X, inputs.mask, inputs.y, inputs.weight,
+                            fit_intercept=fit_intercept,
+                        )
+                        blocked_mp[fit_intercept] = 1
+                        programs.append(linreg_suffstats.__name__)
+                solver, out = self._launch_solver(
+                    stats_cache[fit_intercept], params, inputs.dtype
+                )
+                # the jitted functions this launch dispatched, in order: the
+                # data pass that waits for the frame, then the solve
+                l_span.set_attr(program=",".join(programs + [solver]))
+            result = self._fetch_solution(*out)
             mp = blocked_mp[fit_intercept]
             if mp > 1:
                 G = stats_cache[fit_intercept]["G"]

@@ -29,6 +29,10 @@ class LbfgsResult(NamedTuple):
     f: jax.Array          # final objective (incl. L1 term)
     n_iter: jax.Array     # iterations taken
     converged: jax.Array  # bool
+    # loss+gradient evaluations: one at w0, one for the first trial of each
+    # iteration, one per backtracking trial — what the device's work (data
+    # passes) is proportional to
+    n_evals: jax.Array
 
 
 def _pseudo_gradient(w: jax.Array, g: jax.Array, l1w: jax.Array) -> jax.Array:
@@ -44,6 +48,7 @@ def _pseudo_gradient(w: jax.Array, g: jax.Array, l1w: jax.Array) -> jax.Array:
     return jnp.where(w != 0.0, nonzero, at_zero)
 
 
+@jax.named_scope("lbfgs.two_loop")
 def _two_loop(
     g: jax.Array, S: jax.Array, Y: jax.Array, k: jax.Array
 ) -> jax.Array:
@@ -113,6 +118,7 @@ def minimize_lbfgs(
     use_l1 = l1_weights is not None
     l1w = l1_weights if use_l1 else jnp.zeros((p,), dtype)
 
+    @jax.named_scope("lbfgs.loss_grad")
     def full_obj_parts(w: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """(L1-inclusive objective, smooth gradient) in one fwd+bwd pass."""
         f, g = vg(w)
@@ -120,19 +126,22 @@ def minimize_lbfgs(
 
     f0, g0 = full_obj_parts(w0)
 
-    # state: (w, f, g, S, Y, k, it, converged)
+    # state: (w, f, g, S, Y, k, it, converged, n_evals)
     S0 = jnp.zeros((history, p), dtype)
     Y0 = jnp.zeros((history, p), dtype)
-    state0 = (w0, f0, g0, S0, Y0, jnp.asarray(0), jnp.asarray(0), jnp.asarray(False))
+    state0 = (
+        w0, f0, g0, S0, Y0, jnp.asarray(0), jnp.asarray(0), jnp.asarray(False),
+        jnp.asarray(1, jnp.int32),
+    )
 
     c1 = jnp.asarray(1e-4, dtype)
 
     def cond(state):
-        _, _, _, _, _, _, it, converged = state
+        _, _, _, _, _, _, it, converged, _ = state
         return jnp.logical_and(it < max_iter, jnp.logical_not(converged))
 
     def body(state):
-        w, f, g, S, Y, k, it, _ = state
+        w, f, g, S, Y, k, it, _, n_evals = state
         pg = _pseudo_gradient(w, g, l1w) if use_l1 else g
         d = -_two_loop(pg, S, Y, k)
         if use_l1:
@@ -169,9 +178,10 @@ def minimize_lbfgs(
             return t, f_t, g_t, n_try + 1
 
         f_t0, g_t0 = full_obj_parts(trial_point(t0))
-        t, f_new, g_new, _ = lax.while_loop(
-            ls_cond, ls_body, (t0, f_t0, g_t0, jnp.asarray(0))
-        )
+        with jax.named_scope("lbfgs.line_search"):
+            t, f_new, g_new, n_try = lax.while_loop(
+                ls_cond, ls_body, (t0, f_t0, g_t0, jnp.asarray(0))
+            )
         w_new = trial_point(t)
 
         s = w_new - w
@@ -188,10 +198,11 @@ def minimize_lbfgs(
         # stop on stall (no descent direction / line-search failure) or tol
         converged = jnp.logical_or(rel_impr <= tol, dir_deriv >= 0.0)
 
-        return (w_new, f_new, g_new, S, Y, k, it + 1, converged)
+        n_evals = n_evals + 1 + n_try.astype(jnp.int32)
+        return (w_new, f_new, g_new, S, Y, k, it + 1, converged, n_evals)
 
-    w, f, g, S, Y, k, it, converged = lax.while_loop(cond, body, state0)
-    return LbfgsResult(w=w, f=f, n_iter=it, converged=converged)
+    w, f, g, S, Y, k, it, converged, n_evals = lax.while_loop(cond, body, state0)
+    return LbfgsResult(w=w, f=f, n_iter=it, converged=converged, n_evals=n_evals)
 
 
 class LbfgsBatchedResult(NamedTuple):
@@ -199,6 +210,9 @@ class LbfgsBatchedResult(NamedTuple):
     f: jax.Array          # (B,) final objectives (incl. L1 term)
     n_iter: jax.Array     # (B,) iterations each lane took
     converged: jax.Array  # (B,) bool
+    # (B,) evaluations a solo solve of each lane would have made (the gang
+    # shares its data passes; a lane counts its own trials)
+    n_evals: jax.Array
 
 
 # vmapping the SAME two-loop the solo solver runs (rather than rewriting
@@ -245,6 +259,7 @@ def minimize_lbfgs_batched(
     use_l1 = l1_weights is not None
     l1w = l1_weights if use_l1 else jnp.zeros((B, p), dtype)
 
+    @jax.named_scope("lbfgs.loss_grad")
     def full_obj_parts(W: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """Per-lane (L1-inclusive objective, smooth gradient), ONE shared
         fwd+bwd data pass. The ones-cotangent vjp is exact per-lane: lane
@@ -259,16 +274,16 @@ def minimize_lbfgs_batched(
     S0 = jnp.zeros((B, history, p), dtype)
     Y0 = jnp.zeros((B, history, p), dtype)
     zi = jnp.zeros((B,), jnp.int32)
-    state0 = (w0, f0, g0, S0, Y0, zi, zi, jnp.zeros((B,), bool))
+    state0 = (w0, f0, g0, S0, Y0, zi, zi, jnp.zeros((B,), bool), zi + 1)
 
     c1 = jnp.asarray(1e-4, dtype)
 
     def cond(state):
-        _, _, _, _, _, _, it, converged = state
+        _, _, _, _, _, _, it, converged, _ = state
         return jnp.any(jnp.logical_and(jnp.logical_not(converged), it < max_iter))
 
     def body(state):
-        w, f, g, S, Y, k, it, converged = state
+        w, f, g, S, Y, k, it, converged, n_evals = state
         # lanes still running this iteration; everything a frozen lane
         # "computes" below is discarded by the where-guards at the bottom
         active = jnp.logical_and(jnp.logical_not(converged), it < max_iter)
@@ -311,9 +326,11 @@ def minimize_lbfgs_batched(
 
         f_t0, g_t0 = full_obj_parts(trial_point(t0))
         ok0 = f_t0 <= f + c1 * t0 * dir_deriv
-        t, f_new, g_new, _, _ = lax.while_loop(
-            ls_cond, ls_body, (t0, f_t0, g_t0, jnp.zeros((B,), jnp.int32), ok0)
-        )
+        with jax.named_scope("lbfgs.line_search"):
+            t, f_new, g_new, n_try, _ = lax.while_loop(
+                ls_cond, ls_body,
+                (t0, f_t0, g_t0, jnp.zeros((B,), jnp.int32), ok0),
+            )
         w_new = trial_point(t)
 
         s = w_new - w
@@ -338,10 +355,13 @@ def minimize_lbfgs_batched(
         g = jnp.where(active[:, None], g_new, g)
         converged = jnp.where(active, conv_now, converged)
         it = it + active.astype(jnp.int32)
-        return (w, f, g, S, Y, k, it, converged)
+        n_evals = n_evals + jnp.where(active, 1 + n_try, 0)
+        return (w, f, g, S, Y, k, it, converged, n_evals)
 
-    w, f, g, S, Y, k, it, converged = lax.while_loop(cond, body, state0)
-    return LbfgsBatchedResult(w=w, f=f, n_iter=it, converged=converged)
+    w, f, g, S, Y, k, it, converged, n_evals = lax.while_loop(cond, body, state0)
+    return LbfgsBatchedResult(
+        w=w, f=f, n_iter=it, converged=converged, n_evals=n_evals
+    )
 
 
 def minimize_lbfgs_host(
@@ -385,7 +405,13 @@ def minimize_lbfgs_host(
     use_l1 = l1_weights is not None
     l1w = np.asarray(l1_weights, np.float64) if use_l1 else np.zeros((p,))
 
+    # evaluations made by THIS call: a Python count beside the checkpointed
+    # carry (a resumed fit counts from its resume, the format is unchanged)
+    n_evals = 0
+
     def full_obj(wv):
+        nonlocal n_evals
+        n_evals += 1
         f, g = value_grad(wv)
         return float(f) + float(np.abs(l1w * wv).sum()), np.asarray(g, np.float64)
 
@@ -491,5 +517,6 @@ def minimize_lbfgs_host(
     import jax.numpy as _jnp
 
     return LbfgsResult(
-        w=w, f=_jnp.asarray(f), n_iter=_jnp.asarray(it), converged=_jnp.asarray(converged)
+        w=w, f=_jnp.asarray(f), n_iter=_jnp.asarray(it),
+        converged=_jnp.asarray(converged), n_evals=_jnp.asarray(n_evals),
     )

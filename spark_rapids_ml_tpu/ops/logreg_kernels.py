@@ -68,6 +68,7 @@ def logreg_fit(
     objective_dtype: str = "float32",
 ) -> Dict[str, jax.Array]:
     """Fit logistic regression; returns coef_ (K,d), intercept_ (K,), n_iter,
+    n_evals (loss+gradient evaluations, ``ops/lbfgs.LbfgsResult``),
     objective. K=1 for the binomial (sigmoid) formulation, else n_classes.
 
     With ``mesh`` (rows dp-sharded over it) and qualifying shapes on TPU,
@@ -95,16 +96,17 @@ def logreg_fit(
     yi = y.astype(jnp.int32)
     yf = y.astype(dtype)
 
-    mean = (X.astype(dtype) * mask[:, None]).sum(axis=0) / n
-    if standardization:
-        sq = ((X.astype(dtype) - mean[None, :]) ** 2 * mask[:, None]).sum(
-            axis=0
-        )
-        var = sq / jnp.maximum(n - 1.0, 1.0)
-        std = jnp.sqrt(jnp.maximum(var, 0.0))
-        inv_std = jnp.where(std > 0, 1.0 / std, 1.0)
-    else:
-        inv_std = jnp.ones((d,), dtype)
+    with jax.named_scope("logreg.moments"):
+        mean = (X.astype(dtype) * mask[:, None]).sum(axis=0) / n
+        if standardization:
+            sq = ((X.astype(dtype) - mean[None, :]) ** 2 * mask[:, None]).sum(
+                axis=0
+            )
+            var = sq / jnp.maximum(n - 1.0, 1.0)
+            std = jnp.sqrt(jnp.maximum(var, 0.0))
+            inv_std = jnp.where(std > 0, 1.0 / std, 1.0)
+        else:
+            inv_std = jnp.ones((d,), dtype)
     # the reference skips centering when fit_intercept=False (adds the mean
     # back before scaling, ``classification.py:1036-1037``)
     use_center = standardization and fit_intercept
@@ -210,6 +212,7 @@ def logreg_fit(
         "coef_": coef,
         "intercept_": intercept,
         "n_iter": res.n_iter,
+        "n_evals": res.n_evals,
         "objective": res.f,
     }
 

@@ -59,18 +59,28 @@ def _row_tile(d: int, Kp: int) -> int:
     return _pallas_gram_tile(max(d, 6 * Kp))
 
 
-def logreg_pallas_ok(d: int, n_classes: int, dtype) -> bool:
-    """Trace-time gate: TPU, f32/bf16 X, lane-aligned d, and few enough
+def logreg_pallas_declined(d: int, n_classes: int, dtype) -> str:
+    """The terms of the fused kernel's gate that fail, comma-joined (empty:
+    the kernel is admitted). TPU, f32/bf16 X, lane-aligned d, and few enough
     classes that the sublane-padded class block plus the loss lane pack
     into one 128-lane row (ceil(K/8)*8 + 1 <= 128, i.e. K <= 120). bf16 X
-    feeds both dots directly (f32 accumulation) — no VMEM upcast."""
-    return (
-        (jax.default_backend() == "tpu" or FORCE_INTERPRET)
-        and d % _LANES == 0
-        and d <= 2048
-        and -(-n_classes // 8) * 8 + 1 <= _LANES
-        and dtype in (jnp.float32, jnp.bfloat16)
+    feeds both dots directly (f32 accumulation) — no VMEM upcast. A pure
+    function of its arguments and the backend: the estimator evaluates it
+    again on the host to say on its ``solver.launch`` span why a fit ran
+    XLA's two passes."""
+    terms = (
+        ("backend", jax.default_backend() == "tpu" or FORCE_INTERPRET),
+        ("d%128", d % _LANES == 0),
+        ("d<=2048", d <= 2048),
+        ("n_classes<=120", -(-n_classes // 8) * 8 + 1 <= _LANES),
+        ("dtype", dtype in (jnp.float32, jnp.bfloat16)),
     )
+    return ",".join(name for name, ok in terms if not ok)
+
+
+def logreg_pallas_ok(d: int, n_classes: int, dtype) -> bool:
+    """Trace-time gate of the fused kernel (:func:`logreg_pallas_declined`)."""
+    return not logreg_pallas_declined(d, n_classes, dtype)
 
 
 def _loss_grad_pallas(Xl, yl, ml, A, b_row, *, multinomial: bool,

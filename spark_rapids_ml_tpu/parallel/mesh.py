@@ -224,6 +224,20 @@ def pad_rows(
     return x, mask
 
 
+def _enqueue_span(mesh: Mesh, *arrays: np.ndarray) -> Any:
+    """Span around handing host arrays to the runtime: ``device_put`` only
+    enqueues, so the span closes long before the bytes are on the device —
+    its opening is where a fit's wait for its inputs starts."""
+    from ..runtime import telemetry
+
+    return telemetry.span(
+        "h2d.enqueue",
+        bytes=sum(int(a.nbytes) for a in arrays),
+        arrays=len(arrays),
+        devices=int(mesh.devices.size),
+    )
+
+
 def shard_rows(
     x: np.ndarray, mesh: Mesh, row_multiple: int = 1
 ) -> Tuple[jax.Array, jax.Array]:
@@ -248,8 +262,9 @@ def shard_rows(
     n_dp = mesh.shape[DP_AXIS]
     xp, mask = pad_rows(x, n_dp * row_multiple)
     sh = row_sharding(mesh)
-    xd = jax.device_put(xp, sh)
-    md = jax.device_put(mask, sh)
+    with _enqueue_span(mesh, xp, mask):
+        xd = jax.device_put(xp, sh)
+        md = jax.device_put(mask, sh)
     return xd, md
 
 
@@ -305,8 +320,9 @@ def _shard_rows_multiproc(
     n_dp = mesh.shape[DP_AXIS]
     global_rows = per_dev * n_dp
     sh = row_sharding(mesh)
-    xd = jax.make_array_from_process_local_data(sh, xp, (global_rows,) + x.shape[1:])
-    md = jax.make_array_from_process_local_data(sh, mask, (global_rows,))
+    with _enqueue_span(mesh, xp, mask):
+        xd = jax.make_array_from_process_local_data(sh, xp, (global_rows,) + x.shape[1:])
+        md = jax.make_array_from_process_local_data(sh, mask, (global_rows,))
     return xd, md
 
 
@@ -317,12 +333,14 @@ def shard_aligned(v: np.ndarray, mesh: Mesh, total_rows: int) -> jax.Array:
     v = np.asarray(v)
     if jax.process_count() <= 1:
         vp = np.pad(v, (0, total_rows - v.shape[0]))
-        return jax.device_put(vp, row_sharding(mesh))
+        with _enqueue_span(mesh, vp):
+            return jax.device_put(vp, row_sharding(mesh))
     local_rows = total_rows // jax.process_count()
     vp = np.pad(v, (0, local_rows - v.shape[0]))
-    return jax.make_array_from_process_local_data(
-        row_sharding(mesh), vp, (total_rows,)
-    )
+    with _enqueue_span(mesh, vp):
+        return jax.make_array_from_process_local_data(
+            row_sharding(mesh), vp, (total_rows,)
+        )
 
 
 @functools.lru_cache(maxsize=None)

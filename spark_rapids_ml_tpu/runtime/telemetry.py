@@ -385,6 +385,14 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 
+#: Prefix of a live span's ``jax.profiler.TraceAnnotation``. It keeps the
+#: spans apart from the hand-written phase annotations
+#: (``utils/profiling.annotate``), which readers of a trace match by name.
+ANNOTATION_PREFIX = "tpuml:"
+# jax.profiler.TraceAnnotation, resolved by the first live span
+# (_ensure_hooks): importing this module must not import jax
+_ANNOTATION: Any = None
+
 
 class _Span:
     """One live span: wall interval + optional device fence + attrs."""
@@ -400,6 +408,7 @@ class _Span:
         "_fences",
         "tid",
         "thread_name",
+        "_annotation",
     )
 
     def __init__(self, name: str, attrs: Dict[str, Any]) -> None:
@@ -424,6 +433,15 @@ class _Span:
         t = threading.current_thread()
         self.tid = t.ident or 0
         self.thread_name = t.name
+        # the span's twin on the profiler's clock: inside a jax.profiler
+        # capture it lands on the host plane as ``tpuml:<name>`` with the
+        # ids as event stats, so a device gap can be laid against the span
+        # that covered it; outside a capture it is one atomic load
+        ids = {"span_id": self.span_id}
+        if self.parent_id is not None:
+            ids["parent_id"] = self.parent_id
+        self._annotation = _ANNOTATION(ANNOTATION_PREFIX + self.name, **ids)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         with _RLOCK:
             _ACTIVE[self.span_id] = {
@@ -456,6 +474,7 @@ class _Span:
             except Exception:  # fencing must never fail the fit
                 pass
         dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(None, None, None)
         _CURRENT.reset(self._token)
         _record(self, dur)
         return None
@@ -1104,8 +1123,12 @@ def _ensure_hooks() -> None:
     cost capture) and the crash-path atexit flush on the first enabled
     span; cheap after the first call."""
     global _WD_CHECKED, _ROOFLINE, _ROOFLINE_CONSUME, _ATEXIT_REGISTERED
+    global _ANNOTATION
     if _WD_CHECKED:
         return
+    from jax.profiler import TraceAnnotation
+
+    _ANNOTATION = TraceAnnotation
     _WD_CHECKED = True
     if _retrace_limit() > 0:
         install_retrace_watchdog()

@@ -7,12 +7,13 @@ and the Python benchmarks do phase wall-clock timing
 
 * :func:`annotate` — a ``jax.profiler.TraceAnnotation`` scope; shows up as
   a named range on the TensorBoard trace timeline (and is a no-op when no
-  trace is being captured).
+  trace is being captured). ``core.py`` writes three by hand, whose names
+  readers of a trace match: ``<Estimator>.preprocess``, ``<Estimator>.fit``
+  around the solver dispatch, ``<Model>.transform``. Every live
+  ``runtime.telemetry`` span writes its own as ``tpuml:<span name>``.
 * :func:`trace` — capture a TensorBoard profile of a code region into a
   directory (``tensorboard --logdir <dir>`` → Profile tab). Used by
   ``bench.py`` when ``BENCH_PROFILE_DIR`` is set.
-* :func:`timed` — phase wall-clock logging at debug level, the benchmark
-  harness's ``with_benchmark`` analog for library internals.
 * :class:`StageTimer` — accumulating per-stage breakdown; each stage is
   also a ``runtime.telemetry`` span, so the report dicts built from
   ``totals`` and the exported trace see the same measurement.
@@ -22,7 +23,6 @@ from __future__ import annotations
 
 import contextlib
 import threading
-import time
 from typing import Iterator, Optional
 
 import jax
@@ -49,25 +49,13 @@ def trace(log_dir: Optional[str]) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def timed(logger, phase: str) -> Iterator[None]:
-    """Debug-level phase timing (device work is NOT synchronized — pair
-    with ``block_until_ready`` at the call site when exact numbers
-    matter)."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        logger.debug("%s took %.4fs", phase, time.perf_counter() - t0)
-
-
 class StageTimer:
     """Accumulating per-stage wall-clock breakdown for repeated pipelines
     (the packed-forest transform engine wraps its quantize/traverse
     dispatch and host materialization per micro-batch; one summary line
     per transform call).
 
-    Same caveat as :func:`timed`: dispatch stages measure ASYNC enqueue
+    Dispatch stages measure ASYNC enqueue
     time — device wait lands in whichever stage first materializes
     results (``np.asarray``). The split still attributes host-side costs
     (staging, packing, output copies) faithfully.

@@ -1,0 +1,414 @@
+"""Spans on the profiler's clock, the span tree of a resident fit and a
+batched transform, and the solver's evaluation count.
+
+One file on purpose: the ``jax.profiler`` capture below belongs to one
+process, so one xdist worker (``--dist loadfile``) holds it.
+
+(a) under a sink and a capture every span has its ``tpuml:`` twin in the
+``.xplane.pb``; (b) the span tree of one LogisticRegression fit and one
+transform of three batches; (c) ``n_evals`` from the jitted, the batched
+and the host-driven L-BFGS, with the solution held bitwise to the solver
+as it stood before the counter; (d) with no sink every new site gets the
+shared no-op and outputs are bitwise those of the traced run.
+"""
+
+import glob
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from spark_rapids_ml_tpu import core
+from spark_rapids_ml_tpu.classification import (
+    LogisticRegression,
+    LogisticRegressionModel,
+)
+from spark_rapids_ml_tpu.data import DataFrame
+from spark_rapids_ml_tpu.ops import lbfgs, logreg_pallas
+from spark_rapids_ml_tpu.regression import LinearRegression
+from spark_rapids_ml_tpu.runtime import telemetry
+
+ROWS, COLS, BATCH = 4096, 64, 1500  # three transform batches: 1500, 1500, 1096
+OUTPUTS = ("prediction", "probability", "rawPrediction")
+LEGACY = (
+    "LogisticRegression.preprocess",
+    "LogisticRegression.fit",
+    "LogisticRegressionModel.transform",
+)
+NEW_SITES = {
+    "h2d.enqueue", "solver.launch", "solver.fetch",
+    "LogisticRegressionModel.transform.call", "transform.extract",
+    "transform.stage", "transform.apply", "transform.fetch",
+    "transform.assemble",
+}
+
+
+def _frame(cols=COLS, rows=ROWS, seed=0):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(rows, cols)).astype(np.float32)
+    w = rng.normal(size=cols).astype(np.float32) / np.sqrt(cols)
+    y = (X @ w + rng.normal(size=rows) > 0).astype(np.float32)
+    return X, y, DataFrame({"features": X}).withColumn("label", y)
+
+
+def _job(df):
+    model = LogisticRegression(maxIter=40, regParam=1e-3, num_workers=1).fit(df)
+    out = model.transform(df)
+    return model, {c: np.asarray(out.column(c)) for c in OUTPUTS}
+
+
+def _host_events(xplane):
+    from jax.profiler import ProfileData
+
+    events = []
+    for plane in ProfileData.from_file(xplane).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(telemetry.ANNOTATION_PREFIX) or e.name in LEGACY:
+                    stats = dict(e.stats) if e.name not in LEGACY else {}
+                    events.append((e.name, e.start_ns, e.start_ns + e.duration_ns, stats))
+    return events
+
+
+@pytest.fixture(scope="module")
+def runs(tmp_path_factory):
+    """One job with nothing recording, then the same job under a sink and
+    a profiler capture (both after a warm job, so neither compiles)."""
+    telemetry.reset_telemetry()
+    mp = pytest.MonkeyPatch()
+    mp.delenv("TPUML_TRACE", raising=False)
+    mp.setattr(LogisticRegressionModel, "_transform_batch_rows", lambda self: BATCH)
+    X, y, df = _frame()
+    try:
+        _job(df)
+        # (d): every span() call of an untraced job, by what it returned
+        seen = []
+        real_span = telemetry.span
+
+        def spy(name, **attrs):
+            s = real_span(name, **attrs)
+            seen.append((name, s))
+            return s
+
+        mp.setattr(telemetry, "span", spy)
+        plain_model, plain_out = _job(df)
+        mp.setattr(telemetry, "span", real_span)
+
+        spans = []
+        telemetry.add_span_sink(lambda ev, thread: spans.append(ev))
+        trace_dir = str(tmp_path_factory.mktemp("capture"))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            model, out = _job(df)
+        finally:
+            jax.profiler.stop_trace()
+        xplane = glob.glob(trace_dir + "/plugins/profile/*/*.xplane.pb")
+        assert xplane, "the capture wrote no .xplane.pb"
+        yield {
+            "X": X, "y": y, "spans": spans, "events": _host_events(xplane[0]),
+            "model": model, "out": out, "plain_model": plain_model,
+            "plain_out": plain_out, "untraced_calls": seen,
+        }
+    finally:
+        mp.undo()
+        telemetry.reset_telemetry()
+
+
+def _by_name(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def _one(spans, name):
+    (s,) = _by_name(spans, name)
+    return s
+
+
+# --- (a) the spans on the profiler's clock -----------------------------------
+
+
+def test_every_span_has_its_profiler_event(runs):
+    twins = {}
+    for name, lo, hi, stats in runs["events"]:
+        if name.startswith(telemetry.ANNOTATION_PREFIX):
+            assert stats["span_id"] not in twins
+            twins[stats["span_id"]] = (name, lo, hi, stats)
+    assert len(runs["spans"]) == 21  # 7 of the fit, 14 of the transform
+    for s in runs["spans"]:
+        name, lo, hi, stats = twins[s["args"]["span_id"]]
+        assert name == telemetry.ANNOTATION_PREFIX + s["name"]
+        assert stats.get("parent_id") == s["args"].get("parent_id")
+        dur_us = (hi - lo) * 1e-3
+        assert abs(dur_us - s["dur"]) <= max(1000.0, 0.05 * s["dur"]), (name, dur_us, s["dur"])
+        if "parent_id" in stats:
+            _, plo, phi, _ = twins[stats["parent_id"]]
+            assert plo <= lo and hi <= phi, (name, "not inside its parent")
+    assert len(twins) == len(runs["spans"])
+
+
+def test_legacy_annotations_once_each_and_unprefixed(runs):
+    names = [name for name, *_ in runs["events"]]
+    for legacy in LEGACY:
+        assert names.count(legacy) == 1, legacy
+    # the span named like a legacy annotation is another event: the root
+    assert names.count(telemetry.ANNOTATION_PREFIX + "LogisticRegression.fit") == 1
+
+
+# --- (b) the span tree --------------------------------------------------------
+
+
+def test_span_tree_of_a_fit(runs):
+    spans = runs["spans"]
+    root = _one(spans, "LogisticRegression.fit")
+    pre, dis = _one(spans, "preprocess"), _one(spans, "fit.dispatch")
+    assert "parent_id" not in root["args"]
+    assert pre["args"]["parent_id"] == dis["args"]["parent_id"] == root["args"]["span_id"]
+    puts = _by_name(spans, "h2d.enqueue")
+    assert [p["args"]["parent_id"] for p in puts] == [pre["args"]["span_id"]] * 2
+    x_put, y_put = puts
+    # X (4096 x 64 f32) with its f32 mask, then y: one device, nothing padded
+    assert (x_put["args"]["bytes"], x_put["args"]["arrays"]) == (ROWS * COLS * 4 + ROWS * 4, 2)
+    assert (y_put["args"]["bytes"], y_put["args"]["arrays"]) == (ROWS * 4, 1)
+    assert x_put["args"]["devices"] == y_put["args"]["devices"] == 1
+    launch, fetch = _one(spans, "solver.launch"), _one(spans, "solver.fetch")
+    assert launch["args"]["parent_id"] == fetch["args"]["parent_id"] == dis["args"]["span_id"]
+    assert launch["args"]["program"] == "logreg_fit"
+    order = [s["args"]["span_id"] for s in (root, pre, x_put, y_put, dis, launch, fetch)]
+    assert order == sorted(order)
+    assert launch["ts"] + launch["dur"] <= fetch["ts"] + 1.0
+    model = runs["model"]
+    assert fetch["args"]["n_iter"] == model.n_iter_ >= 2
+    assert fetch["args"]["n_evals"] == model._fit_report["n_evals"] >= model.n_iter_ + 1
+    # provenance only: neither a constructor argument nor a persisted attribute
+    assert "n_evals" not in model._get_model_attributes()
+    assert "_fit_report" not in model._get_model_attributes()
+
+
+def test_span_tree_of_a_transform_in_three_batches(runs):
+    spans = runs["spans"]
+    call = _one(spans, "LogisticRegressionModel.transform.call")
+    inner = _one(spans, "LogisticRegressionModel.transform")
+    extract = _one(spans, "transform.extract")
+    assert "parent_id" not in call["args"]
+    assert inner["args"]["parent_id"] == extract["args"]["parent_id"] == call["args"]["span_id"]
+    assert extract["args"]["bytes"] == ROWS * COLS * 4 and extract["args"]["copied"] is False
+    batched = [s for s in spans if s["name"] in ("transform.stage", "transform.apply", "transform.fetch")]
+    assert all(s["args"]["parent_id"] == inner["args"]["span_id"] for s in batched)
+    batched.sort(key=lambda s: s["args"]["span_id"])
+    # the next batch is staged before this one's outputs are fetched
+    assert [(s["name"].split(".")[1], s["args"]["batch"], s["args"]["rows"]) for s in batched] == [
+        ("stage", 0, 1500), ("stage", 1, 1500), ("apply", 0, 1500), ("fetch", 0, 1500),
+        ("stage", 2, 1096), ("apply", 1, 1500), ("fetch", 1, 1500),
+        ("apply", 2, 1096), ("fetch", 2, 1096),
+    ]
+    concat, frame = _by_name(spans, "transform.assemble")
+    assert concat["args"]["parent_id"] == inner["args"]["span_id"]
+    assert frame["args"]["parent_id"] == call["args"]["span_id"]
+    out_bytes = sum(v.nbytes for v in runs["out"].values())
+    assert concat["args"] == dict(concat["args"], columns=3, bytes=out_bytes)
+    assert frame["args"] == dict(frame["args"], columns=3, bytes=out_bytes)
+    assert extract["args"]["span_id"] < inner["args"]["span_id"] < frame["args"]["span_id"]
+
+
+@pytest.mark.parametrize("cols, forced, loss_grad", [(64, False, "xla_autodiff"), (128, True, "pallas_fused")])
+def test_launch_span_says_which_loss_grad_runs(monkeypatch, cols, forced, loss_grad):
+    telemetry.reset_telemetry()
+    spans = []
+    telemetry.add_span_sink(lambda ev, thread: spans.append(ev))
+    monkeypatch.setattr(logreg_pallas, "FORCE_INTERPRET", forced)
+    _, _, df = _frame(cols=cols, rows=384, seed=3)
+    # FORCE_INTERPRET is read at trace time and is no part of the jit key
+    jax.clear_caches()
+    try:
+        LogisticRegression(maxIter=3, regParam=1e-3, num_workers=1).fit(df)
+    finally:
+        jax.clear_caches()
+        telemetry.reset_telemetry()
+    args = _one(spans, "solver.launch")["args"]
+    assert args["loss_grad"] == loss_grad
+    if forced:
+        assert "declined" not in args
+    else:
+        # on this backend, at this width: not a TPU, and 64 is no lane multiple
+        assert args["declined"] == "backend,d%128"
+
+
+def test_linear_regression_gets_the_same_pair():
+    telemetry.reset_telemetry()
+    spans = []
+    telemetry.add_span_sink(lambda ev, thread: spans.append(ev))
+    X, _, _ = _frame(rows=512)
+    df = DataFrame({"features": X}).withColumn("label", X[:, 0] * 2.0 + 1.0)
+    try:
+        LinearRegression(num_workers=1).fit(df)
+    finally:
+        telemetry.reset_telemetry()
+    dis = _one(spans, "fit.dispatch")
+    launch, fetch = _one(spans, "solver.launch"), _one(spans, "solver.fetch")
+    assert launch["args"]["parent_id"] == fetch["args"]["parent_id"] == dis["args"]["span_id"]
+    assert launch["args"]["program"].split(",")[-1] == "solve_normal"
+    assert launch["args"]["program"].split(",")[0].startswith("linreg_suffstats")
+    assert fetch["args"]["n_iter"] == 1
+
+
+# --- (c) the evaluation count -------------------------------------------------
+
+
+def _bowl(scale):
+    a = jnp.linspace(0.02, 0.05, 6)
+    return (lambda w: 0.5 * scale * jnp.sum((w - a) ** 2)), jnp.zeros((6,), jnp.float32)
+
+
+def _solve(fun, w0, **kw):
+    solve = jax.jit(lambda w: lbfgs.minimize_lbfgs(fun, w, max_iter=50, tol=1e-9, **kw))
+    return solve(w0)
+
+
+def test_n_evals_is_n_iter_plus_one_when_every_first_trial_passes():
+    # unit curvature: the first step is short (t0 = 1/|d| = 1 at |g| < 1
+    # gives the exact minimum), every later one is the exact Newton step
+    res = _solve(*_bowl(1.0))
+    assert int(res.n_iter) >= 1
+    assert int(res.n_evals) == int(res.n_iter) + 1
+
+
+def test_n_evals_counts_backtracking_trials():
+    # curvature 1e4: the first trial overshoots the bowl tenfold and Armijo
+    # halves the step several times
+    res = _solve(*_bowl(1e4))
+    assert int(res.n_evals) > int(res.n_iter) + 1
+
+
+def test_one_batched_lane_counts_what_its_solo_solve_counts():
+    lanes = [1.0, 1e4, 30.0]
+    a = jnp.linspace(0.02, 0.05, 6)
+    scales = jnp.asarray(lanes, jnp.float32)
+
+    def fun_b(W):
+        return 0.5 * scales * jnp.sum((W - a[None, :]) ** 2, axis=1)
+
+    solve_b = jax.jit(
+        lambda W: lbfgs.minimize_lbfgs_batched(fun_b, W, max_iter=50, tol=jnp.full((3,), 1e-9))
+    )
+    out = solve_b(jnp.zeros((3, 6), jnp.float32))
+    for b, scale in enumerate(lanes):
+        solo = _solve(*_bowl(scale))
+        assert int(out.n_evals[b]) == int(solo.n_evals), b
+        assert int(out.n_iter[b]) == int(solo.n_iter), b
+    assert int(out.n_evals[1]) > int(out.n_iter[1]) + 1
+
+
+def test_host_solver_counts_its_value_grad_calls():
+    fun, w0 = _bowl(1e4)
+    vg = jax.jit(jax.value_and_grad(fun))
+    calls = []
+
+    def value_grad(w):
+        calls.append(1)
+        f, g = vg(jnp.asarray(w, jnp.float32))
+        return float(f), np.asarray(g, np.float64)
+
+    res = lbfgs.minimize_lbfgs_host(value_grad, np.zeros(6), max_iter=50, tol=1e-9)
+    assert int(res.n_evals) == len(calls) > int(res.n_iter) + 1
+
+
+def _solver_before_the_counter(fun, w0, *, max_iter, tol, history=10, max_ls=30):
+    """``ops/lbfgs.minimize_lbfgs`` (plain L-BFGS branch) as it stood
+    before ``n_evals`` joined its loop state: the reference that holds the
+    solution bitwise."""
+    dtype = w0.dtype
+    p = w0.shape[0]
+    vg = jax.value_and_grad(fun)
+    f0, g0 = vg(w0)
+    S0 = jnp.zeros((history, p), dtype)
+    Y0 = jnp.zeros((history, p), dtype)
+    state0 = (w0, f0, g0, S0, Y0, jnp.asarray(0), jnp.asarray(0), jnp.asarray(False))
+    c1 = jnp.asarray(1e-4, dtype)
+
+    def cond(state):
+        _, _, _, _, _, _, it, converged = state
+        return jnp.logical_and(it < max_iter, jnp.logical_not(converged))
+
+    def body(state):
+        w, f, g, S, Y, k, it, _ = state
+        d = -lbfgs._two_loop(g, S, Y, k)
+        dir_deriv = jnp.vdot(g, d)
+        d_norm = jnp.sqrt(jnp.vdot(d, d))
+        t0 = jnp.where(k == 0, 1.0 / jnp.maximum(d_norm, 1.0), jnp.asarray(1.0, dtype))
+
+        def ls_cond(carry):
+            t, f_t, _, n_try = carry
+            ok = f_t <= f + c1 * t * dir_deriv
+            return jnp.logical_and(jnp.logical_not(ok), n_try < max_ls)
+
+        def ls_body(carry):
+            t, _, _, n_try = carry
+            t = t * 0.5
+            f_t, g_t = vg(w + t * d)
+            return t, f_t, g_t, n_try + 1
+
+        f_t0, g_t0 = vg(w + t0 * d)
+        t, f_new, g_new, _ = lax.while_loop(ls_cond, ls_body, (t0, f_t0, g_t0, jnp.asarray(0)))
+        w_new = w + t * d
+        s = w_new - w
+        yv = g_new - g
+        store = jnp.vdot(s, yv) > jnp.asarray(1e-10, dtype)
+        idx = k % history
+        S = jnp.where(store, S.at[idx].set(s), S)
+        Y = jnp.where(store, Y.at[idx].set(yv), Y)
+        k = jnp.where(store, k + 1, k)
+        denom = jnp.maximum(jnp.maximum(jnp.abs(f), jnp.abs(f_new)), 1.0)
+        converged = jnp.logical_or((f - f_new) / denom <= tol, dir_deriv >= 0.0)
+        return (w_new, f_new, g_new, S, Y, k, it + 1, converged)
+
+    w, f, _, _, _, _, it, _ = lax.while_loop(cond, body, state0)
+    return w, f, it
+
+
+def test_counter_leaves_the_solution_bitwise(runs):
+    X, y = jnp.asarray(runs["X"]), jnp.asarray(runs["y"])
+
+    def loss(w):
+        z = X @ w[:COLS] + w[COLS]
+        return jnp.mean(jax.nn.softplus(z) - y * z) + 0.5e-3 * jnp.vdot(w[:COLS], w[:COLS])
+
+    w0 = jnp.zeros((COLS + 1,), jnp.float32)
+    kw = dict(max_iter=40, tol=1e-7)
+    solve_new = jax.jit(lambda w: lbfgs.minimize_lbfgs(loss, w, **kw))
+    solve_old = jax.jit(lambda w: _solver_before_the_counter(loss, w, **kw))
+    new = solve_new(w0)
+    w_old, f_old, it_old = solve_old(w0)
+    assert int(new.n_iter) == int(it_old) >= 5
+    np.testing.assert_array_equal(np.asarray(new.w), np.asarray(w_old))
+    np.testing.assert_array_equal(np.asarray(new.f), np.asarray(f_old))
+    assert int(new.n_evals) >= int(new.n_iter) + 1
+
+
+# --- (d) nothing recording ----------------------------------------------------
+
+
+def test_untraced_job_gets_the_shared_null_at_every_site(runs):
+    calls = runs["untraced_calls"]
+    assert NEW_SITES <= {name for name, _ in calls}
+    assert all(s is telemetry._NULL for _, s in calls)
+    # three more span() calls in a fit, fifteen in a transform of three batches
+    names = [name for name, _ in calls]
+    assert names.count("h2d.enqueue") == 2 and names.count("transform.stage") == 3
+
+
+def test_tracing_leaves_outputs_bitwise(runs):
+    for attr in ("coef_", "intercept_"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(runs["model"], attr)), np.asarray(getattr(runs["plain_model"], attr))
+        )
+    assert runs["model"].n_iter_ == runs["plain_model"].n_iter_
+    for c in OUTPUTS:
+        np.testing.assert_array_equal(runs["out"][c], runs["plain_out"][c])
+        assert runs["out"][c].shape[0] == ROWS
+    assert core._TpuModel._transform_batch_rows(runs["model"]) == 1 << 17
