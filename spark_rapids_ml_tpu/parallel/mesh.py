@@ -14,13 +14,16 @@ Axis naming convention used across the framework:
 
 from __future__ import annotations
 
+import collections
 import functools
+import itertools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 DP_AXIS = "dp"
@@ -224,18 +227,85 @@ def pad_rows(
     return x, mask
 
 
-def _enqueue_span(mesh: Mesh, *arrays: np.ndarray) -> Any:
-    """Span around handing host arrays to the runtime: ``device_put`` only
-    enqueues, so the span closes long before the bytes are on the device —
-    its opening is where a fit's wait for its inputs starts."""
+def _enqueue_span(mesh: Mesh, nbytes: int, arrays: int, **attrs: Any) -> Any:
+    """Span around handing host arrays to the runtime. Its opening is where a
+    fit's wait for its inputs starts. A single ``device_put`` only enqueues,
+    so the span closes long before the bytes are on the device; the row-block
+    loop of :func:`shard_rows` waits for all but the last blocks in flight,
+    so there the span lasts nearly as long as the transfer does."""
     from ..runtime import telemetry
 
     return telemetry.span(
         "h2d.enqueue",
-        bytes=sum(int(a.nbytes) for a in arrays),
-        arrays=len(arrays),
+        bytes=nbytes,
+        arrays=arrays,
         devices=int(mesh.devices.size),
+        **attrs,
     )
+
+
+# Byte target of one host→device put of shard_rows. One put of a 6 GB frame
+# pins the whole host buffer before a byte moves (29.9 s on a v5e); the same
+# frame in blocks, two in flight, is on the chip in 0.564 s at this target
+# (786 MB blocks at 3000 f32 columns; median of 5), against 0.561 s at 2 GB,
+# 0.636 s at 512 MB, 0.677 s at 256 MB, 0.765 s at 128 MB (PERF.md §6, PR 28):
+# the smallest of those within 5% of the best.
+_PUT_BLOCK_BYTES = 1 << 30
+# Blocks handed to the runtime whose write into the shard has not run yet.
+# What the runtime charges grows with the bytes it holds pinned at once (all
+# 31 blocks of 197 MB at once: 2.2 s and 11.8 GB of HBM, two: 0.68 s), so the
+# bound is on the process, not on each device: HBM holds a shard + 2 blocks,
+# the host 2 linearized blocks.
+_PUTS_IN_FLIGHT = 2
+
+
+def _put_block_rows(row_bytes: int) -> int:
+    """Rows of one put: the largest power of two whose bytes fit the target,
+    and at least the 8 rows of the device's tile."""
+    return 1 << (max(8, _PUT_BLOCK_BYTES // max(1, row_bytes)).bit_length() - 1)
+
+
+@functools.partial(jax.jit, static_argnames="shape")
+def _fill(value: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
+    """``value`` broadcast to ``shape`` on the device ``value`` is committed to."""
+    return jnp.broadcast_to(value, shape)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_block(buf: jax.Array, block: jax.Array, row0: Any) -> Tuple[jax.Array, jax.Array]:
+    """``buf`` (donated: written in place) with ``block`` at row ``row0``, and
+    a scalar that is ready once the write has run. ``row0`` is traced, so one
+    program serves every block of a shape."""
+    start = (row0,) + (jnp.zeros_like(row0),) * (buf.ndim - 1)
+    return lax.dynamic_update_slice(buf, block, start), row0 + block.shape[0]
+
+
+def _put_row_blocks(
+    x: np.ndarray, shape: Tuple[int, ...], sh: NamedSharding, block_rows: int
+) -> Tuple[jax.Array, int]:
+    """Assemble the row-sharded global array of ``shape`` on the devices from
+    puts of at most ``block_rows`` rows of ``x``; rows past ``len(x)`` are
+    zero. Never holds a second copy of a shard: each device's zero buffer is
+    written in place, block by block. Returns the array and the puts issued."""
+    bufs, todo = {}, []
+    for dev, idx in sh.addressable_devices_indices_map(shape).items():
+        lo, hi, _ = idx[0].indices(shape[0])
+        zero = jax.device_put(np.zeros((), x.dtype), dev)
+        bufs[dev] = _fill(zero, (hi - lo,) + shape[1:])
+        valid = x[lo:hi]  # the slice ends with x: what is past it stays zero
+        todo.append(
+            [(dev, at, valid[at:at + block_rows]) for at in range(0, len(valid), block_rows)]
+        )
+    # round-robin over the devices, so that their DMA queues run together
+    puts = [p for step in itertools.zip_longest(*todo) for p in step if p is not None]
+    pending = collections.deque()
+    for dev, row0, rows in puts:
+        if len(pending) == _PUTS_IN_FLIGHT:
+            pending.popleft().block_until_ready()
+        block = jax.device_put(rows, dev)
+        bufs[dev], written = _write_block(bufs[dev], block, np.int32(row0))
+        pending.append(written)
+    return jax.make_array_from_single_device_arrays(shape, sh, list(bufs.values())), len(puts)
 
 
 def shard_rows(
@@ -249,6 +319,12 @@ def shard_rows(
     multiple (for kernels that scan rows in fixed-size chunks).
     Returns (sharded_x, sharded_mask).
 
+    A shard of at most one block (``_PUT_BLOCK_BYTES``) goes up in one
+    ``device_put`` of the padded array. A larger one goes up in row blocks
+    that are assembled on the device (:func:`_put_row_blocks`): the same
+    array, bit for bit, without the host's padded copy and without one put
+    the size of the frame.
+
     Multi-process: ``x`` is this process's local rows (each worker holds
     its partition, as each Spark barrier task held its Arrow batches).
     Processes agree on a common per-device row count via a host allgather
@@ -260,10 +336,26 @@ def shard_rows(
     if jax.process_count() > 1:
         return _shard_rows_multiproc(x, mesh, row_multiple)
     n_dp = mesh.shape[DP_AXIS]
-    xp, mask = pad_rows(x, n_dp * row_multiple)
     sh = row_sharding(mesh)
-    with _enqueue_span(mesh, xp, mask):
-        xd = jax.device_put(xp, sh)
+    n = x.shape[0]
+    n_padded = n + (-n) % (n_dp * row_multiple)
+    row_bytes = x.dtype.itemsize * int(np.prod(x.shape[1:]))
+    block_rows = _put_block_rows(row_bytes)
+    if n_padded // n_dp <= block_rows:
+        xp, mask = pad_rows(x, n_dp * row_multiple)
+        with _enqueue_span(
+            mesh, xp.nbytes + mask.nbytes, 2, blocks=1, block_bytes=xp.nbytes
+        ):
+            xd = jax.device_put(xp, sh)
+            md = jax.device_put(mask, sh)
+        return xd, md
+    mask = np.zeros((n_padded,), np.float32)
+    mask[:n] = 1.0
+    with _enqueue_span(
+        mesh, n_padded * row_bytes + mask.nbytes, 2, block_bytes=block_rows * row_bytes
+    ) as span:
+        xd, blocks = _put_row_blocks(x, (n_padded,) + x.shape[1:], sh, block_rows)
+        span.set_attr(blocks=blocks)
         md = jax.device_put(mask, sh)
     return xd, md
 
@@ -320,7 +412,7 @@ def _shard_rows_multiproc(
     n_dp = mesh.shape[DP_AXIS]
     global_rows = per_dev * n_dp
     sh = row_sharding(mesh)
-    with _enqueue_span(mesh, xp, mask):
+    with _enqueue_span(mesh, xp.nbytes + mask.nbytes, 2):
         xd = jax.make_array_from_process_local_data(sh, xp, (global_rows,) + x.shape[1:])
         md = jax.make_array_from_process_local_data(sh, mask, (global_rows,))
     return xd, md
@@ -333,11 +425,11 @@ def shard_aligned(v: np.ndarray, mesh: Mesh, total_rows: int) -> jax.Array:
     v = np.asarray(v)
     if jax.process_count() <= 1:
         vp = np.pad(v, (0, total_rows - v.shape[0]))
-        with _enqueue_span(mesh, vp):
+        with _enqueue_span(mesh, vp.nbytes, 1):
             return jax.device_put(vp, row_sharding(mesh))
     local_rows = total_rows // jax.process_count()
     vp = np.pad(v, (0, local_rows - v.shape[0]))
-    with _enqueue_span(mesh, vp):
+    with _enqueue_span(mesh, vp.nbytes, 1):
         return jax.make_array_from_process_local_data(
             row_sharding(mesh), vp, (total_rows,)
         )

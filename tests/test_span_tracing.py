@@ -176,6 +176,9 @@ def test_span_tree_of_a_fit(runs):
     assert (x_put["args"]["bytes"], x_put["args"]["arrays"]) == (ROWS * COLS * 4 + ROWS * 4, 2)
     assert (y_put["args"]["bytes"], y_put["args"]["arrays"]) == (ROWS * 4, 1)
     assert x_put["args"]["devices"] == y_put["args"]["devices"] == 1
+    # a frame of one block: one put of X, no assembly on the device
+    assert (x_put["args"]["blocks"], x_put["args"]["block_bytes"]) == (1, ROWS * COLS * 4)
+    assert "blocks" not in y_put["args"]
     launch, fetch = _one(spans, "solver.launch"), _one(spans, "solver.fetch")
     assert launch["args"]["parent_id"] == fetch["args"]["parent_id"] == dis["args"]["span_id"]
     assert launch["args"]["program"] == "logreg_fit"
