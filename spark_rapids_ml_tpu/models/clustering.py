@@ -38,12 +38,17 @@ from ..params import (
     _mk,
 )
 from ..ops.kmeans_kernels import (
+    _kmeans_lloyd_1d,
+    _kmeans_lloyd_mp,
     count_closest,
     kmeans_lloyd,
     min_sq_dists,
     mp_kmeans_shards,
+    pairwise_sq_dists,
 )
-from ..runtime import envspec
+from ..ops.kmeans_pallas import kmeans_pallas_declined, lloyd_tile
+from ..parallel.mesh import DP_AXIS
+from ..runtime import envspec, telemetry
 
 _CHUNK = 4096
 
@@ -345,33 +350,59 @@ class KMeans(KMeansClass, _TpuEstimator, _KMeansParams):
                 raise ValueError(f"k={k} must be <= number of rows {inputs.n_rows}")
             mm = self._resolve_matmul_dtype(params)
             rng = np.random.default_rng(int(params.get("random_state") or 0))
-            if params.get("init") == "random":
-                centers0 = self._init_random(inputs, k, rng)
-            else:
-                centers0 = self._init_scalable_kmeanspp(
-                    inputs, k, int(params.get("init_steps", 2)),
-                    float(params.get("oversampling_factor", 2.0)), rng,
-                )
-            centers0 = jnp.asarray(centers0, dtype=inputs.dtype)
-            centers, cost, n_iter = kmeans_lloyd(
-                inputs.X,
-                inputs.mask,
-                centers0,
-                mesh=inputs.mesh,
-                csize=inputs.csize,
-                max_iter=int(params["max_iter"]),
-                tol=float(params["tol"]),
-                # bf16 matmul operands / f32 accumulation on the two MXU
-                # contractions (~2x); final cost pass stays f32
-                matmul_dtype=mm,
-            )
-            # strip lane-padding columns (zero by the Lloyd invariant)
-            result = {
-                "cluster_centers": np.asarray(centers)[:, : inputs.n_features],
-                "training_cost": float(cost),
-                "n_iter": int(n_iter),
-            }
+            init = str(params.get("init"))
+            # the seeding gathers its rows from the device, so this span
+            # also holds the wait for the frame to be there
+            with telemetry.span("kmeans.init", mode=init, k=k) as i_span:
+                if init == "random":
+                    centers0 = self._init_random(inputs, k, rng)
+                else:
+                    centers0 = self._init_scalable_kmeanspp(
+                        inputs, k, int(params.get("init_steps", 2)),
+                        float(params.get("oversampling_factor", 2.0)), rng,
+                    )
+                i_span.set_attr(rows_gathered=len(centers0))
+                centers0 = jnp.asarray(centers0, dtype=inputs.dtype)
             mp = mp_kmeans_shards(inputs.mesh, k)
+            # the gate _chunk_stats takes at trace time, asked again on the
+            # host so the span says which Lloyd step the program runs
+            n_local = inputs.X.shape[0] // inputs.mesh.shape[DP_AXIS]
+            d_pad = inputs.X.shape[1]
+            declined = "mp" if mp > 1 else kmeans_pallas_declined(
+                n_local, d_pad, k, inputs.X.dtype, mm
+            )
+            with telemetry.span(
+                "solver.launch",
+                program=(_kmeans_lloyd_mp if mp > 1 else _kmeans_lloyd_1d).__name__,
+                kernel="xla" if declined else "pallas",
+                tile=inputs.csize if declined else lloyd_tile(d_pad, k, mm)[0],
+                **({"declined": declined} if declined else {}),
+            ):
+                centers, cost, n_iter = kmeans_lloyd(
+                    inputs.X,
+                    inputs.mask,
+                    centers0,
+                    mesh=inputs.mesh,
+                    csize=inputs.csize,
+                    max_iter=int(params["max_iter"]),
+                    tol=float(params["tol"]),
+                    # bf16 matmul operands / f32 accumulation on the two MXU
+                    # contractions (~2x); final cost pass stays f32
+                    matmul_dtype=mm,
+                )
+            # the first fetch blocks until the Lloyd program has run
+            with telemetry.span("solver.fetch") as f_span:
+                # strip lane-padding columns (zero by the Lloyd invariant)
+                result = {
+                    "cluster_centers": np.asarray(centers)[:, : inputs.n_features],
+                    "training_cost": float(cost),
+                    "n_iter": int(n_iter),
+                }
+                # passes over X that assign every row: the iterations and
+                # the cost pass
+                f_span.set_attr(
+                    n_iter=result["n_iter"], n_evals=result["n_iter"] + 1
+                )
             if mp > 1:
                 kb = -(-k // mp)
                 result["_fit_report"] = {
@@ -545,21 +576,27 @@ class KMeansModel(KMeansClass, _TpuModel, _KMeansParams):
     def _get_tpu_transform_func(
         self, dataset: Optional[DataFrame] = None
     ) -> Callable[[np.ndarray], Dict[str, np.ndarray]]:
-        from ..ops.kmeans_kernels import pairwise_sq_dists
-
         pred_col = self.getOrDefault("predictionCol")
         centers_np = self.cluster_centers_
 
-        @jax.jit
-        def _assign(Xb: jax.Array) -> jax.Array:
-            centers = jnp.asarray(centers_np, dtype=Xb.dtype)
-            d2 = pairwise_sq_dists(Xb, centers)
-            return jnp.argmin(d2, axis=1).astype(jnp.int32)
+        placed: Dict[Any, jax.Array] = {}  # the centers on the device, per batch dtype
 
         def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
-            return {pred_col: np.asarray(_assign(jnp.asarray(Xb)))}
+            Xd = jnp.asarray(Xb)
+            if Xd.dtype not in placed:
+                placed[Xd.dtype] = jnp.asarray(centers_np, dtype=Xd.dtype)
+            return {pred_col: np.asarray(_assign_nearest(Xd, placed[Xd.dtype]))}
 
         return _fn
+
+
+@jax.jit
+def _assign_nearest(Xb: jax.Array, centers: jax.Array) -> jax.Array:
+    """Index of the nearest center per row. The centers are an ARGUMENT: one
+    program per batch shape serves every model (a closure over them was a
+    new program, compiled or fetched, for every model that transformed)."""
+    d2 = pairwise_sq_dists(Xb, centers)
+    return jnp.argmin(d2, axis=1).astype(jnp.int32)
 
 
 def _weighted_kmeanspp(

@@ -81,8 +81,7 @@ def stats_dot(onehot: jax.Array, x: jax.Array, matmul_dtype=None) -> jax.Array:
     )
 
 
-def _chunk_stats(X_local, mask_local, centers, csize: int, matmul_dtype=None,
-                 exact: bool = False):
+def _chunk_stats(X_local, mask_local, centers, csize: int, matmul_dtype=None):
     """Chunked pass over local rows; returns (sums (k,d), counts int32 (k,),
     cost).
 
@@ -96,29 +95,24 @@ def _chunk_stats(X_local, mask_local, centers, csize: int, matmul_dtype=None,
     a reshaped X — see its docstring for the layout-repack hazard).
     ``matmul_dtype=bfloat16`` also runs the one-hot stats contraction with
     bf16 operands (one-hots are exact; x rounds at ~1e-3 relative, washed
-    out by the per-cluster mean). ``exact`` runs the distance contraction
-    at ``Precision.HIGHEST`` — for the pass whose cost is reported."""
+    out by the per-cluster mean). The distances run at the default
+    precision — an argmin over centers needs no more; the cost that is
+    REPORTED comes from :func:`_chunk_cost`, which carries no statistics."""
     from .kmeans_pallas import kmeans_pallas_ok, lloyd_step_pallas
 
     k = centers.shape[0]
     d = X_local.shape[1]
-    if kmeans_pallas_ok(
-        X_local.shape[0], d, k, X_local.dtype, matmul_dtype, exact
-    ):
+    if kmeans_pallas_ok(X_local.shape[0], d, k, X_local.dtype, matmul_dtype):
         return lloyd_step_pallas(
-            X_local, mask_local, centers, matmul_dtype=matmul_dtype,
-            exact=exact,
+            X_local, mask_local, centers, matmul_dtype=matmul_dtype
         )
-    prec = lax.Precision.HIGHEST if exact else None
     n_chunks = check_row_chunking(X_local.shape[0], csize)
     c_sq = (centers * centers).sum(axis=1)  # (k,)
 
     def body(i, carry):
         sums, counts, cost = carry
         x, m = row_chunk(i, csize, X_local, mask_local)
-        d2 = pairwise_sq_dists(
-            x, centers, c_sq, matmul_dtype=matmul_dtype, precision=prec
-        )
+        d2 = pairwise_sq_dists(x, centers, c_sq, matmul_dtype=matmul_dtype)
         assign = jnp.argmin(d2, axis=1)
         onehot = jax.nn.one_hot(assign, k, dtype=x.dtype) * m[:, None]
         sums = sums + stats_dot(onehot, x, matmul_dtype)
@@ -134,6 +128,53 @@ def _chunk_stats(X_local, mask_local, centers, csize: int, matmul_dtype=None,
         jnp.zeros((), dtype=X_local.dtype),
     )
     return lax.fori_loop(0, n_chunks, body, init)
+
+
+def _chunk_cost(X_local, mask_local, centers, csize: int):
+    """The cost at ``centers`` over local rows, alone: f32 operands at
+    ``Precision.HIGHEST``, no (k, d) sums and no one-hot contraction — the
+    pass whose result is reported. Same two routes as :func:`_chunk_stats`:
+    the fused kernel where its gate admits the shape, else XLA chunks."""
+    from .kmeans_pallas import kmeans_pallas_ok, lloyd_cost_pallas
+
+    if kmeans_pallas_ok(
+        X_local.shape[0], X_local.shape[1], centers.shape[0], X_local.dtype,
+        None, True, False,
+    ):
+        return lloyd_cost_pallas(X_local, mask_local, centers)
+    n_chunks = check_row_chunking(X_local.shape[0], csize)
+    c_sq = (centers * centers).sum(axis=1)  # (k,)
+
+    def body(i, cost):
+        x, m = row_chunk(i, csize, X_local, mask_local)
+        d2 = pairwise_sq_dists(
+            x, centers, c_sq, precision=lax.Precision.HIGHEST
+        )
+        return cost + (jnp.min(d2, axis=1) * m).sum()
+
+    return lax.fori_loop(
+        0, n_chunks, body, jnp.zeros((), dtype=X_local.dtype)
+    )
+
+
+def _lloyd_shift(new_centers, centers, before):
+    """Largest squared move of a center in one iteration — and 0 where the
+    new centers are exactly those of two iterations before. At reduced
+    precision one near-tied row can go back and forth between two centers
+    for ever (measured on a v5e, PR 29: one seed in six of the reference's
+    KMeans run, iterations 12 to 30 alternating between two states); the
+    pair is as converged as the product allows, so the loop ends there as
+    it does at a fixed point. Float32 products cannot cycle."""
+    shift = ((new_centers - centers) ** 2).sum(axis=1).max()
+    return jnp.where((new_centers == before).all(), 0.0, shift)
+
+
+def _lloyd_state(centers, dtype):
+    """(centers, centers of the iteration before: none yet, last shift, it)."""
+    return (
+        centers, jnp.full_like(centers, jnp.nan),
+        jnp.asarray(jnp.inf, dtype), jnp.asarray(0),
+    )
 
 
 def mp_kmeans_shards(mesh, k: int) -> int:
@@ -215,14 +256,15 @@ def _kmeans_lloyd_1d(
 
     def per_device(X_local, mask_local, centers):
         def cond(state):
-            centers, prev_shift, it = state
+            _, _, prev_shift, it = state
             return jnp.logical_and(it < max_iter, prev_shift > tol * tol)
 
         def body(state):
-            centers, _, it = state
-            sums, counts, _ = _chunk_stats(
-                X_local, mask_local, centers, csize, matmul_dtype
-            )
+            centers, before, _, it = state
+            with jax.named_scope("lloyd.iter"):
+                sums, counts, _ = _chunk_stats(
+                    X_local, mask_local, centers, csize, matmul_dtype
+                )
             sums = lax.psum(sums, DP_AXIS)
             counts = lax.psum(counts, DP_AXIS)
             # empty cluster keeps its previous center (Spark behavior)
@@ -231,11 +273,11 @@ def _kmeans_lloyd_1d(
             new_centers = jnp.where(
                 counts[:, None] > 0, sums / safe[:, None], centers
             )
-            shift = ((new_centers - centers) ** 2).sum(axis=1).max()
-            return (new_centers, shift, it + 1)
+            shift = _lloyd_shift(new_centers, centers, before)
+            return (new_centers, centers, shift, it + 1)
 
-        state = (centers, jnp.asarray(jnp.inf, X_local.dtype), jnp.asarray(0))
-        centers, _, it = lax.while_loop(cond, body, state)
+        state = _lloyd_state(centers, X_local.dtype)
+        centers, _, _, it = lax.while_loop(cond, body, state)
         # final pass: cost at converged centers. NOTE: reading X after the
         # while loop makes XLA's buffer analysis insert a defensive copy of
         # the matrix at lane-unaligned d — but that copy is inserted even
@@ -252,9 +294,8 @@ def _kmeans_lloyd_1d(
         # assignments only need inter-center contrast. On the MXU an f32
         # dot at DEFAULT precision is such a reduced product (measured on
         # v5e, PR 22: reported cost 1.7e-3 off a plain f32 Lloyd).
-        _, _, cost = _chunk_stats(
-            X_local, mask_local, centers, csize, exact=True
-        )
+        with jax.named_scope("lloyd.cost"):
+            cost = _chunk_cost(X_local, mask_local, centers, csize)
         cost = lax.psum(cost, DP_AXIS)
         return centers, cost, it
 
@@ -354,12 +395,13 @@ def _kmeans_lloyd_mp(
             return block, lax.fori_loop(0, nc, body, init)
 
         def cond(state):
-            centers, prev_shift, it = state
+            _, _, prev_shift, it = state
             return jnp.logical_and(it < max_iter, prev_shift > tol * tol)
 
         def body(state):
-            centers, _, it = state
-            block, (sums, counts, _) = iter_stats(centers, matmul_dtype)
+            centers, before, _, it = state
+            with jax.named_scope("lloyd.iter"):
+                block, (sums, counts, _) = iter_stats(centers, matmul_dtype)
             sums = lax.psum(sums, DP_AXIS)
             counts = lax.psum(counts, DP_AXIS)
             countsf = counts.astype(sums.dtype)
@@ -372,14 +414,15 @@ def _kmeans_lloyd_mp(
             new_centers = lax.all_gather(
                 new_block, MP_AXIS, tiled=True
             )  # (k_pad, d), shard-order = global centroid order
-            shift = ((new_centers - centers) ** 2).sum(axis=1).max()
-            return (new_centers, shift, it + 1)
+            shift = _lloyd_shift(new_centers, centers, before)
+            return (new_centers, centers, shift, it + 1)
 
-        state = (centers, jnp.asarray(jnp.inf, X_local.dtype), jnp.asarray(0))
-        centers, _, it = lax.while_loop(cond, body, state)
+        state = _lloyd_state(centers, X_local.dtype)
+        centers, _, _, it = lax.while_loop(cond, body, state)
         # final cost pass at converged centers, always f32 operands (see
         # the 1-D kernel's cancellation note)
-        _, (_, _, cost) = iter_stats(centers, None)
+        with jax.named_scope("lloyd.cost"):
+            _, (_, _, cost) = iter_stats(centers, None)
         cost = lax.psum(cost, DP_AXIS)
         return centers, cost, it
 

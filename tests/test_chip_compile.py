@@ -101,6 +101,22 @@ def test_lloyd_kernel_compiles(one_chip, k, exact):
     assert _has_kernel(c)
 
 
+@pytest.mark.parametrize("cost_only", [False, True], ids=["lloyd", "cost_pass_highest"])
+def test_lloyd_kernel_compiles_at_the_reference_width(one_chip, cost_only):
+    """``kmeans_dbx``'s shard as the estimator places it: 500,000 rows padded
+    to 500,118 (123 chunks of 4,066: the rows do not divide by the tile, the
+    last block is ragged) x 3072 lane-padded columns, k=1000. The tile rule
+    gives 1024 for both passes; at 2048 the cost pass needs 146-158 MiB of
+    VMEM and Mosaic refuses it (PERF.md section 6, PR 29)."""
+    from spark_rapids_ml_tpu.ops.kmeans_pallas import lloyd_cost_pallas, lloyd_step_pallas, lloyd_tile
+
+    n, d, k = 500_118, 3072, 1000
+    assert lloyd_tile(d, k, None, cost_only, not cost_only)[0] == 1024
+    fn = lloyd_cost_pallas if cost_only else lloyd_step_pallas
+    c = fn.lower(one_chip((n, d)), one_chip((n,)), one_chip((k, d)), interpret=False).compile()
+    assert _has_kernel(c)
+
+
 def test_logreg_loss_grad_kernel_compiles(one_chip):
     from spark_rapids_ml_tpu.ops.logreg_pallas import _loss_grad_pallas, _row_tile
 
