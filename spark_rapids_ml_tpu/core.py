@@ -648,12 +648,15 @@ class _TpuEstimator(Params, _TpuParams):
         )
 
     def _feature_pad_multiple(self) -> int:
-        """Column multiple to zero-pad the design matrix to before sharding
+        """Column multiple the design matrix is zero-padded to on the device
         (0 = none). Estimators whose fit kernel reads X inside a
         ``while_loop`` (KMeans) override: at lane-unaligned d XLA inserts a
         defensive full copy of X around the loop, and on TPU the minor dim
         is physically tiled to 128 anyway, so explicit zero columns cost no
-        extra HBM while removing the 2x copy."""
+        extra HBM while removing the 2x copy. The pad is the device's:
+        ``_pre_process_data`` hands the padded width to ``shard_rows``,
+        which puts the host frame as it is into a zero buffer of that width
+        (no ``np.pad``, no second host copy of X)."""
         return 0
 
     def _x_placement_dtype(self) -> Optional[Any]:
@@ -703,12 +706,11 @@ class _TpuEstimator(Params, _TpuParams):
         csize = self._chunk_rows(n_global, mesh.shape["dp"])
         if X_sparse is not None:
             X = np.asarray(X_sparse.todense(), dtype=dtype)
-        if d_padded != n_features:
-            X = np.pad(X, ((0, 0), (0, d_padded - int(n_features))))
         place = self._x_placement_dtype()
         if place is not None and np.dtype(dtype) == np.dtype(np.float32):
             X = X.astype(place)
-        Xd, maskd = shard_rows(X, mesh, csize)
+        # the zero columns up to d_padded are written on the device
+        Xd, maskd = shard_rows(X, mesh, csize, cols=d_padded)
 
         y = w = None
         if self._require_label():

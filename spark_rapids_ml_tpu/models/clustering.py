@@ -182,6 +182,9 @@ class KMeans(KMeansClass, _TpuEstimator, _KMeansParams):
         shape); zero columns are invariant under Lloyd updates (zero-seeded
         centers stay zero, distances/costs unchanged) and TPU tiles the
         minor dim to 128 physically anyway, so the padding is HBM-free.
+        The zero columns are written on the device by ``shard_rows``
+        (``cols``), not by a host ``np.pad`` of the frame (7.8 s at
+        500,000 x 3000; PERF.md §6, PR 30).
         ``TPUML_LANE_PAD`` overrides (CI exercises the path on CPU)."""
         env = envspec.get("TPUML_LANE_PAD")
         if env is not None:

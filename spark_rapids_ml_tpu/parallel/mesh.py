@@ -271,13 +271,44 @@ def _fill(value: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
     return jnp.broadcast_to(value, shape)
 
 
+# Rows of a block that _write_block relays at a time where the block is
+# narrower than the buffer: 12 MB at 3000 f32 columns, which the TPU compiler
+# keeps in VMEM (no HBM temporary at all; 8192 rows: one of 98 MB; the whole
+# 65,536-row block at once: 805 MB a write, two writes outstanding, and
+# 0.061 s of device time a frame against 0.048 s; PERF.md §6, PR 30).
+_WRITE_PIECE_ROWS = 1024
+
+
 @functools.partial(jax.jit, donate_argnums=0)
 def _write_block(buf: jax.Array, block: jax.Array, row0: Any) -> Tuple[jax.Array, jax.Array]:
-    """``buf`` (donated: written in place) with ``block`` at row ``row0``, and
-    a scalar that is ready once the write has run. ``row0`` is traced, so one
-    program serves every block of a shape."""
-    start = (row0,) + (jnp.zeros_like(row0),) * (buf.ndim - 1)
-    return lax.dynamic_update_slice(buf, block, start), row0 + block.shape[0]
+    """``buf`` (donated: written in place) with ``block`` at row ``row0``,
+    column 0, and a scalar that is ready once the write has run. ``row0`` is
+    traced, so one program serves every block of a shape.
+
+    A block narrower than ``buf`` leaves the columns past its own as they
+    are (the zeros of :func:`_fill`). On a TPU such a block has another
+    layout than the buffer (``f32[65536,3000]`` comes up with its rows minor,
+    the 3072-wide buffer with its columns minor), so the write has to relay
+    it: piece by piece, or the program holds a second copy of the block."""
+    zero = jnp.zeros_like(row0)
+
+    def write(b, rows, at):
+        return lax.dynamic_update_slice(b, rows, (at,) + (zero,) * (buf.ndim - 1))
+
+    if block.ndim != 2 or block.shape[1] == buf.shape[1]:
+        return write(buf, block, row0), row0 + block.shape[0]
+    step = _WRITE_PIECE_ROWS
+    pieces, tail = divmod(block.shape[0], step)
+    if pieces:
+        buf = lax.fori_loop(
+            0,
+            pieces,
+            lambda i, b: write(b, lax.dynamic_slice_in_dim(block, i * step, step), row0 + i * step),
+            buf,
+        )
+    if tail:
+        buf = write(buf, block[pieces * step:], row0 + pieces * step)
+    return buf, row0 + block.shape[0]
 
 
 def _put_row_blocks(
@@ -285,8 +316,11 @@ def _put_row_blocks(
 ) -> Tuple[jax.Array, int]:
     """Assemble the row-sharded global array of ``shape`` on the devices from
     puts of at most ``block_rows`` rows of ``x``; rows past ``len(x)`` are
-    zero. Never holds a second copy of a shard: each device's zero buffer is
-    written in place, block by block. Returns the array and the puts issued."""
+    zero, and so are columns past ``x``'s own where ``shape`` is the wider:
+    the blocks are slices of ``x`` as it is, written at column 0. Never holds
+    a second copy of a shard, on the host or on a device: each device's zero
+    buffer is written in place, block by block. Returns the array and the
+    puts issued."""
     bufs, todo = {}, []
     for dev, idx in sh.addressable_devices_indices_map(shape).items():
         lo, hi, _ = idx[0].indices(shape[0])
@@ -309,21 +343,25 @@ def _put_row_blocks(
 
 
 def shard_rows(
-    x: np.ndarray, mesh: Mesh, row_multiple: int = 1
+    x: np.ndarray, mesh: Mesh, row_multiple: int = 1, cols: Optional[int] = None
 ) -> Tuple[jax.Array, jax.Array]:
     """Pad + device_put a host array row-sharded over the dp axis.
 
     This is the data-plane replacement for the reference's Arrow-batch →
     cupy ingestion inside the barrier task (``core.py:717-741``).
     ``row_multiple`` > 1 additionally aligns each device's shard to that
-    multiple (for kernels that scan rows in fixed-size chunks).
-    Returns (sharded_x, sharded_mask).
+    multiple (for kernels that scan rows in fixed-size chunks). ``cols`` is
+    the width a 2-D ``x`` is to have on the device (default: its own);
+    columns past ``x``'s are zero. Returns (sharded_x, sharded_mask).
 
     A shard of at most one block (``_PUT_BLOCK_BYTES``) goes up in one
     ``device_put`` of the padded array. A larger one goes up in row blocks
     that are assembled on the device (:func:`_put_row_blocks`): the same
     array, bit for bit, without the host's padded copy and without one put
-    the size of the frame.
+    the size of the frame. A shard that is to be wider than ``x`` is
+    assembled on the device whatever its size (a small one from a single
+    block): the zero columns are the device's own fill, and the host never
+    pads or copies ``x``.
 
     Multi-process: ``x`` is this process's local rows (each worker holds
     its partition, as each Spark barrier task held its Arrow batches).
@@ -333,28 +371,39 @@ def shard_rows(
     every process's padding rows invalid.
     """
     x = np.asarray(x)
+    width = x.shape[1] if x.ndim > 1 else 1
+    cols = width if cols is None else int(cols)
+    if cols != width and (x.ndim != 2 or cols < width):
+        raise ValueError(f"cannot place an array of shape {x.shape} at {cols} columns")
+    pad_cols = cols - width
     if jax.process_count() > 1:
-        return _shard_rows_multiproc(x, mesh, row_multiple)
+        return _shard_rows_multiproc(x, mesh, row_multiple, pad_cols)
     n_dp = mesh.shape[DP_AXIS]
     sh = row_sharding(mesh)
     n = x.shape[0]
     n_padded = n + (-n) % (n_dp * row_multiple)
     row_bytes = x.dtype.itemsize * int(np.prod(x.shape[1:]))
     block_rows = _put_block_rows(row_bytes)
-    if n_padded // n_dp <= block_rows:
+    if not pad_cols and n_padded // n_dp <= block_rows:
         xp, mask = pad_rows(x, n_dp * row_multiple)
         with _enqueue_span(
-            mesh, xp.nbytes + mask.nbytes, 2, blocks=1, block_bytes=xp.nbytes
+            mesh, xp.nbytes + mask.nbytes, 2, blocks=1, block_bytes=xp.nbytes, cols=cols, pad_cols=0
         ):
             xd = jax.device_put(xp, sh)
             md = jax.device_put(mask, sh)
         return xd, md
     mask = np.zeros((n_padded,), np.float32)
     mask[:n] = 1.0
+    shape = (n_padded, cols) if pad_cols else (n_padded,) + x.shape[1:]
     with _enqueue_span(
-        mesh, n_padded * row_bytes + mask.nbytes, 2, block_bytes=block_rows * row_bytes
+        mesh,
+        x.dtype.itemsize * int(np.prod(shape)) + mask.nbytes,
+        2,
+        block_bytes=min(block_rows, n_padded // n_dp) * row_bytes,
+        cols=cols,
+        pad_cols=pad_cols,
     ) as span:
-        xd, blocks = _put_row_blocks(x, (n_padded,) + x.shape[1:], sh, block_rows)
+        xd, blocks = _put_row_blocks(x, shape, sh, block_rows)
         span.set_attr(blocks=blocks)
         md = jax.device_put(mask, sh)
     return xd, md
@@ -384,10 +433,14 @@ def _local_dp_devices(mesh: Mesh) -> int:
 
 
 def _shard_rows_multiproc(
-    x: np.ndarray, mesh: Mesh, row_multiple: int
+    x: np.ndarray, mesh: Mesh, row_multiple: int, pad_cols: int = 0
 ) -> Tuple[jax.Array, jax.Array]:
     from jax.experimental import multihost_utils
 
+    if pad_cols:
+        # one put a process: this path still pads on the host (a second copy
+        # of the local rows); the single-process path pads on the device
+        x = np.pad(x, ((0, 0), (0, pad_cols)))
     local_dp = _local_dp_devices(mesh)
     counts = np.asarray(
         multihost_utils.process_allgather(np.asarray([x.shape[0]]))
@@ -412,7 +465,8 @@ def _shard_rows_multiproc(
     n_dp = mesh.shape[DP_AXIS]
     global_rows = per_dev * n_dp
     sh = row_sharding(mesh)
-    with _enqueue_span(mesh, xp.nbytes + mask.nbytes, 2):
+    cols = x.shape[1] if x.ndim > 1 else 1
+    with _enqueue_span(mesh, xp.nbytes + mask.nbytes, 2, cols=cols, pad_cols=pad_cols):
         xd = jax.make_array_from_process_local_data(sh, xp, (global_rows,) + x.shape[1:])
         md = jax.make_array_from_process_local_data(sh, mask, (global_rows,))
     return xd, md

@@ -239,7 +239,10 @@ SPEC: Dict[str, EnvVar] = _registry(
         "KMeans feature lane-padding multiple override (default: 128 on "
         "TPU, off elsewhere). Padding to the lane multiple is HBM-free on "
         "TPU and removes XLA's defensive copy of X around the Lloyd loop "
-        "at `d % 128 != 0`.",
+        "at `d % 128 != 0`. The zero columns are written on the device "
+        "(`parallel/mesh.shard_rows(cols=...)` puts the frame at its own "
+        "width into a zero buffer of the padded one); the host frame is "
+        "never padded or copied.",
         minimum=0, category="kmeans",
     ),
     EnvVar(

@@ -117,6 +117,22 @@ def test_lloyd_kernel_compiles_at_the_reference_width(one_chip, cost_only):
     assert _has_kernel(c)
 
 
+@pytest.mark.parametrize("block_rows", [65_536, 41_248], ids=["whole_block", "tail_block"])
+def test_narrow_block_is_written_without_a_second_copy(one_chip, block_rows):
+    """``kmeans_dbx``'s frame goes up 3000 columns wide into the 3072-wide
+    zero buffer. The block comes up with its rows minor (3000 is no multiple
+    of 128), the buffer has its columns minor: written at once, the program
+    holds the relaid block besides (a temporary of 805 MB a write, which the
+    runtime's memory peak does not show; PERF.md section 6, PR 30). Piece by
+    piece it holds nothing."""
+    from spark_rapids_ml_tpu.parallel.mesh import _write_block
+
+    c = _write_block.lower(one_chip((500_118, 3072)), one_chip((block_rows, 3000)), one_chip((), I32)).compile()
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= 500_118 * 3072 * 4   # the buffer is written in place
+    assert mem.temp_size_in_bytes < (32 << 20)
+
+
 def test_logreg_loss_grad_kernel_compiles(one_chip):
     from spark_rapids_ml_tpu.ops.logreg_pallas import _loss_grad_pallas, _row_tile
 

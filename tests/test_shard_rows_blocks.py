@@ -2,7 +2,12 @@
 (``_PUT_BLOCK_BYTES``) goes up in puts of a bounded size that are assembled
 on the device. The array that comes back must be the one a single put of the
 padded host array gives — same shape, dtype, sharding and bits — and a shard
-of at most one block must not touch the assembly program at all."""
+of at most one block must not touch the assembly program at all. A shard
+that is to be wider on the device than on the host (``cols``: KMeans' lane
+padding) is assembled whatever its size, from blocks of the host's own
+width: the zero columns are the device's, and the bits are ``np.pad``'s."""
+
+import contextlib
 
 import ml_dtypes
 import numpy as np
@@ -10,7 +15,9 @@ import pytest
 
 import jax
 
+from spark_rapids_ml_tpu import core
 from spark_rapids_ml_tpu.classification import LogisticRegression
+from spark_rapids_ml_tpu.clustering import KMeans
 from spark_rapids_ml_tpu.data import DataFrame
 from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
 from spark_rapids_ml_tpu.parallel.mesh import make_mesh, pad_rows, row_sharding, shard_rows
@@ -32,6 +39,17 @@ def assembly(monkeypatch):
 
     monkeypatch.setattr(mesh_mod, "_write_block", recording)
     return calls
+
+
+@contextlib.contextmanager
+def _h2d_puts():
+    """The attributes of the ``h2d.enqueue`` spans opened inside the block."""
+    puts = []
+    telemetry.add_span_sink(lambda ev, thread: puts.append(ev["args"]) if ev["name"] == "h2d.enqueue" else None)
+    try:
+        yield puts
+    finally:
+        telemetry.reset_telemetry()
 
 
 def _host(rows, dtype, ndim):
@@ -61,11 +79,38 @@ CASES = {
     "shard_of_exactly_one_block": (8, 1, 1, 8 * 16, np.float32, 2, 16, False),
     "shard_below_one_block": (2, 1, 128, 97, np.float32, 2, 128, False),
 }
+# id: (a case as above, the columns the shard is to have on the device)
+WIDER = {
+    "to16_dp1": (CASES["dp1"], 16),
+    "to16_dp2": (CASES["dp2"], 16),
+    "to128_dp1": (CASES["dp1"], 128),
+    "to128_dp2": (CASES["dp2"], 128),
+    "to128_dp8": (CASES["dp8"], 128),
+    "to128_dp4_mp2": (CASES["dp4_mp2"], 128),
+    "to128_dp1_multiple128": (CASES["dp1_multiple128"], 128),
+    "to128_dp8_multiple128": (CASES["dp8_multiple128"], 128),
+    "to16_dp8_multiple128_shards_of_padding": (CASES["dp8_multiple128_shards_of_padding"], 16),
+    "to16_tail_block_of_3_rows": (CASES["tail_block_of_3_rows"], 16),
+    "to128_whole_blocks_no_tail": (CASES["whole_blocks_no_tail"], 128),
+    "to16_bf16": (CASES["bf16"], 16),
+    "to128_bf16": (CASES["bf16"], 128),
+    "to16_f64": (CASES["f64"], 16),
+    # blocks above _WRITE_PIECE_ROWS are relaid piece by piece: 2 pieces, then 1 piece and a tail of 476 rows
+    "to128_pieces_and_tail": ((1, 1, 1, 2048 + 1500, np.float32, 2, 2048, True), 128),
+    "to16_dp2_pieces_and_tail": ((2, 1, 1, 2 * (4096 + 1030), np.float32, 2, 4096, True), 16),
+    "to128_bf16_pieces_and_tail": ((1, 1, 8, 2048 + 1500, ml_dtypes.bfloat16, 2, 2048, True), 128),
+    # a shard of at most one block is assembled all the same: one write a device
+    "to128_shard_of_exactly_one_block": ((8, 1, 1, 8 * 16, np.float32, 2, 16, True), 128),
+    "to16_shard_below_one_block": ((2, 1, 128, 97, np.float32, 2, 128, True), 16),
+    # its own width asked for by name is no pad: the plain put, no assembly
+    "own_width_shard_below_one_block": (CASES["shard_below_one_block"], COLS),
+    "own_width_dp2": (CASES["dp2"], COLS),
+}
 
 
-@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("case", [*CASES, *WIDER])
 def test_shard_rows_equals_the_padded_host_array(case, monkeypatch, assembly):
-    dp, mp, row_multiple, rows, dtype, ndim, block_rows, assembled = CASES[case]
+    (dp, mp, row_multiple, rows, dtype, ndim, block_rows, assembled), cols = WIDER.get(case, (CASES.get(case), None))
     x = _host(rows, dtype, ndim)
     row_bytes = x.dtype.itemsize * (COLS if ndim == 2 else 1)
     # a byte target of less than the tile's 8 rows still gives 8
@@ -73,8 +118,11 @@ def test_shard_rows_equals_the_padded_host_array(case, monkeypatch, assembly):
     assert mesh_mod._put_block_rows(row_bytes) == block_rows
     mesh = make_mesh(dp, mp=mp)
     with jax.enable_x64(dtype == np.float64):
-        xd, md = shard_rows(x, mesh, row_multiple)
+        with _h2d_puts() as puts:
+            xd, md = shard_rows(x, mesh, row_multiple, cols=cols)
         want, want_mask = pad_rows(x, dp * row_multiple)
+        if cols is not None:
+            want = np.pad(want, ((0, 0), (0, cols - COLS)))
         assert xd.shape == want.shape and xd.dtype == want.dtype
         assert xd.sharding == md.sharding == row_sharding(mesh)
         np.testing.assert_array_equal(np.asarray(xd).view(np.uint8), want.view(np.uint8))
@@ -82,8 +130,11 @@ def test_shard_rows_equals_the_padded_host_array(case, monkeypatch, assembly):
         assert md.dtype == np.float32
         for shard in xd.addressable_shards:
             np.testing.assert_array_equal(np.asarray(shard.data), want[shard.index])
+    (put,) = puts
+    assert (put["cols"], put["pad_cols"]) == (want.shape[1] if ndim == 2 else 1, (cols or COLS) - COLS)
+    assert put["bytes"] == want.nbytes + want_mask.nbytes
     if not assembled:
-        assert assembly == []
+        assert assembly == [] and put["blocks"] == 1
         return
     # each device got the valid rows of its shard, block after block
     per_dev = want.shape[0] // dp
@@ -93,7 +144,10 @@ def test_shard_rows_equals_the_padded_host_array(case, monkeypatch, assembly):
         lo = index_map[dev][0].start or 0
         valid = min(max(rows - lo, 0), per_dev)
         expected += [(dev.id, at, min(block_rows, valid - at)) for at in range(0, valid, block_rows)]
-    assert sorted(assembly) == sorted(expected) and len(expected) > dp * mp
+    assert sorted(assembly) == sorted(expected) and put["blocks"] == len(expected)
+    # a shard above one block takes several writes, a wider one of at most one block a single write
+    assert len(expected) > dp * mp if per_dev > block_rows else 0 < len(expected) <= dp * mp
+    assert put["block_bytes"] == min(block_rows, per_dev) * row_bytes
     # the devices take turns: block k of every device before block k + 1 of any
     assert [row0 for _, row0, _ in assembly] == sorted(row0 for _, row0, _ in assembly)
 
@@ -138,12 +192,8 @@ def test_logreg_fit_is_bitwise_the_same_through_blocks(monkeypatch):
     df = DataFrame({"features": X}).withColumn("label", y)
 
     def fit():
-        puts = []
-        telemetry.add_span_sink(lambda ev, thread: puts.append(ev["args"]) if ev["name"] == "h2d.enqueue" else None)
-        try:
+        with _h2d_puts() as puts:
             return LogisticRegression(maxIter=30, regParam=1e-3, num_workers=2).fit(df), puts
-        finally:
-            telemetry.reset_telemetry()
 
     whole, (x_whole, y_whole) = fit()
     monkeypatch.setattr(mesh_mod, "_PUT_BLOCK_BYTES", cols * 4 * 64)
@@ -158,3 +208,51 @@ def test_logreg_fit_is_bitwise_the_same_through_blocks(monkeypatch):
     for key in ("bytes", "arrays", "devices"):
         assert x_blocked[key] == x_whole[key] and y_blocked[key] == y_whole[key]
     assert x_whole["bytes"] == 2052 * (cols * 4 + 4) and x_whole["devices"] == 2
+
+
+@pytest.mark.parametrize("block_rows", [None, 64], ids=["one_block", "blocks_of_64_rows"])
+def test_kmeans_fit_is_bitwise_the_same_with_the_pad_on_the_device(block_rows, monkeypatch):
+    """Lane padding (``TPUML_LANE_PAD``, the TPU's default) no longer pads the
+    frame on the host: the fit equals, bit for bit, the fit on the frame that
+    the parent's path padded with ``np.pad`` before ``shard_rows``."""
+    rng = np.random.default_rng(9)
+    rows, cols, k = 1501, 10, 4
+    X = (rng.normal(size=(rows, cols)) + 6.0 * rng.integers(0, k, size=(rows, 1))).astype(np.float32)
+    df = DataFrame({"features": X})
+    monkeypatch.setenv("TPUML_LANE_PAD", "128")
+    if block_rows:
+        monkeypatch.setattr(mesh_mod, "_PUT_BLOCK_BYTES", cols * 4 * block_rows)
+
+    def fit():
+        with _h2d_puts() as puts:
+            model = KMeans(k=k, maxIter=20, seed=3, num_workers=2).fit(df)
+            return model, np.asarray(model.transform(df).column("prediction")), puts
+
+    def host_padded_shard_rows(x, mesh, row_multiple=1, cols=None):
+        return shard_rows(np.pad(x, ((0, 0), (0, cols - x.shape[1]))), mesh, row_multiple)
+
+    with monkeypatch.context() as parent:
+        parent.setattr(core, "shard_rows", host_padded_shard_rows)
+        want, want_pred, (want_put,) = fit()
+
+    padded_2d, real_pad = [], np.pad
+
+    def spy(array, *args, **kwargs):
+        if np.ndim(array) == 2:
+            padded_2d.append(np.shape(array))
+        return real_pad(array, *args, **kwargs)
+
+    with monkeypatch.context() as watched:
+        watched.setattr(np, "pad", spy)
+        got, got_pred, (got_put,) = fit()
+    assert padded_2d == []
+    assert got.cluster_centers_.shape == (k, cols)
+    np.testing.assert_array_equal(np.asarray(got.cluster_centers_), np.asarray(want.cluster_centers_))
+    assert got.trainingCost == want.trainingCost and got.numIter == want.numIter >= 2
+    np.testing.assert_array_equal(got_pred, want_pred)
+    # the same array on the devices; the puts are of the host's 10 columns
+    assert (want_put["cols"], want_put["pad_cols"], got_put["cols"], got_put["pad_cols"]) == (128, 0, 128, 118)
+    assert got_put["bytes"] == want_put["bytes"]
+    per_dev = got_put["bytes"] // (2 * (128 * 4 + 4))   # a device's rows, padded to KMeans' chunks
+    assert got_put["blocks"] == (2 * -(-(rows + 1) // 2 // block_rows) if block_rows else 2)
+    assert got_put["block_bytes"] == (block_rows or per_dev) * cols * 4
