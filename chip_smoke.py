@@ -52,7 +52,7 @@ from spark_rapids_ml_tpu.knn import NearestNeighbors  # noqa: E402
 from spark_rapids_ml_tpu.regression import LinearRegression  # noqa: E402
 from spark_rapids_ml_tpu.utils.platform import enable_compile_cache  # noqa: E402
 
-D = 256                 # feature width (bench.py's chip shape)
+D = 256                 # feature width
 PCA_K = 3
 KMEANS_K = 1024         # cut to rows // 16 when a rehearsal asks for fewer rows
 KMEANS_ITERS = 10

@@ -9,16 +9,16 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "== static checks =="
-python -m compileall -q spark_rapids_ml_tpu benchmark tests tpuml_lint bench.py benchmark_runner.py
+python -m compileall -q spark_rapids_ml_tpu benchmark tests tpuml_lint benchmark_runner.py
 # tpuml-lint is stdlib-only so (unlike the tools below) it always runs:
 # TPU/JAX invariants + env-var registry/doc drift. Rule catalog and
 # suppression syntax: docs/static_analysis.md.
-python -m tpuml_lint spark_rapids_ml_tpu benchmark tests scripts ci bench.py benchmark_runner.py
+python -m tpuml_lint spark_rapids_ml_tpu benchmark tests scripts ci benchmark_runner.py
 # concurrency-correctness rules, explicitly against an empty baseline:
 # the lock-hierarchy (TPU010), blocking-under-lock (TPU011) and
 # thread-lifecycle (TPU012) findings must be zero — fixed, never
 # grandfathered (runtime/lockspec.py is the declared hierarchy)
-python -m tpuml_lint spark_rapids_ml_tpu benchmark tests scripts ci bench.py benchmark_runner.py \
+python -m tpuml_lint spark_rapids_ml_tpu benchmark tests scripts ci benchmark_runner.py \
     --no-baseline --rule TPU010 --rule TPU011 --rule TPU012
 python scripts/gen_config_docs.py --check
 if python -c "import black" 2>/dev/null; then
@@ -57,31 +57,6 @@ fi
 
 echo "== benchmark smoke =="
 ./run_benchmark.sh cpu 5000 64
-
-echo "== transform bench smoke (rf packed engine + gbt + umap) =="
-# Serving-path contract: the rf, gbt, and umap entries must emit
-# transform_vs_baseline (BENCH_REQUIRE_TRANSFORM makes a silently
-# dropped transform metric a hard failure), and the rf entry must carry
-# the tree-batch provenance columns. Tiny CPU scales — this checks the
-# metric plumbing, not the TPU throughput target.
-JAX_PLATFORMS=cpu BENCH_ONLY=rf,gbt,umap BENCH_REQUIRE_TRANSFORM=rf,gbt,umap \
-    BENCH_ROWS=4096 BENCH_RF_ROWS=4096 BENCH_RF_TREES=4 BENCH_RF_DEPTH=8 \
-    BENCH_GBT_ROWS=4096 BENCH_GBT_ROUNDS=3 BENCH_GBT_DEPTH=4 \
-    BENCH_UMAP_ROWS=1024 python bench.py > /tmp/tpuml_bench_tree.out
-python - <<'EOF'
-import json
-
-with open("/tmp/tpuml_bench_tree.out") as f:
-    line = json.loads(f.read().strip().splitlines()[-1])
-rf, gbt = line["rf"], line["gbt"]
-assert rf["tree_batch"] >= 1 and rf["hist_strategy"], rf
-assert rf["seconds_per_level"] > 0, rf
-assert "transform_vs_baseline" in gbt and gbt["seconds_per_round"] > 0, gbt
-print(
-    "bench rf/gbt columns OK: tree_batch", rf["tree_batch"],
-    "hist", rf["hist_strategy"], "gbt engine", gbt["transform_engine"],
-)
-EOF
 
 echo "== tree-batched growth dispatch + gbt fit/transform smoke =="
 # TPUML_RF_TREE_BATCH contract: off and auto produce bit-identical
@@ -376,23 +351,6 @@ print(f"ring overlap smoke: decode={t_decode:.3f}s fold={t_fold:.3f}s "
 assert overlap > 0.5, (t_decode, t_fold, t_total, overlap)
 EOF
 
-# bench pca_stream artifact: the JSON line must carry the new wire
-# provenance columns
-BENCH_ONLY=pca_stream BENCH_STREAM_SECONDS=3 BENCH_STREAM_CHUNK=65536 \
-TPUML_WIRE_DTYPE=int8 JAX_PLATFORMS=cpu python bench.py cpu \
-  > /tmp/tpuml_bench_wire.out
-python - <<'EOF'
-import json
-
-with open("/tmp/tpuml_bench_wire.out") as f:
-    line = json.loads(f.read().strip().splitlines()[-1])
-entry = line["pca_stream"]
-assert entry["wire_dtype"] == "int8", entry
-assert "decode_seconds" in entry and "overlap_efficiency" in entry, entry
-print("bench pca_stream wire columns OK:", entry["wire_dtype"],
-      entry["ingest_gbps"], "GB/s logical")
-EOF
-
 echo "== gang-fit dispatch smoke =="
 # TPUML_GANG_FIT=4 CV run must come back with gang provenance in every
 # sub-model's _fit_report, and with the env UNSET the sequential path must
@@ -449,25 +407,6 @@ assert counters.get("gang_lanes_total") == 12, counters.snapshot()
 print(
     "gang-fit smoke OK: dispatches", counters.get("gang_dispatches"),
     "lane widths", sorted(lanes),
-)
-EOF
-
-# bench logreg_multi artifact: the gang leg must carry its amortization
-# columns (tiny CPU scale — metric plumbing, not the TPU 3x target)
-BENCH_ONLY=logreg_multi BENCH_ROWS=20000 BENCH_COLS=64 \
-JAX_PLATFORMS=cpu python bench.py cpu > /tmp/tpuml_bench_gang.out
-python - <<'EOF'
-import json
-
-with open("/tmp/tpuml_bench_gang.out") as f:
-    line = json.loads(f.read().strip().splitlines()[-1])
-entry = line["logreg_multi"]
-assert entry["gang_lanes"] == 24, entry
-assert entry["solves_per_sec"] > 0 and entry["vs_sequential"] > 0, entry
-assert "mfu" in entry and "seq_fit_seconds" in entry, entry
-print(
-    "bench logreg_multi columns OK: vs_sequential",
-    round(entry["vs_sequential"], 2),
 )
 EOF
 
@@ -600,51 +539,8 @@ assert len(logs) == 1, os.listdir(tdir)
 with open(os.path.join(tdir, logs[0])) as f:
     for line in f:
         json.loads(line)
-# roofline attribution: compiled sites must carry measured cost-model
-# numbers (XLA cost_analysis, not hand formulas) with an MFU + verdict
-roofed = {
-    site: st for site, st in stats.items()
-    if "flops_total" in st and "mfu" in st
-}
-assert roofed, sorted(stats)
-for site, st in roofed.items():
-    assert st["flops_total"] > 0 and st["mfu"] > 0, (site, st)
-    assert st["bound"] in ("compute", "memory"), (site, st)
 print(f"telemetry trace smoke OK: {len(names)} span sites, "
-      f"coverage {covered / root_ev['dur']:.3f}, "
-      f"{len(roofed)} roofline-attributed sites")
-EOF
-
-# bench artifact with tracing on: every entry carries span provenance
-# columns, and the run drops Prometheus/JSON metric dumps next to the
-# trace
-rm -rf /tmp/tpuml_trace_bench
-BENCH_ONLY=pca_stream BENCH_STREAM_SECONDS=3 BENCH_STREAM_CHUNK=65536 \
-TPUML_TRACE=/tmp/tpuml_trace_bench JAX_PLATFORMS=cpu python bench.py cpu \
-  > /tmp/tpuml_bench_tele.out
-python - <<'EOF'
-import json
-import os
-
-with open("/tmp/tpuml_bench_tele.out") as f:
-    line = json.loads(f.read().strip().splitlines()[-1])
-entry = line["pca_stream"]
-assert "device_seconds" in entry, entry
-assert entry["spans"] and all(v >= 1 for v in entry["spans"].values()), entry
-assert "suffstats.pass" in entry["spans"], entry
-assert "stream.ingest" in entry["spans"], entry
-# measured roofline MFU: cost-analysis FLOPs replace the hand formula,
-# which survives as the labeled mfu_derived fallback
-assert entry.get("flops_measured", 0) > 0, entry
-assert "mfu_derived" in entry and entry["mfu"] > 0, entry
-files = os.listdir("/tmp/tpuml_trace_bench")
-assert any(f.startswith("metrics-") and f.endswith(".prom") for f in files), files
-assert any(f.startswith("metrics-") and f.endswith(".json") for f in files), files
-prom = [f for f in files if f.endswith(".prom")][0]
-with open(os.path.join("/tmp/tpuml_trace_bench", prom)) as f:
-    text = f.read()
-assert "# TYPE tpuml_span_seconds summary" in text, text[:400]
-print("bench telemetry columns OK:", sorted(entry["spans"])[:4], "...")
+      f"coverage {covered / root_ev['dur']:.3f}")
 EOF
 
 # defaults inert: with TPUML_TRACE unset nothing is recorded, nothing is
@@ -682,57 +578,6 @@ assert np.asarray(plain.cluster_centers_).tobytes() == \
     np.asarray(traced.cluster_centers_).tobytes()
 print("telemetry defaults-inert smoke OK")
 EOF
-
-echo "== bench-regress gate smoke =="
-# Synthetic trajectory: a fabricated prior run plus a current run with
-# one entry perturbed past the ±15% threshold must exit nonzero naming
-# the offender; the unperturbed pair must pass.
-python - <<'EOF'
-import json
-import os
-import subprocess
-import sys
-import tempfile
-
-def wrapper(n, entries):
-    tail = json.dumps(
-        {"metric": "pca_fit_throughput", "value": 1.0, **entries}
-    )
-    return {"n": n, "cmd": "python bench.py", "rc": 0,
-            "tail": "log noise\n" + tail, "parsed": None}
-
-def entry(sec, vs, mfu):
-    return {"samples_per_sec_per_chip": 1e6, "fit_seconds": sec,
-            "vs_baseline": vs, "mfu": mfu}
-
-with tempfile.TemporaryDirectory() as td:
-    base = {"pca": entry(1.0, 2.0, 0.2), "kmeans": entry(2.0, 3.0, 0.3)}
-    with open(os.path.join(td, "BENCH_r01.json"), "w") as f:
-        json.dump(wrapper(1, base), f)
-    ok = {"pca": entry(1.05, 1.95, 0.21), "kmeans": entry(1.9, 3.1, 0.29)}
-    with open(os.path.join(td, "BENCH_r02.json"), "w") as f:
-        json.dump(wrapper(2, ok), f)
-    r = subprocess.run(
-        [sys.executable, "scripts/bench_regress.py",
-         "--trajectory", os.path.join(td, "BENCH_r*.json")],
-        capture_output=True, text=True,
-    )
-    assert r.returncode == 0, (r.returncode, r.stdout)
-
-    bad = dict(ok, kmeans=entry(2.5, 3.1, 0.29))  # +31% seconds
-    with open(os.path.join(td, "BENCH_r03.json"), "w") as f:
-        json.dump(wrapper(3, bad), f)
-    r = subprocess.run(
-        [sys.executable, "scripts/bench_regress.py",
-         "--trajectory", os.path.join(td, "BENCH_r*.json")],
-        capture_output=True, text=True,
-    )
-    assert r.returncode != 0, (r.returncode, r.stdout)
-    assert "kmeans.fit_seconds" in r.stdout, r.stdout
-print("bench-regress synthetic gate OK")
-EOF
-# the real recorded trajectory must be clean (newest vs prior run)
-python scripts/bench_regress.py
 
 echo "== multi-host trace merge smoke =="
 # Two simulated ranks (the launcher's TPUML_PROC_ID layout) trace into
@@ -1641,40 +1486,6 @@ for var in ("TPUML_AUTOTUNE", "TPUML_AUTOTUNE_CACHE", "TPUML_TRACE",
 print(f"autotuner smoke OK: cold search measured {cold_spans} probes "
       f"(winner {won.value}, {won.provenance}), warm consult cache_hit "
       "with zero new probes, 0 retrace storms")
-EOF
-
-# bench autotune artifact: the tuned-vs-default A/B must post its ratio
-# columns and clear the bench_regress absolute floor (tiny CPU scale —
-# this checks the search + gate plumbing, not TPU speedups)
-JAX_PLATFORMS=cpu BENCH_ONLY=autotune BENCH_AUTOTUNE_BUDGET_MS=20000 \
-    BENCH_AUTOTUNE_RF_ROWS=2048 python bench.py cpu \
-    > /tmp/tpuml_bench_autotune.out
-python - <<'EOF'
-import json
-import subprocess
-import sys
-
-with open("/tmp/tpuml_bench_autotune.out") as f:
-    line = json.loads(f.read().strip().splitlines()[-1])
-entry = line["autotune"]
-assert entry["tuned_vs_default"] >= 0.85, entry
-legs = entry["legs"]
-assert set(legs) == {"rf", "pca_stream", "serving"}, sorted(legs)
-for name, leg in legs.items():
-    assert leg["tuned_vs_default"] > 0, (name, leg)
-    # default-wins legs must show the tuner RETURNING the default
-    if leg["tuned"] == leg["default"]:
-        assert leg["tuned_vs_default"] == 1.0, (name, leg)
-r = subprocess.run(
-    [sys.executable, "scripts/bench_regress.py",
-     "--current", "/tmp/tpuml_bench_autotune.out",
-     "--trajectory", "/tmp/tpuml_nonexistent_r*.json"],
-    capture_output=True, text=True,
-)
-assert "tuned_vs_default>=floor" in r.stdout, r.stdout
-assert r.returncode == 0, (r.returncode, r.stdout)
-print("bench autotune columns OK:",
-      {k: v["tuned_vs_default"] for k, v in legs.items()})
 EOF
 
 echo "CI OK"

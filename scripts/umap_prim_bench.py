@@ -26,7 +26,7 @@ def timed(fn, *args, reps=3):
     best = 1e30
     for r in range(reps):
         # fresh salt per rep: no rep repeats an earlier (executable,
-        # buffers) pair exactly (see bench.py module docstring)
+        # buffers) pair exactly, which a remote backend may memoize
         salt = jnp.float32(1e-22 * (r + 1))
         t0 = time.perf_counter()
         float(jitted(salt, *args))  # scalar fetch forces completion

@@ -882,8 +882,6 @@ class _TpuEstimator(Params, _TpuParams):
                 models.append(model)
                 continue
             res_base = _res_counters.snapshot()
-            # no fence here (nor on the gang dispatch): a fit function returns
-            # its results on the host, so no device array is left to wait on
             with autotune.collect() as tuned, annotate(
                 f"{cls_name}.fit"
             ), telemetry.span("fit.dispatch", lane=lane, streaming=streaming):

@@ -423,13 +423,12 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                     tree_batch=tree_batch,
                     hist_strategy=cfg.hist_strategy,
                     gather=gather,
-                ) as f_span:
+                ):
                     outg = build_forest(
                         bins, inputs.mask, stats, kg,
                         mesh=inputs.mesh, cfg=cfg, gather=gather,
                         tree_batch=tree_batch,
                     )
-                    f_span.fence(outg)
                     for k, a in outg.items():
                         h = fetch_global(a, inputs.mesh)
                         pieces.setdefault(k, []).append(

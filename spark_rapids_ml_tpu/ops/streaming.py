@@ -804,8 +804,8 @@ def iter_device_chunks(
         depth = int(envspec.get("TPUML_STREAM_STAGE_DEPTH"))
         if not envspec.is_set("TPUML_STREAM_STAGE_DEPTH") and autotune.active():
             # consult-only: a ring depth cannot be measured from inside
-            # one pipeline pass, so entries come from the bench probe
-            # (bench.py autotune) rather than an in-situ search
+            # one pipeline pass, so there is no in-situ search; nothing
+            # in the tree writes this entry (ROADMAP.md D14)
             depth_key = autotune.shape_key(
                 n=first.X.shape[0],
                 d=first.X.shape[1] if first.X.ndim > 1 else 0,
@@ -1383,7 +1383,7 @@ def streamed_kmeans_lloyd(
             "cost": jnp.zeros((), dtype),
         }
         guard = StreamGuard()
-        with telemetry.span("kmeans.lloyd_pass", iteration=_it) as p_span:
+        with telemetry.span("kmeans.lloyd_pass", iteration=_it):
             with contextlib.closing(
                 iter_device_chunks(
                     source, mesh, chunk_rows, dtype, need_y=False, need_w=False
@@ -1395,7 +1395,6 @@ def streamed_kmeans_lloyd(
                     )
                     guard.tick(dev, acc)
             guard.flush(acc)
-            p_span.fence(acc)
         # per-iteration allreduce of (sums, counts, cost) partials — the
         # Lloyd-loop NCCL allreduce; every rank then updates identically
         s_h, c_h, cost_h = allreduce_sum_host(

@@ -27,9 +27,7 @@ Three layers:
   contract). The search is wall-clock bounded by
   ``TPUML_AUTOTUNE_BUDGET_MS``; the heuristic default is always
   measured first, so a truncated search can never do worse than no
-  tuner. Fitness is measured seconds (lower wins); when telemetry is
-  recording, the probe site's roofline stats (mfu / achieved_gbps)
-  ride into the cache entry as diagnostics.
+  tuner. Fitness is measured seconds (lower wins).
 - **resolver hook** — :func:`consult` (cache read) and :func:`tune`
   (consult-else-probe) are checked by every ``auto`` resolver before
   its static heuristic, gated by ``TPUML_AUTOTUNE=off|on|force``.
@@ -476,12 +474,6 @@ def probe(
         "measured": len(scores),
         "default_s": round(scores[0], 6) if 0 in scores else None,
     }
-    if telemetry.enabled():
-        stats = telemetry.span_stats().get(site, {})
-        for diag in ("mfu", "achieved_gbps", "bound"):
-            if diag in stats:
-                extra[diag] = stats[diag]
-
     telemetry.counter("autotune_probes_total").inc(1, knob=knob)
     telemetry.histogram("autotune_probe_ms").observe(elapsed_ms, knob=knob)
     decision = Decision(

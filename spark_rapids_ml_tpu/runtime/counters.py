@@ -5,9 +5,10 @@ metrics layer (:mod:`runtime.telemetry`), with the metric catalog in
 :mod:`runtime.metricspec` — gauge-vs-counter semantics are a property
 of the registered metric, not a name check here. This shim keeps the
 API every call site and test already uses (``bump`` / ``note`` /
-``get`` / ``snapshot`` / ``delta_since`` / ``reset``), so bench.py can
-still attach ``retries`` / ``resumed_from`` columns to every entry and
-tests can still assert the clean path is fully inert (all deltas zero).
+``get`` / ``snapshot`` / ``delta_since`` / ``reset``), so
+``core``'s ``_resilience_report`` keeps its ``retries`` /
+``resumed_from`` deltas and tests can still assert the clean path is
+fully inert (all deltas zero).
 
 Names bumped through this shim must be declared in
 ``runtime/metricspec.py`` — lint rule TPU007 rejects uncataloged metric

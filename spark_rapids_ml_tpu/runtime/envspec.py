@@ -787,16 +787,6 @@ SPEC: Dict[str, EnvVar] = _registry(
         also_documented_in=("docs/observability.md",),
     ),
     EnvVar(
-        "TPUML_TELEMETRY_DEVICE_TIME", "bool", False,
-        "Opt-in device-time fencing: spans that wrap device work call "
-        "`block_until_ready` on close so their duration includes device "
-        "execution, and per-span `device_seconds` aggregates become "
-        "meaningful. Off by default because the fence serializes "
-        "dispatch against the host. Only read when `TPUML_TRACE` is set.",
-        category="observability",
-        also_documented_in=("docs/observability.md",),
-    ),
-    EnvVar(
         "TPUML_TELEMETRY_RETRACE_LIMIT", "int", 16,
         "Retrace-watchdog threshold: warn once per span site when XLA "
         "compilations attributed to it exceed this count in steady state "
@@ -812,25 +802,6 @@ SPEC: Dict[str, EnvVar] = _registry(
         "deterministic last-N window feeding the exported quantiles); "
         "running count/sum/min/max are exact regardless of the bound.",
         minimum=1, category="observability",
-        also_documented_in=("docs/observability.md",),
-    ),
-    EnvVar(
-        "TPUML_PEAK_FLOPS", "float", None,
-        "Per-chip peak FLOP/s used as the roofline MFU denominator "
-        "(`runtime/roofline.py`). Unset = the built-in per-device-kind "
-        "bf16 table (same figures as bench.py). Set it when the "
-        "workload runs a different dtype mix or the device kind is "
-        "missing from the table. Only read when `TPUML_TRACE` is set.",
-        exclusive_minimum=0, category="observability",
-        also_documented_in=("docs/observability.md",),
-    ),
-    EnvVar(
-        "TPUML_PEAK_HBM_GBPS", "float", None,
-        "Per-chip peak HBM bandwidth in GB/s for the roofline "
-        "memory-bound verdict (`runtime/roofline.py`). Unset = the "
-        "built-in per-device-kind table. Only read when `TPUML_TRACE` "
-        "is set.",
-        exclusive_minimum=0, category="observability",
         also_documented_in=("docs/observability.md",),
     ),
     # --- live operations plane (runtime/opsplane.py) ----------------------

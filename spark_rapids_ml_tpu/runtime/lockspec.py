@@ -30,7 +30,7 @@ Rank bands (outermost first — the order a request naturally descends):
 36-47 serving data plane (router fleet, runtime, replicas)
 50s   model registry + admission primitives
 70s   SLO evaluator state
-80s   fault injection, roofline attribution
+80s   fault injection, autotuner
 88+   flight recorder + telemetry registries (innermost leaves —
       every layer above records metrics/spans while holding its own
       lock, so these must never wrap a call back out)
@@ -180,7 +180,7 @@ SPEC: Dict[str, LockSpec] = _registry(
         "SLO burn-rate evaluator tick state; holders snapshot the "
         "telemetry registry and may trigger a flight dump.",
     ),
-    # --- fault injection + roofline ----------------------------------------
+    # --- fault injection + autotuner ---------------------------------------
     LockSpec(
         "faults.plan", 80, "lock",
         f"{_RT}/faults.py", "FaultInjector", "_lock",
@@ -206,16 +206,6 @@ SPEC: Dict[str, LockSpec] = _registry(
         "Autotuner in-memory cache + probe bookkeeping; holders may "
         "file autotune metrics (telemetry band below) but never call "
         "back out into dispatch layers.",
-    ),
-    LockSpec(
-        "roofline.peaks", 84, "lock",
-        f"{_RT}/roofline.py", "", "_PEAK_LOCK",
-        "Resolved per-device peak FLOPs/bandwidth cache.",
-    ),
-    LockSpec(
-        "roofline.state", 85, "lock",
-        f"{_RT}/roofline.py", "", "_LOCK",
-        "Per-site cost-attribution accumulators.",
     ),
     # --- flight recorder + telemetry (innermost leaves) --------------------
     LockSpec(

@@ -161,37 +161,6 @@ SPEC: Dict[str, MetricSpec] = _registry(
         "that report none).",
         labels=("site",),
     ),
-    # --- roofline attribution (PR 10) -------------------------------------
-    MetricSpec(
-        "span_flops_total", "counter",
-        "XLA cost-model FLOPs attributed to each span site, labeled by "
-        "span name: the sum over distinct programs compiled while the "
-        "site was innermost, times the site's call count "
-        "(`runtime/roofline.py`).",
-        labels=("name",),
-    ),
-    MetricSpec(
-        "span_bytes_total", "counter",
-        "XLA cost-model bytes accessed attributed to each span site, "
-        "labeled like `span_flops_total`.",
-        labels=("name",),
-    ),
-    MetricSpec(
-        "span_mfu", "histogram",
-        "Model FLOP/s utilization of each roofline-attributed span "
-        "call: cost-model FLOPs over fenced device seconds times the "
-        "per-chip peak (`TPUML_PEAK_FLOPS` or the built-in device-kind "
-        "table) times device count.",
-        labels=("name",),
-    ),
-    MetricSpec(
-        "span_achieved_gbps", "histogram",
-        "Achieved HBM GB/s of each roofline-attributed span call "
-        "(cost-model bytes over fenced device seconds), compared "
-        "against `TPUML_PEAK_HBM_GBPS` for the compute/memory-bound "
-        "verdict.",
-        labels=("name",),
-    ),
     # --- online serving (PR 11) -------------------------------------------
     MetricSpec(
         "serve_requests_total", "counter",

@@ -6,11 +6,11 @@ threads, streaming stages, and device dispatches:
 - **Spans** — hierarchical wall-clock intervals with ``contextvars``
   parent propagation that survives worker threads (the fold pool in
   ``tuning.py``, the decode/stage threads in ``ops/streaming.py``) via
-  :func:`bind_context`. Wall time is always measured; device time is
-  opt-in (``TPUML_TELEMETRY_DEVICE_TIME``) through a
-  ``block_until_ready`` fence at span close. Spans export as a
-  Chrome-trace/Perfetto JSON plus a JSONL event log under
-  ``TPUML_TRACE=<dir>``.
+  :func:`bind_context`. A span measures wall time; each live span also
+  writes a ``jax.profiler.TraceAnnotation``, so inside a profiler
+  capture it lies on the profiler's clock beside the device's own
+  events. Spans export as a Chrome-trace/Perfetto JSON plus a JSONL
+  event log under ``TPUML_TRACE=<dir>``.
 - **Typed metrics** — counter / gauge / histogram-with-bounded-ring,
   optionally labeled, cataloged in :mod:`metricspec` (lint rule TPU007
   keeps call sites and catalog in sync). The legacy
@@ -20,11 +20,6 @@ threads, streaming stages, and device dispatches:
   active span (``jax.monitoring`` events) and warns once per site past
   ``TPUML_TELEMETRY_RETRACE_LIMIT`` — the runtime enforcement of lint
   rule TPU003.
-- **Roofline attribution** (:mod:`runtime.roofline`) — the same compile
-  listener hands each program's XLA ``cost_analysis()`` to the
-  innermost span site, so closing spans carry measured ``flops_total``
-  / ``bytes_total`` / ``mfu`` attributes and :func:`span_stats` answers
-  compute-bound vs memory-bound per stage.
 - **HBM accounting** — :func:`record_hbm_estimate` files each budget
   resolver's peak estimate (gang fit, tree batch, stream staging) as a
   labeled gauge next to the backend's live memory stats.
@@ -111,10 +106,6 @@ def _recording() -> bool:
 
 def _trace_dir() -> Optional[str]:
     return envspec.get("TPUML_TRACE")
-
-
-def _device_time() -> bool:
-    return bool(envspec.get("TPUML_TELEMETRY_DEVICE_TIME"))
 
 
 def _process_index() -> int:
@@ -319,7 +310,7 @@ _EPOCH: Optional[float] = None  # perf_counter origin of trace timestamps
 _EVENTS: List[Dict[str, Any]] = []  # chrome-trace "X" events
 _PENDING_LINES: List[str] = []  # jsonl lines not yet appended to disk
 _THREADS: Dict[int, str] = {}  # tid -> thread name (trace metadata)
-_STATS: Dict[str, List[float]] = {}  # name -> [count, wall_s, device_s]
+_STATS: Dict[str, List[float]] = {}  # name -> [count, wall_s]
 _ATEXIT_REGISTERED = False
 # span sinks: callables fed every completed span/instant event dict
 # (chrome-trace shape) plus the originating thread name — the ops-plane
@@ -379,9 +370,6 @@ class _NullSpan:
     def set_attr(self, **attrs: Any) -> None:
         return None
 
-    def fence(self, arrays: Any) -> None:
-        return None
-
 
 _NULL = _NullSpan()
 
@@ -395,7 +383,7 @@ _ANNOTATION: Any = None
 
 
 class _Span:
-    """One live span: wall interval + optional device fence + attrs."""
+    """One live span: wall interval + attrs."""
 
     __slots__ = (
         "name",
@@ -404,8 +392,6 @@ class _Span:
         "parent_id",
         "_token",
         "_t0",
-        "device_s",
-        "_fences",
         "tid",
         "thread_name",
         "_annotation",
@@ -414,8 +400,6 @@ class _Span:
     def __init__(self, name: str, attrs: Dict[str, Any]) -> None:
         self.name = name
         self.attrs = attrs
-        self.device_s = 0.0
-        self._fences: List[Any] = []
 
     def __enter__(self) -> "_Span":
         parent = _CURRENT.get()
@@ -456,23 +440,7 @@ class _Span:
     def set_attr(self, **attrs: Any) -> None:
         self.attrs.update(attrs)
 
-    def fence(self, arrays: Any) -> None:
-        """Register device arrays to ``block_until_ready`` at close when
-        ``TPUML_TELEMETRY_DEVICE_TIME`` is on, so the span's duration
-        includes device execution and the blocked wait is accounted as
-        ``device_seconds``."""
-        self._fences.append(arrays)
-
     def __exit__(self, *exc: Any) -> None:
-        if self._fences and _device_time():
-            t_fence = time.perf_counter()
-            try:
-                import jax
-
-                jax.block_until_ready(self._fences)
-                self.device_s = time.perf_counter() - t_fence
-            except Exception:  # fencing must never fail the fit
-                pass
         dur = time.perf_counter() - self._t0
         self._annotation.__exit__(None, None, None)
         _CURRENT.reset(self._token)
@@ -485,8 +453,7 @@ def span(name: str, **attrs: Any) -> Any:
 
     No-op (a shared singleton, no allocation or recording) while
     ``TPUML_TRACE`` is unset and no span sink is attached. The returned
-    object supports ``set_attr(**kw)`` and ``fence(arrays)`` in both
-    modes.
+    object supports ``set_attr(**kw)`` in both modes.
     """
     if not _recording():
         return _NULL
@@ -535,14 +502,6 @@ def bind_context(fn: Any) -> Any:
 def _record(s: _Span, dur: float) -> None:
     global _EPOCH, _ATEXIT_REGISTERED
     root_closed = s.parent_id is None
-    roofline = _ROOFLINE
-    if roofline is not None:
-        try:  # roofline attribution must never fail a span close
-            extra = roofline.annotate(s.name, s.device_s, dur)
-            if extra:
-                s.attrs.update(extra)
-        except Exception:
-            pass
     exporting = enabled()
     with _RLOCK:
         _ACTIVE.pop(s.span_id, None)
@@ -553,8 +512,6 @@ def _record(s: _Span, dur: float) -> None:
         args["span_id"] = s.span_id
         if s.parent_id is not None:
             args["parent_id"] = s.parent_id
-        if s.device_s:
-            args["device_seconds"] = round(s.device_s, 6)
         ev = {
             "name": s.name,
             "ph": "X",
@@ -581,7 +538,6 @@ def _record(s: _Span, dur: float) -> None:
                         "thread": s.thread_name,
                         "ts_us": round(ts_us, 3),
                         "wall_seconds": round(dur, 6),
-                        "device_seconds": round(s.device_s, 6),
                         "attrs": s.attrs,
                     },
                     sort_keys=True,
@@ -590,10 +546,9 @@ def _record(s: _Span, dur: float) -> None:
             )
             st = _STATS.get(s.name)
             if st is None:
-                st = _STATS[s.name] = [0, 0.0, 0.0]
+                st = _STATS[s.name] = [0, 0.0]
             st[0] += 1
             st[1] += dur
-            st[2] += s.device_s
             if not _ATEXIT_REGISTERED:
                 _ATEXIT_REGISTERED = True
                 atexit.register(_atexit_flush)
@@ -683,29 +638,14 @@ def add_span_event(name: str, **attrs: Any) -> None:
 
 
 def span_stats() -> Dict[str, Dict[str, float]]:
-    """Per-span-name running aggregates:
-    ``{name: {count, wall_seconds, device_seconds}}`` (empty while
-    tracing never enabled — the inertness sentinel). Sites with
-    cost-model attribution additionally carry ``flops_total`` /
-    ``bytes_total`` / ``mfu`` / ``achieved_gbps`` / ``bound`` —
-    measured roofline position, absent (never zero/NaN) where the
-    backend reported no cost analysis."""
+    """Per-span-name running aggregates: ``{name: {count,
+    wall_seconds}}`` (empty while tracing never enabled — the inertness
+    sentinel)."""
     with _RLOCK:
-        stats: Dict[str, Dict[str, float]] = {
-            name: {
-                "count": int(st[0]),
-                "wall_seconds": st[1],
-                "device_seconds": st[2],
-            }
+        return {
+            name: {"count": int(st[0]), "wall_seconds": st[1]}
             for name, st in _STATS.items()
         }
-    roofline = _ROOFLINE
-    if roofline is not None and stats:
-        try:
-            return roofline.aggregate(stats)
-        except Exception:
-            pass
-    return stats
 
 
 def flush() -> Optional[str]:
@@ -767,8 +707,7 @@ def flush() -> Optional[str]:
 
 
 def reset_telemetry() -> None:
-    """Clear spans, metrics, watchdog, and roofline state (test
-    isolation)."""
+    """Clear spans, metrics and watchdog state (test isolation)."""
     global _EPOCH
     with _RLOCK:
         _EPOCH = None
@@ -782,8 +721,6 @@ def reset_telemetry() -> None:
     with _WD_LOCK:
         _WD_COUNTS.clear()
         _WD_WARNED.clear()
-    if _ROOFLINE is not None:
-        _ROOFLINE.reset_roofline()
 
 
 # --------------------------------------------------------------------------
@@ -1024,11 +961,6 @@ _WD_INSTALLED = False
 _WD_CHECKED = False
 _WD_COUNTS: Dict[str, int] = {}
 _WD_WARNED: set = set()
-# the roofline module once installed (span-close annotation), and its
-# compile-event consumer (cost attribution) — both None until the first
-# enabled span installs the hooks, keeping import and defaults inert
-_ROOFLINE: Any = None
-_ROOFLINE_CONSUME: Any = None
 
 
 def _retrace_limit() -> int:
@@ -1055,18 +987,10 @@ def _on_event_duration(event: str, duration: float, **kw: Any) -> None:
     if event != _COMPILE_EVENT:
         return
     try:  # a listener exception would poison every jax compile
-        cur = _CURRENT.get()
-        site = cur.name if cur is not None else "<untraced>"
-        consume = _ROOFLINE_CONSUME
-        if consume is not None:
-            # hand the just-compiled program's cost analysis (stashed by
-            # the roofline compile hook on this same thread) to the
-            # innermost span site — the attribution moment. Runs even
-            # while the watchdog is dormant: the pending list is
-            # thread-local and would otherwise grow without bound.
-            consume(site)
         if not _watchdog_active():
             return
+        cur = _CURRENT.get()
+        site = cur.name if cur is not None else "<untraced>"
         counter("xla_compiles").inc(1, site=site)
         histogram("xla_compile_seconds").observe(duration, site=site)
         if cur is not None and cur.attrs.get("warmup"):
@@ -1119,11 +1043,10 @@ def install_retrace_watchdog() -> bool:
 
 
 def _ensure_hooks() -> None:
-    """Install the compile-event hooks (retrace watchdog + roofline
-    cost capture) and the crash-path atexit flush on the first enabled
-    span; cheap after the first call."""
-    global _WD_CHECKED, _ROOFLINE, _ROOFLINE_CONSUME, _ATEXIT_REGISTERED
-    global _ANNOTATION
+    """Bind ``TraceAnnotation``, install the retrace watchdog's
+    compile-event listener and register the crash-path atexit flush on
+    the first live span; cheap after the first call."""
+    global _WD_CHECKED, _ATEXIT_REGISTERED, _ANNOTATION
     if _WD_CHECKED:
         return
     from jax.profiler import TraceAnnotation
@@ -1132,14 +1055,6 @@ def _ensure_hooks() -> None:
     _WD_CHECKED = True
     if _retrace_limit() > 0:
         install_retrace_watchdog()
-    try:
-        from . import roofline
-
-        if roofline.install():
-            _ROOFLINE_CONSUME = roofline._consume_pending
-            _ROOFLINE = roofline
-    except Exception:  # roofline degrades to absent, never breaks spans
-        pass
     with _RLOCK:
         if not _ATEXIT_REGISTERED:
             _ATEXIT_REGISTERED = True

@@ -189,9 +189,9 @@ class ServingRuntime:
             and autotune.active()
         ):
             # consult-only: the window trades p99 against batch fill, so
-            # winners come from the serving bench probe (bench.py
-            # autotune) where both ends of the trade are measured —
-            # never from inside a live runtime's constructor
+            # a winner needs both ends of the trade measured — never from
+            # inside a live runtime's constructor; nothing in the tree
+            # writes this entry (ROADMAP.md D14)
             tune_key = autotune.shape_key(k=MIN_BUCKET_ROWS)
             tuned = autotune.consult("serve_batch_window_us", tune_key)
             if isinstance(tuned, int) and 0 <= tuned <= 100_000:

@@ -14,8 +14,8 @@ holds it, and a child that needs it then fails or hangs. Entry points
 therefore run everything in one process.
 
 :func:`enable_compile_cache` is the single rule for the persistent
-compilation cache, called by ``chip_smoke.py``, ``bench.py``,
-``benchmark_runner.py`` and ``tests/conftest.py`` alike.
+compilation cache, called by ``chip_smoke.py``, ``benchmark_runner.py``
+and ``tests/conftest.py`` alike.
 """
 
 from __future__ import annotations

@@ -12,8 +12,7 @@ and the Python benchmarks do phase wall-clock timing
   around the solver dispatch, ``<Model>.transform``. Every live
   ``runtime.telemetry`` span writes its own as ``tpuml:<span name>``.
 * :func:`trace` — capture a TensorBoard profile of a code region into a
-  directory (``tensorboard --logdir <dir>`` → Profile tab). Used by
-  ``bench.py`` when ``BENCH_PROFILE_DIR`` is set.
+  directory (``tensorboard --logdir <dir>`` → Profile tab).
 * :class:`StageTimer` — accumulating per-stage breakdown; each stage is
   also a ``runtime.telemetry`` span, so the report dicts built from
   ``totals`` and the exported trace see the same measurement.

@@ -6,7 +6,7 @@ that does not fit HBM). These tests hand the kernel functions themselves
 (``interpret=False``; the ``*_ok`` gates ask ``jax.default_backend()`` and
 would take the CPU branch here) to the installed TPU compiler at the widths
 ``chip_smoke.py`` runs, plus — compile only, they are off the smoke path —
-the RF and UMAP kernels at ``bench.py``'s shapes. A compile that passes is
+the RF and UMAP kernels at 131,072 x 256. A compile that passes is
 not a chip run.
 
 Rules (on-chip-measurement guide, section 2): the topology is described
@@ -200,7 +200,7 @@ def test_knn_ring_compiles_for_four_chips(four_chips, monkeypatch):
     assert "tpu_custom_call" in txt and "collective-permute" in txt
 
 
-# ---- off the smoke path: compile only, bench.py's RF / UMAP shapes -------
+# ---- off the smoke path: compile only, RF / UMAP kernels -----------------
 
 
 def test_rf_subblock_hist_kernel_compiles(one_chip):
@@ -219,7 +219,7 @@ def test_rf_packed_traverse_kernel_compiles(one_chip):
     """Depth 13 (k1=7, k2=6), d=256 (64 packed words), 131,072 rows. The
     kernel unrolls a static loop over every tree and its compile time grows
     faster than the tree count (here: 4 s at 1 tree, 7 s at 2, 80 s at 8 —
-    the smallest forest the gate admits — and minutes at bench.py's 56; see
+    the smallest forest the gate admits — and minutes at 56; see
     CHANGES.md PR 22). Two trees run the lockstep loop twice and keep this
     case inside tier-1's clock."""
     from spark_rapids_ml_tpu.ops.rf_pallas import packed_traverse

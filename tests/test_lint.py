@@ -5,6 +5,7 @@ mechanics, envspec parse semantics, and the whole-repo integration run
 import ast
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -496,7 +497,7 @@ def test_tpu009_allows_parallel_package_and_out_of_scope_paths():
         "spark_rapids_ml_tpu/parallel/layout.py",
         "spark_rapids_ml_tpu/parallel/mesh.py",
         "tests/test_mesh2d.py",
-        "bench.py",
+        "benchmark_runner.py",
     ):
         assert lint_snippet(tpu009_inline_pspec, code, path=path) == []
 
@@ -623,7 +624,7 @@ def _run_lint(*args, cwd=REPO_ROOT):
 
 def test_repo_lints_clean():
     """The acceptance gate: the tree has zero non-baselined findings."""
-    r = _run_lint("spark_rapids_ml_tpu", "tests", "bench.py")
+    r = _run_lint("spark_rapids_ml_tpu", "tests", "benchmark_runner.py")
     assert r.returncode == 0, r.stdout + r.stderr
 
 
@@ -685,3 +686,39 @@ def test_gen_config_docs_check_mode():
         cwd=REPO_ROOT, capture_output=True, text=True,
     )
     assert r.returncode == 0, r.stdout + r.stderr
+
+
+# a repo-relative script path: not part of a longer word, an absolute
+# path, or a shell expansion
+_SCRIPT_PATH_RE = re.compile(
+    r"(?<![\w/.$}-])((?:\./)?(?:[\w-]+/)*[\w-]+\.(?:py|sh))\b"
+)
+_LINT_TARGETS_RE = re.compile(r"python -m (?:compileall|tpuml_lint)\b(.*)")
+
+
+@pytest.mark.parametrize(
+    "script",
+    ["ci/test.sh", "run_benchmark.sh", "run_benchmark_multihost.sh"],
+)
+def test_shell_entry_points_name_files_that_exist(script):
+    """Every repo-relative ``*.py`` / ``*.sh`` a shell entry point runs
+    (heredoc bodies included), and every path it hands to ``compileall``
+    or ``tpuml_lint``, is in the tree."""
+    with open(os.path.join(REPO_ROOT, script)) as f:
+        text = f.read().replace("\\\n", " ")
+    named = set()
+    for line in text.splitlines():
+        if line.lstrip().startswith("#"):
+            continue
+        named.update(_SCRIPT_PATH_RE.findall(line))
+        targets = _LINT_TARGETS_RE.search(line)
+        if targets:
+            named.update(
+                a for a in targets.group(1).split()
+                if not a.startswith("-") and not re.fullmatch(r"TPU\d+", a)
+            )
+    assert named, script
+    missing = sorted(
+        n for n in named if not os.path.exists(os.path.join(REPO_ROOT, n))
+    )
+    assert not missing, f"{script} names files that do not exist: {missing}"

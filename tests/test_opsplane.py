@@ -21,7 +21,7 @@ import pytest
 
 from spark_rapids_ml_tpu.data import DataFrame
 from spark_rapids_ml_tpu.models.clustering import KMeans
-from spark_rapids_ml_tpu.models.feature import PCA
+from spark_rapids_ml_tpu.models.regression import LinearRegression
 from spark_rapids_ml_tpu.runtime import opsplane, telemetry
 from spark_rapids_ml_tpu.serving import ModelRegistry
 
@@ -50,10 +50,14 @@ def _clean_plane(monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def pca_model():
+def linreg_model():
+    """A resident that coalesces on the CPU backend too: the registry's
+    pad-invariance probe passes for it there (PCA's k=3 projection does
+    not, and would serve exact shapes with nothing to warm)."""
     rng = np.random.default_rng(5)
     X = rng.normal(size=(96, 6)).astype(np.float32)
-    return PCA(k=3).fit(DataFrame({"features": X}))
+    y = (X @ rng.normal(size=6)).astype(np.float32)
+    return LinearRegression().fit(DataFrame({"features": X, "label": y}))
 
 
 def _get(path):
@@ -239,7 +243,7 @@ def test_live_scrape_during_streamed_kmeans_fit(monkeypatch):
 # --- readiness -------------------------------------------------------------
 
 
-def test_readyz_flips_on_registry_warmup(monkeypatch, pca_model):
+def test_readyz_flips_on_registry_warmup(monkeypatch, linreg_model):
     monkeypatch.setenv("TPUML_OPS_PORT", "0")
     monkeypatch.setenv("TPUML_SLO_EVAL_MS", "60000")
     assert opsplane.ensure_started()
@@ -249,8 +253,8 @@ def test_readyz_flips_on_registry_warmup(monkeypatch, pca_model):
     assert code == 200 and json.loads(body)["ready"]
 
     reg = ModelRegistry(warmup=False)
-    entry = reg.register("pca", pca_model)
-    assert entry.coalesce  # premise: pca coalesces on this backend
+    entry = reg.register("linreg", linreg_model)
+    assert entry.coalesce  # premise: linreg coalesces on this backend
 
     code, _, body = _get("/readyz")
     assert code == 503
@@ -260,7 +264,7 @@ def test_readyz_flips_on_registry_warmup(monkeypatch, pca_model):
     code, _, body = _get("/statusz")
     st = json.loads(body)
     assert st["ready"] is False
-    assert st["registries"][0]["models"]["pca"]["pending_buckets"]
+    assert st["registries"][0]["models"]["linreg"]["pending_buckets"]
 
     reg.warm(entry)
     code, _, body = _get("/readyz")

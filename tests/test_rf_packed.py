@@ -5,10 +5,6 @@ two-hop bins descent — leaf routing is integer comparisons and the
 payload reduction replicates the bins path's association exactly, so
 equality is exact, not approximate."""
 
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
@@ -318,40 +314,3 @@ def test_export_random_forest_packed():
         assert pk["feat2"].shape == (pk["feat1"].shape[0] * (1 << k1), 64)
     with pytest.raises(TypeError):
         random_forest_packed(object())
-
-
-# ---------------------------------------------------------------------------
-# bench smoke
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_bench_rf_transform_smoke(tmp_path):
-    """bench.py at smoke scale must emit rf.transform_vs_baseline (the
-    packed-engine serving metric) and umap.transform_vs_baseline —
-    BENCH_REQUIRE_TRANSFORM=rf makes a silently dropped rf transform
-    figure a nonzero exit."""
-    import json
-
-    env = dict(os.environ)
-    env.update(
-        JAX_PLATFORMS="cpu",
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        BENCH_ONLY="rf,umap",
-        BENCH_REQUIRE_TRANSFORM="rf",
-        BENCH_ROWS="4096",
-        BENCH_RF_ROWS="4096",
-        BENCH_RF_TREES="4",
-        BENCH_RF_DEPTH="8",
-        BENCH_UMAP_ROWS="1024",
-    )
-    proc = subprocess.run(
-        [sys.executable, "bench.py"],
-        capture_output=True, text=True, timeout=900, env=env, cwd="/root/repo",
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    rf = line["rf"]
-    assert "transform_vs_baseline" in rf
-    assert rf["transform_engine"] in ("packed", "bins")
-    assert "transform_vs_baseline" in line["umap"]

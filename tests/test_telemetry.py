@@ -1,11 +1,17 @@
 """Telemetry runtime: span nesting (including across threads), trace
 export validity, Prometheus format, histogram ring bounds, the retrace
-watchdog, the counters shim's kind-aware deltas, and the defaults-inert
-contract (env unset => no files, no spans, bit-identical results)."""
+watchdog, the counters shim's kind-aware deltas, the defaults-inert
+contract (env unset => no files, no spans, bit-identical results), span
+events from retries and injected faults, the crash-path flush, and the
+multi-host merge (parity between scripts/merge_traces.py and
+telemetry.merge_metric_snapshots)."""
 
+import importlib.util
 import json
 import logging
 import os
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -16,7 +22,10 @@ import jax.numpy as jnp
 
 from spark_rapids_ml_tpu.clustering import KMeans
 from spark_rapids_ml_tpu.data import DataFrame
-from spark_rapids_ml_tpu.runtime import counters, telemetry
+from spark_rapids_ml_tpu.runtime import counters, faults, telemetry
+from spark_rapids_ml_tpu.runtime.retry import with_retries
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -31,6 +40,15 @@ def traced(tmp_path, monkeypatch):
     """Enable tracing into a per-test directory."""
     monkeypatch.setenv("TPUML_TRACE", str(tmp_path))
     return tmp_path
+
+
+def _load_by_path(name):
+    spec = importlib.util.spec_from_file_location(
+        f"_test_{name}", os.path.join(REPO_ROOT, "scripts", f"{name}.py")
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _load_trace(tdir):
@@ -291,7 +309,6 @@ def test_defaults_inert_no_spans_no_files(tmp_path, monkeypatch):
     assert telemetry.span("a") is telemetry.span("b", k=1)
     with telemetry.span("a") as sp:
         sp.set_attr(x=1)
-        sp.fence(None)
     assert telemetry.span_stats() == {}
     assert telemetry.flush() is None
     assert telemetry.write_metrics() is None
@@ -312,3 +329,244 @@ def test_traced_fit_bit_identical_to_untraced(tmp_path, monkeypatch):
     monkeypatch.setenv("TPUML_TRACE", str(tmp_path))
     traced = centers()
     assert plain.tobytes() == traced.tobytes()
+
+
+# --- histogram quantile edge cases -----------------------------------------
+
+
+def test_quantile_empty_and_single_sample():
+    h = telemetry._Hist(8)
+    assert h.quantile(0.5) is None  # empty: None, not IndexError
+    h.observe(3.0)
+    for q in (-1.0, 0.0, 0.5, 1.0, 2.0):  # single sample: any q, clamped
+        assert h.quantile(q) == 3.0
+    h.observe(5.0)
+    assert h.quantile(0.0) == 3.0
+    assert h.quantile(1.0) == 5.0
+
+
+# --- span events: retries + fault injection --------------------------------
+
+
+def test_retry_records_span_event(traced):
+    calls = []
+
+    def boom():
+        calls.append(1)
+        if len(calls) < 2:
+            raise ValueError("transient")
+        return 42
+
+    with telemetry.span("retry.root"):
+        out = with_retries(
+            boom, what="test-op", retries=2, backoff_ms=0.01,
+            sleep=lambda _s: None,
+        )
+    assert out == 42
+    telemetry.flush()
+
+    doc = _load_trace(traced)
+    points = [e for e in doc["traceEvents"] if e.get("ph") == "i"]
+    assert len(points) == 1
+    ev = points[0]
+    assert ev["name"] == "retry"
+    assert ev["args"]["what"] == "test-op"
+    assert ev["args"]["attempt"] == 1
+    assert "transient" in ev["args"]["error"]
+    root = next(
+        e for e in doc["traceEvents"]
+        if e.get("ph") == "X" and e["name"] == "retry.root"
+    )
+    assert ev["args"]["span_id"] == root["args"]["span_id"]
+
+    logs = [f for f in os.listdir(traced) if f.startswith("events-")]
+    with open(os.path.join(traced, logs[0])) as f:
+        lines = [json.loads(line) for line in f]
+    assert any(
+        rec["event"] == "point" and rec["name"] == "retry" for rec in lines
+    )
+
+
+def test_fault_injection_records_event_and_counter(traced, monkeypatch):
+    monkeypatch.setenv("TPUML_FAULT_SPEC", "ingest:chunk:0:raise")
+    faults.reset_faults()
+    try:
+        with telemetry.span("faulty.fit"):
+            with pytest.raises(faults.InjectedFault):
+                faults.fault_site("ingest:chunk")
+    finally:
+        faults.reset_faults()
+    telemetry.flush()
+
+    assert telemetry.counter("fault_injections").value(kind="raise") == 1
+    doc = _load_trace(traced)
+    ev = next(
+        e for e in doc["traceEvents"]
+        if e.get("ph") == "i" and e["name"] == "fault_injected"
+    )
+    assert ev["args"]["site"] == "ingest:chunk"
+    assert ev["args"]["action"] == "raise"
+
+
+def test_add_span_event_noop_untraced(tmp_path, monkeypatch):
+    monkeypatch.delenv("TPUML_TRACE", raising=False)
+    telemetry.add_span_event("retry", what="x")
+    assert telemetry.flush() is None
+    assert os.listdir(tmp_path) == []
+
+
+# --- crash-path flush ------------------------------------------------------
+
+
+def test_atexit_flush_survives_crash(tmp_path):
+    """An unhandled exception mid-run must still leave the trace shard
+    AND a metric snapshot on disk (the atexit flush), even though
+    write_metrics was never called."""
+    prog = (
+        "from spark_rapids_ml_tpu.runtime import telemetry\n"
+        "with telemetry.span('crash.victim'):\n"
+        "    pass\n"
+        "telemetry.counter('retries').inc(5)\n"
+        "raise RuntimeError('boom')\n"
+    )
+    env = dict(os.environ)
+    env.update(JAX_PLATFORMS="cpu", TPUML_TRACE=str(tmp_path))
+    r = subprocess.run(
+        [sys.executable, "-c", prog], env=env, cwd=REPO_ROOT,
+        capture_output=True, text=True,
+    )
+    assert r.returncode != 0 and "boom" in r.stderr
+    names = os.listdir(tmp_path)
+    traces = [f for f in names if f.startswith("trace-")]
+    metrics = [f for f in names if f.startswith("metrics-") and f.endswith(".json")]
+    assert len(traces) == 1 and len(metrics) == 1, names
+    with open(os.path.join(tmp_path, metrics[0])) as f:
+        snap = json.load(f)
+    assert snap["retries"]["series"][0]["value"] == 5
+
+
+# --- multi-host aggregation ------------------------------------------------
+
+
+def _sample_snapshots():
+    return [
+        {
+            "retries": {"kind": "counter",
+                        "series": [{"labels": {}, "value": 2}]},
+            "hbm_budget_bytes": {
+                "kind": "gauge",
+                "series": [{"labels": {"site": "gang_fit"}, "value": 10.0}],
+            },
+            "span_seconds": {
+                "kind": "histogram",
+                "series": [{"labels": {"name": "fit"}, "count": 3,
+                            "sum": 1.5, "min": 0.1, "max": 1.0, "p50": 0.4}],
+            },
+        },
+        {
+            "retries": {"kind": "counter",
+                        "series": [{"labels": {}, "value": 5}]},
+            "hbm_budget_bytes": {
+                "kind": "gauge",
+                "series": [{"labels": {"site": "gang_fit"}, "value": 30.0}],
+            },
+            "span_seconds": {
+                "kind": "histogram",
+                "series": [{"labels": {"name": "fit"}, "count": 1,
+                            "sum": 2.0, "min": 2.0, "max": 2.0, "p50": 2.0}],
+            },
+        },
+    ]
+
+
+def test_merge_metric_snapshots_rules():
+    merged = telemetry.merge_metric_snapshots(_sample_snapshots())
+    assert merged["retries"]["series"][0]["value"] == 7  # counters SUM
+    assert merged["hbm_budget_bytes"]["series"][0]["value"] == 30.0  # gauge MAX
+    h = merged["span_seconds"]["series"][0]
+    assert h["count"] == 4 and h["sum"] == 3.5
+    assert h["min"] == 0.1 and h["max"] == 2.0
+    assert "p50" not in h  # per-rank ring quantiles cannot merge — dropped
+
+
+def test_merge_traces_script_parity_and_tracks():
+    mt = _load_by_path("merge_traces")
+    snaps = _sample_snapshots()
+    assert mt.merge_metric_snapshots(snaps) == telemetry.merge_metric_snapshots(
+        snaps
+    )
+
+    def shard(rank, pid):
+        return {
+            "traceEvents": [
+                {"ph": "M", "name": "process_name", "pid": pid, "tid": 0,
+                 "args": {"name": "spark_rapids_ml_tpu"}},
+                {"name": "fit", "ph": "X", "ts": 0.0, "dur": 5.0,
+                 "pid": pid, "tid": 1, "args": {"span_id": 1}},
+            ],
+            "metadata": {"process_index": rank},
+        }
+
+    merged = mt.merge_trace_docs([shard(0, 111), shard(1, 222)])
+    assert merged["metadata"]["hosts"] == [0, 1]
+    tracks = {
+        e["pid"]: e["args"]["name"]
+        for e in merged["traceEvents"]
+        if e.get("ph") == "M" and e.get("name") == "process_name"
+    }
+    assert set(tracks) == {0, 1}
+    assert "111" in tracks[0] and "222" in tracks[1]
+    xs = [e for e in merged["traceEvents"] if e.get("ph") == "X"]
+    assert {e["pid"] for e in xs} == {0, 1}  # events remapped to rank pids
+
+
+def test_aggregate_metrics_single_process_degrades_to_local(traced):
+    telemetry.counter("retries").inc(3)
+    agg = telemetry.aggregate_metrics()
+    assert agg == telemetry.merge_metric_snapshots(
+        [telemetry.metrics_snapshot()]
+    )
+    assert agg["retries"]["series"][0]["value"] == 3
+
+
+# --- a live span installs nothing in jax -----------------------------------
+
+
+@pytest.fixture(params=["sink", "trace_env"])
+def live_events(request, tmp_path, monkeypatch):
+    """Make spans live one of the two ways; returns a callable giving
+    the completed span events (from the sink, or from the trace file)."""
+    monkeypatch.delenv("TPUML_TRACE", raising=False)
+    if request.param == "sink":
+        events = []
+        telemetry.add_span_sink(lambda ev, _thread: events.append(ev))
+        return lambda: events
+    monkeypatch.setenv("TPUML_TRACE", str(tmp_path))
+
+    def from_trace_file():
+        telemetry.flush()
+        return _load_trace(tmp_path)["traceEvents"]
+
+    return from_trace_file
+
+
+def test_live_spans_patch_nothing_in_jax(live_events):
+    from jax._src import compiler
+
+    compile_entry = compiler.backend_compile_and_load
+
+    @jax.jit
+    def f(x):
+        return (x @ x.T).sum()
+
+    with telemetry.span("live.victim", rows=16) as sp:
+        sp.set_attr(cols=4)
+        f(jnp.ones((16, 4), jnp.float32)).block_until_ready()
+
+    assert compiler.backend_compile_and_load is compile_entry
+    assert compile_entry.__module__ == "jax._src.compiler"  # jax's own
+    (ev,) = [e for e in live_events() if e["name"] == "live.victim"]
+    # what the call site set, plus the span's own id: no cost model, no fence
+    assert ev["args"] == {
+        "rows": 16, "cols": 4, "span_id": ev["args"]["span_id"],
+    }

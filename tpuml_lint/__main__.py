@@ -58,7 +58,7 @@ def main(argv: List[str] = None) -> int:
 
     if not args.paths:
         ap.error("no paths given (try: python -m tpuml_lint "
-                 "spark_rapids_ml_tpu tests bench.py)")
+                 "spark_rapids_ml_tpu tests benchmark_runner.py)")
 
     repo_root = repo_root_from(os.getcwd()) or repo_root_from(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
