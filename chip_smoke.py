@@ -474,7 +474,7 @@ def phase_logreg(ctx) -> dict:
         "smoke_seconds": {"fit": t_fit, "reference_newton_device": t_ref, "transform_sub": t_tr},
         "objective": {"fit": obj_fit, "reference": obj_ref, "accuracy": acc, "accuracy_reference": acc_ref},
         "checks": checks,
-        "kernel": gate("logreg_pallas_ok", logreg_pallas_ok(D, 1, jnp.float32), ctx["on_tpu"]),
+        "kernel": gate("logreg_pallas_ok", logreg_pallas_ok(len(X) // ctx["chips"], D, 1, jnp.float32), ctx["on_tpu"]),
         "memory": mem,
     }
 

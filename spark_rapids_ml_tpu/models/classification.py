@@ -43,7 +43,7 @@ from ..params import (
     TypeConverters,
     _mk,
 )
-from ..ops.logreg_kernels import logreg_fit, logreg_fit_batched, logreg_predict
+from ..ops.logreg_kernels import logreg_fit, logreg_fit_batched, logreg_predict, objective_read_dtype
 from ..runtime import envspec, telemetry
 from ..utils.logging import get_logger
 
@@ -267,17 +267,20 @@ class LogisticRegression(
             l1_ratio = float(params["l1_ratio"])
             # the gate logreg_fit takes at trace time, asked again on the
             # host so the span says which loss+gradient the program runs
-            from ..ops.logreg_pallas import logreg_pallas_declined
+            from ..ops.logreg_pallas import binary_pass_tile, logreg_pallas_declined
+            from ..parallel.mesh import DP_AXIS
 
+            shard = (inputs.X.shape[0] // inputs.mesh.shape[DP_AXIS], inputs.X.shape[1])
+            device = inputs.mesh.devices.flat[0]
+            objective_dtype = _resolve_objective_dtype(params)
             declined = logreg_pallas_declined(
-                inputs.X.shape[1], n_classes if multinomial else 1, inputs.X.dtype
+                *shard, n_classes if multinomial else 1,
+                objective_read_dtype(inputs.X, inputs.mesh, objective_dtype), device,
             )
-            with telemetry.span(
-                "solver.launch",
-                program=logreg_fit.__name__,
-                loss_grad="xla_autodiff" if declined else "pallas_fused",
-                **({"declined": declined} if declined else {}),
-            ):
+            launch = {"loss_grad": "xla_autodiff", "declined": declined} if declined else {"loss_grad": "pallas_fused"}
+            if not declined and not multinomial:
+                launch["tile"] = binary_pass_tile(*shard, device)[0]
+            with telemetry.span("solver.launch", program=logreg_fit.__name__, **launch):
                 out = logreg_fit(
                     inputs.X,
                     inputs.mask,
@@ -296,7 +299,7 @@ class LogisticRegression(
                     mesh=inputs.mesh,
                     # bf16 objective reads (f32 accumulation) via framework
                     # kwarg or env; default full f32
-                    objective_dtype=_resolve_objective_dtype(params),
+                    objective_dtype=objective_dtype,
                 )
             # the first fetch blocks until the frame is on the device and
             # the solver's program has run

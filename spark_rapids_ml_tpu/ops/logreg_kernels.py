@@ -32,7 +32,25 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from ..parallel.mesh import DP_AXIS
 from .lbfgs import minimize_lbfgs, minimize_lbfgs_batched
+
+
+def objective_read_dtype(X, mesh, objective_dtype: str):
+    """dtype of the X copy ``logreg_fit``'s objective reads: X's own, or bf16
+    where ``objective_dtype="bfloat16"`` asks for it over an f32 X small
+    enough to convert in the program. The near-HBM-capacity guard: the
+    convert holds the f32 argument AND the bf16 copy live — per chip, so the
+    budget is the PER-DEVICE shard (global bytes / dp size on a mesh). Past
+    ~1 GB per device callers must pass X in bf16 instead (zero-copy; the
+    estimator's ``_x_placement_dtype`` hook does exactly that)."""
+    n_dp = dict(mesh.shape).get(DP_AXIS, 1) if mesh is not None else 1
+    narrow = (
+        objective_dtype == "bfloat16"
+        and X.dtype == jnp.float32
+        and X.size * X.dtype.itemsize // max(n_dp, 1) <= (1 << 30)
+    )
+    return jnp.dtype(jnp.bfloat16) if narrow else X.dtype
 
 
 @functools.partial(
@@ -71,15 +89,22 @@ def logreg_fit(
     n_evals (loss+gradient evaluations, ``ops/lbfgs.LbfgsResult``),
     objective. K=1 for the binomial (sigmoid) formulation, else n_classes.
 
-    With ``mesh`` (rows dp-sharded over it) and qualifying shapes on TPU,
-    the per-evaluation data pass runs through the fused Pallas loss+grad
-    kernel (``ops/logreg_pallas.py``) — one HBM read of X per L-BFGS
-    objective evaluation instead of autodiff's forward+backward two.
+    With ``mesh`` (rows dp-sharded over it) on a TPU the data term of every
+    L-BFGS evaluation is ONE read of X through a fused Pallas loss+gradient
+    pass (``ops/logreg_pallas.py``) instead of autodiff's forward and
+    backward pass, chosen from K, the dtype of X and the backend:
+
+    * binomial (K = 1), f32 X, any width: float32 multiplies and adds on
+      the VPU — as exact as the XLA path below, which it replaces;
+    * multinomial (K >= 3), f32/bf16 X, lane-aligned d <= 2048: two MXU
+      products at default precision (one bf16 pass with f32 accumulation);
+    * anything else (no mesh, no TPU, a bf16-placed X with K = 1, a wide
+      multinomial): XLA's two passes — for K = 1 a float32 multiply-reduce
+      on the VPU, for K >= 3 a ``dot`` at default precision.
 
     ``objective_dtype="bfloat16"`` stores the X copy the objective reads
     in bf16 (statistics, parameters and accumulation stay f32): the
-    bandwidth-bound eval reads half the HBM bytes — the TPU analog of the
-    TF32 tensor-core reads cuML gets implicitly on Ampere. Per-element
+    bandwidth-bound eval reads half the HBM bytes. Per-element
     rounding is ~1e-2 relative but i.i.d. across rows, so gradient sums
     see it averaged down by sqrt(n); solution drift at bench scales is
     well inside the solver tolerance.
@@ -141,18 +166,10 @@ def logreg_fit(
         )
     X_obj = X
     if objective_dtype == "bfloat16" and X.dtype == jnp.float32:
-        # near-HBM-capacity guard: the in-program convert holds the f32
-        # argument AND the bf16 copy live — per chip, so the budget is the
-        # PER-DEVICE shard (global bytes / dp size on a mesh). Past ~1 GB
-        # per device callers must pass X in bf16 instead (zero-copy here;
-        # the estimator's ``_x_placement_dtype`` hook does exactly that).
-        # The skip is trace-time, so the warning fires once per shape.
-        from ..parallel.mesh import DP_AXIS
-
-        n_dp = dict(mesh.shape).get(DP_AXIS, 1) if mesh is not None else 1
-        if X.size * X.dtype.itemsize // max(n_dp, 1) <= (1 << 30):
+        if objective_read_dtype(X, mesh, objective_dtype) == jnp.bfloat16:
             X_obj = X.astype(jnp.bfloat16)
         else:
+            # trace-time, so the warning fires once per shape
             from ..utils.logging import get_logger
 
             get_logger("logreg_fit").warning(
@@ -164,7 +181,9 @@ def logreg_fit(
             )
 
     fused_data = None
-    if mesh is not None and logreg_pallas_ok(d, K, X_obj.dtype):
+    if mesh is not None and logreg_pallas_ok(
+        X.shape[0] // mesh.shape[DP_AXIS], d, K, X_obj.dtype, mesh.devices.flat[0]
+    ):
         fused_data = make_fused_data_loss(
             X_obj, yf, mask, mesh, K, multinomial
         )
@@ -353,14 +372,8 @@ def logreg_fit_batched(
         raise ValueError(
             f"objective_dtype must be float32|bfloat16, got {objective_dtype!r}"
         )
-    X_obj = X
-    if objective_dtype == "bfloat16" and X.dtype == jnp.float32:
-        # same residency guard as the solo kernel (see logreg_fit)
-        from ..parallel.mesh import DP_AXIS
-
-        n_dp = dict(mesh.shape).get(DP_AXIS, 1) if mesh is not None else 1
-        if X.size * X.dtype.itemsize // max(n_dp, 1) <= (1 << 30):
-            X_obj = X.astype(jnp.bfloat16)
+    # same residency guard as the solo kernel
+    X_obj = X.astype(objective_read_dtype(X, mesh, objective_dtype))
 
     def smooth_loss(W: jax.Array) -> jax.Array:
         A, b = unpack(W)

@@ -133,14 +133,39 @@ def test_narrow_block_is_written_without_a_second_copy(one_chip, block_rows):
     assert mem.temp_size_in_bytes < (32 << 20)
 
 
-def test_logreg_loss_grad_kernel_compiles(one_chip):
+@pytest.mark.parametrize(
+    "rows,cols,minor_rows",
+    [(ROWS, D, False), (500_000, 3000, True), (5_000, 3000, False), (4_100, 300, True)],
+    ids=["smoke_shape", "reference_shape", "unaligned_width_row_major", "width_no_multiple_of_8"],
+)
+def test_logreg_loss_grad_kernel_compiles(topo, one_chip, rows, cols, minor_rows):
+    """The binary pass (float32 on the VPU) reads a shard as the described
+    v5e keeps it: ``chip_smoke.py``'s 4,194,304 x 256 row-major, ``logreg_dbx``'s
+    500,000 x 3000 with its ROWS minor (as its transpose, a bitcast); 5,000 x
+    3000 pads the same either way and stays row-major, with a last lane tile
+    of 56 columns. Whichever way, no copy of the frame stands in front of the
+    kernel: the program's temporaries stay under 64 MiB."""
+    from spark_rapids_ml_tpu.ops import logreg_pallas as lp
+
+    assert lp.rows_minor(topo.devices[0], rows, cols) == minor_rows
+    assert lp.binary_tile(cols, minor_rows)[0] > 0
+    fn = jax.jit(
+        lambda X, y, m, a, b: lp.binary_loss_grad(X, y, m, a, b, minor_rows=minor_rows, interpret=False)
+    )
+    c = fn.lower(
+        one_chip((rows, cols)), one_chip((rows,)), one_chip((rows,)), one_chip((cols,)), one_chip(()),
+    ).compile()
+    assert _has_kernel(c)
+    assert c.memory_analysis().temp_size_in_bytes < (64 << 20)
+
+
+def test_logreg_multinomial_kernel_compiles(one_chip):
     from spark_rapids_ml_tpu.ops.logreg_pallas import _loss_grad_pallas, _row_tile
 
-    Kp = 8  # binary: one class row, sublane-padded
+    Kp = 8  # three classes, sublane-padded
     fn = jax.jit(
         lambda X, y, m, A, b: _loss_grad_pallas(
-            X, y, m, A, b, multinomial=False, n_valid_classes=1,
-            tile=_row_tile(D, Kp), interpret=False,
+            X, y, m, A, b, n_valid_classes=3, tile=_row_tile(D, Kp), interpret=False,
         )
     )
     c = fn.lower(
@@ -148,6 +173,37 @@ def test_logreg_loss_grad_kernel_compiles(one_chip):
         one_chip((Kp, D)), one_chip((1, 128)),
     ).compile()
     assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip_500k_rows", "four_chips_1m_rows"])
+def test_logreg_fit_reads_the_reference_frame_in_place(topo, no_compile_cache, monkeypatch, chips):
+    """The whole ``logreg_fit`` program at ``logreg_dbx``'s shard (500,000 x
+    3000 f32 on one described chip; the source's whole 1,000,000 rows over
+    four, 250,000 a chip, rows minor too): the fused pass is in it, inside the
+    L-BFGS loops, and the program holds no second copy of the frame — a
+    relayout in front of the custom call would be one that the runtime's
+    memory peak cannot see (PERF.md section 6, PR 30) and that would cost more
+    than the pass saves."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.ops.logreg_kernels import logreg_fit
+
+    mesh = Mesh(np.asarray(topo.devices[:chips]).reshape(chips, 1), ("dp", "mp"))
+    rows = lambda shape: jax.ShapeDtypeStruct(shape, F32, sharding=NamedSharding(mesh, P("dp")))
+    scalar = jax.ShapeDtypeStruct((), F32, sharding=NamedSharding(mesh, P()))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the gate
+    n, d = 500_000 * (1 if chips == 1 else 2), 3000
+    c = logreg_fit.lower(
+        rows((n, d)), rows((n,)), rows((n,)),
+        n_classes=2, multinomial=False, fit_intercept=True, standardization=True,
+        l1=scalar, l2=scalar, use_l1=False, max_iter=200, tol=scalar, mesh=mesh,
+    ).compile()
+    txt = c.as_text()
+    assert txt.count("tpu_custom_call") >= 3   # before the loop, in its body, in the line search
+    assert chips == 1 or "all-reduce" in txt
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes > n // chips * d * 4
+    assert mem.temp_size_in_bytes < (64 << 20)
 
 
 def test_knn_pass_kernel_compiles(one_chip):

@@ -54,16 +54,38 @@ def test_shifted_gram_pallas_all_masked_tail():
     assert np.abs(np.asarray(G, np.float64) - G_ref).max() / np.abs(G_ref).max() < 1e-5
 
 
-@pytest.mark.parametrize("multinomial,K", [(False, 1), (True, 3)])
-def test_fused_logreg_loss_grad_matches_autodiff(multinomial, K):
+def _logreg_data_term(X, y, m, multinomial):
+    """The XLA data term of ``logreg_fit``: Σ m·logloss at (Aeff, beff)."""
+    def ref(a, b):
+        logits = X @ a.T + b[None, :]
+        if multinomial:
+            ll = jax.nn.logsumexp(logits, axis=1) - jnp.take_along_axis(
+                logits, y.astype(jnp.int32)[:, None], axis=1
+            )[:, 0]
+        else:
+            z = logits[:, 0]
+            ll = jax.nn.softplus(z) - y * z
+        return (ll * m).sum()
+
+    return ref
+
+
+@pytest.mark.parametrize(
+    "multinomial,K,minor_rows",
+    [(False, 1, False), (False, 1, True), (True, 3, False)],
+    ids=["binary_cols_minor", "binary_rows_minor", "multinomial"],
+)
+def test_fused_logreg_loss_grad_matches_autodiff(monkeypatch, multinomial, K, minor_rows):
     """The fused Pallas loss+grad (one data pass) must match
     jax.value_and_grad of the reference formulation, including masking and
-    the padded-classes guard."""
+    the padded-classes guard — the binary pass whichever way the device
+    keeps the shard."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from spark_rapids_ml_tpu.ops.logreg_pallas import make_fused_data_loss
+    from spark_rapids_ml_tpu.ops import logreg_pallas
     from spark_rapids_ml_tpu.parallel.mesh import make_mesh
 
+    monkeypatch.setattr(logreg_pallas, "rows_minor", lambda *a: minor_rows)
     mesh = make_mesh(8)
     rng = np.random.default_rng(0)
     n, d = 8 * 40, 256
@@ -76,34 +98,55 @@ def test_fused_logreg_loss_grad_matches_autodiff(multinomial, K):
     Aeff = jnp.asarray(rng.normal(size=(K, d)).astype(np.float32) * 0.1)
     beff = jnp.asarray(rng.normal(size=(K,)).astype(np.float32) * 0.1)
 
-    f = make_fused_data_loss(Xd, yd, md, mesh, K, multinomial, interpret=True)
+    f = logreg_pallas.make_fused_data_loss(Xd, yd, md, mesh, K, multinomial, interpret=True)
     loss, (gA, gb) = jax.value_and_grad(
         lambda a, b: f(a, b), argnums=(0, 1)
     )(Aeff, beff)
 
-    def ref(a, b):
-        logits = Xd @ a.T + b[None, :]
-        if multinomial:
-            yi = yd.astype(jnp.int32)
-            ll = jax.nn.logsumexp(logits, axis=1) - jnp.take_along_axis(
-                logits, yi[:, None], axis=1
-            )[:, 0]
-        else:
-            z = logits[:, 0]
-            ll = jax.nn.softplus(z) - yd * z
-        return (ll * md).sum()
-
-    rl, (rgA, rgb) = jax.value_and_grad(ref, argnums=(0, 1))(Aeff, beff)
+    rl, (rgA, rgb) = jax.value_and_grad(_logreg_data_term(Xd, yd, md, multinomial), argnums=(0, 1))(Aeff, beff)
     assert abs(float(loss) - float(rl)) < 1e-2
     assert float(jnp.abs(gA - rgA).max() / jnp.abs(rgA).max()) < 1e-4
     assert float(jnp.abs(gb - rgb).max()) < 1e-2
 
 
-def test_logreg_fit_fused_branch_matches_xla(monkeypatch):
+@pytest.mark.parametrize("minor_rows", [False, True], ids=["cols_minor", "rows_minor"])
+@pytest.mark.parametrize("n,d", [(4100, 300), (4100, 2176), (1029, 8)])
+def test_binary_pass_at_any_width(n, d, minor_rows):
+    """The binary pass against value_and_grad of the XLA data term where the
+    width is no lane multiple, or above the old kernel's 2048 columns: the
+    rows do not divide by the tile (a masked tail of garbage rows), 300 and
+    2176 are no multiples of 128, 300 is none of 8 (the rows-minor kernel's
+    last sublane group is partial). Float32 sums: 1e-5 of the loss, of the
+    largest gradient coordinate, and of Σ|r| for the intercept's."""
+    from spark_rapids_ml_tpu.ops.logreg_pallas import binary_loss_grad, binary_tile
+
+    assert n % binary_tile(d, minor_rows)[0]
+    rng = np.random.default_rng(n + d)
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    y = rng.integers(0, 2, size=n).astype(np.float32)
+    mask = (rng.random(n) > 0.1).astype(np.float32)
+    a = (rng.normal(size=d) / np.sqrt(d)).astype(np.float32)
+    b = np.float32(0.3)
+
+    loss, g, gb = binary_loss_grad(X, y, mask, a, b, minor_rows=minor_rows, interpret=True)
+    ref = _logreg_data_term(jnp.asarray(X), jnp.asarray(y), jnp.asarray(mask), False)
+    rl, (rg, rgb) = jax.value_and_grad(lambda a, b: ref(a[None, :], b[None]), argnums=(0, 1))(jnp.asarray(a), jnp.asarray(b))
+    assert abs(float(loss) - float(rl)) / float(rl) < 1e-5
+    assert float(jnp.abs(g - rg).max() / jnp.abs(rg).max()) < 1e-5
+    assert abs(float(gb) - float(rgb)) / float(mask.sum()) < 1e-5
+
+
+@pytest.mark.parametrize(
+    "d,minor_rows", [(256, False), (256, True), (300, False), (300, True)],
+    ids=["d256_cols_minor", "d256_rows_minor", "d300_cols_minor", "d300_rows_minor"],
+)
+def test_logreg_fit_fused_branch_matches_xla(monkeypatch, d, minor_rows):
     """Run the REAL fused branch inside logreg_fit (gate -> custom_vjp ->
-    L-BFGS) via the interpret override and require coefficient parity with
-    the XLA branch — guards the integration wiring (the /n scaling, the
-    standardization reparametrization feeding Aeff/beff)."""
+    L-BFGS) via the interpret override and require parity with the XLA
+    branch (``mesh=None``) in coefficients, intercept and objective — guards
+    the integration wiring (the /n scaling, the standardization
+    reparametrization feeding Aeff/beff) at a lane-aligned width and at one
+    that is not, whichever way the device keeps the shard."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from spark_rapids_ml_tpu.ops import logreg_pallas
@@ -112,7 +155,7 @@ def test_logreg_fit_fused_branch_matches_xla(monkeypatch):
 
     mesh = make_mesh(8)
     rng = np.random.default_rng(2)
-    n, d = 8 * 48, 256
+    n = 8 * 48
     X = rng.normal(size=(n, d)).astype(np.float32)
     w = rng.normal(size=d).astype(np.float32) * 0.2
     y = (X @ w > 0).astype(np.float32)
@@ -128,13 +171,16 @@ def test_logreg_fit_fused_branch_matches_xla(monkeypatch):
     ref = logreg_fit(Xd, md, yd, mesh=None, **kw)
 
     monkeypatch.setattr(logreg_pallas, "FORCE_INTERPRET", True)
-    assert logreg_pallas.logreg_pallas_ok(d, 1, jnp.float32)
+    monkeypatch.setattr(logreg_pallas, "rows_minor", lambda *a: minor_rows)
+    assert logreg_pallas.logreg_pallas_ok(n // 8, d, 1, jnp.float32)
     # FORCE_INTERPRET is read at trace time but is not part of the jit
     # cache key: drop cached executables so this call really traces (and
     # runs) the fused branch, and again afterwards so no interpreted
     # executable leaks into later same-signature calls
     jax.clear_caches()
     try:
+        assert "pallas_call" in str(jax.make_jaxpr(
+            lambda X, m, y: logreg_fit(X, m, y, mesh=mesh, **kw))(Xd, md, yd))
         fused = logreg_fit(Xd, md, yd, mesh=mesh, **kw)
     finally:
         jax.clear_caches()
@@ -143,14 +189,41 @@ def test_logreg_fit_fused_branch_matches_xla(monkeypatch):
     cf = np.asarray(fused["coef_"])
     assert np.abs(cr - cf).max() / max(np.abs(cr).max(), 1e-9) < 1e-3
     assert abs(float(ref["intercept_"][0]) - float(fused["intercept_"][0])) < 1e-3
+    assert abs(float(ref["objective"]) - float(fused["objective"])) < 1e-5 * float(ref["objective"])
+
+
+@pytest.mark.parametrize(
+    "K,d,dtype,tpu,declined",
+    [
+        (1, 3000, "float32", True, ""),            # the binary pass: any width
+        (1, 256, "float32", True, ""),
+        (1, 4096, "float32", True, ""),            # above the multinomial kernel's 2048
+        (1, 3000, "bfloat16", True, "dtype"),      # a bf16-placed X keeps XLA's passes
+        (1, 3000, "float32", False, "backend"),
+        (1, 2_000_000, "float32", True, "tile"),   # no block of this width fits VMEM
+        (3, 256, "float32", True, ""),
+        (3, 256, "bfloat16", True, ""),
+        (3, 3000, "float32", True, "d%128,d<=2048"),
+        (3, 4096, "float32", True, "d<=2048"),
+        (3, 256, "float64", False, "backend,dtype"),
+    ],
+)
+def test_logreg_pallas_gate_terms(monkeypatch, K, d, dtype, tpu, declined):
+    """The gate's terms: K = 1 asks for a TPU, f32 X and a tile that fits, at
+    any width; K >= 3 keeps the multinomial kernel's own terms."""
+    from spark_rapids_ml_tpu.ops import logreg_pallas
+
+    monkeypatch.setattr(logreg_pallas, "FORCE_INTERPRET", tpu)
+    assert logreg_pallas.logreg_pallas_declined(4096, d, K, jnp.dtype(dtype)) == declined
+    assert logreg_pallas.logreg_pallas_ok(4096, d, K, jnp.dtype(dtype)) == (not declined)
 
 
 def test_logreg_pallas_gate_rejects_overwide_class_packing():
     # K in 121..127 would make the packed row exceed 128 lanes (Kp=128 + loss)
     from spark_rapids_ml_tpu.ops.logreg_pallas import logreg_pallas_ok
 
-    assert not logreg_pallas_ok(256, 121, jnp.float32)
-    assert not logreg_pallas_ok(256, 127, jnp.float32)
+    assert not logreg_pallas_ok(4096, 256, 121, jnp.float32)
+    assert not logreg_pallas_ok(4096, 256, 127, jnp.float32)
 
 
 def test_mean_and_cov_chunked_pallas_branch_matches_scan(monkeypatch):

@@ -219,7 +219,9 @@ def test_span_tree_of_a_transform_in_three_batches(runs):
     assert extract["args"]["span_id"] < inner["args"]["span_id"] < frame["args"]["span_id"]
 
 
-@pytest.mark.parametrize("cols, forced, loss_grad", [(64, False, "xla_autodiff"), (128, True, "pallas_fused")])
+@pytest.mark.parametrize(
+    "cols, forced, loss_grad", [(64, False, "xla_autodiff"), (128, True, "pallas_fused"), (300, True, "pallas_fused")]
+)
 def test_launch_span_says_which_loss_grad_runs(monkeypatch, cols, forced, loss_grad):
     telemetry.reset_telemetry()
     spans = []
@@ -236,10 +238,12 @@ def test_launch_span_says_which_loss_grad_runs(monkeypatch, cols, forced, loss_g
     args = _one(spans, "solver.launch")["args"]
     assert args["loss_grad"] == loss_grad
     if forced:
+        # a binary fit takes the fused pass at any width, and says its tile
         assert "declined" not in args
+        assert args["tile"] == logreg_pallas.binary_tile(cols, False)[0]
     else:
-        # on this backend, at this width: not a TPU, and 64 is no lane multiple
-        assert args["declined"] == "backend,d%128"
+        # on this backend: not a TPU (the width is no term of a binary fit's gate)
+        assert args["declined"] == "backend" and "tile" not in args
 
 
 def test_linear_regression_gets_the_same_pair():
