@@ -300,6 +300,7 @@ def phase_pca(ctx) -> dict:
     from spark_rapids_ml_tpu.ops import linalg
 
     X, chips = ctx["X"], ctx["chips"]
+    declined = linalg.gram_pallas_declined(len(X) // chips, D, jnp.float32)
     t0 = time.perf_counter()
     model = PCA(k=PCA_K, inputCol="features", num_workers=chips).fit(ctx["df"])
     t_fit = time.perf_counter() - t0
@@ -348,7 +349,10 @@ def phase_pca(ctx) -> dict:
         "shape": {"rows": len(X), "d": D, "k": PCA_K},
         "smoke_seconds": {"fit": t_fit, "transform_sub": t_tr, "reference_host_f64": t_ref},
         "checks": checks,
-        "kernel": gate("linalg._pallas_gram_ok", linalg._pallas_gram_ok(D, jnp.float32), ctx["on_tpu"]),
+        # a row-major shard (this lane-aligned width) takes XLA's blocked pass
+        # at HIGHEST by design: the kernel reads a rows-minor shard's transpose
+        "kernel": {"gate": "linalg.gram_pallas_declined", "pallas": not declined, "declined": declined,
+                   "ok": declined in ("", "rows_minor") or not ctx["on_tpu"]},
         "memory": mem,
     }
 

@@ -14,8 +14,9 @@ API parity targets:
 
 TPU-native fit (vs reference's cuML ``PCAMG.fit``, ``feature.py:216-259``):
 one jitted global-math function over the row-sharded design matrix — masked
-mean + Gram (psum'd by XLA over the dp mesh axis), replicated ``eigh`` of
-the d×d covariance, deterministic sign flip.
+mean + float32-exact Gram (psum'd over the dp mesh axis), the k leading
+eigenpairs of the replicated d×d covariance (``ops.linalg.topk_eigh``),
+deterministic sign flip.
 """
 
 from __future__ import annotations
@@ -40,11 +41,17 @@ from ..params import (
     _mk,
 )
 from ..ops.linalg import (
+    gram_block_rows,
+    gram_pallas_declined,
+    gram_tile,
     mean_and_cov,
     mean_and_cov_chunked,
     mp_gram_blocks,
+    rows_minor,
     topk_eigh,
 )
+from ..parallel.mesh import DP_AXIS
+from ..runtime import telemetry
 
 
 class PCAClass:
@@ -99,14 +106,16 @@ def _pca_fit_kernel(
     mp_blocks: bool = False,
 ):
     """Resident-fit kernel. With ``mesh``/``csize`` (rows dp-sharded, padded
-    to a per-device ``csize`` multiple) the covariance is accumulated in
-    row-chunk scans with O(csize·d) temporaries — at double-digit-GB row
-    counts the fused form can materialize the centered copy of X and OOM;
-    without them (e.g. 2-D (dp, mp)-sharded dry runs) the fused global-math
-    path is used. ``mp_blocks`` (static; resolve with ``mp_gram_blocks``
-    outside jit) column-shards the Gram accumulator over the mesh's mp
-    axis; the blocked covariance also rides out in the result so the
-    caller can measure its per-shard bytes."""
+    to a per-device ``csize`` multiple) the covariance is accumulated block
+    by block in one float32-exact pass over X (``mean_and_cov_chunked``: the
+    Pallas Gram kernel over a rows-minor shard, XLA's blocked pass
+    otherwise) — at double-digit-GB row counts the fused form can
+    materialize the centered copy of X and OOM; without them (e.g. 2-D
+    (dp, mp)-sharded dry runs) the fused global-math path is used.
+    ``mp_blocks`` (static; resolve with ``mp_gram_blocks`` outside jit)
+    column-shards the Gram accumulator over the mesh's mp axis; the blocked
+    covariance also rides out in the result so the caller can measure its
+    per-shard bytes."""
     if mesh is not None and _TpuEstimator.rows_chunkable(
         X.shape[0], mesh, csize
     ):
@@ -119,6 +128,16 @@ def _pca_fit_kernel(
     if mp_blocks:
         out["cov"] = cov
     return out
+
+
+@jax.jit
+def _project(Xb: jax.Array, components: jax.Array) -> jax.Array:
+    """``Xb·componentsᵀ``: Spark semantics, no mean removal (reference
+    ``feature.py:426-439``). The components are an argument, so every model
+    of one width and k runs the one program. HIGHEST: an f32 dot on the MXU
+    at default precision is one bf16 pass, 2⁻⁹ a product; the k columns are
+    nothing beside the batch's way to the chip."""
+    return jnp.matmul(Xb, components.T, precision=jax.lax.Precision.HIGHEST)
 
 
 class PCA(PCAClass, _TpuEstimator, _PCAParams):
@@ -142,10 +161,30 @@ class PCA(PCAClass, _TpuEstimator, _PCAParams):
         return self
 
     def _chunk_rows(self, n_rows: int, n_dp: int) -> int:
-        # route resident fits through the chunked covariance scan: 64k-row
-        # chunks keep temporaries O(chunk·d) so a near-HBM-sized X cannot
-        # OOM on the centered copy (see mean_and_cov_chunked)
+        # route resident fits through the blocked covariance pass: its
+        # temporaries are one row block, so a near-HBM-sized X cannot OOM on
+        # the centered copy (see mean_and_cov_chunked); the chunk is the
+        # upper bound of that block and the size of the mean's sample
         return self._equal_chunk_rows(n_rows, n_dp, 65_536)
+
+    def _gram_launch_attrs(self, inputs: FitInputs, use_mp: bool) -> Dict[str, Any]:
+        """What ``solver.launch`` says of the Gram pass: the gate that
+        ``mean_and_cov_chunked`` takes at trace time, asked again on the
+        host."""
+        n_local = inputs.X.shape[0] // inputs.mesh.shape[DP_AXIS]
+        d = inputs.X.shape[1]
+        device = inputs.mesh.devices.flat[0]
+        attrs: Dict[str, Any] = {
+            "precision": "highest",
+            "rows_minor": jax.default_backend() == "tpu" and rows_minor(device, n_local, d, inputs.X.dtype),
+        }
+        if not _TpuEstimator.rows_chunkable(inputs.X.shape[0], inputs.mesh, inputs.csize):
+            return dict(attrs, gram="xla_fused", tile=inputs.X.shape[0])
+        declined = gram_pallas_declined(n_local, d, inputs.X.dtype, device, mp_blocks=use_mp)
+        if declined:
+            tile = gram_block_rows(n_local, d, inputs.csize, inputs.X.dtype.itemsize)
+            return dict(attrs, gram="xla", tile=tile, declined=declined)
+        return dict(attrs, gram="pallas", tile=gram_tile(d)[0])
 
     def _get_tpu_fit_func(self, dataset: DataFrame) -> FitFunc:
         def _fit(inputs: FitInputs, params: Dict[str, Any]) -> Dict[str, Any]:
@@ -158,10 +197,15 @@ class PCA(PCAClass, _TpuEstimator, _PCAParams):
             use_mp = mp > 1 and _TpuEstimator.rows_chunkable(
                 inputs.X.shape[0], inputs.mesh, inputs.csize
             )
-            out = _pca_fit_kernel(
-                inputs.X, inputs.mask, k, mesh=inputs.mesh,
-                csize=inputs.csize, mp_blocks=use_mp,
-            )
+            with telemetry.span(
+                "solver.launch",
+                program=_pca_fit_kernel.__name__,
+                **self._gram_launch_attrs(inputs, use_mp),
+            ):
+                out = _pca_fit_kernel(
+                    inputs.X, inputs.mask, k, mesh=inputs.mesh,
+                    csize=inputs.csize, mp_blocks=use_mp,
+                )
             report = None
             if use_mp:
                 cov = out.pop("cov")
@@ -171,7 +215,9 @@ class PCA(PCAClass, _TpuEstimator, _PCAParams):
                         cov.addressable_shards[0].data.nbytes
                     ),
                 }
-            result = {key: np.asarray(v) for key, v in out.items()}
+            # the first fetch blocks until the fit program has run
+            with telemetry.span("solver.fetch", k=k, d=inputs.n_features):
+                result = {key: np.asarray(v) for key, v in out.items()}
             if report:
                 result["_fit_report"] = report
             return result
@@ -261,14 +307,8 @@ class PCAModel(PCAClass, _TpuModel, _PCAParams):
         def _build() -> Callable[[np.ndarray], Dict[str, np.ndarray]]:
             components = jnp.asarray(self.components_)  # (k, d)
 
-            @jax.jit
-            def _project(Xb: jax.Array) -> jax.Array:
-                # Spark semantics: no mean removal (reference
-                # ``feature.py:426-439``)
-                return Xb @ components.T
-
             def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
-                return {out_col: np.asarray(_project(jnp.asarray(Xb)))}
+                return {out_col: np.asarray(_project(jnp.asarray(Xb), components))}
 
             return _fn
 

@@ -13,9 +13,12 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
+from jax.experimental.layout import Layout
 
 from ..parallel.layout import LAYOUT
 from ..parallel.mesh import DP_AXIS, MP_AXIS
@@ -41,14 +44,23 @@ def mean_and_cov(X: jax.Array, mask: jax.Array) -> Tuple[jax.Array, jax.Array, j
     # catastrophically cancels in f32 when |μ| >> σ. The subtraction fuses
     # into the matmul's operand read, so the extra pass is ~free on TPU.
     Xc = (X - mean[None, :]) * mask[:, None]
-    cov = (Xc.T @ Xc) / (n - 1.0)
+    # HIGHEST: at default precision an f32 dot on the MXU is one bf16 pass
+    cov = jnp.matmul(Xc.T, Xc, precision=lax.Precision.HIGHEST) / (n - 1.0)
     return mean, cov, n
 
-# Test hook (mirrors ops.logreg_pallas.FORCE_INTERPRET): when True,
-# _pallas_gram_ok ignores the backend check and the kernels run through the
-# Pallas interpreter, letting CPU CI exercise the real kernel branches
-# inside the fit paths.
+
+# Test hook (mirrors ops.logreg_pallas.FORCE_INTERPRET): when True, the Gram
+# kernel's gate ignores the backend and layout terms and the kernel runs
+# through the Pallas interpreter, letting CPU CI exercise the real kernel
+# branch inside the fit paths.
 FORCE_INTERPRET = False
+
+_LANES = 128
+# Handed to Mosaic as its limit, and what the Gram kernel's counted residents
+# must fit (a v5e has 128 MiB of VMEM).
+_GRAM_VMEM_LIMIT = 110 * 1024 * 1024
+# XLA's blocked Gram: bytes of one row block (8,192 rows at d = 3000).
+_GRAM_BLOCK_BYTES = 96 << 20
 
 
 def row_chunk(i, csize: int, *arrays):
@@ -56,11 +68,13 @@ def row_chunk(i, csize: int, *arrays):
 
     The canonical chunk access for every chunked-scan kernel. Slice with
     ``dynamic_slice`` — do NOT ``lax.scan`` over a reshaped X: scan
-    materializes its xs operand in the layout the loop body's matmuls
-    prefer, which at lane-unaligned d (e.g. 3000) is a full transposed
-    copy of the design matrix — doubling memory and OOMing resident fits
-    that otherwise fit (observed at 1M×3000 on v5e). Slicing reads the
-    original buffer in place.
+    materializes its xs operand as a second (chunks, csize, d) array, a full
+    copy of the design matrix beside the resident one. The slice reads the
+    original buffer in place whichever way the device keeps it: a TPU lays
+    ``f32[500000,3000]`` out with its rows minor and the slice of a whole
+    number of 128-row lane tiles comes out rows minor too (PERF.md section 6,
+    PR 33: the parent's 6.9 GB temporary was the strided mean sample's
+    gather, not this slice).
 
     Use :func:`check_row_chunking` at kernel entry so a non-divisible row
     count fails loudly at trace time instead of silently dropping the tail.
@@ -81,88 +95,180 @@ def check_row_chunking(n_rows: int, csize: int) -> int:
     return n_rows // csize
 
 
-def _pallas_gram_tile(d: int) -> int:
-    """Row-tile size for :func:`_shifted_gram_pallas`: ~16 MB of f32 per
-    block (double-buffered by the pipeline) regardless of feature width,
-    in VPU-sublane multiples. Measured on v5e at 12M×256: 8 MB blocks
-    sustain ~670 GB/s, 16 MB ~715 GB/s (against ~735 achievable)."""
-    return max(256, (4_194_304 // d) // 8 * 8)
+def rows_minor(device, n_local: int, d: int, dtype=jnp.float32) -> bool:
+    """Whether ``device`` keeps a ``(n_local, d)`` array with its rows minor
+    (the samples along the lanes). A TPU lays a 2-D array out whichever way
+    pads less to its (8, 128) tiles: ``f32[500000,3000]`` has its rows minor
+    (3000 x 500,096), ``f32[4194304,256]`` its columns. Asked of the runtime
+    (a described device answers too), not reckoned: the answer decides which
+    way the binary pass reads the frame, and a wrong one would put a relayout
+    of the whole frame in front of the kernel."""
+    layout = device.client.get_default_layout(np.dtype(dtype), (n_local, d), device)
+    return tuple(Layout.from_pjrt_layout(layout).major_to_minor) == (1, 0)
+
+
+def gram_tile(d: int) -> Tuple[int, int, int]:
+    """``(tile, block, vmem bytes)`` of :func:`_shifted_gram_pallas` at width
+    ``d``: samples a grid step, rows of one accumulator block, and the bytes
+    the kernel keeps in VMEM. The ONE rule the gate, the compile test and the
+    kernel read.
+
+    The accumulator is cut into square blocks of ``block`` = 512 rows (the
+    lane-padded width where that is narrower), of which the kernel fills the
+    upper triangle. ``tile`` is the power of two that makes the (padded d,
+    tile) block of the transposed frame about 3 MB, between 128 and 2048
+    lanes (256 at d = 3000: on a v5e the pass takes 0.178 s there, 0.185 s
+    at 512 and 0.196 s at 1024, and Mosaic 6, 11 and 14 s to compile it).
+    Resident: the accumulator once (its block never moves, so it is
+    single-buffered), the frame's block twice (the pipeline's) and once more
+    centred, the (padded d, 128) row-sum partials and μ̂ along the lanes (two
+    buffers each), and the operands of one block product split three ways in
+    bf16."""
+    block = min(512, -(-d // _LANES) * _LANES)
+    dp = -(-d // block) * block
+    tile = min(2048, max(_LANES, 1 << (max(1, (3 << 20) // (dp * 4)).bit_length() - 1)))
+    need = dp * dp * 4 + 3 * dp * tile * 4 + 4 * dp * _LANES * 4 + 2 * 3 * block * tile * 2 + block * block * 4
+    return tile, block, need
 
 
 def _shifted_gram_pallas(
-    Xl: jax.Array,
+    Xt: jax.Array,
     ml: jax.Array,
     mean_hat: jax.Array,
     *,
     tile: int | None = None,
     interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Pallas TPU kernel: one pass over local rows accumulating the shifted
-    Gram ``Σ m·(x-μ̂)(x-μ̂)ᵀ`` and row-sum ``Σ m·(x-μ̂)``.
+    """Pallas TPU kernel: one pass over a shard kept with its ROWS minor,
+    accumulating the shifted Gram ``Σ m·(x-μ̂)(x-μ̂)ᵀ`` and row-sum
+    ``Σ m·(x-μ̂)`` with float32-exact products.
 
-    XLA's fused ``(X-μ̂)ᵀ(X-μ̂)`` on a skinny (d≈256) design matrix sustains
-    only ~half the chip's HBM bandwidth (measured 385 GB/s vs 735 GB/s
-    achievable on v5e); this kernel streams row tiles HBM→VMEM with the
-    d×d accumulator resident in VMEM and reaches ~715 GB/s. Rows beyond
-    ``n`` (the last partial tile) are zeroed by an index-validity guard, so
-    any row count works. f32 end to end.
+    ``Xt`` is the (d, n) transpose of the shard — the same bytes (a TPU keeps
+    ``f32[500000,3000]`` as 3000 × 500,096: whichever way pads less) — so the
+    Gram is ``A·Aᵀ`` of that view and a ``(d, tile)`` block holds ``tile``
+    samples along the lanes. The whole (d, d) accumulator stays in VMEM
+    (37.7 MB at d = 3000 → 3072), so X is read from HBM once. Per grid step
+    the block is centred and masked into a scratch, and for every pair of
+    ``block``-row slabs (i ≤ j) one ``dot_general`` over the lanes at
+    ``Precision.HIGHEST`` (six bf16 passes: float32 to the last bit that an
+    f32 accumulator keeps) adds to accumulator block (i, j): the upper block
+    triangle, 21 of 36 blocks at d = 3000. The lower triangle is its mirror,
+    written once in XLA after the call. The block of the frame is taken
+    ``padded d`` rows tall: the rows past ``d`` (the overhang of the one block
+    along that axis) are zeroed, as are lanes past ``n`` and masked samples.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, d = Xl.shape
-    if tile is None:
-        tile = _pallas_gram_tile(d)
+    d, n = Xt.shape
+    rule_tile, bs, _ = gram_tile(d)
+    tile = rule_tile if tile is None else tile
+    nb = -(-d // bs)
+    dp = nb * bs
     if interpret is None:
         interpret = FORCE_INTERPRET
 
-    def kern(x_ref, m_ref, mu_ref, G_ref, s_ref):
-        i = pl.program_id(0)
+    def kern(x_ref, m_ref, mu_ref, G_ref, s_ref, xs_ref):
+        t = pl.program_id(0)
 
-        @pl.when(i == 0)
+        @pl.when(t == 0)
         def _():
             G_ref[:] = jnp.zeros_like(G_ref)
             s_ref[:] = jnp.zeros_like(s_ref)
 
-        # rows past n: the block is fetched beyond the array — zero them
-        # explicitly (jnp.where, not multiply: OOB fill could be non-finite)
-        row = i * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
-        valid = row < n
-        x = jnp.where(valid, x_ref[:], 0.0)
-        m = jnp.where(valid[:, 0], m_ref[:], 0.0)
-        xs = (x - mu_ref[:]) * m[:, None]
-        G_ref[:] += jax.lax.dot_general(
-            xs, xs, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        s_ref[:] += jnp.sum(xs, axis=0, keepdims=True)
+        # lanes past n and rows past d: the block is fetched beyond the
+        # array — zero them explicitly (where, not multiply: the fill
+        # could be non-finite)
+        lane = t * tile + lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        m = jnp.where(lane < n, m_ref[:], 0.0)                      # (1, tile)
+        keep = (lax.broadcasted_iota(jnp.int32, (dp, 1), 0) < d) & (m != 0.0)
+        mu = mu_ref[:]                                              # (dp, 128): μ̂ along the lanes
+        part = jnp.zeros((dp, _LANES), jnp.float32)
+        for q in range(tile // _LANES):
+            sl = slice(q * _LANES, (q + 1) * _LANES)
+            xq = jnp.where(keep[:, sl], (x_ref[:, sl] - mu) * m[:, sl], 0.0)
+            xs_ref[:, sl] = xq
+            part = part + xq
+        s_ref[:] += part
+        for j in range(nb):
+            xj = xs_ref[j * bs:(j + 1) * bs, :]
 
+            def slab(i, _, j=j, xj=xj):
+                xi = xs_ref[pl.ds(pl.multiple_of(i * bs, bs), bs), :]
+                G_ref[i, :, j * bs:(j + 1) * bs] += lax.dot_general(
+                    xi, xj, (((1,), (1,)), ((), ())),
+                    precision=lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32,
+                )
+                return 0
+
+            lax.fori_loop(0, j + 1, slab, 0)
+
+    once = pl.Buffered(1)
     G, s = pl.pallas_call(
         kern,
         grid=(pl.cdiv(n, tile),),
         in_specs=[
-            pl.BlockSpec((tile, d), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile,), lambda i: (i,), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((dp, tile), lambda t: (0, t)),
+            pl.BlockSpec((1, tile), lambda t: (0, t)),
+            pl.BlockSpec((dp, _LANES), lambda t: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((d, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((nb, bs, dp), lambda t: (0, 0, 0), pipeline_mode=once),
+            pl.BlockSpec((dp, _LANES), lambda t: (0, 0), pipeline_mode=once),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((d, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, d), jnp.float32),
+            jax.ShapeDtypeStruct((nb, bs, dp), jnp.float32),
+            jax.ShapeDtypeStruct((dp, _LANES), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((dp, tile), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            # 16 MB double-buffered row tiles + centering temporaries + the
-            # d×d accumulator (16 MB at d=2048) need headroom past the
-            # 64 MB default (v5e has 128 MB VMEM)
-            vmem_limit_bytes=100 * 1024 * 1024,
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_GRAM_VMEM_LIMIT
         ),
+        name="pca_gram_pass",
         interpret=interpret,
-    )(Xl, ml, mean_hat.reshape(1, d))
-    return G, s[0]
+    )(Xt, ml.reshape(1, n), jnp.broadcast_to(jnp.pad(mean_hat, (0, dp - d))[:, None], (dp, _LANES)))
+    U = G.reshape(dp, dp)
+    bi = lax.broadcasted_iota(jnp.int32, (dp, dp), 0) // bs
+    bj = lax.broadcasted_iota(jnp.int32, (dp, dp), 1) // bs
+    G = jnp.where(bi < bj, U, jnp.where(bi > bj, U.T, 0.5 * (U + U.T)))
+    return G[:d, :d], s.sum(axis=1)[:d]
+
+
+def _shifted_gram_xla(
+    Xl: jax.Array, ml: jax.Array, mean_hat: jax.Array, *, block: int, c0=0, bw: int | None = None
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """XLA's blocked pass: ``(Σ m·(x-μ̂)(x-μ̂[c0:c0+bw])ᵀ, Σ m·(x-μ̂), Σ m)``
+    over row blocks of ``block`` rows, each one ``dot_general`` at
+    ``Precision.HIGHEST``, in a ``fori_loop`` whose ``dynamic_slice`` reads
+    the frame in place whichever way the device keeps it. Any row count: the
+    last block is moved back to end on the last row, and the rows it shares
+    with the block before are masked out of it."""
+    n, d = Xl.shape
+    bw = d if bw is None else bw
+    block = min(block, n)
+
+    def body(i, carry):
+        s, cnt, G = carry
+        start = jnp.minimum(i * block, n - block)
+        x = lax.dynamic_slice_in_dim(Xl, start, block, 0)
+        m = lax.dynamic_slice_in_dim(ml, start, block, 0)
+        m = jnp.where(start + lax.iota(jnp.int32, block) >= i * block, m, 0.0)
+        xs = (x - mean_hat[None, :]) * m[:, None]
+        xb = xs if bw == d else lax.dynamic_slice_in_dim(xs, c0, bw, 1)
+        G = G + lax.dot_general(
+            xs, xb, (((0,), (0,)), ((), ())),
+            precision=lax.Precision.HIGHEST, preferred_element_type=Xl.dtype,
+        )
+        return s + xs.sum(axis=0), cnt + m.sum(), G
+
+    s, cnt, G = lax.fori_loop(
+        0,
+        -(-n // block),
+        body,
+        (jnp.zeros((d,), Xl.dtype), jnp.zeros((), Xl.dtype), jnp.zeros((d, bw), Xl.dtype)),
+    )
+    return G, s, cnt
 
 
 def mp_gram_blocks(mesh, d: int) -> int:
@@ -181,48 +287,92 @@ def mp_gram_blocks(mesh, d: int) -> int:
     return n_mp
 
 
-def _pallas_gram_ok(d: int, dtype) -> bool:
-    """Trace-time gate for the Pallas gram path: TPU backend, lane-aligned
-    feature width, f32 (the kernel accumulates in f32; f64 fits keep the
-    scan path). d is capped so the d×d VMEM accumulator plus double-buffered
-    16 MB row blocks stay under the kernel's 100 MB VMEM budget — wider
-    fits route to the scan path, which handles any d."""
-    return (
-        (jax.default_backend() == "tpu" or FORCE_INTERPRET)
-        and d % 128 == 0
-        and d <= 2048
-        and dtype == jnp.float32
-    )
+def gram_pallas_declined(n_local: int, d: int, dtype, device=None, mp_blocks: bool = False) -> str:
+    """The terms of the Gram kernel's gate that fail, comma-joined (empty:
+    :func:`_shifted_gram_pallas` is admitted; otherwise XLA's blocked pass
+    runs, :func:`_shifted_gram_xla`). A TPU; f32 X (the kernel's products
+    and accumulator are float32: an f64 fit keeps XLA's); a shard the device
+    keeps with its ROWS minor (``rows_minor``: a width that is no multiple of
+    128 under many rows), which the kernel reads as its transpose without a
+    copy — a row-major shard (a lane-aligned width) would get a relayout of
+    the whole frame in front of it; a (d, d) accumulator that fits VMEM
+    beside the frame's blocks (:func:`gram_tile`: up to d ≈ 4,000); and no
+    column-blocked accumulator (``mp_blocks``). ``device`` is one device of
+    the shard's mesh (default: the first of the backend). A pure function of
+    its arguments and the backend: the estimator evaluates it again on the
+    host to say on its ``solver.launch`` span which pass a fit ran, and why."""
+    terms = [("dtype", dtype == jnp.float32), ("vmem", gram_tile(d)[2] <= _GRAM_VMEM_LIMIT), ("mp", not mp_blocks)]
+    if not FORCE_INTERPRET:
+        on_tpu = jax.default_backend() == "tpu"
+        terms.insert(0, ("backend", on_tpu))
+        terms.append(("rows_minor", on_tpu and rows_minor(device or jax.devices()[0], n_local, d)))
+    return ",".join(name for name, ok in terms if not ok)
+
+
+def gram_block_rows(n_local: int, d: int, csize: int, itemsize: int = 4) -> int:
+    """Rows of one block of XLA's pass: at most ``csize`` (the caller's bound
+    on temporaries) and ``_GRAM_BLOCK_BYTES``, in whole 128-row lane tiles
+    where that many rows are there."""
+    rows = min(csize, max(_LANES, _GRAM_BLOCK_BYTES // (d * itemsize)), n_local)
+    return rows - rows % _LANES if rows >= _LANES else rows
+
+
+def _mean_sample(Xl: jax.Array, ml: jax.Array, rows: int) -> Tuple[jax.Array, jax.Array]:
+    """(Σ m·x, Σ m) over about ``rows`` rows taken as up to 64 runs of
+    consecutive rows spread evenly over the shard. Runs, not a stride: a
+    strided gather along the minor dimension of a rows-minor frame put a
+    row-major copy of the WHOLE frame in front of it (6.9 GB at 500,000 x
+    3000: PERF.md section 6, PR 33); a run of whole lane tiles is a slice."""
+    n = Xl.shape[0]
+    runs = max(1, min(64, rows))
+    width = rows // runs
+    if width >= _LANES:
+        width -= width % _LANES
+    s = jnp.zeros((Xl.shape[1],), Xl.dtype)
+    c = jnp.zeros((), Xl.dtype)
+    for r in range(runs):
+        lo = r * n // runs
+        lo = min(lo - lo % _LANES if width >= _LANES else lo, n - width)
+        x, m = Xl[lo:lo + width], ml[lo:lo + width]
+        s, c = s + (x * m[:, None]).sum(axis=0), c + m.sum()
+    return s, c
 
 
 def mean_and_cov_chunked(
     X: jax.Array, mask: jax.Array, mesh, csize: int, *, mp_blocks: bool = False
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """:func:`mean_and_cov` with O(csize·d) temporaries and ~1 pass over X.
+    """:func:`mean_and_cov` with bounded temporaries, ONE pass over X and
+    float32-exact products.
 
     The fused form relies on XLA folding the ``(X - μ)·mask`` centering into
     the Gram matmul's operand read; at double-digit-GB row counts the
     compiler can instead materialize the centered copy and OOM a chip whose
-    HBM the resident matrix already half-fills. Here each device scans its
-    rows in fixed ``csize`` chunks (same pattern as the KMeans Lloyd kernel)
-    so peak extra memory is one chunk.
+    HBM the resident matrix already half-fills. Here each device reads its
+    rows block by block, so peak extra memory is one block (at most
+    ``csize`` rows, and 96 MB), and no product is a single bf16 pass: an f32
+    ``dot`` on the MXU at default precision is, and the covariance of a fit
+    that states float32 is not to be.
 
     Numerics: the naive one-pass ``(XᵀX - n·μμᵀ)/(n-1)`` catastrophically
     cancels in f32 when |μ| >> σ, and a full two-pass centering reads X
-    twice from HBM. Instead the mean is *estimated* from each device's
-    first chunk (one cheap psum), the main pass accumulates shifted sums
-    ``Σ m·(x-μ̂)`` and Gram ``Σ m·(x-μ̂)(x-μ̂)ᵀ``, and a final rank-1
-    correction re-centers exactly: with ``δ = mean - μ̂`` small, the
-    cancellation term is harmless — two-pass stability at one-pass
-    bandwidth. The estimate samples ``csize`` rows *strided across the
-    whole device shard* (not the leading chunk), so data sorted or
-    drifting in magnitude still yields δ = O(σ/√csize); only then does
-    the f32 rank-1 correction stay clear of the cancellation the shift
-    avoids. Partials combine with one ``psum`` over dp — the same
-    communication volume as the fused form.
+    twice from HBM. Instead the mean is *estimated* from a sample (one cheap
+    psum), the main pass accumulates shifted sums ``Σ m·(x-μ̂)`` and Gram
+    ``Σ m·(x-μ̂)(x-μ̂)ᵀ``, and a final rank-1 correction re-centers exactly:
+    with ``δ = mean - μ̂`` small, the cancellation term is harmless —
+    two-pass stability at one-pass bandwidth. The sample is ``csize`` rows
+    in runs spread *across the whole device shard* (:func:`_mean_sample`,
+    not the leading chunk), so data sorted or drifting in magnitude still
+    yields δ = O(σ/√csize); only then does the f32 rank-1 correction stay
+    clear of the cancellation the shift avoids. Partials combine with one
+    ``psum`` over dp — the same communication volume as the fused form.
 
-    Requires per-device rows divisible by ``csize`` (``shard_rows`` pads to
-    this); rows must be sharded over dp only.
+    Which pass (*separated*, by what the code can observe): a shard the TPU
+    keeps with its rows minor takes the Pallas kernel over its transposed
+    view (:func:`_shifted_gram_pallas`; gate :func:`gram_pallas_declined`);
+    every other shard — row-major, f64, another backend, a column-blocked
+    accumulator, a width past the kernel's VMEM — takes XLA's blocked pass
+    (:func:`_shifted_gram_xla`). Rows must be sharded over dp only; any row
+    count.
 
     With ``mp_blocks`` (resolve via :func:`mp_gram_blocks` — env is read
     outside jit) each device accumulates only its OWN column block of the
@@ -232,7 +382,7 @@ def mean_and_cov_chunked(
     psum stays over dp only (mp peers hold *different* blocks, dp peers the
     same block) and the returned covariance is column-sharded over mp
     (``LAYOUT.cols()``). Per-element reduction order matches the full-width
-    scan, so parity with the 1-D path is tight (see docs/mesh.md tolerance
+    pass, so parity with the 1-D path is tight (see docs/mesh.md tolerance
     contract).
     """
 
@@ -243,51 +393,26 @@ def mean_and_cov_chunked(
             f"by the mp extent ({n_mp}); gate with mp_gram_blocks"
         )
     bw = X.shape[1] // n_mp
-    use_pallas = n_mp == 1 and _pallas_gram_ok(X.shape[1], X.dtype)
+    n_local = X.shape[0] // int(mesh.shape[DP_AXIS])
+    use_pallas = not gram_pallas_declined(
+        n_local, X.shape[1], X.dtype, mesh.devices.flat[0], mp_blocks=n_mp > 1
+    )
 
     def per_device(Xl, ml):
-        d = Xl.shape[1]
+        s0, c0 = _mean_sample(Xl, ml, min(csize, Xl.shape[0]))
+        mean_hat = lax.psum(s0, DP_AXIS) / jnp.maximum(lax.psum(c0, DP_AXIS), 1.0)
 
-        # mean estimate from rows strided across the whole shard — a
-        # leading-chunk sample misestimates μ̂ on sorted/drifting data
-        # and the rank-1 correction then reintroduces cancellation; the
-        # mask weights out any padding rows the stride lands on
-        e = min(csize, Xl.shape[0])
-        stride = max(1, Xl.shape[0] // e)
-        x0, m0 = Xl[::stride][:e], ml[::stride][:e]
-        s0 = lax.psum((x0 * m0[:, None]).sum(axis=0), DP_AXIS)
-        c0 = lax.psum(m0.sum(), DP_AXIS)
-        mean_hat = s0 / jnp.maximum(c0, 1.0)
-
-        if use_pallas:
-            G, s = _shifted_gram_pallas(Xl, ml, mean_hat)
-            cnt = ml.sum()
-        else:
-            nc = check_row_chunking(Xl.shape[0], csize)
-            # column-block start of THIS device's Gram panel (0 at mp=1)
-            c0 = lax.axis_index(MP_AXIS) * bw if n_mp > 1 else 0
-
-            def body(i, carry):
-                s, cnt, G = carry
-                x, m = row_chunk(i, csize, Xl, ml)
-                xs = (x - mean_hat[None, :]) * m[:, None]
-                xb = (
-                    lax.dynamic_slice_in_dim(xs, c0, bw, 1)
-                    if n_mp > 1
-                    else xs
+        with jax.named_scope("pca.gram"):
+            if use_pallas:
+                G, s = _shifted_gram_pallas(Xl.T, ml, mean_hat)
+                cnt = ml.sum()
+            else:
+                G, s, cnt = _shifted_gram_xla(
+                    Xl, ml, mean_hat,
+                    block=gram_block_rows(Xl.shape[0], Xl.shape[1], csize, Xl.dtype.itemsize),
+                    # column-block start of THIS device's Gram panel (0 at mp=1)
+                    c0=lax.axis_index(MP_AXIS) * bw if n_mp > 1 else 0, bw=bw,
                 )
-                return (s + xs.sum(axis=0), cnt + m.sum(), G + xs.T @ xb)
-
-            s, cnt, G = lax.fori_loop(
-                0,
-                nc,
-                body,
-                (
-                    jnp.zeros((d,), Xl.dtype),
-                    jnp.zeros((), Xl.dtype),
-                    jnp.zeros((d, bw), Xl.dtype),
-                ),
-            )
         n = lax.psum(cnt, DP_AXIS)
         s = lax.psum(s, DP_AXIS)
         G = lax.psum(G, DP_AXIS)
@@ -324,6 +449,76 @@ def sign_flip(vectors: jax.Array) -> jax.Array:
     return vectors * signs[None, :]
 
 
+# Widest matrix handed to ``jnp.linalg.eigh`` whole. On a TPU that routine is
+# Jacobi up to 256 columns (compiles in a second) and QDWH above (20 s at 512,
+# 283 s at 3000: PERF.md section 6, PR 33), so a wider matrix is projected
+# onto a block of at most this many columns first.
+_EIGH_DIRECT = 256
+# Subspace iteration: the least columns of the block, the most steps, and the
+# residual (over λ₁, in units of the dtype's epsilon) under which a step that
+# no longer halves it ends the loop.
+_EIG_BLOCK = 64
+_EIG_MAX_STEPS = 256
+_EIG_TOL_EPS = 64.0
+
+
+def _host_topk_eigh(cov, k: int):
+    """The k leading pairs by LAPACK in float64 on the host: what
+    :func:`topk_eigh` answers with where its iteration did not converge."""
+    a = np.asarray(cov, np.float64)
+    try:
+        w, v = np.linalg.eigh(0.5 * (a + a.T))
+    except np.linalg.LinAlgError:
+        w, v = np.full((a.shape[0],), np.nan), np.full(a.shape, np.nan)
+    dt = np.asarray(cov).dtype
+    return w[::-1][:k].astype(dt), np.ascontiguousarray(v[:, ::-1][:, :k]).astype(dt)
+
+
+def _subspace_topk(cov: jax.Array, k: int):
+    """(eigenvalues (k,), eigenvectors (d, k), steps, converged) of the k
+    leading pairs of a wide symmetric ``cov`` by subspace iteration.
+
+    A block Q of ``b = max(_EIG_BLOCK, 2k)`` orthonormal columns (from a fixed
+    key: the same matrix gives the same pairs) is multiplied by ``cov`` and
+    orthonormalized again, step after step; every step the Rayleigh–Ritz
+    pairs of ``Qᵀ·cov·Q`` (a b × b ``eigh``) give the k leading candidates
+    (θ_i, x_i) and their residual ``max_i ‖cov·x_i − θ_i·x_i‖ / θ_1`` exactly,
+    from the product the step has made anyway. The error falls by
+    λ_{b+1}/λ_k a step, so a covariance with a decaying spectrum needs a
+    handful. The loop ends at the float32 floor, not at a tolerance: on the
+    first step whose residual is under ``_EIG_TOL_EPS`` epsilons (3.8e-6 in
+    float32) and no longer half the step's before. Every product is ``Precision.HIGHEST``."""
+    d = cov.shape[0]
+    b = min(d, max(_EIG_BLOCK, 2 * k))
+    mm = lambda a, c: jnp.matmul(a, c, precision=lax.Precision.HIGHEST)
+    q0, _ = jnp.linalg.qr(jax.random.normal(jax.random.PRNGKey(0), (d, b), cov.dtype))
+    tiny = jnp.finfo(cov.dtype).tiny
+    tol = _EIG_TOL_EPS * float(jnp.finfo(cov.dtype).eps)
+
+    def step(state):
+        it, q, _, _, prev, _ = state
+        z = mm(cov, q)
+        t = mm(q.T, z)
+        theta, w = jnp.linalg.eigh(0.5 * (t + t.T))
+        theta, w = theta[::-1][:k], w[:, ::-1][:, :k]
+        x = mm(q, w)
+        r = mm(z, w) - x * theta[None, :]
+        res = jnp.sqrt((r * r).sum(axis=0)).max() / jnp.maximum(jnp.abs(theta[0]), tiny)
+        done = (res <= tol) & (res >= 0.5 * prev)
+        return it + 1, jnp.linalg.qr(z)[0], theta, x, res, done
+
+    def unfinished(state):
+        it, *_, done = state
+        return (it < _EIG_MAX_STEPS) & ~done
+
+    init = (
+        jnp.int32(0), q0, jnp.zeros((k,), cov.dtype), jnp.zeros((d, k), cov.dtype),
+        jnp.asarray(jnp.inf, cov.dtype), jnp.asarray(False),
+    )
+    it, _, theta, x, _, done = lax.while_loop(unfinished, step, init)
+    return theta, x, it, done
+
+
 def topk_eigh(cov: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     """Top-k eigenpairs of a symmetric matrix, descending, sign-fixed.
 
@@ -331,10 +526,27 @@ def topk_eigh(cov: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     on one GPU via ``raft::linalg::eigDC`` + column/row reversal
     (``rapidsml_jni.cu:215-268``); here it runs replicated on every chip
     (d is small relative to HBM; replication avoids a gather).
+
+    Up to ``_EIGH_DIRECT`` columns: ``jnp.linalg.eigh`` of the whole matrix.
+    Wider: :func:`_subspace_topk`, named ``pca.eig`` among the program's
+    operations, and where its loop ran out of steps before its residual
+    reached the float32 floor (a spectrum that does not decay: λ_{b+1}/λ_k
+    above ≈ 0.95) the pairs come from LAPACK on the host instead, in float64
+    (:func:`_host_topk_eigh`, seconds at d = 3000) — never an unconverged
+    block. Either way the k leading pairs to float32.
     """
-    evals, evecs = jnp.linalg.eigh(cov)        # ascending
-    evals = evals[::-1][:k]
-    evecs = evecs[:, ::-1][:, :k]
+    d = cov.shape[0]
+    if d <= _EIGH_DIRECT:
+        evals, evecs = jnp.linalg.eigh(cov)        # ascending
+        return evals[::-1][:k], sign_flip(evecs[:, ::-1][:, :k])
+    with jax.named_scope("pca.eig"):
+        theta, x, _, done = _subspace_topk(cov, k)
+        shapes = (jax.ShapeDtypeStruct((k,), cov.dtype), jax.ShapeDtypeStruct((d, k), cov.dtype))
+        evals, evecs = lax.cond(
+            done,
+            lambda: (theta, x),
+            lambda: tuple(jax.pure_callback(lambda a: _host_topk_eigh(a, k), shapes, cov)),
+        )
     return evals, sign_flip(evecs)
 
 

@@ -74,12 +74,68 @@ def _has_kernel(compiled) -> bool:
 # ---- the smoke path, one chip -------------------------------------------
 
 
-def test_gram_kernel_compiles(one_chip):
-    from spark_rapids_ml_tpu.ops.linalg import _shifted_gram_pallas
+@pytest.mark.parametrize(
+    "rows,cols", [(500_000, 3000), (200_000, 4000), (131_072, 300)],
+    ids=["reference_shape", "widest_accumulator", "narrow_width"],
+)
+def test_gram_kernel_compiles(topo, one_chip, rows, cols):
+    """The Gram pass reads a rows-minor shard as its transpose (a bitcast),
+    with the whole (padded d)² float32 accumulator in VMEM: 37.7 MB at
+    ``pca_dbx``'s 3000 → 3072 columns, 67 MB at 4000 → 4096, the widest the
+    gate admits beside the frame's blocks. No copy of the frame stands in
+    front of the kernel: the program's temporaries are the accumulator's
+    mirror and no more."""
+    from spark_rapids_ml_tpu.ops import linalg
 
-    fn = jax.jit(lambda X, m, mu: _shifted_gram_pallas(X, m, mu, interpret=False))
-    c = fn.lower(one_chip((ROWS, D)), one_chip((ROWS,)), one_chip((D,))).compile()
+    assert linalg.rows_minor(topo.devices[0], rows, cols)
+    tile, block, need = linalg.gram_tile(cols)
+    assert need <= linalg._GRAM_VMEM_LIMIT
+    fn = jax.jit(lambda X, m, mu: linalg._shifted_gram_pallas(X.T, m, mu, interpret=False))
+    c = fn.lower(one_chip((rows, cols)), one_chip((rows,)), one_chip((cols,))).compile()
     assert _has_kernel(c)
+    dp = -(-cols // block) * block
+    assert c.memory_analysis().temp_size_in_bytes < 2.5 * dp * dp * 4
+
+
+def test_pca_fit_reads_the_reference_frame_in_place(topo, no_compile_cache, monkeypatch):
+    """The whole ``_pca_fit_kernel`` program at ``pca_dbx``'s shard (500,000 x
+    3000 f32 on one described chip): the mean's sample, the Gram kernel, the
+    subspace iteration and its host finish. The parent held a 6.9 GB
+    row-major copy of the frame for a strided sample and compiled
+    ``jnp.linalg.eigh`` for 262 s (PERF.md section 6, PR 33); this program's
+    temporaries stay under one 786 MB block of the frame, by far."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.models.feature import _pca_fit_kernel
+
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "mp"))
+    rows = lambda shape: jax.ShapeDtypeStruct(shape, F32, sharding=NamedSharding(mesh, P("dp")))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the gate
+    n, d = 500_000, 3000
+    c = _pca_fit_kernel.lower(rows((n, d)), rows((n,)), k=3, mesh=mesh, csize=62_500).compile()
+    txt = c.as_text()
+    assert "tpu_custom_call" in txt
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes > n * d * 4
+    assert mem.temp_size_in_bytes < (128 << 20)
+
+
+def test_pca_fit_at_a_row_major_shard_takes_xlas_pass(topo, no_compile_cache, monkeypatch):
+    """``chip_smoke.py``'s 4,194,304 x 256 lives row-major, where the kernel
+    would get a relayout of the whole frame in front of it: the gate says
+    ``rows_minor`` and XLA's blocked pass at HIGHEST reads the frame in place."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.models.feature import _pca_fit_kernel
+    from spark_rapids_ml_tpu.ops import linalg
+
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "mp"))
+    rows = lambda shape: jax.ShapeDtypeStruct(shape, F32, sharding=NamedSharding(mesh, P("dp")))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert linalg.gram_pallas_declined(ROWS, D, F32, topo.devices[0]) == "rows_minor"
+    c = _pca_fit_kernel.lower(rows((ROWS, D)), rows((ROWS,)), k=3, mesh=mesh, csize=65_536).compile()
+    assert "tpu_custom_call" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < (128 << 20)
 
 
 @pytest.mark.parametrize(
@@ -223,19 +279,21 @@ def test_knn_pass_kernel_compiles(one_chip):
 
 
 def test_sharded_gram_step_compiles_for_four_chips(four_chips, monkeypatch):
-    """The PCA fit program at num_workers=4: the Gram kernel on every shard
-    and a psum of the partials, a quarter of X per device."""
+    """The PCA fit program at num_workers=4 on the source's whole 1,000,000 x
+    3000 set: the Gram kernel on every shard (250,000 rows a chip, rows minor
+    too) and a psum of the partials, a quarter of X per device."""
     from spark_rapids_ml_tpu.models.feature import _pca_fit_kernel
 
     mesh, rows = four_chips
+    n, d = 1_000_000, 3000
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the gate
     c = _pca_fit_kernel.lower(
-        rows((ROWS, D)), rows((ROWS,)), k=3, mesh=mesh, csize=65_536
+        rows((n, d)), rows((n,)), k=3, mesh=mesh, csize=62_500
     ).compile()
     txt = c.as_text()
     assert "tpu_custom_call" in txt and "all-reduce" in txt
     per_device = c.memory_analysis().argument_size_in_bytes
-    assert per_device < 0.3 * ROWS * D * 4
+    assert per_device < 0.3 * n * d * 4
 
 
 def test_knn_ring_compiles_for_four_chips(four_chips, monkeypatch):
