@@ -187,8 +187,8 @@ class FitInputs:
     ragged partitions become an even row-shard plus a validity mask.
     """
 
-    X: jax.Array                     # (N_pad, d_padded) row-sharded over dp
-    mask: jax.Array                  # (N_pad,) 1.0 valid / 0.0 padding
+    X: Optional[jax.Array]           # (N_pad, d_padded) row-sharded over dp; None until placed
+    mask: Optional[jax.Array]        # (N_pad,) 1.0 valid / 0.0 padding
     mesh: Any
     n_rows: int                      # true (unpadded) row count
     n_features: int                  # true (logical) feature count
@@ -198,6 +198,8 @@ class FitInputs:
     dtype: Any = jnp.float32
     csize: int = 1                   # per-device row-chunk size (scan kernels)
     n_features_padded: int = 0       # X's column count incl. lane padding
+    X_host: Optional[np.ndarray] = None  # the frame before it is placed (an estimator that places its own: PCA)
+    folded: Optional[Any] = None     # what such an estimator's fold left on the devices, for the later lanes
 
 
 # fit function: (inputs, params_dict) -> dict of named numpy arrays/scalars
@@ -677,7 +679,12 @@ class _TpuEstimator(Params, _TpuParams):
         (KMeans centroids, IVF lists) override."""
         return float(n_features_padded) ** 2 * np.dtype(dtype).itemsize
 
-    def _pre_process_data(self, dataset: DataFrame) -> FitInputs:
+    def _host_inputs(self, dataset: DataFrame) -> FitInputs:
+        """The host part of preprocessing: the feature column resolved,
+        cast and made contiguous, the mesh and the chunk size — a
+        :class:`FitInputs` whose frame is still on the host (``X_host``),
+        with nothing placed. ``_pre_process_data`` places it; an estimator
+        that places its own frame (PCA) returns this as it is."""
         X, X_sparse = _resolve_feature_matrix(self, dataset)
         if X_sparse is not None:
             # Sparse path: the device arrays are densified (TPUs have no
@@ -709,14 +716,36 @@ class _TpuEstimator(Params, _TpuParams):
         place = self._x_placement_dtype()
         if place is not None and np.dtype(dtype) == np.dtype(np.float32):
             X = X.astype(place)
-        # the zero columns up to d_padded are written on the device
-        Xd, maskd = shard_rows(X, mesh, csize, cols=d_padded)
+        return FitInputs(
+            X=None,
+            mask=None,
+            mesh=mesh,
+            n_rows=n_global,
+            n_features=int(n_features),
+            X_sparse=X_sparse,
+            dtype=jnp.dtype(dtype),
+            csize=csize,
+            n_features_padded=d_padded,
+            X_host=X,
+        )
 
-        y = w = None
+    def _pre_process_data(self, dataset: DataFrame) -> FitInputs:
+        """Put-then-solve: the frame, its mask, labels and weights on the
+        devices before the fit function runs — for a solver that needs the
+        whole frame at once (every pass of L-BFGS and Lloyd reads every
+        row). An estimator whose fit is a sum over rows owns its placement
+        instead (``PCA._pre_process_data``)."""
+        inputs = self._host_inputs(dataset)
+        dtype = inputs.dtype
+        # the zero columns up to d_padded are written on the device
+        inputs.X, inputs.mask = shard_rows(
+            inputs.X_host, inputs.mesh, inputs.csize, cols=inputs.n_features_padded
+        )
+        inputs.X_host = None
         if self._require_label():
             label_col = self.getOrDefault("labelCol")
             y_host = np.asarray(dataset.column(label_col), dtype=dtype)
-            y = shard_aligned(y_host, mesh, Xd.shape[0])
+            inputs.y = shard_aligned(y_host, inputs.mesh, inputs.X.shape[0])
         wcol = self._resolved_weight_col()
         if wcol is not None:
             if wcol not in dataset:
@@ -724,21 +753,8 @@ class _TpuEstimator(Params, _TpuParams):
                     f"weightCol {wcol!r} not found in dataset columns {dataset.columns}"
                 )
             w_host = np.asarray(dataset.column(wcol), dtype=dtype)
-            w = shard_aligned(w_host, mesh, Xd.shape[0])
-
-        return FitInputs(
-            X=Xd,
-            mask=maskd,
-            mesh=mesh,
-            n_rows=n_global,
-            n_features=int(n_features),
-            y=y,
-            weight=w,
-            X_sparse=X_sparse,
-            dtype=jnp.dtype(dtype),
-            csize=csize,
-            n_features_padded=d_padded,
-        )
+            inputs.weight = shard_aligned(w_host, inputs.mesh, inputs.X.shape[0])
+        return inputs
 
     # ---- fit -------------------------------------------------------------
     def fit(self, dataset: DataFrame, params: Optional[Dict[Any, Any]] = None) -> "_TpuModel":

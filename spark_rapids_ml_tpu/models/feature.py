@@ -13,9 +13,12 @@ API parity targets:
     back (``feature.py:426-439``). We compute ``X @ pc`` directly.
 
 TPU-native fit (vs reference's cuML ``PCAMG.fit``, ``feature.py:216-259``):
-one jitted global-math function over the row-sharded design matrix — masked
-mean + float32-exact Gram (psum'd over the dp mesh axis), the k leading
-eigenpairs of the replicated d×d covariance (``ops.linalg.topk_eigh``),
+the covariance is a sum over rows, so the estimator places its own frame and
+folds every row block into float32-exact shifted sums as the block lands on
+its device (``ops.linalg.gram_fold`` under ``parallel.mesh.shard_rows``), the
+Gram running under the frame's crossing; one finish program then psums the
+partials over the dp mesh axis, re-centres, and takes the k leading
+eigenpairs of the replicated d×d covariance (``ops.linalg.topk_eigh``) with a
 deterministic sign flip.
 """
 
@@ -41,16 +44,20 @@ from ..params import (
     _mk,
 )
 from ..ops.linalg import (
+    cov_from_gram_folds,
     gram_block_rows,
+    gram_fold,
+    gram_fold_zeros,
     gram_pallas_declined,
     gram_tile,
+    host_mean_sample,
     mean_and_cov,
     mean_and_cov_chunked,
     mp_gram_blocks,
     rows_minor,
     topk_eigh,
 )
-from ..parallel.mesh import DP_AXIS
+from ..parallel.mesh import DP_AXIS, allreduce_sum_host, row_sharding, shard_rows
 from ..runtime import telemetry
 
 
@@ -105,17 +112,23 @@ def _pca_fit_kernel(
     X: jax.Array, mask: jax.Array, k: int, mesh=None, csize=None,
     mp_blocks: bool = False,
 ):
-    """Resident-fit kernel. With ``mesh``/``csize`` (rows dp-sharded, padded
-    to a per-device ``csize`` multiple) the covariance is accumulated block
-    by block in one float32-exact pass over X (``mean_and_cov_chunked``: the
-    Pallas Gram kernel over a rows-minor shard, XLA's blocked pass
-    otherwise) — at double-digit-GB row counts the fused form can
-    materialize the centered copy of X and OOM; without them (e.g. 2-D
-    (dp, mp)-sharded dry runs) the fused global-math path is used.
-    ``mp_blocks`` (static; resolve with ``mp_gram_blocks`` outside jit)
-    column-shards the Gram accumulator over the mesh's mp axis; the blocked
-    covariance also rides out in the result so the caller can measure its
-    per-shard bytes."""
+    """The whole fit as ONE program over a frame that is already resident:
+    covariance, then :func:`_pca_from_cov`. What ``PCA.fit`` runs only where
+    the Gram's accumulator is column-sharded over the mesh's mp axis
+    (``mp_blocks``; static, resolve with ``mp_gram_blocks`` outside jit; the
+    blocked covariance rides out in the result so the caller can measure its
+    per-shard bytes) — every other resident fit folds the row blocks under
+    the frame's crossing (:class:`_GramFold`, :func:`_pca_finish`) and never
+    hands the frame to one program. Also the multi-chip dry run's
+    (``__graft_entry__``).
+
+    With ``mesh``/``csize`` (rows dp-sharded, padded to a per-device
+    ``csize`` multiple) the covariance is accumulated block by block in one
+    float32-exact pass over X (``mean_and_cov_chunked``: the Pallas Gram
+    kernel over a rows-minor shard, XLA's blocked pass otherwise) — at
+    double-digit-GB row counts the fused form can materialize the centered
+    copy of X and OOM; without them (2-D (dp, mp)-sharded dry runs) the fused
+    global-math path is used."""
     if mesh is not None and _TpuEstimator.rows_chunkable(
         X.shape[0], mesh, csize
     ):
@@ -128,6 +141,85 @@ def _pca_fit_kernel(
     if mp_blocks:
         out["cov"] = cov
     return out
+
+
+@functools.partial(jax.jit, static_argnames=("k", "mesh", "d", "pallas"))
+def _pca_finish(G, s, cnt, mean_hat, k: int, mesh, d: int, pallas: bool):
+    """What is left of a fit when the last row block has been folded: the
+    devices' partial sums to the covariance (``cov_from_gram_folds``: a psum
+    over dp, the rank-one correction) and :func:`_pca_from_cov`. Takes the
+    accumulators, not the frame. The covariance and the count ride out too:
+    a ``fitMultiple``'s later lanes run :func:`_pca_from_cov` on them."""
+    mean, cov, n = cov_from_gram_folds(G, s, cnt, mean_hat, mesh, d, pallas)
+    return _pca_from_cov(mean, cov, n, k), cov, n
+
+
+class _GramFold:
+    """PCA's fold over the row blocks of its frame (``parallel.mesh.RowFold``):
+    each block's shifted Gram, row sum and count are added into its device's
+    running float32 accumulators by one :func:`gram_fold` program, dispatched
+    when the block's put has been issued — so it runs while the next block
+    crosses the link.
+
+    μ̂, which every block is shifted by, has to exist before block 0 is
+    folded: it comes from the HOST array (``host_mean_sample``: runs of rows
+    from all over it, summed over the process world), taken inside the first
+    call, while block 0 is on its way. Which pass folds (the Pallas kernel or
+    XLA's blocked one) is the gate's answer for the FIRST block's shape, asked
+    once a fit: the accumulators have one form, and a short tail block that
+    the device keeps the other way round costs a relayout of that tail alone.
+    """
+
+    def __init__(self, x_host: np.ndarray, csize: int):
+        self.x_host, self.csize = x_host, csize
+        self.d, self.dtype = x_host.shape[1], x_host.dtype
+        self.mean_hat: Optional[np.ndarray] = None
+        self._on_device: Dict[Any, jax.Array] = {}
+        self.attrs: Dict[str, Any] = {}
+
+    def _first_call(self, rows: jax.Array, device) -> None:
+        s, c = allreduce_sum_host(*host_mean_sample(self.x_host))
+        self.mean_hat = (s / max(float(c), 1.0)).astype(self.dtype)
+        n, d = rows.shape
+        declined = gram_pallas_declined(n, d, self.dtype, device)
+        self.pallas = not declined
+        self.block = gram_block_rows(n, d, self.csize, self.dtype.itemsize)
+        self.attrs = {
+            "precision": "highest",
+            "rows_minor": jax.default_backend() == "tpu" and rows_minor(device, n, d, self.dtype),
+            "gram": "pallas" if self.pallas else "xla",
+            "tile": gram_tile(d)[0] if self.pallas else self.block,
+        }
+        if declined:
+            self.attrs["declined"] = declined
+
+    def __call__(self, state, rows: jax.Array, row0: int, valid: int):
+        (device,) = rows.devices()
+        if self.mean_hat is None:
+            self._first_call(rows, device)
+        if device not in self._on_device:
+            self._on_device[device] = jax.device_put(self.mean_hat, device)
+        if state is None:
+            state = gram_fold_zeros(self.d, self.dtype, self.pallas, device)
+        return gram_fold(
+            state, rows, self._on_device[device], np.int32(valid), pallas=self.pallas, block=self.block
+        )
+
+    def sums(self, states: Dict[Any, Any], mesh) -> tuple:
+        """Every device's state as three global arrays, stacked along axis 0
+        and sharded over dp (a device that got no block: zeros)."""
+        sh = row_sharding(mesh)
+        per_device = [
+            states[dev] or gram_fold_zeros(self.d, self.dtype, self.pallas, dev)
+            for dev in sh.addressable_devices_indices_map((mesh.shape[DP_AXIS],))
+        ]
+        n_dp = mesh.shape[DP_AXIS]
+        return tuple(
+            jax.make_array_from_single_device_arrays(
+                (n_dp * parts[0].shape[0],) + parts[0].shape[1:], sh, list(parts)
+            )
+            for parts in zip(*per_device)
+        )
 
 
 @jax.jit
@@ -167,24 +259,64 @@ class PCA(PCAClass, _TpuEstimator, _PCAParams):
         # upper bound of that block and the size of the mean's sample
         return self._equal_chunk_rows(n_rows, n_dp, 65_536)
 
-    def _gram_launch_attrs(self, inputs: FitInputs, use_mp: bool) -> Dict[str, Any]:
-        """What ``solver.launch`` says of the Gram pass: the gate that
-        ``mean_and_cov_chunked`` takes at trace time, asked again on the
-        host."""
+    def _enable_fit_multiple_in_single_pass(self) -> bool:
+        # one placement and one fold; the later lanes reuse the covariance
+        return True
+
+    def _pre_process_data(self, dataset: DataFrame) -> FitInputs:
+        """The host part alone (column, dtype, contiguity, mesh, chunk size).
+        The fit is a sum over rows, so the fit function places the frame
+        itself and folds each row block as it lands (:class:`_GramFold`): the
+        frame's crossing lies inside ``fit.dispatch``, with the Gram under it."""
+        return self._host_inputs(dataset)
+
+    def _fit_under_the_put(self, inputs: FitInputs, k: int) -> Dict[str, Any]:
+        """Place the frame with the Gram folded under its crossing, then
+        dispatch the finish program. Leaves the frame and the covariance on
+        ``inputs`` for the later lanes of a ``fitMultiple``."""
+        fold = _GramFold(inputs.X_host, inputs.csize)
+        inputs.X, inputs.mask, states = shard_rows(inputs.X_host, inputs.mesh, inputs.csize, fold=fold)
+        inputs.X_host = None
+        if fold.mean_hat is None:
+            raise ValueError("PCA.fit: the dataset has no rows")
+        with telemetry.span(
+            "solver.launch",
+            program=f"{gram_fold.__name__},{_pca_finish.__name__}",
+            gram_under_put=True,
+            **fold.attrs,
+        ):
+            out, cov, n = _pca_finish(
+                *fold.sums(states, inputs.mesh), fold.mean_hat, k=k, mesh=inputs.mesh,
+                d=fold.d, pallas=fold.pallas,
+            )
+        inputs.folded = (out["mean"], cov, n)
+        return out
+
+    def _fit_mp_blocked(self, inputs: FitInputs, k: int, mp: int):
+        """The Gram's accumulator column-sharded over the mesh's mp axis: the
+        frame goes up first, then ONE program reads it whole
+        (:func:`_pca_fit_kernel`); nothing runs under the put."""
+        if inputs.X is None:
+            inputs.X, inputs.mask = shard_rows(inputs.X_host, inputs.mesh, inputs.csize)
+            inputs.X_host = None
         n_local = inputs.X.shape[0] // inputs.mesh.shape[DP_AXIS]
         d = inputs.X.shape[1]
         device = inputs.mesh.devices.flat[0]
-        attrs: Dict[str, Any] = {
-            "precision": "highest",
-            "rows_minor": jax.default_backend() == "tpu" and rows_minor(device, n_local, d, inputs.X.dtype),
-        }
-        if not _TpuEstimator.rows_chunkable(inputs.X.shape[0], inputs.mesh, inputs.csize):
-            return dict(attrs, gram="xla_fused", tile=inputs.X.shape[0])
-        declined = gram_pallas_declined(n_local, d, inputs.X.dtype, device, mp_blocks=use_mp)
-        if declined:
-            tile = gram_block_rows(n_local, d, inputs.csize, inputs.X.dtype.itemsize)
-            return dict(attrs, gram="xla", tile=tile, declined=declined)
-        return dict(attrs, gram="pallas", tile=gram_tile(d)[0])
+        with telemetry.span(
+            "solver.launch",
+            program=_pca_fit_kernel.__name__,
+            gram_under_put=False,
+            precision="highest",
+            rows_minor=jax.default_backend() == "tpu" and rows_minor(device, n_local, d, inputs.X.dtype),
+            gram="xla",
+            tile=gram_block_rows(n_local, d, inputs.csize, inputs.X.dtype.itemsize),
+            declined=gram_pallas_declined(n_local, d, inputs.X.dtype, device, mp_blocks=True),
+        ):
+            out = _pca_fit_kernel(
+                inputs.X, inputs.mask, k, mesh=inputs.mesh, csize=inputs.csize, mp_blocks=True,
+            )
+        cov = out.pop("cov")
+        return out, {"mp_degree": mp, "gram_shard_bytes": int(cov.addressable_shards[0].data.nbytes)}
 
     def _get_tpu_fit_func(self, dataset: DataFrame) -> FitFunc:
         def _fit(inputs: FitInputs, params: Dict[str, Any]) -> Dict[str, Any]:
@@ -193,28 +325,16 @@ class PCA(PCAClass, _TpuEstimator, _PCAParams):
                 raise ValueError(
                     f"k={k} must be <= number of features {inputs.n_features}"
                 )
-            mp = mp_gram_blocks(inputs.mesh, inputs.X.shape[1])
-            use_mp = mp > 1 and _TpuEstimator.rows_chunkable(
-                inputs.X.shape[0], inputs.mesh, inputs.csize
-            )
-            with telemetry.span(
-                "solver.launch",
-                program=_pca_fit_kernel.__name__,
-                **self._gram_launch_attrs(inputs, use_mp),
-            ):
-                out = _pca_fit_kernel(
-                    inputs.X, inputs.mask, k, mesh=inputs.mesh,
-                    csize=inputs.csize, mp_blocks=use_mp,
-                )
+            mp = mp_gram_blocks(inputs.mesh, inputs.n_features_padded)
             report = None
-            if use_mp:
-                cov = out.pop("cov")
-                report = {
-                    "mp_degree": mp,
-                    "gram_shard_bytes": int(
-                        cov.addressable_shards[0].data.nbytes
-                    ),
-                }
+            if mp > 1 and inputs.csize > 1:
+                out, report = self._fit_mp_blocked(inputs, k, mp)
+            elif inputs.folded is None:
+                out = self._fit_under_the_put(inputs, k)
+            else:
+                # a later lane of a fitMultiple: the first lane's covariance
+                with telemetry.span("solver.launch", program=_pca_from_cov.__name__, gram_under_put=False):
+                    out = _pca_from_cov(*inputs.folded, k)
             # the first fetch blocks until the fit program has run
             with telemetry.span("solver.fetch", k=k, d=inputs.n_features):
                 result = {key: np.asarray(v) for key, v in out.items()}

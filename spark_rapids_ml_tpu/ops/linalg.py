@@ -11,6 +11,7 @@ role NCCL allreduce played for cuML.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import numpy as np
@@ -131,7 +132,7 @@ def gram_tile(d: int) -> Tuple[int, int, int]:
     return tile, block, need
 
 
-def _shifted_gram_pallas(
+def _gram_triangle_pallas(
     Xt: jax.Array,
     ml: jax.Array,
     mean_hat: jax.Array,
@@ -139,9 +140,12 @@ def _shifted_gram_pallas(
     tile: int | None = None,
     interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Pallas TPU kernel: one pass over a shard kept with its ROWS minor,
-    accumulating the shifted Gram ``Σ m·(x-μ̂)(x-μ̂)ᵀ`` and row-sum
-    ``Σ m·(x-μ̂)`` with float32-exact products.
+    """Pallas TPU kernel: one pass over a shard (or one row block of it) kept
+    with its ROWS minor, accumulating the shifted Gram ``Σ m·(x-μ̂)(x-μ̂)ᵀ``
+    and row-sum ``Σ m·(x-μ̂)`` with float32-exact products. Returns them as
+    the kernel leaves them — the upper block triangle ``(nb, block, padded
+    d)`` and the row sum's ``(padded d, 128)`` lane partials — which add up
+    over row blocks as they are; :func:`_mirror_gram_triangle` finishes them.
 
     ``Xt`` is the (d, n) transpose of the shard — the same bytes (a TPU keeps
     ``f32[500000,3000]`` as 3000 × 500,096: whichever way pads less) — so the
@@ -152,8 +156,7 @@ def _shifted_gram_pallas(
     ``block``-row slabs (i ≤ j) one ``dot_general`` over the lanes at
     ``Precision.HIGHEST`` (six bf16 passes: float32 to the last bit that an
     f32 accumulator keeps) adds to accumulator block (i, j): the upper block
-    triangle, 21 of 36 blocks at d = 3000. The lower triangle is its mirror,
-    written once in XLA after the call. The block of the frame is taken
+    triangle, 21 of 36 blocks at d = 3000. The block of the frame is taken
     ``padded d`` rows tall: the rows past ``d`` (the overhang of the one block
     along that axis) are zeroed, as are lanes past ``n`` and masked samples.
     """
@@ -228,11 +231,34 @@ def _shifted_gram_pallas(
         name="pca_gram_pass",
         interpret=interpret,
     )(Xt, ml.reshape(1, n), jnp.broadcast_to(jnp.pad(mean_hat, (0, dp - d))[:, None], (dp, _LANES)))
+    return G, s
+
+
+def _mirror_gram_triangle(G: jax.Array, s: jax.Array, d: int) -> Tuple[jax.Array, jax.Array]:
+    """``(Gram (d, d), row sum (d,))`` from :func:`_gram_triangle_pallas`'s
+    outputs, or from sums of them over row blocks and devices: the lower
+    block triangle is the mirror of the upper, written once in XLA; the
+    diagonal blocks are symmetrized."""
+    nb, bs, dp = G.shape
     U = G.reshape(dp, dp)
     bi = lax.broadcasted_iota(jnp.int32, (dp, dp), 0) // bs
     bj = lax.broadcasted_iota(jnp.int32, (dp, dp), 1) // bs
     G = jnp.where(bi < bj, U, jnp.where(bi > bj, U.T, 0.5 * (U + U.T)))
     return G[:d, :d], s.sum(axis=1)[:d]
+
+
+def _shifted_gram_pallas(
+    Xt: jax.Array,
+    ml: jax.Array,
+    mean_hat: jax.Array,
+    *,
+    tile: int | None = None,
+    interpret: bool | None = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """``(Σ m·(x-μ̂)(x-μ̂)ᵀ, Σ m·(x-μ̂))`` of a whole rows-minor shard in one
+    call: :func:`_gram_triangle_pallas`, mirrored."""
+    G, s = _gram_triangle_pallas(Xt, ml, mean_hat, tile=tile, interpret=interpret)
+    return _mirror_gram_triangle(G, s, Xt.shape[0])
 
 
 def _shifted_gram_xla(
@@ -338,11 +364,48 @@ def _mean_sample(Xl: jax.Array, ml: jax.Array, rows: int) -> Tuple[jax.Array, ja
     return s, c
 
 
+def host_mean_sample(x: np.ndarray, rows: int = 4096) -> Tuple[np.ndarray, float]:
+    """``(Σ x, count)`` in float64 over about ``rows`` rows of the HOST array,
+    taken as 64 runs of consecutive rows spread evenly from its first row to
+    its last (an array of at most ``rows`` rows: all of it). What
+    :func:`_mean_sample` is to a shard on the device, for a caller that needs
+    μ̂ before the first row block is there (:func:`gram_fold`): 49 MB of host
+    reads at 3000 f32 columns, a few milliseconds. From all over the array
+    and not its leading rows, so that sorted or drifting data still gives
+    δ = O(σ/√rows)."""
+    n = x.shape[0]
+    if n <= rows:
+        return x.sum(axis=0, dtype=np.float64), float(n)
+    runs = 64
+    width = max(1, rows // runs)
+    s = np.zeros(x.shape[1:], np.float64)
+    for r in range(runs):
+        lo = r * (n - width) // (runs - 1)
+        s += x[lo:lo + width].sum(axis=0, dtype=np.float64)
+    return s, float(runs * width)
+
+
+def _recentre(G, s, n, mean_hat, cols=None):
+    """``(mean, covariance over n-1)`` from the shifted sums ``G = Σ m·(x-μ̂)
+    (x-μ̂)ᵀ``, ``s = Σ m·(x-μ̂)`` and the count: with ``δ = s/n`` the exact
+    mean minus μ̂, ``cov = (G − n·δδᵀ)/(n−1)`` exactly, whatever μ̂ — only the
+    rounding of the correction depends on how small δ is. ``cols`` = (start,
+    width) of the column block that ``G`` holds, where it is not the whole."""
+    delta = s / n
+    delta_b = delta if cols is None else lax.dynamic_slice_in_dim(delta, cols[0], cols[1], 0)
+    return mean_hat + delta, (G - n * jnp.outer(delta, delta_b)) / (n - 1.0)
+
+
 def mean_and_cov_chunked(
     X: jax.Array, mask: jax.Array, mesh, csize: int, *, mp_blocks: bool = False
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """:func:`mean_and_cov` with bounded temporaries, ONE pass over X and
-    float32-exact products.
+    float32-exact products — over a frame that is already WHOLE on the
+    devices: one program, which starts when the last row block has landed.
+    ``PCA.fit`` runs it (inside ``_pca_fit_kernel``) only with ``mp_blocks``;
+    its other resident fits run the same passes block by block under the
+    frame's crossing (:func:`gram_fold`, :func:`cov_from_gram_folds`), with
+    μ̂ from the host (:func:`host_mean_sample`) instead of the device sample.
 
     The fused form relies on XLA folding the ``(X - μ)·mask`` centering into
     the Gram matmul's operand read; at double-digit-GB row counts the
@@ -416,15 +479,9 @@ def mean_and_cov_chunked(
         n = lax.psum(cnt, DP_AXIS)
         s = lax.psum(s, DP_AXIS)
         G = lax.psum(G, DP_AXIS)
-        delta = s / n                      # exact mean minus μ̂
-        mean = mean_hat + delta
-        if n_mp > 1:
-            delta_b = lax.dynamic_slice_in_dim(
-                delta, lax.axis_index(MP_AXIS) * bw, bw, 0
-            )
-            cov = (G - n * jnp.outer(delta, delta_b)) / (n - 1.0)
-        else:
-            cov = (G - n * jnp.outer(delta, delta)) / (n - 1.0)
+        mean, cov = _recentre(
+            G, s, n, mean_hat, cols=(lax.axis_index(MP_AXIS) * bw, bw) if n_mp > 1 else None
+        )
         return mean, cov, n
 
     return shard_map(
@@ -434,6 +491,70 @@ def mean_and_cov_chunked(
         out_specs=(LAYOUT.replicated(), LAYOUT.cols() if n_mp > 1 else LAYOUT.replicated(), LAYOUT.replicated()),
         check_vma=False,
     )(X, mask)
+
+
+def gram_fold_zeros(d: int, dtype, pallas: bool, device) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The state :func:`gram_fold` starts from on ``device``: zero ``(Gram,
+    row sum, count)`` in the form its pass leaves them — the Pallas kernel's
+    upper block triangle and lane partials, or XLA's (d, d) and (d,)."""
+    if pallas:
+        _, bs, _ = gram_tile(d)
+        dp = -(-d // bs) * bs
+        shapes = ((dp // bs, bs, dp), (dp, _LANES), (1,))
+    else:
+        shapes = ((d, d), (d,), (1,))
+    return tuple(jnp.zeros(shape, dtype, device=device) for shape in shapes)
+
+
+@functools.partial(jax.jit, static_argnames=("pallas", "block"), donate_argnums=0)
+def gram_fold(acc, rows: jax.Array, mean_hat: jax.Array, valid, *, pallas: bool, block: int):
+    """``acc`` (one device's running ``(Gram, row sum, count)``, donated) plus
+    the shifted sums of one row block: ``Σ (x-μ̂)(x-μ̂)ᵀ``, ``Σ (x-μ̂)`` and
+    the count over the ``valid`` leading rows of ``rows``. The covariance is
+    a sum over rows, so over row blocks: handed to ``shard_rows`` as its fold,
+    this program runs on block *i* while block *i+1* crosses the link, and
+    :func:`cov_from_gram_folds` finishes what the blocks left.
+
+    The pass is the one :func:`mean_and_cov_chunked` runs on a whole shard,
+    chosen the same way (``pallas``: :func:`gram_pallas_declined` of the
+    block's shape): the Pallas kernel over the block's transposed view where
+    the device keeps the block rows minor — its upper block triangle is added
+    as it is, and mirrored once, in the finish — XLA's blocked pass (``block``
+    rows a product) otherwise. Both at ``Precision.HIGHEST``; the sums over
+    blocks are float32 adds of float32 partials, eight an entry at
+    ``pca_dbx``'s frame. ``valid`` is traced: one program a block shape."""
+    m = (lax.iota(jnp.int32, rows.shape[0]) < valid).astype(rows.dtype)
+    with jax.named_scope("pca.gram"):
+        if pallas:
+            G, s = _gram_triangle_pallas(rows.T, m, mean_hat)
+            cnt = m.sum()
+        else:
+            G, s, cnt = _shifted_gram_xla(rows, m, mean_hat, block=block)
+    return acc[0] + G, acc[1] + s, acc[2] + cnt
+
+
+def cov_from_gram_folds(
+    G: jax.Array, s: jax.Array, cnt: jax.Array, mean_hat: jax.Array, mesh, d: int, pallas: bool
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``(mean, covariance, count)``, replicated, from every device's
+    :func:`gram_fold` state stacked along axis 0 and sharded over dp: one
+    ``psum`` over dp (the communication of :func:`mean_and_cov_chunked`), the
+    triangle's mirror where the Pallas kernel left one, and the rank-one
+    correction. The frame is no operand of it."""
+
+    def per_device(Gl, sl, cl, mu):
+        G, s, n = lax.psum(Gl, DP_AXIS), lax.psum(sl, DP_AXIS), lax.psum(cl[0], DP_AXIS)
+        if pallas:
+            G, s = _mirror_gram_triangle(G, s, d)
+        return _recentre(G, s, n, mu) + (n,)
+
+    return shard_map(
+        per_device,
+        mesh=mesh,
+        in_specs=(LAYOUT.rows(),) * 3 + (LAYOUT.replicated(),),
+        out_specs=(LAYOUT.replicated(),) * 3,
+        check_vma=False,
+    )(G, s, cnt, mean_hat)
 
 
 def sign_flip(vectors: jax.Array) -> jax.Array:

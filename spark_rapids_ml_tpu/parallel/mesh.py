@@ -17,7 +17,7 @@ from __future__ import annotations
 import collections
 import functools
 import itertools
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -311,16 +311,44 @@ def _write_block(buf: jax.Array, block: jax.Array, row0: Any) -> Tuple[jax.Array
     return buf, row0 + block.shape[0]
 
 
+# A fold over the row blocks of a shard as they land on its device:
+# ``fold(state, rows, row0, valid) -> state``. ``state`` is that device's
+# running state (``None`` before its first block), ``rows`` the block as it
+# sits on the device, ``row0`` its first row within the device's shard and
+# ``valid`` how many of its leading rows are rows of the host array (all of
+# them for a block of the loop; a shard that went up in one put carries its
+# padding rows behind them). It dispatches its work and never waits for it.
+RowFold = Callable[[Any, jax.Array, int, int], Any]
+
+
 def _put_row_blocks(
-    x: np.ndarray, shape: Tuple[int, ...], sh: NamedSharding, block_rows: int
-) -> Tuple[jax.Array, int]:
+    x: np.ndarray,
+    shape: Tuple[int, ...],
+    sh: NamedSharding,
+    block_rows: int,
+    fold: Optional[RowFold] = None,
+) -> Tuple[jax.Array, int, Dict[Any, Any]]:
     """Assemble the row-sharded global array of ``shape`` on the devices from
     puts of at most ``block_rows`` rows of ``x``; rows past ``len(x)`` are
     zero, and so are columns past ``x``'s own where ``shape`` is the wider:
     the blocks are slices of ``x`` as it is, written at column 0. Never holds
     a second copy of a shard, on the host or on a device: each device's zero
-    buffer is written in place, block by block. Returns the array and the
-    puts issued."""
+    buffer is written in place, block by block.
+
+    With a ``fold`` (:data:`RowFold`) every block is handed to it as it sits
+    on the device, in row order, right AFTER its write is dispatched: the
+    fold's program runs while the next blocks cross the link. The fold only
+    dispatches; the loop's one wait stays the write of the put two before,
+    and that write must not stand behind the block's fold on the device —
+    the next put is issued when it has run, and a put issued 28 ms later
+    leaves the link with nothing to send that long (write before fold: the
+    frame is up in 0.61 s; fold before write: 0.69 s; no fold: 0.58 s;
+    PERF.md section 6, PR 34). A block lives until its fold has run, so
+    three are on the device for the length of a fold, not two. Without a
+    fold the loop is what it was.
+
+    Returns the array, the puts issued and each device's fold state (``None``
+    for a device that got no block, and for every device without a fold)."""
     bufs, todo = {}, []
     for dev, idx in sh.addressable_devices_indices_map(shape).items():
         lo, hi, _ = idx[0].indices(shape[0])
@@ -333,18 +361,25 @@ def _put_row_blocks(
     # round-robin over the devices, so that their DMA queues run together
     puts = [p for step in itertools.zip_longest(*todo) for p in step if p is not None]
     pending = collections.deque()
+    states = dict.fromkeys(bufs)
     for dev, row0, rows in puts:
         if len(pending) == _PUTS_IN_FLIGHT:
             pending.popleft().block_until_ready()
         block = jax.device_put(rows, dev)
         bufs[dev], written = _write_block(bufs[dev], block, np.int32(row0))
+        if fold is not None:
+            states[dev] = fold(states[dev], block, row0, len(rows))
         pending.append(written)
-    return jax.make_array_from_single_device_arrays(shape, sh, list(bufs.values())), len(puts)
+    return jax.make_array_from_single_device_arrays(shape, sh, list(bufs.values())), len(puts), states
 
 
 def shard_rows(
-    x: np.ndarray, mesh: Mesh, row_multiple: int = 1, cols: Optional[int] = None
-) -> Tuple[jax.Array, jax.Array]:
+    x: np.ndarray,
+    mesh: Mesh,
+    row_multiple: int = 1,
+    cols: Optional[int] = None,
+    fold: Optional[RowFold] = None,
+) -> Tuple[Any, ...]:
     """Pad + device_put a host array row-sharded over the dp axis.
 
     This is the data-plane replacement for the reference's Arrow-batch →
@@ -363,6 +398,17 @@ def shard_rows(
     block): the zero columns are the device's own fill, and the host never
     pads or copies ``x``.
 
+    ``fold`` (:data:`RowFold`) is for a caller whose work is a sum over rows
+    (PCA's covariance): it is handed every row block as the block lands on
+    its device, so its programs run under the frame's crossing instead of
+    after it. A shard that goes up in one put, and a process's one put in
+    the multi-process path, call it once a device on the whole shard — one
+    contract whatever the number of blocks. The frame is assembled all the
+    same, and the return gains a third element: ``{device: state}`` over this
+    process's devices. The ``h2d.enqueue`` span then says ``folded_blocks``.
+    Without a fold nothing here differs from a call that has no such
+    argument.
+
     Multi-process: ``x`` is this process's local rows (each worker holds
     its partition, as each Spark barrier task held its Arrow batches).
     Processes agree on a common per-device row count via a host allgather
@@ -377,7 +423,7 @@ def shard_rows(
         raise ValueError(f"cannot place an array of shape {x.shape} at {cols} columns")
     pad_cols = cols - width
     if jax.process_count() > 1:
-        return _shard_rows_multiproc(x, mesh, row_multiple, pad_cols)
+        return _shard_rows_multiproc(x, mesh, row_multiple, pad_cols, fold)
     n_dp = mesh.shape[DP_AXIS]
     sh = row_sharding(mesh)
     n = x.shape[0]
@@ -388,10 +434,11 @@ def shard_rows(
         xp, mask = pad_rows(x, n_dp * row_multiple)
         with _enqueue_span(
             mesh, xp.nbytes + mask.nbytes, 2, blocks=1, block_bytes=xp.nbytes, cols=cols, pad_cols=0
-        ):
+        ) as span:
             xd = jax.device_put(xp, sh)
             md = jax.device_put(mask, sh)
-        return xd, md
+            states = _fold_whole_shards(fold, xd, n, span) if fold is not None else None
+        return (xd, md) if fold is None else (xd, md, states)
     mask = np.zeros((n_padded,), np.float32)
     mask[:n] = 1.0
     shape = (n_padded, cols) if pad_cols else (n_padded,) + x.shape[1:]
@@ -403,10 +450,26 @@ def shard_rows(
         cols=cols,
         pad_cols=pad_cols,
     ) as span:
-        xd, blocks = _put_row_blocks(x, shape, sh, block_rows)
+        xd, blocks, states = _put_row_blocks(x, shape, sh, block_rows, fold)
         span.set_attr(blocks=blocks)
         md = jax.device_put(mask, sh)
-    return xd, md
+        if fold is not None:
+            span.set_attr(folded_blocks=blocks)
+    return (xd, md) if fold is None else (xd, md, states)
+
+
+def _fold_whole_shards(fold: RowFold, xd: jax.Array, n_rows: int, span: Any) -> Dict[Any, Any]:
+    """``fold`` once on every local shard of ``xd`` (a frame that went up in
+    one put a device or a process), whole: ``{device: state}``. The process's
+    ``n_rows`` host rows lead its shards; what follows them is padding."""
+    spans = {s.device: s.index[0].indices(xd.shape[0])[:2] for s in xd.addressable_shards}
+    first = min(lo for lo, _ in spans.values())
+    states = {}
+    for shard in xd.addressable_shards:
+        lo, hi = spans[shard.device]
+        states[shard.device] = fold(None, shard.data, 0, min(max(first + n_rows - lo, 0), hi - lo))
+    span.set_attr(folded_blocks=len(states))
+    return states
 
 
 def _local_dp_devices(mesh: Mesh) -> int:
@@ -433,8 +496,8 @@ def _local_dp_devices(mesh: Mesh) -> int:
 
 
 def _shard_rows_multiproc(
-    x: np.ndarray, mesh: Mesh, row_multiple: int, pad_cols: int = 0
-) -> Tuple[jax.Array, jax.Array]:
+    x: np.ndarray, mesh: Mesh, row_multiple: int, pad_cols: int = 0, fold: Optional[RowFold] = None
+) -> Tuple[Any, ...]:
     from jax.experimental import multihost_utils
 
     if pad_cols:
@@ -466,10 +529,12 @@ def _shard_rows_multiproc(
     global_rows = per_dev * n_dp
     sh = row_sharding(mesh)
     cols = x.shape[1] if x.ndim > 1 else 1
-    with _enqueue_span(mesh, xp.nbytes + mask.nbytes, 2, cols=cols, pad_cols=pad_cols):
+    with _enqueue_span(mesh, xp.nbytes + mask.nbytes, 2, cols=cols, pad_cols=pad_cols) as span:
         xd = jax.make_array_from_process_local_data(sh, xp, (global_rows,) + x.shape[1:])
         md = jax.make_array_from_process_local_data(sh, mask, (global_rows,))
-    return xd, md
+        # one put a process: nothing to run under, the fold sees whole shards
+        states = _fold_whole_shards(fold, xd, x.shape[0], span) if fold is not None else None
+    return (xd, md) if fold is None else (xd, md, states)
 
 
 def shard_aligned(v: np.ndarray, mesh: Mesh, total_rows: int) -> jax.Array:

@@ -100,9 +100,11 @@ def test_gram_kernel_compiles(topo, one_chip, rows, cols):
 def test_pca_fit_reads_the_reference_frame_in_place(topo, no_compile_cache, monkeypatch):
     """The whole ``_pca_fit_kernel`` program at ``pca_dbx``'s shard (500,000 x
     3000 f32 on one described chip): the mean's sample, the Gram kernel, the
-    subspace iteration and its host finish. The parent held a 6.9 GB
+    subspace iteration and its host finish — one program over a resident
+    frame, which since PR 34 the cell's fit no longer runs (it folds the row
+    blocks: the two tests below). PR 33's parent held a 6.9 GB
     row-major copy of the frame for a strided sample and compiled
-    ``jnp.linalg.eigh`` for 262 s (PERF.md section 6, PR 33); this program's
+    ``jnp.linalg.eigh`` for 262 s (PERF.md section 6); this program's
     temporaries stay under one 786 MB block of the frame, by far."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -118,6 +120,47 @@ def test_pca_fit_reads_the_reference_frame_in_place(topo, no_compile_cache, monk
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes > n * d * 4
     assert mem.temp_size_in_bytes < (128 << 20)
+
+
+@pytest.mark.parametrize("block_rows", [65_536, 41_248], ids=["whole_block", "tail_block"])
+def test_gram_fold_compiles_at_the_cells_block_shapes(topo, one_chip, monkeypatch, block_rows):
+    """``pca_dbx``'s frame goes up in seven blocks of 65,536 rows and a tail
+    of 41,248, each 3000 wide and rows minor on the device: the fold reads a
+    block as its transpose (a bitcast), adds the kernel's triangle into the
+    donated accumulators, and holds that triangle and little more — no
+    relaid copy of the 786 MB block, which the runtime's peak would not show."""
+    from spark_rapids_ml_tpu.ops import linalg
+
+    d = 3000
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the gate
+    assert linalg.gram_pallas_declined(block_rows, d, F32, topo.devices[0]) == ""
+    acc = (one_chip((6, 512, 3072)), one_chip((3072, 128)), one_chip((1,)))
+    c = linalg.gram_fold.lower(acc, one_chip((block_rows, d)), one_chip((d,)), one_chip((), I32), pallas=True, block=0).compile()
+    assert _has_kernel(c)
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < (80 << 20)
+    assert mem.alias_size_in_bytes >= 6 * 512 * 3072 * 4      # the accumulators are added to in place
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "four_chips"])
+def test_pca_finish_takes_the_accumulators_not_the_frame(topo, no_compile_cache, chips):
+    """What runs after the last block's fold: psum over dp, mirror, rank-one
+    correction, the subspace iteration. Its operands are every device's
+    triangle, row sum partials and count, and μ̂ — 39 MB a device, nothing of
+    the frame's 6 GB; on the 2x2 host (what ``num_workers=4`` runs since PR 34)
+    the partials meet in an all-reduce."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.models.feature import _pca_finish
+
+    mesh = Mesh(np.asarray(topo.devices[:chips]).reshape(chips, 1), ("dp", "mp"))
+    rows = lambda *shape: jax.ShapeDtypeStruct((chips * shape[0],) + shape[1:], F32, sharding=NamedSharding(mesh, P("dp")))
+    mu = jax.ShapeDtypeStruct((3000,), F32, sharding=NamedSharding(mesh, P()))
+    c = _pca_finish.lower(rows(6, 512, 3072), rows(3072, 128), rows(1), mu, k=3, mesh=mesh, d=3000, pallas=True).compile()
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes < (48 << 20)
+    assert mem.temp_size_in_bytes < (256 << 20)
+    assert ("all-reduce" in c.as_text()) == (chips > 1)
 
 
 def test_pca_fit_at_a_row_major_shard_takes_xlas_pass(topo, no_compile_cache, monkeypatch):

@@ -78,15 +78,24 @@ def test_estimator_against_the_plain_reference(judged, name):
 
 
 def test_fit_opens_launch_and_fetch_under_dispatch(fitted):
+    """The frame's placement is the fit function's: ``h2d.enqueue`` opens
+    under ``fit.dispatch`` (and ``preprocess`` places nothing), the launch
+    names the fold and the finish, and both say that the fold engaged."""
     _, columns, _, spans, _ = fitted
     rows, cols = columns["features"].shape
     by_name = {}
     for ev in spans:
         by_name.setdefault(ev["name"], []).append(ev["args"])
     dispatch = by_name["fit.dispatch"][0]["span_id"]
-    launch, fetch = by_name["solver.launch"][0], by_name["solver.fetch"][0]
-    assert launch["parent_id"] == fetch["parent_id"] == dispatch
-    assert launch["program"] == "_pca_fit_kernel" and launch["gram"] == "xla" and launch["precision"] == "highest"
+    enqueue, launch, fetch = by_name["h2d.enqueue"][0], by_name["solver.launch"][0], by_name["solver.fetch"][0]
+    assert enqueue["parent_id"] == launch["parent_id"] == fetch["parent_id"] == dispatch
+    assert len(by_name["h2d.enqueue"]) == len(by_name["fit.dispatch"]) == 2      # one placement a fit
+    assert [ev["name"] for ev in spans if ev["name"] in ("h2d.enqueue", "solver.launch", "solver.fetch")][:3] == [
+        "h2d.enqueue", "solver.launch", "solver.fetch"
+    ]
+    assert enqueue["folded_blocks"] == enqueue["blocks"] == 1 and enqueue["bytes"] == rows * (cols * 4 + 4)
+    assert launch["program"] == "gram_fold,_pca_finish" and launch["gram_under_put"] is True
+    assert launch["gram"] == "xla" and launch["precision"] == "highest"
     assert launch["rows_minor"] is False and set(launch["declined"].split(",")) == {"backend", "rows_minor"}
     assert launch["tile"] == linalg.gram_block_rows(rows, cols, rows) == rows   # one block: the frame is small
     assert (fetch["k"], fetch["d"]) == (3, cols)
@@ -221,6 +230,156 @@ def test_mean_sample_is_spread_over_the_shard():
     assert abs(float(s[0] / c) - 50.0) < 1.0
     s, c = linalg._mean_sample(jnp.asarray(X), jnp.ones((n,), jnp.float32), 8192)   # runs of whole lane tiles
     assert float(c) == 8192 and abs(float(s[0] / c) - 50.0) < 1e-2
+
+
+def test_host_mean_sample_is_spread_over_the_array():
+    """μ̂ for the fold comes from the host array before block 0 is there:
+    runs of rows from its first to its last, so sorted data gives the mean
+    of all of it and not of its leading rows; a small array is read whole."""
+    n, d = 100_000, 4
+    X = np.repeat(np.linspace(0.0, 100.0, n, dtype=np.float32)[:, None], d, axis=1)
+    s, c = linalg.host_mean_sample(X)
+    assert c == 4096 and s.dtype == np.float64 and abs(s[0] / c - 50.0) < 0.1
+    s, c = linalg.host_mean_sample(X[:1000])
+    assert c == 1000 and s[0] / c == pytest.approx(X[:1000, 0].astype(np.float64).mean())
+    s, c = linalg.host_mean_sample(X[:0])
+    assert c == 0 and not s.any()
+
+
+def _sorted_offset_frame(n, d, seed=7):
+    """Rows sorted by a drift of 25 σ from first to last, on a mean of 1000 σ:
+    a leading block's mean is nowhere near the frame's."""
+    rng = np.random.default_rng(seed)
+    drift = np.linspace(0.0, 50.0, n)[:, None] * np.linspace(1.0, 0.2, d)
+    return (rng.normal(size=(n, d)) * np.linspace(0.5, 2.0, d) + drift + 1e3).astype(np.float32)
+
+
+@pytest.fixture(scope="module")
+def sorted_frame():
+    from spark_rapids_ml_tpu.data import DataFrame
+    from spark_rapids_ml_tpu.feature import PCA
+
+    X = _sorted_offset_frame(9000, 40)
+    df = DataFrame({"features": X})
+    one_put = PCA(k=3, num_workers=1, inputCol="features").fit(df)
+    return X, df, one_put, np.cov(X.astype(np.float64).T)
+
+
+@pytest.mark.parametrize(
+    "workers,block_rows,blocks",
+    [(1, None, 1), (1, 3200, 3), (1, 1200, 8), (2, 1600, 3), (8, 600, 2)],
+    ids=["one_put", "3_blocks", "8_blocks", "2_devices_3_blocks", "8_devices_2_blocks"],
+)
+def test_fit_through_blocks_agrees_with_the_one_put_fit(monkeypatch, sorted_frame, workers, block_rows, blocks):
+    """The covariance folded block by block — 1, 3 and 8 blocks a device, a
+    ragged tail each, the partials of 2 and 8 devices — is the one-put fit's
+    to 2e-6 of λ₁ and the float64 covariance's to float32, on a sorted frame
+    with a large mean: the host's μ̂ is of the whole frame."""
+    from spark_rapids_ml_tpu.feature import PCA
+    from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
+    from spark_rapids_ml_tpu.runtime import telemetry
+
+    X, df, one_put, cov64 = sorted_frame
+    if block_rows:
+        monkeypatch.setattr(mesh_mod, "_put_block_rows", lambda row_bytes: block_rows)
+    puts = []
+    sink = lambda ev, thread: puts.append(ev["args"]) if ev["name"] == "h2d.enqueue" else None  # noqa: E731
+    telemetry.add_span_sink(sink)
+    try:
+        model = PCA(k=3, num_workers=workers, inputCol="features").fit(df)
+    finally:
+        telemetry.remove_span_sink(sink)
+    (put,) = puts
+    assert put["folded_blocks"] == put["blocks"] == workers * blocks
+    lam, lam_one = np.asarray(model.explained_variance_, np.float64), np.asarray(one_put.explained_variance_, np.float64)
+    assert np.abs(lam - lam_one).max() <= 2e-6 * lam_one[0]
+    w, V = np.linalg.eigh(cov64)
+    assert np.abs(lam / w[::-1][:3] - 1.0).max() < 2e-5
+    comp = np.asarray(model.components_, np.float64)
+    assert np.linalg.norm(cov64 @ comp.T - comp.T * lam, axis=0).max() / w[-1] < 2e-5
+    assert np.abs(np.asarray(model.mean_, np.float64) - X.astype(np.float64).mean(axis=0)).max() < 1e-3
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["xla_blocked", "pallas_interpret"])
+def test_folded_covariance_against_the_float64_covariance(monkeypatch, pallas):
+    """``gram_fold`` over the blocks of ``shard_rows`` and its finish, as the
+    estimator strings them, against numpy's float64 covariance — the check of
+    ``test_gram_pass_against_the_float64_covariance`` on the sorted frame."""
+    from spark_rapids_ml_tpu.models.feature import _GramFold
+    from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
+    from spark_rapids_ml_tpu.parallel.mesh import make_mesh, shard_rows
+
+    n, d = 4500, 300
+    X = _sorted_offset_frame(n, d)
+    mesh = make_mesh(2)
+    monkeypatch.setattr(mesh_mod, "_PUT_BLOCK_BYTES", d * 4 * 1024)     # three blocks a device, the last of 202 rows
+    monkeypatch.setattr(linalg, "FORCE_INTERPRET", pallas)
+    jax.clear_caches()  # FORCE_INTERPRET is read at trace time, not cached
+    try:
+        fold = _GramFold(X, 563)
+        _, _, states = shard_rows(X, mesh, 563, fold=fold)
+        assert fold.pallas == pallas and fold.attrs["gram"] == ("pallas" if pallas else "xla")
+        finish = jax.jit(lambda G, s, c, mu: linalg.cov_from_gram_folds(G, s, c, mu, mesh, d, pallas))
+        mean, cov, cnt = finish(*fold.sums(states, mesh), fold.mean_hat)
+    finally:
+        jax.clear_caches()
+    x64 = X.astype(np.float64)
+    cov64 = np.cov(x64.T)
+    assert float(cnt) == n
+    assert np.abs(fold.mean_hat.astype(np.float64) - x64.mean(axis=0)).max() < 1.0     # 25 σ of drift, and μ̂ within one
+    assert np.abs(np.asarray(mean, np.float64) - x64.mean(axis=0)).max() < 1e-3
+    assert np.abs(np.asarray(cov, np.float64) - cov64).max() / np.abs(cov64).max() < 2e-5
+
+
+def test_pallas_fold_over_two_blocks_is_the_whole_frames_triangle(monkeypatch):
+    """The kernel's upper block triangle adds up over row blocks as it is:
+    two folds (the second a ragged block, 37 of its rows past ``valid``)
+    against one call on the whole transposed frame."""
+    n, d, split = 1500, 300, 1024
+    X, mask = _offset_frame(n, d)
+    mu = jnp.asarray(X[:64].mean(axis=0))
+    monkeypatch.setattr(linalg, "FORCE_INTERPRET", True)
+    jax.clear_caches()
+    try:
+        G, s = linalg._gram_triangle_pallas(jnp.asarray(X).T, jnp.asarray(mask), mu)
+        acc = linalg.gram_fold_zeros(d, jnp.float32, True, jax.devices()[0])
+        assert acc[0].shape == G.shape == (1, 384, 384) and acc[1].shape == s.shape == (384, 128)
+        acc = linalg.gram_fold(acc, jnp.asarray(X[:split]), mu, np.int32(split), pallas=True, block=0)
+        acc = linalg.gram_fold(acc, jnp.asarray(X[split:]), mu, np.int32(n - 37 - split), pallas=True, block=0)
+    finally:
+        jax.clear_caches()
+    assert float(acc[2][0]) == n - 37
+    scale = np.abs(np.asarray(G)).max()
+    assert np.abs(np.asarray(acc[0]) - np.asarray(G)).max() / scale < 2e-6
+    assert np.abs(np.asarray(acc[1]).sum(axis=1) - np.asarray(s).sum(axis=1)).max() < 0.5
+    full, _ = linalg._mirror_gram_triangle(acc[0], acc[1], d)
+    np.testing.assert_array_equal(np.asarray(full), np.asarray(full).T)
+
+
+def test_fit_multiple_over_two_k_places_and_folds_once(sorted_frame):
+    """The later lanes of a ``fitMultiple`` take the first lane's covariance:
+    one ``h2d.enqueue``, one fold, and each lane the model its own fit gives."""
+    from spark_rapids_ml_tpu.feature import PCA
+    from spark_rapids_ml_tpu.runtime import telemetry
+
+    X, df, one_put, _ = sorted_frame
+    spans = []
+    sink = lambda ev, thread: spans.append((ev["name"], ev["args"]))  # noqa: E731
+    telemetry.add_span_sink(sink)
+    try:
+        est = PCA(k=3, num_workers=2, inputCol="features")
+        models = dict(est.fitMultiple(df, [{est.k: 3}, {est.k: 5}]))
+    finally:
+        telemetry.remove_span_sink(sink)
+    assert [name for name, _ in spans].count("h2d.enqueue") == 1
+    launches = [args for name, args in spans if name == "solver.launch"]
+    assert [a["program"] for a in launches] == ["gram_fold,_pca_finish", "_pca_from_cov"]
+    assert [a["gram_under_put"] for a in launches] == [True, False]
+    assert models[0].components_.shape == (3, X.shape[1]) and models[1].components_.shape == (5, X.shape[1])
+    np.testing.assert_allclose(models[1].explained_variance_[:3], models[0].explained_variance_, rtol=1e-6)
+    np.testing.assert_allclose(models[0].explained_variance_, one_put.explained_variance_, rtol=2e-6)
+    alone = PCA(k=5, num_workers=2, inputCol="features").fit(df)
+    np.testing.assert_array_equal(models[1].components_, alone.components_)
 
 
 @pytest.mark.parametrize(

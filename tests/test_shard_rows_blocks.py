@@ -256,3 +256,109 @@ def test_kmeans_fit_is_bitwise_the_same_with_the_pad_on_the_device(block_rows, m
     per_dev = got_put["bytes"] // (2 * (128 * 4 + 4))   # a device's rows, padded to KMeans' chunks
     assert got_put["blocks"] == (2 * -(-(rows + 1) // 2 // block_rows) if block_rows else 2)
     assert got_put["block_bytes"] == (block_rows or per_dev) * cols * 4
+
+
+# ---- a fold over the blocks as they land -------------------------------------
+
+# id: (a case as above, the columns the shard is to have on the device)
+FOLDED = {
+    "blocks_dp1": (CASES["dp1"], None),
+    "blocks_dp2": (CASES["dp2"], None),
+    "blocks_dp4_mp2": (CASES["dp4_mp2"], None),
+    "ragged_tail_of_3_rows": (CASES["tail_block_of_3_rows"], None),
+    "whole_blocks_no_tail": (CASES["whole_blocks_no_tail"], None),
+    "shards_of_padding_get_no_block": (CASES["dp8_multiple128_shards_of_padding"], None),
+    "one_put_shard_of_exactly_one_block": (CASES["shard_of_exactly_one_block"], None),
+    "one_put_shard_with_padding_rows": (CASES["shard_below_one_block"], None),
+    "one_put_shards_of_padding": ((8, 1, 128, 130, np.float32, 2, 128, False), None),
+    "width_padded_to128_dp2": (CASES["dp2"], 128),
+    "width_padded_to16_one_block": ((2, 1, 128, 97, np.float32, 2, 128, True), 16),
+    "f64": (CASES["f64"], None),
+}
+
+
+@pytest.mark.parametrize("case", list(FOLDED))
+def test_fold_is_handed_every_block_once_in_row_order(case, monkeypatch, assembly):
+    """A fold sees each device's rows once, block after block as the loop
+    places them (a one-put shard: whole, its padding rows behind ``valid``),
+    at the host's own width; the frame that comes back is bit for bit the one
+    built without a fold, and the span counts the folds."""
+    (dp, mp, row_multiple, rows, dtype, ndim, block_rows, assembled), cols = FOLDED[case]
+    x = _host(rows, dtype, ndim)
+    monkeypatch.setattr(mesh_mod, "_PUT_BLOCK_BYTES", x.dtype.itemsize * COLS * block_rows)
+    mesh = make_mesh(dp, mp=mp)
+
+    def fold(state, block, row0, valid):
+        (dev,) = block.devices()
+        assert block.shape[1] == COLS and 0 <= valid <= block.shape[0]
+        return (state or []) + [(dev.id, row0, valid, np.asarray(block)[:valid])]
+
+    with jax.enable_x64(dtype == np.float64):
+        with _h2d_puts() as plain_puts:
+            want, want_mask = shard_rows(x, mesh, row_multiple, cols=cols)
+        writes_without = list(assembly)
+        del assembly[:]
+        with _h2d_puts() as puts:
+            xd, md, states = shard_rows(x, mesh, row_multiple, cols=cols, fold=fold)
+        np.testing.assert_array_equal(np.asarray(xd).view(np.uint8), np.asarray(want).view(np.uint8))
+        np.testing.assert_array_equal(np.asarray(md), np.asarray(want_mask))
+        assert xd.sharding == want.sharding and xd.dtype == want.dtype
+    assert assembly == writes_without and (assembly != []) == assembled     # the same writes, in the same order
+    assert "folded_blocks" not in plain_puts[0]
+    per_dev = xd.shape[0] // dp
+    index_map = row_sharding(mesh).addressable_devices_indices_map(xd.shape)
+    assert set(states) == set(mesh.devices.flat)
+    calls = 0
+    for dev in mesh.devices.flat:
+        lo = index_map[dev][0].start or 0
+        valid_rows = min(max(rows - lo, 0), per_dev)
+        seen = states[dev] or []
+        calls += len(seen)
+        if assembled:       # a block a call, none for a device that holds padding alone
+            assert [(row0, valid) for _, row0, valid, _ in seen] == [
+                (at, min(block_rows, valid_rows - at)) for at in range(0, valid_rows, block_rows)
+            ]
+        else:               # one put: once, on the whole shard
+            assert [(row0, valid) for _, row0, valid, _ in seen] == [(0, valid_rows)]
+        for dev_id, row0, valid, got in seen:
+            assert dev_id == dev.id
+            np.testing.assert_array_equal(got, x[lo + row0:lo + row0 + valid])
+    assert puts[0]["folded_blocks"] == calls == (puts[0]["blocks"] if assembled else dp * mp)
+
+
+def test_fold_follows_its_write_with_at_most_two_writes_outstanding(monkeypatch):
+    """Block k is folded after the wait for write k − 2 and right after write
+    k is issued — never ahead of it, where it would hold back the write that
+    the next put waits for: the fold adds no wait and keeps the bound on the
+    writes outstanding."""
+    monkeypatch.setattr(mesh_mod, "_PUT_BLOCK_BYTES", COLS * 4 * 16)
+    events = []
+    real_write = mesh_mod._write_block
+
+    class Written:
+        def __init__(self, k, scalar):
+            self.k, self.scalar = k, scalar
+
+        def block_until_ready(self):
+            events.append(("waited", self.k))
+            return self.scalar.block_until_ready()
+
+    def write(buf, block, row0):
+        k = sum(1 for e in events if e[0] == "issued")
+        events.append(("issued", k))
+        out, scalar = real_write(buf, block, row0)
+        return out, Written(k, scalar)
+
+    def fold(state, block, row0, valid):
+        events.append(("folded", sum(1 for e in events if e[0] == "folded")))
+        return (state or 0) + valid
+
+    monkeypatch.setattr(mesh_mod, "_write_block", write)
+    x = _host(2 * 5 * 16 - 7, np.float32, 2)
+    xd, _, states = shard_rows(x, make_mesh(2), fold=fold)
+    np.testing.assert_array_equal(np.asarray(xd)[:len(x)], x)
+    assert sorted(states.values()) == [76, 77]     # 153 rows over two devices, one padding row
+    expected = [("issued", 0), ("folded", 0), ("issued", 1), ("folded", 1)]
+    for k in range(2, 10):
+        expected += [("waited", k - 2), ("issued", k), ("folded", k)]
+    assert events == expected
