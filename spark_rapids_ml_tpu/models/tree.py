@@ -44,7 +44,7 @@ from ..params import (
     TypeConverters,
     _mk,
 )
-from ..parallel.mesh import DP_AXIS, fetch_global, gather_rows_global
+from ..parallel.mesh import DP_AXIS, fetch_global
 from ..ops.tree_kernels import (
     resolve_contract_gather,
     resolve_hist_strategy,
@@ -52,9 +52,10 @@ from ..ops.tree_kernels import (
     ForestConfig,
     binize,
     build_forest,
-    make_bin_edges,
     max_nodes,
     next_pow2,
+    plan_levels,
+    quantile_edges,
     rf_classify,
     rf_regress,
 )
@@ -229,31 +230,58 @@ def _resolve_k_features(
     return max(1, min(int(k), d))
 
 
-def _quantize_features(
-    inputs: "FitInputs", n_bins: int, d_pad: int, seed: int, algo: str
-):
-    """Host quantile sketch -> device binize, shared by the forest and
-    boosting fits. Strided VALID-row sample: unbiased under any dataset
-    sort order (a prefix sample would skew edges on sorted data), and
-    mask-aware so per-process padding rows never enter the sketch."""
-    step = max(1, inputs.n_rows // 131072)
-    valid_pos = np.nonzero(fetch_global(inputs.mask, inputs.mesh) > 0)[0]
-    sample = gather_rows_global(inputs.X, valid_pos[::step], inputs.mesh)
-    # Input contract: features must be FINITE. binize routes NaN to bin 0
-    # (compare-count semantics; see its docstring) where searchsorted
-    # would route it to the top bin — consistent between fit and
-    # transform, but silently different from engines that impute. The
-    # quantile sample is already on the host, so screening it is ~free;
-    # TPUML_RF_CHECK_FINITE=1 extends the check to every transform batch.
-    if not np.isfinite(sample).all():
+def _quantize_features(inputs: "FitInputs", n_bins: int, d_pad: int, algo: str):
+    """Device quantile sketch -> device binize, shared by the forest and
+    boosting fits. The sketch sorts a sample of the VALID rows on the device
+    (``ops.tree_kernels.quantile_edges``: runs of consecutive rows spread
+    over the whole frame, so a sorted dataset still gives even bins, and
+    mask-aware so padding rows never enter it); only the edges and one flag
+    cross to the host."""
+    with telemetry.span(
+        "forest.sketch", rows=int(min(inputs.n_rows, inputs.X.shape[0]))
+    ) as sp:
+        edges, finite = quantile_edges(inputs.X, inputs.mask, n_bins=n_bins)
+        edges_np = fetch_global(edges, inputs.mesh)
+        # Input contract: features must be FINITE. binize routes NaN to bin 0
+        # (compare-count semantics; see its docstring) where searchsorted
+        # would route it to the top bin — consistent between fit and
+        # transform, but silently different from engines that impute. The
+        # sketch screens its sample on the device;
+        # TPUML_RF_CHECK_FINITE=1 extends the check to every transform batch.
+        ok = bool(fetch_global(finite, inputs.mesh))
+        sp.set_attr(bytes=int(edges_np.nbytes) + 1)
+    if not ok:
         raise ValueError(
             f"{algo} features contain NaN/Inf; clean or "
             "impute before fit (binize would route non-finite "
             "values to bin 0)"
         )
-    edges_np = make_bin_edges(sample, n_bins, seed=seed)
-    bins = binize(inputs.X, jnp.asarray(edges_np), d_pad=d_pad)
+    with telemetry.span("forest.binize", cols=int(d_pad)):
+        bins = binize(inputs.X, jnp.asarray(edges_np), d_pad=d_pad)
     return edges_np, bins
+
+
+def _bins_width(d: int, subset: bool) -> int:
+    """Columns of the binned matrix. The fused-selection histogram kernel
+    (a per-node feature subset at more than 1024 columns) reads whole rows
+    of it at every level, so there the width is the lane multiple (3000 ->
+    3072: a quarter less to read, gather and keep than 4096); every other
+    strategy chunks the columns by powers of two and keeps the power of two."""
+    lanes = -(-d // 128) * 128
+    return lanes if subset and lanes > 1024 else next_pow2(d)
+
+
+def _dispatch_group(t_local: int) -> int:
+    """Trees grown by one dispatched program: ONE size for the whole fit, so
+    a fit compiles its growth once (a last group of another size cost a
+    second Mosaic compile of minutes at 500,000 x 3072). Up to 8 trees, all
+    of them; beyond, the largest of 8..4 that divides the count (50 -> 5), or
+    8 with the last group filled by trees that are grown and dropped. It
+    bounds a dispatch to seconds (a multi-minute single dispatch can outlive
+    remote-runtime health checks) while the compile is shared."""
+    if t_local <= 8:
+        return t_local
+    return next((g for g in (8, 7, 6, 5, 4) if t_local % g == 0), 8)
 
 
 class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _RandomForestParams):
@@ -344,23 +372,36 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                 self.logger.warning("maxBins=%d clamped to 256", n_bins)
                 n_bins = 256
             d = inputs.n_features
-            d_pad = next_pow2(d)
             seed = int(params.get("random_state") or 0)
+            k_features = _resolve_k_features(
+                params["max_features"], d, is_classification
+            )
+            d_pad = _bins_width(d, k_features < d)
 
-            # 1) quantize features (host quantile sketch -> device binize)
+            # 1) quantize features (device quantile sketch -> device binize)
             edges_np, bins = _quantize_features(
-                inputs, n_bins, d_pad, seed, "RandomForest"
+                inputs, n_bins, d_pad, "RandomForest"
             )
 
             # 2) per-row sufficient stats
             stats = self._label_stats(inputs.y, n_stats)
 
-            # 3) per-device tree split (reference ``tree.py:256-267``)
+            # 3) per-device tree split (reference ``tree.py:256-267``), in
+            # dispatch groups of one size: tree t's key is split(key, T)[t]
+            # whatever the grouping; the trees that fill the last group reuse
+            # the first key and are dropped
             n_dp = inputs.mesh.shape[DP_AXIS]
             t_local = -(-n_trees // n_dp)
+            group = _dispatch_group(t_local)
+            n_groups = -(-t_local // group)
             keys_np = np.asarray(
                 jax.random.split(jax.random.PRNGKey(seed), n_dp * t_local)
             ).reshape(n_dp, t_local, 2)
+            fill = n_groups * group - t_local
+            if fill:
+                keys_np = np.concatenate(
+                    [keys_np, np.repeat(keys_np[:, :1], fill, axis=1)], axis=1
+                )
             # make_array_from_callback: each process materializes only its
             # addressable shards (device_put of a multi-host-sharded host
             # array is not possible)
@@ -376,15 +417,16 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                 n_features=d,
                 n_stats=n_stats,
                 impurity=self._impurity_name(params),
-                k_features=_resolve_k_features(
-                    params["max_features"], d, is_classification
-                ),
+                k_features=k_features,
                 min_samples_leaf=int(params["min_samples_leaf"]),
                 min_info_gain=float(params.get("min_impurity_decrease", 0.0) or 0.0),
                 min_samples_split=int(params.get("min_samples_split", 2)),
                 bootstrap=bool(params["bootstrap"]),
                 hist_strategy=resolve_hist_strategy(),
                 contract_gather=resolve_contract_gather(),
+                # X stays on the device through the growth (the frame is the
+                # caller's, and a later lane of a fitMultiple bins it again)
+                held_bytes=int(inputs.X.nbytes // inputs.mesh.devices.size),
             )
             # rows-per-tree mode: "all" gathers the binned matrix to every
             # device (quality independent of worker count — the TPU-first
@@ -400,60 +442,79 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
             gather = n_dp > 1 and (
                 mode == "all" or (mode == "auto" and gathered_bytes <= budget)
             )
-            # bound trees per dispatch: the whole group builds inside ONE
-            # device program (lax.map over trees), and a multi-minute
-            # single dispatch can outlive remote-runtime health checks
-            # (observed: 50 deep trees in one call crashed the worker
-            # where 8-tree calls succeed); groups also amortize compiles
-            group = min(t_local, 8)
             # tree-batched growth (TPUML_RF_TREE_BATCH): B trees advance
             # one level per dispatch, bit-identical to sequential at the
             # same keys — the budget sees the rows each tree actually
-            # trains on (gathered vs local shard)
+            # trains on (gathered vs local shard) and the width it reads
             rows_per_tree = n_pad_global if gather else n_pad_global // n_dp
-            # per key: list of host arrays shaped (n_dp, group_size, ...)
+            tree_batch = resolve_tree_batch(group, cfg, rows_per_tree, d_pad)
+            strategies, declined = plan_levels(
+                rows_per_tree, d_pad, cfg, stats.dtype
+            )
+            # per key: list of host arrays shaped (n_dp, group, ...)
             pieces: Dict[str, List[np.ndarray]] = {}
-            for g0 in range(0, t_local, group):
-                kg = keys[:, g0 : min(g0 + group, t_local)]
-                gsz = kg.shape[1]
-                tree_batch = resolve_tree_batch(gsz, cfg, rows_per_tree)
-                with telemetry.span(
-                    "forest.grow_group",
-                    trees=gsz,
-                    tree_batch=tree_batch,
-                    hist_strategy=cfg.hist_strategy,
-                    gather=gather,
-                ):
-                    outg = build_forest(
-                        bins, inputs.mask, stats, kg,
-                        mesh=inputs.mesh, cfg=cfg, gather=gather,
+            # the spans every fit carries (PERF.md section 3): the launch
+            # names the program whose runs the device trace is read for, the
+            # fetch closes when the last group's tables are on the host
+            with telemetry.span(
+                "solver.launch",
+                program="build_forest",
+                trees=n_trees,
+                group=group,
+                groups=n_groups,
+                tree_batch=tree_batch,
+                cols=int(d_pad),
+            ):
+                pass
+            with telemetry.span("solver.fetch", groups=n_groups):
+                for g in range(n_groups):
+                    with telemetry.span(
+                        "forest.grow_group",
+                        group=g,
+                        trees=group,
                         tree_batch=tree_batch,
-                    )
-                    for k, a in outg.items():
-                        h = fetch_global(a, inputs.mesh)
-                        pieces.setdefault(k, []).append(
-                            h.reshape(n_dp, gsz, *h.shape[1:])
+                        hist_strategy=cfg.hist_strategy,
+                        gather=gather,
+                        strategy=strategies,
+                        levels_declined=len(declined),
+                        **({"declined": str(declined)} if declined else {}),
+                    ):
+                        outg = jax.block_until_ready(
+                            build_forest(
+                                bins, inputs.mask, stats,
+                                keys[:, g * group : (g + 1) * group],
+                                mesh=inputs.mesh, cfg=cfg, gather=gather,
+                                tree_batch=tree_batch,
+                            )
                         )
+                    with telemetry.span("forest.fetch_group", group=g):
+                        for k, a in outg.items():
+                            h = fetch_global(a, inputs.mesh)
+                            pieces.setdefault(k, []).append(
+                                h.reshape(n_dp, group, *h.shape[1:])
+                            )
 
             # interleave device-major -> tree-major so the slice to n_trees
             # takes trees evenly from every device
             def _gather(key: str) -> np.ndarray:
-                a = np.concatenate(pieces[key], axis=1)  # (n_dp, t_local, ...)
+                # (n_dp, t_local, ...): the trees that filled the last group go
+                a = np.concatenate(pieces[key], axis=1)[:, :t_local]
                 return np.swapaxes(a, 0, 1).reshape(
                     -1, *a.shape[2:]
                 )[:n_trees]
 
-            feat = _gather("feature")
-            thr_bin = _gather("threshold_bin")
-            leaf_stats = _gather("leaf_stats")
-            gains = _gather("gain")
+            with telemetry.span("forest.assemble", trees=n_trees):
+                feat = _gather("feature")
+                thr_bin = _gather("threshold_bin")
+                leaf_stats = _gather("leaf_stats")
+                gains = _gather("gain")
 
-            # bin thresholds -> raw feature-space values (x >= thr -> right)
-            thr = np.where(
-                feat >= 0,
-                edges_np[np.clip(feat, 0, d - 1), np.clip(thr_bin, 0, n_bins - 2)],
-                0.0,
-            ).astype(np.float32)
+                # bin thresholds -> raw feature-space values (x >= thr -> right)
+                thr = np.where(
+                    feat >= 0,
+                    edges_np[np.clip(feat, 0, d - 1), np.clip(thr_bin, 0, n_bins - 2)],
+                    0.0,
+                ).astype(np.float32)
 
             return {
                 "features": feat.astype(np.int32),
@@ -511,7 +572,7 @@ class _ForestModelBase(_TpuModel):
         forces the raw-threshold descent, =bins the per-tree bin-space
         descent (incl. CPU, for parity tests), =packed the packed-forest
         lockstep engine (falls back down the chain if its kernel cannot
-        lower); auto prefers packed > bins > legacy on TPU."""
+        lower); auto takes bins on a TPU and legacy elsewhere."""
         return str(envspec.get("TPUML_RF_APPLY"))
 
     def _bins_apply_ready(self, mode: Optional[str] = None) -> bool:
@@ -537,7 +598,12 @@ class _ForestModelBase(_TpuModel):
         forest shape (or the forest is shallow enough that hop-1 alone
         reaches every leaf — no kernel needed)."""
         mode = self._apply_mode() if mode is None else mode
-        if mode == "bins" or not self._bins_apply_ready(mode):
+        # only where pinned: the traversal kernel unrolls every tree and its
+        # compile grows faster than the tree count (80 s at 8 trees, not
+        # finished at 56), where the bins engine compiles in 10 s whatever
+        # the forest and transformed rf_dbx's 500,000 x 3000 rows through 8
+        # trees in 1.03 s (the raw-threshold descent: 2.15 s; PERF.md, PR 35)
+        if mode != "packed" or not self._bins_apply_ready(mode):
             return False
         from ..ops.rf_pallas import packed_traverse_ok
 
@@ -607,7 +673,12 @@ class _ForestModelBase(_TpuModel):
                 return binize(jnp.asarray(Xb), edges, d_pad=d_pad)
 
             return _binz
-        return lambda Xb: binize(jnp.asarray(Xb), edges, d_pad=d_pad)
+
+        def _binz_batch(Xb):
+            with telemetry.span("forest.binize_batch", rows=int(Xb.shape[0])):
+                return binize(jnp.asarray(Xb), edges, d_pad=d_pad)
+
+        return _binz_batch
 
     # -- shared transform dispatch -----------------------------------------
     # Classification and regression route through ONE engine resolution:
@@ -630,8 +701,9 @@ class _ForestModelBase(_TpuModel):
         return st
 
     def _resolve_transform_engine(self, mode: Optional[str] = None) -> str:
-        """packed > bins > legacy under ``mode`` (default: the
-        env-resolved `TPUML_RF_APPLY`). The serving registry resolves
+        """bins (a TPU, bin tables, depth <= 14) else legacy under ``auto``;
+        packed only where ``mode`` pins it (default: the env-resolved
+        `TPUML_RF_APPLY`). The serving registry resolves
         with the default mode on purpose: serving promises bit-identity
         with direct transform, and the packed/legacy descents differ by
         one f32 ulp in vote normalization on some inputs — same engine,
@@ -657,7 +729,23 @@ class _ForestModelBase(_TpuModel):
             cache = self._transform_engine_cache = {}
         fn = cache.get(key)
         if fn is None:
-            fn = cache[key] = getattr(self, f"_{engine}_transform_fn")()
+            with telemetry.span(
+                "forest.transform_engine",
+                engine=engine,
+                trees=self.getNumTrees(),
+                depth=self._max_depth_built,
+                cols=self.numFeatures,
+            ):
+                inner = getattr(self, f"_{engine}_transform_fn")()
+
+            def fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
+                # one batch through the engine, to its columns on the host
+                with telemetry.span(
+                    "forest.descent", engine=engine, rows=int(Xb.shape[0])
+                ):
+                    return inner(Xb)
+
+            cache[key] = fn
         return fn
 
     def _out_cols(self) -> List[str]:
@@ -1317,9 +1405,7 @@ class _GBTEstimator(_GBTClass, _TpuEstimatorSupervised, _GBTParams):
             d_pad = next_pow2(d)
             seed = int(params.get("random_state") or 0)
 
-            edges_np, bins = _quantize_features(
-                inputs, n_bins, d_pad, seed, "GBT"
-            )
+            edges_np, bins = _quantize_features(inputs, n_bins, d_pad, "GBT")
 
             # loss kind + output head width. Spark's GBTClassifier is
             # binary-only; K>2 extends it sklearn-style (one tree per

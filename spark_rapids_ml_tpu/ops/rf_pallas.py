@@ -66,30 +66,40 @@ def _block_rows(k: int, nb: int) -> int:
     return BLOCK_ROWS
 
 
+def rf_hist_pallas_declined(
+    n_pad: int, k: int, nb: int, S: int, r_sub: int
+) -> str:
+    """The terms of the sub-block kernel's gate that fail, comma-joined
+    (empty: the kernel is admitted): TPU (or interpret), lane-aligned
+    one-hot width, power-of-two sub-blocks dividing the block, block-aligned
+    row count. A pure function of its arguments and the backend: the level
+    plan evaluates it again on the host to say on the grow group's span
+    which levels took which histogram, and why."""
+    R = _block_rows(k, nb)
+    terms = (
+        ("backend", jax.default_backend() == "tpu" or FORCE_INTERPRET),
+        ("width%128", (k * nb) % 128 == 0),
+        ("bins<=256", nb <= 256),
+        ("stats<=16", 1 <= S <= 16),
+        ("r_sub", r_sub >= 1 and (r_sub & (r_sub - 1)) == 0 and R % r_sub == 0),
+        ("rows%block", n_pad % R == 0),
+        # Mosaic block rule: the (L*S, W) output block's sublane dim must
+        # be a multiple of 8 once the grid has more than one block
+        ("sublanes%8", (R // max(r_sub, 1)) * S % 8 == 0),
+        # one-hot width cap: wider levels feature-chunk down to this
+        ("width<=8192", k * nb <= 8192),
+    )
+    return ",".join(name for name, ok in terms if not ok)
+
+
 def rf_hist_pallas_ok(
     n_pad: int, k: int, nb: int, S: int, r_sub: int, variance: bool = False
 ) -> bool:
-    """Trace-time gate: TPU (or interpret), lane-aligned one-hot width,
-    power-of-two sub-blocks dividing the block, block-aligned row count,
-    and a probed lowering."""
-    R = _block_rows(k, nb)
-    ok = (
-        (jax.default_backend() == "tpu" or FORCE_INTERPRET)
-        and (k * nb) % 128 == 0
-        and nb <= 256
-        and 1 <= S <= 16
-        and r_sub >= 1
-        and (r_sub & (r_sub - 1)) == 0
-        and R % r_sub == 0
-        and n_pad % R == 0
-        # Mosaic block rule: the (L*S, W) output block's sublane dim must
-        # be a multiple of 8 once the grid has more than one block
-        and (R // r_sub) * S % 8 == 0
-        # one-hot width cap: wider levels feature-chunk down to this
-        and k * nb <= 8192
-    )
+    """Trace-time gate (:func:`rf_hist_pallas_declined`) and a probed
+    lowering."""
+    ok = not rf_hist_pallas_declined(n_pad, k, nb, S, r_sub)
     if ok and not FORCE_INTERPRET:
-        ok = _probe_lowering(k, nb, S, r_sub, R, variance)
+        ok = _probe_lowering(k, nb, S, r_sub, _block_rows(k, nb), variance)
     return ok
 
 
@@ -201,6 +211,7 @@ def subblock_hist(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="rf_hist_pass",
     )(binq, swT)
     return out.reshape(n_pad // r_sub, S, W)
 
@@ -325,6 +336,7 @@ def subblock_hist_sel(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="rf_hist_sel_pass",
     )(bq, fq, swT)
     return out.reshape(n_pad // r_sub, S, W)
 
@@ -334,31 +346,38 @@ def subblock_hist_sel(
 _SEL_LOWERING_OK: dict = {}
 
 
+def rf_hist_sel_declined(
+    n_pad: int, d_pad: int, k: int, nb: int, S: int, r_sub: int
+) -> str:
+    """The failing terms of the fused-selection kernel's gate, comma-joined:
+    :func:`rf_hist_pallas_declined`'s rules plus a lane-aligned full-bins
+    width, eight sub-blocks a block and the block's VMEM residency."""
+    R = BLOCK_ROWS
+    base = rf_hist_pallas_declined(n_pad, k, nb, S, r_sub)
+    terms = (
+        # the (L, k_lanes) feature-id block needs >= 8 sublanes
+        ("sub-blocks>=8", R // max(r_sub, 1) >= 8),
+        ("d_pad%128", d_pad % 128 == 0),
+        # (R, d_pad) f32 rows + (r_sub, W) transients + sel, x2 buffers
+        (
+            "vmem",
+            (R * d_pad * 4 + r_sub * k * nb * 4 + d_pad * k * 4) * 2
+            <= 80 * 1024 * 1024,
+        ),
+    )
+    return ",".join(
+        ([base] if base else []) + [name for name, ok in terms if not ok]
+    )
+
+
 def rf_hist_sel_ok(
     n_pad: int, d_pad: int, k: int, nb: int, S: int, r_sub: int,
     variance: bool = False,
 ) -> bool:
-    """Gate for the fused-selection kernel: subblock_hist's rules plus a
-    lane-aligned full-bins width and its VMEM residency."""
+    """Gate for the fused-selection kernel (:func:`rf_hist_sel_declined`)
+    and a probed lowering."""
     R = BLOCK_ROWS
-    ok = (
-        (jax.default_backend() == "tpu" or FORCE_INTERPRET)
-        and (k * nb) % 128 == 0
-        and nb <= 256
-        and 1 <= S <= 16
-        and r_sub >= 1
-        and (r_sub & (r_sub - 1)) == 0
-        and R % r_sub == 0
-        and n_pad % R == 0
-        and (R // r_sub) * S % 8 == 0
-        # the (L, k_lanes) feature-id block needs >= 8 sublanes
-        and R // r_sub >= 8
-        and k * nb <= 8192
-        and d_pad % 128 == 0
-        # (R, d_pad) f32 rows + (r_sub, W) transients + sel, x2 buffers
-        and (R * d_pad * 4 + r_sub * k * nb * 4 + d_pad * k * 4) * 2
-        <= 80 * 1024 * 1024
-    )
+    ok = not rf_hist_sel_declined(n_pad, d_pad, k, nb, S, r_sub)
     if ok and not FORCE_INTERPRET:
         key = (d_pad, k, nb, S, r_sub, variance)
 
@@ -434,83 +453,6 @@ def subblock_hist_sel_batched(
         n_bins=n_bins, r_sub=r_sub, variance=variance, interpret=interpret,
     )
     return out.reshape(T, n_sb, S, k * n_bins)
-
-
-# ---------------------------------------------------------------------------
-# packed-byte lane gather (inference): bins[r, idx[r, j]] via the hardware
-# lane shuffle
-# ---------------------------------------------------------------------------
-
-_GATHER_BLOCK = 2048
-_BG_LOWERING_OK: dict = {}
-
-
-def packed_byte_gather_ok(n: int, words: int, k: int) -> bool:
-    """Gate for ``packed_byte_gather``: TPU (or interpret), lane extents
-    within one shuffle width (probe: W=256 fails to lower), block-aligned
-    rows. The caller pads rows/columns to satisfy the alignment."""
-    W = max(64, words)
-    ok = (
-        (jax.default_backend() == "tpu" or FORCE_INTERPRET)
-        and W <= 128
-        and k <= W
-        and n % _GATHER_BLOCK == 0
-    )
-    if ok and not FORCE_INTERPRET:
-        key = ("bg", W)
-
-        def compile_fn():
-            p = jax.ShapeDtypeStruct((2 * _GATHER_BLOCK, W), jnp.int32)
-            i = jax.ShapeDtypeStruct((2 * _GATHER_BLOCK, W), jnp.int32)
-            packed_byte_gather.lower(p, i).compile()
-
-        from .linalg import probe_pallas_lowering
-
-        ok = probe_pallas_lowering(
-            _BG_LOWERING_OK, key, compile_fn, "RF packed-byte gather"
-        )
-    return ok
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def packed_byte_gather(
-    packed: jax.Array,   # (n, W) int32 word-packed bins, W in [64, 128]
-    idx: jax.Array,      # (n, W) int32 byte indices into the row (< 4*W);
-                         # only the caller's first k lanes are meaningful
-    *,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """out[r, j] = byte ``idx[r, j]`` of row r's packed bins, as int32.
-
-    The word select is ONE in-register lane shuffle (``tpu.dynamic_gather``
-    via ``take_along_axis`` axis=1 with idx.shape == x.shape — measured
-    ~1e11 lane-gathers/s), then the byte shifts out arithmetically. The
-    XLA compare-select contraction this replaces costs n*k*W compare ops
-    (~70 ms across a 56-tree forest evaluation at the bench shape).
-    """
-    from jax.experimental import pallas as pl
-
-    if interpret is None:
-        interpret = FORCE_INTERPRET
-    n, W = packed.shape
-
-    def kern(p_ref, i_ref, o_ref):
-        iv = i_ref[...]
-        w = jnp.take_along_axis(p_ref[...], iv >> 2, axis=1)
-        o_ref[...] = (w >> ((iv & 3) * 8)) & 0xFF
-
-    B = _GATHER_BLOCK
-    return pl.pallas_call(
-        kern,
-        grid=(n // B,),
-        in_specs=[
-            pl.BlockSpec((B, W), lambda i: (i, 0)),
-            pl.BlockSpec((B, W), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((B, W), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, W), jnp.int32),
-        interpret=interpret,
-    )(packed, idx)
 
 
 # ---------------------------------------------------------------------------
@@ -694,39 +636,3 @@ def packed_traverse(
         ),
         interpret=interpret,
     )(packed, i1, f2f, t2f)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def packed_byte_gather_many(
-    packed: jax.Array,   # (n, W) int32 word-packed bins
-    idx: jax.Array,      # (G, n, W) int32 byte indices
-    *,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Batched ``packed_byte_gather``: one pallas_call for G index sets
-    against the same packed rows (56 separate calls measured ~6 ms of
-    per-call/fusion-barrier overhead EACH inside a jitted forest
-    evaluation; this runs the same work in one launch)."""
-    from jax.experimental import pallas as pl
-
-    if interpret is None:
-        interpret = FORCE_INTERPRET
-    G, n, W = idx.shape
-
-    def kern(p_ref, i_ref, o_ref):
-        iv = i_ref[0]
-        w = jnp.take_along_axis(p_ref[...], iv >> 2, axis=1)
-        o_ref[0] = (w >> ((iv & 3) * 8)) & 0xFF
-
-    B = _GATHER_BLOCK
-    return pl.pallas_call(
-        kern,
-        grid=(G, n // B),
-        in_specs=[
-            pl.BlockSpec((B, W), lambda g, i: (i, 0)),
-            pl.BlockSpec((1, B, W), lambda g, i: (g, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, B, W), lambda g, i: (g, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, n, W), jnp.int32),
-        interpret=interpret,
-    )(packed, idx)

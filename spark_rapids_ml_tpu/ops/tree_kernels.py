@@ -108,7 +108,9 @@ def _largest_divisor_leq(t: int, b: int) -> int:
     return 1
 
 
-def resolve_tree_batch(t_group: int, cfg: "ForestConfig", n_rows: int) -> int:
+def resolve_tree_batch(
+    t_group: int, cfg: "ForestConfig", n_rows: int, d_pad: int = 0
+) -> int:
     """Trees advanced per batched level dispatch (1 = sequential builder).
 
     ``TPUML_RF_TREE_BATCH``: ``off`` pins the sequential per-tree builder,
@@ -117,7 +119,11 @@ def resolve_tree_batch(t_group: int, cfg: "ForestConfig", n_rows: int) -> int:
     group reshapes to (G, B, 2) key batches — and (b) the widest batch
     whose per-level residents fit the HBM budget: the histogram tile, its
     gain-chain copies, and the per-tree row state (stat weights, routing
-    ids, subset-gathered bins) all scale xT, while the per-level strategy
+    ids, subset-gathered bins — and, where the fused-selection kernel
+    engages at ``d_pad`` bins a row, its node-sorted copy of the FULL bins
+    rows and its partials: 1.6 + 0.5 GB a tree at 500,000 x 3072, which
+    this budget once left out, so that eight trees asked for 17 GB) all
+    scale xT, while the per-level strategy
     gates deliberately stay per-tree so batched and sequential builds
     select identical strategies — a precondition of their bit-identity
     (see docs/rf_performance.md).
@@ -170,6 +176,12 @@ def resolve_tree_batch(t_group: int, cfg: "ForestConfig", n_rows: int) -> int:
         4 * n_rows * (cfg.n_stats + 4 + (d_hist if subset else 0))
         + 16 * tile
     )
+    if d_pad:
+        deepest = level_plan(n_rows, d_pad, max(0, cfg.max_depth - 1), cfg)
+        if deepest.strategy == "pallas_sel":
+            per_tree += deepest.n_pad * d_pad + (
+                deepest.n_pad // deepest.r_sub
+            ) * cfg.n_stats * d_hist * cfg.n_bins * 4
     fit = max(1, int(budget // max(1, per_tree)))
     batch = _largest_divisor_leq(t_group, min(want, fit))
     if tune_key is not None:
@@ -199,6 +211,10 @@ class ForestConfig(NamedTuple):
     # subset-extraction strategy: "auto" | "on" | "off" (see
     # resolve_contract_gather); static for the same cache-key reason
     contract_gather: str = "auto"
+    # bytes the caller keeps on the device through the growth besides the
+    # bins (the estimator's X): counted among the fused-selection kernel's
+    # residents, which has no fallback on a runtime OOM
+    held_bytes: int = 0
 
 
 def max_nodes(max_depth: int) -> int:
@@ -229,6 +245,58 @@ def make_bin_edges(
     qs = np.linspace(0.0, 1.0, n_bins + 1)[1:-1]
     edges = np.quantile(np.asarray(Xs, dtype=np.float64), qs, axis=0)
     return np.ascontiguousarray(edges.T.astype(np.float32))  # (d, nb-1)
+
+
+# rows of the quantile sketch, taken as runs of one lane tile of consecutive
+# rows spread evenly over the frame
+_SKETCH_ROWS = 131072
+_SKETCH_RUN = 128
+
+
+@functools.partial(jax.jit, static_argnames=("n_bins",))
+def quantile_edges(X: jax.Array, mask: jax.Array, *, n_bins: int):
+    """Per-feature quantile bin edges ON THE DEVICE: ``((d, n_bins - 1)
+    edges, all sampled values finite)``. Only the edges cross to the host
+    (1.5 MB at 3000 x 127), where the strided sample of old brought every
+    third row of the frame (2.0 GB at 500,000 x 3000) by a row gather that
+    a rows-minor frame answers with a relaid copy of itself.
+
+    A frame of more than ``_SKETCH_ROWS`` rows is sampled as 1024 runs of
+    128 consecutive rows spread evenly from its first row to its last: a run
+    of whole lane tiles is a slice of a rows-minor frame, a stride is a
+    gather. On data SORTED by a column a run holds 128 neighbouring ranks
+    and the next run starts n/1024 rows on, so an edge of that column can
+    miss its rank by at most n/1024 - 128 rows (0.07% of the rows at
+    500,000, a tenth of a 128-quantile bin); 64 runs of 2048 would miss by
+    1.2%, more than a bin. Rows the mask leaves out sort past every valid
+    value (+inf) and the quantile positions are taken among the valid ones
+    (``numpy.quantile``'s linear interpolation, in the frame's dtype).
+    """
+    n, d = X.shape
+    if n > _SKETCH_ROWS:
+        runs = _SKETCH_ROWS // _SKETCH_RUN
+        los = (np.arange(runs) * (n - _SKETCH_RUN)) // (runs - 1)
+        los = jnp.asarray(los // _SKETCH_RUN * _SKETCH_RUN, jnp.int32)
+        sample = lax.map(
+            lambda lo: lax.dynamic_slice(X, (lo, 0), (_SKETCH_RUN, d)), los
+        ).reshape(runs * _SKETCH_RUN, d)
+        valid = lax.map(
+            lambda lo: lax.dynamic_slice(mask, (lo,), (_SKETCH_RUN,)), los
+        ).reshape(runs * _SKETCH_RUN) > 0
+    else:
+        sample, valid = X, mask > 0
+    finite = jnp.all(jnp.isfinite(sample) | ~valid[:, None])
+    ordered = jnp.sort(
+        jnp.where(valid[:, None], sample, jnp.inf), axis=0, stable=False
+    )
+    m = jnp.maximum(valid.sum(), 1)
+    qs = jnp.linspace(0.0, 1.0, n_bins + 1)[1:-1].astype(X.dtype)
+    pos = qs * (m - 1).astype(X.dtype)
+    lo = jnp.floor(pos).astype(jnp.int32)
+    hi = jnp.minimum(lo + 1, m - 1)
+    a, b = ordered[lo], ordered[hi]
+    edges = a + (b - a) * (pos - lo.astype(X.dtype))[:, None]
+    return edges.T, finite
 
 
 @functools.partial(jax.jit, static_argnames=("d_pad",))
@@ -586,6 +654,157 @@ def _best_splits_from_hist(hist, parent, pcount, pimp, realf, nb, cfg):
 
 
 # ---------------------------------------------------------------------------
+# per-level histogram plan (static): which strategy a level takes, and why
+# ---------------------------------------------------------------------------
+
+
+class LevelPlan(NamedTuple):
+    """What one level's histogram takes, from static shapes alone."""
+
+    r_sub: int       # sub-block rows of the node-sorted copy
+    n_pad: int       # its block-aligned padded row count
+    f_chunk: int     # feature chunk of the pre-gathered Pallas kernel
+    strategy: str    # "pallas_sel" | "pallas" | "matmul" | "scatter"
+    declined: str    # why the shape's own Pallas kernel was not taken ("" where it was)
+
+
+def level_plan(
+    n: int, d_pad: int, level: int, cfg: ForestConfig, dt=jnp.float32
+) -> LevelPlan:
+    """The histogram strategy of ``level`` for ``n`` rows of ``d_pad`` bins.
+
+    Static per level, so both builders and the estimator's span evaluate the
+    SAME expressions: the sequential and the tree-batched builder pick the
+    same strategy (a precondition of their bit-identity), and a level that
+    falls to the XLA scatter (~1e8 updates/s, 8x slower) says why.
+
+    compact strategy (TPU): node-contiguous rows + the Pallas sub-block
+    kernel (ops/rf_pallas.py). Eligibility: f32 stats, lane-aligned one-hot
+    width, a full-level histogram tile that fits HBM comfortably, and a
+    probed lowering. The fused-selection variant selects each node's k
+    columns in-kernel over node-sorted FULL bins rows and skips the per-row
+    subset gather entirely (the single dominant cost at wide d). It is
+    single-shot (no feature chunking), so its transients are gated against
+    an HBM budget: the probe compiles a tiny instance and cannot see HBM
+    pressure, and a runtime OOM here has no fallback. Residents counted:
+    what the caller holds (``cfg.held_bytes``), bins + the row-gathered copy
+    (both n-scale uint8), partials, and two histogram tiles.
+    """
+    from .rf_pallas import (
+        BLOCK_ROWS,
+        rf_hist_pallas_declined,
+        rf_hist_pallas_ok,
+        rf_hist_sel_declined,
+        rf_hist_sel_ok,
+    )
+
+    S, nb = cfg.n_stats, cfg.n_bins
+    n_nodes = 1 << level
+    subset = cfg.k_features < cfg.n_features
+    d_hist = next_pow2(cfg.k_features) if subset else d_pad
+    variance = cfg.impurity == "variance"
+    r_sub = _compact_r_sub(n, n_nodes, BLOCK_ROWS, S)
+    # Pad with the DEEPEST split level's node count when that waste is small
+    # relative to n: r_sub converges to its cap at scale, so one padded row
+    # count then serves every level and the Pallas kernels have ONE shape per
+    # tree config instead of one per level. At small n the uniform pad would
+    # triple the kernel's row count, so fall back to per-level padding there.
+    n_nodes_max = 1 << max(0, cfg.max_depth - 1)
+    pad_nodes = n_nodes_max if (n_nodes_max + 1) * r_sub * 3 <= n else n_nodes
+    n_pad_c = -(-(n + (pad_nodes + 1) * r_sub) // BLOCK_ROWS) * BLOCK_ROWS
+    n_sb_c = n_pad_c // r_sub
+    # feature chunk: largest power of two satisfying the kernel's one-hot
+    # width cap (Fc*nb <= 8192) AND a ~256 MB partials transient budget; must
+    # divide d_hist
+    Fc = 1 << max(0, min(d_hist, 8192 // nb).bit_length() - 1)
+    while Fc > 1 and (
+        d_hist % Fc != 0 or n_sb_c * S * Fc * nb * 4 > (256 << 20)
+    ):
+        Fc //= 2
+    shape_terms = (
+        ("strategy", cfg.hist_strategy in ("auto", "compact")),
+        ("dtype", dt == jnp.float32),
+        ("chunk", d_hist % Fc == 0),
+        ("tile", n_nodes * d_hist * nb * S <= (1 << 28)),
+    )
+    shape_declined = ",".join(name for name, ok in shape_terms if not ok)
+    sel_resident = (
+        cfg.held_bytes
+        + n * d_pad                      # bins (uint8)
+        + n_pad_c * d_pad              # gathered node-sorted copy
+        + n_sb_c * S * d_hist * nb * 4  # partials (f32)
+        + 2 * n_nodes * S * d_hist * nb * 4  # hist + transpose
+    )
+    sel_terms = (
+        ("subset", subset),
+        # only where the per-row subset gather is the dominant cost (see
+        # _SEL_MIN_DPAD)
+        ("d_pad", d_pad > _SEL_MIN_DPAD),
+        ("hbm", sel_resident <= _sel_hbm_budget()),
+    )
+    sel_declined = ",".join(
+        t for t in (
+            shape_declined,
+            ",".join(name for name, ok in sel_terms if not ok),
+            rf_hist_sel_declined(n_pad_c, d_pad, d_hist, nb, S, r_sub),
+        ) if t
+    )
+    if not sel_declined and rf_hist_sel_ok(
+        n_pad_c, d_pad, d_hist, nb, S, r_sub, variance=variance
+    ):
+        return LevelPlan(r_sub, n_pad_c, Fc, "pallas_sel", "")
+    compact_declined = ",".join(
+        t for t in (
+            shape_declined,
+            rf_hist_pallas_declined(n_pad_c, Fc, nb, S, r_sub),
+        ) if t
+    )
+    if not compact_declined and rf_hist_pallas_ok(
+        n_pad_c, Fc, nb, S, r_sub, variance=variance
+    ):
+        # the pre-gathered kernel where the fused one was the shape's own
+        # (a per-row subset gather of ~780 ms a level at 1M x 3000) says so
+        wanted_sel = subset and d_pad > _SEL_MIN_DPAD
+        return LevelPlan(
+            r_sub, n_pad_c, Fc, "pallas",
+            f"sel:{sel_declined}" if wanted_sel else "",
+        )
+    # strategy per level (static). Subset path: the gathered operand is only
+    # k_pad wide, and measured v5e scatter on it is ~2.2 ms/level FLAT in
+    # n_nodes while the one-hot matmul grows past 8 ms — scatter always
+    # wins. No-subset path: one-hot matmuls on the MXU until the
+    # 2*n_nodes*nb waste factor exceeds a scatter-add update's cost. "auto"
+    # is TPU-only: the trade inverts on CPU, where scatter-adds are cheap
+    # and dense one-hot matmuls are pure waste. Forced-compact levels that
+    # fail the eligibility gate take scatter, as resolve_hist_strategy
+    # documents — matmul would silently change variance-stat numerics.
+    if cfg.hist_strategy == "matmul":
+        use_matmul = True
+    elif cfg.hist_strategy in ("scatter", "compact") or subset:
+        use_matmul = False
+    else:
+        use_matmul = (
+            jax.default_backend() == "tpu"
+            and (2.0 * n_nodes * nb) < _SCATTER_EQ_FLOPS
+        )
+    return LevelPlan(
+        r_sub, n_pad_c, Fc, "matmul" if use_matmul else "scatter",
+        f"sel:{sel_declined};compact:{compact_declined}",
+    )
+
+
+def plan_levels(n: int, d_pad: int, cfg: ForestConfig, dt=jnp.float32):
+    """:func:`level_plan` of every split level, for a span: the strategies
+    comma-joined in level order, the levels that did not take their Pallas
+    kernel as ``{level: declined}``."""
+    plans = [level_plan(n, d_pad, lv, cfg, dt) for lv in range(cfg.max_depth)]
+    return (
+        ",".join(p.strategy for p in plans),
+        {lv: p.declined for lv, p in enumerate(plans) if p.declined},
+    )
+
+
+# ---------------------------------------------------------------------------
 # single-tree level-wise builder
 # ---------------------------------------------------------------------------
 
@@ -714,89 +933,10 @@ def _build_tree(
                 bins, jnp.clip(row_feats, 0, d_pad - 1), axis=1
             )  # (n, k_pad) uint8
 
-        # compact strategy (TPU): node-contiguous rows + the Pallas
-        # sub-block kernel (ops/rf_pallas.py). Eligibility is static per
-        # level: f32 stats, lane-aligned one-hot width, a full-level
-        # histogram tile that fits HBM comfortably, and a probed
-        # lowering. Wins by ~8x per level over the scatter wall at the
-        # bench shape (scripts/rf_deep_microbench2.py), on every level —
-        # scatter cost is n-bound, so shallow levels paid it too.
-        from .rf_pallas import BLOCK_ROWS, rf_hist_pallas_ok, rf_hist_sel_ok
-
-        r_sub = _compact_r_sub(n, n_nodes, BLOCK_ROWS, S)
-        # Pad with the DEEPEST split level's node count when that waste
-        # is small relative to n: r_sub converges to its cap at scale,
-        # so one padded row count then serves every level and the Pallas
-        # kernels compile ONCE per tree config instead of once per level
-        # (measured ~107 s Mosaic compile for the fused-selection kernel
-        # at the 1M x 3072 shape — 13 per-level compiles would cost
-        # ~20 min). At small n the uniform pad would triple the kernel's
-        # row count (observed: bench rf 4.5 s -> 10.4 s), so fall back
-        # to per-level padding there — those shapes compile in seconds.
-        n_nodes_max = 1 << max(0, cfg.max_depth - 1)
-        if (n_nodes_max + 1) * r_sub * 3 <= n:
-            n_pad_c = (
-                -(-(n + (n_nodes_max + 1) * r_sub) // BLOCK_ROWS)
-                * BLOCK_ROWS
-            )
-        else:
-            n_pad_c = (
-                -(-(n + (n_nodes + 1) * r_sub) // BLOCK_ROWS) * BLOCK_ROWS
-            )
-        n_sb_c = n_pad_c // r_sub
-        # feature chunk: largest power of two satisfying the kernel's
-        # one-hot width cap (Fc*nb <= 8192) AND a ~256 MB partials
-        # transient budget (single-shot partials OOMed the 1M x 3000
-        # reference shape alongside its other residents); must divide
-        # d_hist
-        Fc = 1 << max(0, min(d_hist, 8192 // nb).bit_length() - 1)
-        while Fc > 1 and (
-            d_hist % Fc != 0 or n_sb_c * S * Fc * nb * 4 > (256 << 20)
-        ):
-            Fc //= 2
-        compact_shape_ok = (
-            cfg.hist_strategy in ("auto", "compact")
-            and dt == jnp.float32
-            and d_hist % Fc == 0
-            and n_nodes * d_hist * nb * S <= (1 << 28)
-        )
-        # fused-selection variant: in-kernel per-node column selection
-        # over node-sorted FULL bins rows — skips the per-row subset
-        # gather entirely (the single dominant cost at wide d: ~780 ms
-        # per level at 1M x 3000). Single-shot (no feature chunking), so
-        # its transients are gated against an HBM budget instead: the
-        # probe compiles a tiny instance and cannot see HBM pressure,
-        # and a runtime OOM here has no fallback. Residents counted:
-        # bins + the row-gathered copy (both n-scale uint8), partials,
-        # and two histogram tiles; the sort/index arrays are a few
-        # percent of these and deliberately ignored.
-        sel_resident = (
-            n * d_pad                      # bins (uint8)
-            + n_pad_c * d_pad              # gathered node-sorted copy
-            + n_sb_c * S * d_hist * nb * 4  # partials (f32)
-            + 2 * n_nodes * S * d_hist * nb * 4  # hist + transpose
-        )
-        sel_budget = _sel_hbm_budget()
-        use_sel = (
-            compact_shape_ok
-            and subset
-            # only where the per-row subset gather is the dominant cost
-            # (see _SEL_MIN_DPAD; at bench d_pad=256 fused engagement
-            # SLOWED rf 4.5 -> 10.4 s)
-            and d_pad > _SEL_MIN_DPAD
-            and sel_resident <= sel_budget
-            and rf_hist_sel_ok(
-                n_pad_c, d_pad, d_hist, nb, S, r_sub,
-                variance=(cfg.impurity == "variance"),
-            )
-        )
-        use_compact = use_sel or (
-            compact_shape_ok
-            and rf_hist_pallas_ok(
-                n_pad_c, Fc, nb, S, r_sub,
-                variance=(cfg.impurity == "variance"),
-            )
-        )
+        plan = level_plan(n, d_pad, level, cfg, dt)
+        r_sub, n_pad_c, Fc = plan.r_sub, plan.n_pad, plan.f_chunk
+        use_sel = plan.strategy == "pallas_sel"
+        use_compact = use_sel or plan.strategy == "pallas"
         if use_sel:
             hist_full, parent = _hist_compact(
                 None, seg, sw, n_nodes=n_nodes, nb=nb, r_sub=r_sub,
@@ -850,28 +990,7 @@ def _build_tree(
                 bf = jnp.where(upd, f, bf)
                 bb = jnp.where(upd, b, bb)
         else:
-            # strategy per level (static). Subset path: the gathered operand is
-            # only k_pad wide, and measured v5e scatter on it is ~2.2 ms/level
-            # FLAT in n_nodes while the one-hot matmul grows past 8 ms — scatter
-            # always wins. No-subset path: one-hot matmuls on the MXU until the
-            # 2*n_nodes*nb waste factor exceeds a scatter-add update's cost.
-            # "auto" is TPU-only: the trade inverts on CPU, where scatter-adds
-            # are cheap and dense one-hot matmuls are pure waste (a CPU run of
-            # the reference forest config went from ~seconds to minutes).
-            if cfg.hist_strategy == "matmul":
-                use_matmul = True
-            elif cfg.hist_strategy in ("scatter", "compact"):
-                # forced-compact levels that fail the eligibility gate
-                # take scatter, as resolve_hist_strategy documents —
-                # matmul would silently change variance-stat numerics
-                use_matmul = False
-            elif subset:
-                use_matmul = False
-            else:
-                use_matmul = (
-                    jax.default_backend() == "tpu"
-                    and (2.0 * n_nodes * nb) < _SCATTER_EQ_FLOPS
-                )
+            use_matmul = plan.strategy == "matmul"
 
             # the narrow subset-scatter tile ((k_pad, n_nodes*nb, S): 67 MB at
             # k=16/depth-13) runs single-chunk under a raised budget — chunking
@@ -1313,59 +1432,13 @@ def _grow_trees_batched(
                 )
             )(row_feats)                                # (T, n, k_pad) u8
 
-        # compact-strategy eligibility: the SAME per-tree static
-        # expressions as _build_tree — resolve_tree_batch's budget is
-        # what accounts for the xT transients, NOT these gates, so both
-        # builders always pick the same strategy per level
-        from .rf_pallas import BLOCK_ROWS, rf_hist_pallas_ok, rf_hist_sel_ok
-
-        r_sub = _compact_r_sub(n, n_nodes, BLOCK_ROWS, S)
-        n_nodes_max = 1 << max(0, cfg.max_depth - 1)
-        if (n_nodes_max + 1) * r_sub * 3 <= n:
-            n_pad_c = (
-                -(-(n + (n_nodes_max + 1) * r_sub) // BLOCK_ROWS)
-                * BLOCK_ROWS
-            )
-        else:
-            n_pad_c = (
-                -(-(n + (n_nodes + 1) * r_sub) // BLOCK_ROWS) * BLOCK_ROWS
-            )
-        n_sb_c = n_pad_c // r_sub
-        Fc = 1 << max(0, min(d_hist, 8192 // nb).bit_length() - 1)
-        while Fc > 1 and (
-            d_hist % Fc != 0 or n_sb_c * S * Fc * nb * 4 > (256 << 20)
-        ):
-            Fc //= 2
-        compact_shape_ok = (
-            cfg.hist_strategy in ("auto", "compact")
-            and dt == jnp.float32
-            and d_hist % Fc == 0
-            and n_nodes * d_hist * nb * S <= (1 << 28)
-        )
-        sel_resident = (
-            n * d_pad
-            + n_pad_c * d_pad
-            + n_sb_c * S * d_hist * nb * 4
-            + 2 * n_nodes * S * d_hist * nb * 4
-        )
-        sel_budget = _sel_hbm_budget()
-        use_sel = (
-            compact_shape_ok
-            and subset
-            and d_pad > _SEL_MIN_DPAD
-            and sel_resident <= sel_budget
-            and rf_hist_sel_ok(
-                n_pad_c, d_pad, d_hist, nb, S, r_sub,
-                variance=(cfg.impurity == "variance"),
-            )
-        )
-        use_compact = use_sel or (
-            compact_shape_ok
-            and rf_hist_pallas_ok(
-                n_pad_c, Fc, nb, S, r_sub,
-                variance=(cfg.impurity == "variance"),
-            )
-        )
+        # the SAME per-tree plan as _build_tree — resolve_tree_batch's
+        # budget is what accounts for the xT transients, NOT these gates,
+        # so both builders always pick the same strategy per level
+        plan = level_plan(n, d_pad, level, cfg, dt)
+        r_sub, n_pad_c, Fc = plan.r_sub, plan.n_pad, plan.f_chunk
+        use_sel = plan.strategy == "pallas_sel"
+        use_compact = use_sel or plan.strategy == "pallas"
         if use_sel:
             hist_full, parent = _hist_compact_batched(
                 None, seg, sw, n_nodes=n_nodes, nb=nb, r_sub=r_sub,
@@ -1417,17 +1490,7 @@ def _grow_trees_batched(
                 bf = jnp.where(upd, f, bf)
                 bb = jnp.where(upd, b, bb)
         else:
-            if cfg.hist_strategy == "matmul":
-                use_matmul = True
-            elif cfg.hist_strategy in ("scatter", "compact"):
-                use_matmul = False
-            elif subset:
-                use_matmul = False
-            else:
-                use_matmul = (
-                    jax.default_backend() == "tpu"
-                    and (2.0 * n_nodes * nb) < _SCATTER_EQ_FLOPS
-                )
+            use_matmul = plan.strategy == "matmul"
 
             hist_src = make_hist_src()
             budget = (1 << 25) if (subset and not use_matmul) else _HIST_BUDGET
@@ -1835,17 +1898,6 @@ def forest_apply(
     return jax.vmap(one_tree)(tbl)
 
 
-# The lane-shuffle byte-gather kernel measures ~1e11 lane-gathers/s in
-# isolation, but engaging it in the descent loses badly (161 ms -> ~500 ms
-# for the bench forest, single or batched pallas_call alike): the call
-# boundary de-fuses the surrounding pipeline. Opt-in knob kept for future
-# toolchains; the compare-select contraction is the default. Read ONCE at
-# import (the callers-outside-jit rule: an env read inside the traced
-# functions would be silently ignored on jit cache hits; a module-level
-# read is likewise cache-safe — the value is fixed per process).
-_RF_BYTE_GATHER = bool(envspec.get("TPUML_RF_BYTE_GATHER"))
-
-
 # --- two-hop subtree descent (bin space, zero per-row gathers) -------------
 #
 # The level-synchronous descent above pays one (n,2)-row gather per
@@ -1912,16 +1964,7 @@ def _twohop_group(xb16, packed, feat_g, thr_g, val_g, *, max_depth, d):
     k2 = D - k1
     n1 = (1 << k1) - 1          # hop-1 internal candidate nodes 0..n1-1
     iota_d = jnp.arange(d, dtype=jnp.int32)
-    from .rf_pallas import packed_byte_gather_many, packed_byte_gather_ok
-
-    words = packed.shape[1]
-    Wg = max(64, words)
     nint = (1 << k2) - 1 if k2 > 0 else 0
-    use_bg = k2 > 0 and _RF_BYTE_GATHER and packed_byte_gather_ok(
-        n, words, nint
-    )
-    if use_bg and words < Wg:
-        packed = jnp.pad(packed, ((0, 0), (0, Wg - words)))
 
     leaf_ids = []
     vals_sum = None
@@ -1972,21 +2015,9 @@ def _twohop_group(xb16, packed, feat_g, thr_g, val_g, *, max_depth, d):
     if k2 == 0:
         return jnp.stack(leaf_ids, axis=0), vals_sum
 
-    # phase B: ONE batched lane-shuffle gather for the whole group (per-tree
-    # pallas_call dispatches measured ~6 ms of overhead each inside a jitted
-    # forest evaluation; the contraction fallback costs ~70 ms per forest)
-    if use_bg:
-        idx_all = jnp.stack(
-            [jnp.pad(p[5], ((0, 0), (0, Wg - nint))) for p in ph]
-        )                                                   # (G, n, Wg)
-        xv_all = packed_byte_gather_many(packed, idx_all)   # (G, n, Wg)
-
     # phase C (per tree): hop-2 navigation + leaf/value resolution
     for g, (i1, done1, l7, rfeat, rthr, ridx) in enumerate(ph):
-        if use_bg:
-            xv = xv_all[g][:, :nint]
-        else:
-            xv = _contract_gather(packed, ridx)             # (n, nint) i32
+        xv = _contract_gather(packed, ridx)                 # (n, nint) i32
         bits2 = ((xv > rthr) & (rfeat >= 0)).astype(jnp.int32)
         enc2 = (1 + bits2) * (rfeat >= 0).astype(jnp.int32)
         enc2 = jnp.where(done1[:, None], 0, enc2)
@@ -2013,13 +2044,8 @@ def _twohop_drive(xb, feat, thr_bin, values, *, max_depth, group):
     bf16 cast + word packing, tree-group loop, and row unpadding. With
     ``values`` None returns stacked (T, n) leaf ids; otherwise the (n, V)
     value sum over trees."""
-    from .rf_pallas import _GATHER_BLOCK
-
     T = feat.shape[0]
     n0 = xb.shape[0]
-    if _RF_BYTE_GATHER and jax.default_backend() == "tpu":
-        # block-align rows so the Pallas lane-gather gate engages
-        xb = jnp.pad(xb, ((0, (-n0) % _GATHER_BLOCK), (0, 0)))
     xb16 = xb.astype(jnp.bfloat16)
     packed = _pack_bins(xb)
     ids_out = []

@@ -329,11 +329,12 @@ SPEC: Dict[str, EnvVar] = _registry(
     ),
     EnvVar(
         "TPUML_RF_APPLY", "choice", "auto",
-        "Forest inference path: `auto` prefers the FIL-style packed-forest "
-        "lockstep engine on TPU (bit-identical to both descents), falling "
-        "back to the two-hop bin-space descent then the raw-threshold "
-        "descent; `legacy`/`bins`/`packed` pin one engine (see "
-        "`docs/rf_performance.md`).",
+        "Forest inference path: `auto` takes the two-hop bin-space descent "
+        "on a TPU (bounded compile; 2x the raw-threshold descent at 3000 "
+        "columns) and the raw-threshold descent elsewhere; "
+        "`legacy`/`bins`/`packed` pin one engine — the packed-forest "
+        "lockstep engine only ever runs pinned: its compile grows faster "
+        "than the tree count (see `docs/rf_performance.md`).",
         choices=("auto", "legacy", "bins", "packed"), category="random-forest",
     ),
     EnvVar(
@@ -342,14 +343,6 @@ SPEC: Dict[str, EnvVar] = _registry(
         "boundary (a full host pass, so off by default). Fit always "
         "rejects non-finite features; without this flag, transform-time "
         "NaN silently routes to bin 0 in the bin-space descents.",
-        category="random-forest",
-    ),
-    EnvVar(
-        "TPUML_RF_BYTE_GATHER", "bool", False,
-        "Opt-in Pallas lane-shuffle byte gather in the two-hop descent. "
-        "Measured 3x slower in situ on the current toolchain "
-        "(call-boundary de-fusion; `docs/rf_performance.md` round 5) — a "
-        "documented negative result kept for future toolchains.",
         category="random-forest",
     ),
     EnvVar(

@@ -723,6 +723,9 @@ class _Handler(BaseHTTPRequestHandler):
         except Exception as exc:  # a handler bug must not kill the fit
             code = 500
             body = json.dumps({"error": str(exc)}).encode()
+        # counted before the reply goes out: a client that reads the counter
+        # right after its response must find its own request in it
+        telemetry.counter("ops_requests_total").inc(endpoint=endpoint)
         try:
             self.send_response(code)
             self.send_header("Content-Type", ctype)
@@ -731,7 +734,6 @@ class _Handler(BaseHTTPRequestHandler):
             self.wfile.write(body)
         except Exception:  # client went away mid-write
             pass
-        telemetry.counter("ops_requests_total").inc(endpoint=endpoint)
         telemetry.histogram("ops_request_seconds").observe(
             time.perf_counter() - t0, endpoint=endpoint
         )

@@ -25,7 +25,20 @@ from spark_rapids_ml_tpu.utils.platform import enable_compile_cache  # noqa: E40
 # Persistent compilation cache (the one rule in utils/platform.py): the
 # tree-builder programs dominate suite wall-clock; caching compiled
 # executables on disk makes repeat runs on the same machine start warm.
-enable_compile_cache(min_compile_secs=0.5)
+_cache_dir = enable_compile_cache(min_compile_secs=0.5)
+# One cache directory per xdist worker. JAX writes an entry in place
+# (``Path.write_bytes``, no rename, and no lock unless eviction is on), so a
+# worker that looks up a program while another worker is still writing the same
+# program's entry reads half a file, and XLA:CPU's AOT loader aborts the
+# process on it: ``tests/test_gbt.py::test_regressor_matches_sklearn_r2`` died
+# so under six workers (``Fatal Python error: Aborted`` inside ``gbt_round``,
+# right after the loader's lines) and passes alone. Workers run different
+# files (``--dist loadfile``), so they share few programs anyway.
+_worker = os.environ.get("PYTEST_XDIST_WORKER")
+if _worker:
+    jax.config.update(
+        "jax_compilation_cache_dir", os.path.join(_cache_dir, _worker)
+    )
 
 import threading  # noqa: E402
 

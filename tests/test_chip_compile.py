@@ -372,6 +372,37 @@ def test_rf_subblock_hist_kernel_compiles(one_chip):
     assert _has_kernel(c)
 
 
+def test_rf_fused_selection_kernel_compiles_at_the_reference_width(one_chip):
+    """``rf_dbx``'s histogram kernel: whole rows of 3072 uint8 bins (3000
+    columns at the lane multiple, not 4096), 64 feature slots (55 sampled),
+    128 bins, 2 stats, sub-blocks of 64 rows — two grid blocks, as the
+    lowering probe compiles it."""
+    from spark_rapids_ml_tpu.ops.rf_pallas import BLOCK_ROWS, rf_hist_sel_declined, subblock_hist_sel
+
+    n, d_pad, k, r_sub, S = 2 * BLOCK_ROWS, 3072, 64, 64, 2
+    assert rf_hist_sel_declined(n, d_pad, k, 128, S, r_sub) == "backend"   # every other term holds
+    c = subblock_hist_sel.lower(
+        one_chip((n, d_pad), jnp.uint8), one_chip((n // r_sub, k), I32), one_chip((S, n)),
+        n_bins=128, r_sub=r_sub, variance=False, interpret=False,
+    ).compile()
+    assert _has_kernel(c)
+
+
+def test_rf_sketch_reads_the_reference_frame_in_place(one_chip):
+    """The quantile sketch at ``rf_dbx``'s shard (500,000 x 3000 f32, rows
+    minor): 1024 runs of 128 consecutive rows, sorted a column on the device.
+    Its temporaries are the sample's (131,072 x 3000 f32 = 1.57 GB, twice:
+    3.2 GB), never the frame's: the strided row gather it replaced put a
+    relaid copy of the whole 6 GB frame in front of a 2 GB fetch."""
+    from spark_rapids_ml_tpu.ops.tree_kernels import quantile_edges
+
+    rows, cols = 500_000, 3000
+    c = quantile_edges.lower(one_chip((rows, cols)), one_chip((rows,)), n_bins=128).compile()
+    sample = 131_072 * cols * 4
+    assert c.memory_analysis().temp_size_in_bytes < 2.5 * sample
+    assert c.memory_analysis().output_size_in_bytes < 2 * cols * 127 * 4
+
+
 def test_rf_packed_traverse_kernel_compiles(one_chip):
     """Depth 13 (k1=7, k2=6), d=256 (64 packed words), 131,072 rows. The
     kernel unrolls a static loop over every tree and its compile time grows
