@@ -74,9 +74,10 @@ def build_tree_variant(bins, stats, valid, key, cfg, *, knock=None):
 
         r_sub = tk._compact_r_sub(n, n_nodes, BLOCK_ROWS, S)
         n_pad_c = -(-(n + (n_nodes + 1) * r_sub) // BLOCK_ROWS) * BLOCK_ROWS
-        hist_full, parent = tk._hist_compact(
-            hist_src, seg, sw, n_nodes=n_nodes, nb=nb, r_sub=r_sub,
-            n_pad=n_pad_c, f_chunk=K, variance=False)
+        hist_full, parent, _ = tk._hist_compact(
+            lambda r, _nd, h=hist_src: h[r], seg, sw, n_nodes=n_nodes,
+            n_slots=K, nb=nb, r_sub=r_sub, n_pad=n_pad_c, f_chunk=K,
+            variance=False)
         leaf = leaf.at[offset:offset + n_nodes].set(parent)
         pcount = tk._count(parent, cfg.impurity)
         pimp = tk._impurity(parent, cfg.impurity)

@@ -132,18 +132,19 @@ def main():
 
     # --- full _hist_compact
     def f_hist(c, i, seg, sw, hist_src):
-        h, p = tk._hist_compact(
-            jnp.where(c >= jnp.float32(-1e30), hist_src, 0), seg, sw,
-            n_nodes=N_NODES, nb=NB, r_sub=r_sub, n_pad=n_pad,
+        src = jnp.where(c >= jnp.float32(-1e30), hist_src, 0)
+        h, p, _ = tk._hist_compact(
+            lambda r, _nd: src[r], seg, sw,
+            n_nodes=N_NODES, n_slots=K, nb=NB, r_sub=r_sub, n_pad=n_pad,
             f_chunk=K, variance=False)
         return h.sum() + p.sum()
     t_hist = timed(loop(f_hist), seg, sw, hist_src)
     print(f"hist_compact    : {t_hist*1e3:6.2f} ms")
 
     # --- gain search
-    hist_full, parent = tk._hist_compact(
-        hist_src, seg, sw, n_nodes=N_NODES, nb=NB, r_sub=r_sub,
-        n_pad=n_pad, f_chunk=K, variance=False)
+    hist_full, parent, _ = tk._hist_compact(
+        lambda r, _nd: hist_src[r], seg, sw, n_nodes=N_NODES, n_slots=K,
+        nb=NB, r_sub=r_sub, n_pad=n_pad, f_chunk=K, variance=False)
     cfg = tk.ForestConfig(
         max_depth=13, n_bins=NB, n_features=D, n_stats=S, impurity="gini",
         k_features=K, min_samples_leaf=1, min_info_gain=0.0,

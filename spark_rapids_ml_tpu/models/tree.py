@@ -478,7 +478,7 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                         strategy=strategies,
                         levels_declined=len(declined),
                         **({"declined": str(declined)} if declined else {}),
-                    ):
+                    ) as grow:
                         outg = jax.block_until_ready(
                             build_forest(
                                 bins, inputs.mask, stats,
@@ -487,12 +487,24 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                                 tree_batch=tree_batch,
                             )
                         )
-                    with telemetry.span("forest.fetch_group", group=g):
-                        for k, a in outg.items():
-                            h = fetch_global(a, inputs.mesh)
-                            pieces.setdefault(k, []).append(
-                                h.reshape(n_dp, group, *h.shape[1:])
-                            )
+                        # the program has ended: the fetch waits on no device
+                        # work, and the group's live-row counts come with its
+                        # tables
+                        with telemetry.span("forest.fetch_group", group=g):
+                            for k, a in outg.items():
+                                h = fetch_global(a, inputs.mesh)
+                                pieces.setdefault(k, []).append(
+                                    h.reshape(n_dp, group, *h.shape[1:])
+                                )
+                        live = pieces["live_rows"][-1]  # (n_dp, group, levels)
+                        grow.set_attr(
+                            # rows the group's levels worked on, over every
+                            # row on every level of every tree
+                            live_share=float(
+                                live.sum() / max(1, live.size * rows_per_tree)
+                            ),
+                            live_rows_by_level=live.mean(axis=(0, 1)).tolist(),
+                        )
 
             # interleave device-major -> tree-major so the slice to n_trees
             # takes trees evenly from every device

@@ -127,6 +127,21 @@ def _probe_lowering(
     )
 
 
+def _live_index(i, live_ref):
+    """Block index of grid step ``i`` under a live-block count: a step past
+    the count names the LAST live block again, so the pipeline fetches no
+    new input block for it and writes no new output block (its body is
+    skipped by ``pl.when``). Output blocks past the count are therefore
+    never written; with a count of 0 not even block 0 is."""
+    return jnp.minimum(i, jnp.maximum(live_ref[0] - 1, 0))
+
+
+def _live_operand(live_blocks, n_blocks: int) -> jax.Array:
+    if live_blocks is None:
+        return jnp.full((1,), n_blocks, jnp.int32)
+    return jnp.asarray(live_blocks, jnp.int32).reshape(1)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_bins", "r_sub", "variance", "interpret", "transposed_sw"),
@@ -134,6 +149,7 @@ def _probe_lowering(
 def subblock_hist(
     binq: jax.Array,   # (n_pad, k) int32 bins in node-contiguous order
     sw: jax.Array,     # (n_pad, S) f32 stats*weight (0 on padding rows)
+    live_blocks: jax.Array | None = None,  # (1,) int32; None = every block
     *,
     n_bins: int,
     r_sub: int,
@@ -148,6 +164,10 @@ def subblock_hist(
     Sub-block j covers rows [j*r_sub, (j+1)*r_sub); summing the
     sub-blocks of one node — they are consecutive — yields that node's
     (S, k, n_bins) histogram.
+
+    ``live_blocks`` (scalar prefetch): only the first ``live_blocks[0]``
+    grid blocks hold rows (:func:`_live_index`); the partials of the others
+    are NOT written and the caller must not read them.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -157,18 +177,19 @@ def subblock_hist(
     n_pad, k = binq.shape
     nb = n_bins
     W = k * nb
-    if transposed_sw:
-        S, _ = sw.shape
-        swT = sw
-    else:
-        _, S = sw.shape
-        swT = sw.T  # (S, n_pad) — lane-major rows per stat
+    swT = sw if transposed_sw else sw.T  # (S, n_pad): lane-major rows per stat
+    S = swT.shape[0]
     R = _block_rows(k, nb)
     L = R // r_sub
     n_blocks = n_pad // R
     prec = lax.Precision.HIGHEST if variance else None
 
-    def kern(b_ref, s_ref, out_ref):
+    def kern(live_ref, b_ref, s_ref, out_ref):
+        pl.when(pl.program_id(0) < live_ref[0])(
+            lambda: body(b_ref, s_ref, out_ref)
+        )
+
+    def body(b_ref, s_ref, out_ref):
         # static lane-expansion matrix: E[f, f*nb + j] = 1 (built from
         # iotas in-kernel; Pallas forbids captured array constants)
         fi = lax.broadcasted_iota(jnp.int32, (k, W), 0)
@@ -197,13 +218,23 @@ def subblock_hist(
 
     out = pl.pallas_call(
         kern,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((R, k), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((S, R), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (L * S, W), lambda i: (i, 0), memory_space=pltpu.VMEM
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_blocks,),
+            in_specs=[
+                pl.BlockSpec(
+                    (R, k), lambda i, live: (_live_index(i, live), 0),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (S, R), lambda i, live: (0, _live_index(i, live)),
+                    memory_space=pltpu.VMEM,
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (L * S, W), lambda i, live: (_live_index(i, live), 0),
+                memory_space=pltpu.VMEM,
+            ),
         ),
         out_shape=jax.ShapeDtypeStruct((n_blocks * L * S, W), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -212,7 +243,7 @@ def subblock_hist(
         ),
         interpret=interpret,
         name="rf_hist_pass",
-    )(binq, swT)
+    )(_live_operand(live_blocks, n_blocks), binq, swT)
     return out.reshape(n_pad // r_sub, S, W)
 
 
@@ -229,6 +260,7 @@ def subblock_hist_sel(
     bq: jax.Array,      # (n_pad, d_pad) uint8 FULL bins, node-sorted
     featsq: jax.Array,  # (n_sb, k) int32 selected feature ids per sub-block
     swT: jax.Array,     # (S, n_pad) f32 stats*weight (0 on padding rows)
+    live_blocks: jax.Array | None = None,  # (1,) int32; None = every block
     *,
     n_bins: int,
     r_sub: int,
@@ -236,7 +268,8 @@ def subblock_hist_sel(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Per-sub-block histograms with IN-KERNEL feature-subset selection:
-    (n_pad//r_sub, S, k*n_bins) float32.
+    (n_pad//r_sub, S, k*n_bins) float32. ``live_blocks`` as in
+    :func:`subblock_hist`: partials past it are unwritten, never zeros.
 
     The pre-gathered variant (``subblock_hist``) needs hist_src =
     bins[row, feats[node[row]]] built OUTSIDE the kernel — a per-row
@@ -284,7 +317,12 @@ def subblock_hist_sel(
         featsq, ((0, 0), (0, k_lanes - k)), constant_values=d_pad
     )                                                      # (n_sb, k_lanes)
 
-    def kern(b_ref, f_ref, s_ref, out_ref):
+    def kern(live_ref, b_ref, f_ref, s_ref, out_ref):
+        pl.when(pl.program_id(0) < live_ref[0])(
+            lambda: body(b_ref, f_ref, s_ref, out_ref)
+        )
+
+    def body(b_ref, f_ref, s_ref, out_ref):
         # Mosaic has no direct u8->f32 cast; hop through int32
         rows_all = (
             b_ref[:].astype(jnp.int32).astype(jnp.float32)
@@ -319,16 +357,27 @@ def subblock_hist_sel(
 
     out = pl.pallas_call(
         kern,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((R, d_pad), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(
-                (L, k_lanes), lambda i: (i, 0), memory_space=pltpu.VMEM
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_blocks,),
+            in_specs=[
+                pl.BlockSpec(
+                    (R, d_pad), lambda i, live: (_live_index(i, live), 0),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (L, k_lanes), lambda i, live: (_live_index(i, live), 0),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (S, R), lambda i, live: (0, _live_index(i, live)),
+                    memory_space=pltpu.VMEM,
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (L * S, W), lambda i, live: (_live_index(i, live), 0),
+                memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec((S, R), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (L * S, W), lambda i: (i, 0), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((n_blocks * L * S, W), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -337,7 +386,7 @@ def subblock_hist_sel(
         ),
         interpret=interpret,
         name="rf_hist_sel_pass",
-    )(bq, fq, swT)
+    )(_live_operand(live_blocks, n_blocks), bq, fq, swT)
     return out.reshape(n_pad // r_sub, S, W)
 
 

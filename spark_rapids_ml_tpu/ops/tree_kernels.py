@@ -464,12 +464,53 @@ def _sorted_block_reduce(partials2d, pstart, r_sub, n_nodes):
     return bounds[1:] - bounds[:-1]
 
 
+# rows of the node-sorted order that one trip of a level's loops works on: 32
+# kernel blocks, 50 MB of whole rows at 3072 bins a row. ONE size for every
+# level of every tree, so a tree's thirteen histogram calls are one Mosaic
+# shape; what follows the live rows is the trip count.
+_LIVE_CHUNK = 16384
+
+
+def _level_seg(node: jax.Array, level: int, weight=None):
+    """``(local, in_level, seg)`` of ``level``: the level-local node id of
+    every row, whether the row sits in a node of the level, and the id the
+    histograms group by — ``n_nodes`` (the dump slot) for a dead row.
+
+    THE one place that decides liveness, for both builders (their
+    bit-identity on variance statistics rests on equal groupings of the f32
+    sums, so they must form the same sub-blocks). A row is live iff it sits
+    in a node of the level and, where ``weight`` is given, its weight is
+    positive: a row the bootstrap drew zero times (36.8% of a tree's rows)
+    or the mask leaves out adds 0 to every histogram cell, to the parents'
+    and to the leaves' statistics, and is no output of the fit — it sorts
+    past the live rows and is not gathered, histogrammed, reduced or routed.
+    A caller that needs EVERY row's final node (``return_rows``) passes no
+    weight and keeps such rows live."""
+    n_nodes = 1 << level
+    local = node - (n_nodes - 1)
+    in_level = (local >= 0) & (local < n_nodes)
+    live = in_level if weight is None else in_level & (weight > 0)
+    return local, in_level, jnp.where(live, local, n_nodes).astype(jnp.int32)
+
+
+class _Frontier(NamedTuple):
+    """A level's live rows in node-sorted order, for the routing."""
+
+    rows: jax.Array     # (n_ceil,) original row id at a sorted position; n = padding
+    sb_node: jax.Array  # (n_ceil // r_sub,) level-local node of a sub-block (clipped)
+    trips: jax.Array    # () chunks that hold a live row: ceil(P / chunk)
+    chunk: int          # rows a chunk (a BLOCK_ROWS multiple)
+    r_sub: int
+
+
 def _hist_compact(
-    hist_src,             # (n, F) int bin values, or None with full_bins
+    row_bins,             # (row ids (m,), their level-local nodes (m,)) ->
+                          # (m, F) int bins; None with full_bins
     seg: jax.Array,       # (n,) int32 level-local node id; n_nodes = dead
     sw: jax.Array,        # (n, S) f32 stats*weight
     *,
     n_nodes: int,
+    n_slots: int,         # F: the histogram's feature slots
     nb: int,
     r_sub: int,
     n_pad: int,           # from the caller's eligibility gate: the SAME
@@ -480,32 +521,49 @@ def _hist_compact(
     feats=None,           # (n_nodes, F) int32 per-node feature ids
     interpret=None,
 ):
-    """(F, n_nodes, nb, S) histogram + (n_nodes, S) parent stats via the
-    node-contiguous Pallas path (``ops/rf_pallas.py``).
+    """(F, n_nodes, nb, S) histogram, (n_nodes, S) parent stats and the
+    level's :class:`_Frontier` via the node-contiguous Pallas path
+    (``ops/rf_pallas.py``).
 
     One stable sort groups rows by node; every node's run is padded to an
     ``r_sub`` multiple so each aligned sub-block is node-pure; the Pallas
     kernel turns each sub-block into a (S, F*nb) histogram with a bin-only
-    one-hot (NO node dimension — the whole point); and one wide-row
-    segment-sum over the node-sorted sub-blocks finishes the per-node
-    histograms. Parent stats fall out of the histogram (bin-sum of the
-    first subset slot — slot 0 is always a real feature), saving the
-    per-level parent scatter the other strategies pay.
+    one-hot (NO node dimension — the whole point); and a segment-sum over
+    the node-sorted sub-blocks finishes the per-node histograms. Parent
+    stats fall out of the histogram (bin-sum of the first subset slot —
+    slot 0 is always a real feature), saving the per-level parent scatter
+    the other strategies pay.
+
+    **The live frontier.** Dead rows sort to the end, so the live rows are
+    the prefix ``[0, P)`` of the padded order, ``P = pstart[n_nodes]``, a
+    device scalar. Every shape stays static; what follows ``P`` is a trip
+    count. The level is ONE loop over chunks of ``_LIVE_CHUNK`` sorted
+    positions, ``ceil(P / chunk)`` trips: a trip gathers its rows' ids and
+    weights and the rows themselves (whole uint8 rows for the fused
+    selection — ~100 GB/s, wide contiguous rows), hands that chunk to the
+    kernel, and adds the chunk's partials to the per-node sums in sub-block
+    order, as ``segment_sum`` adds them (the batched builder's grouping of
+    the f32 sums). No node-sorted copy of a level is held (1.5-2.3 GB at
+    500,000 x 3072, and its partials 0.5-0.8 GB): a chunk and its partials
+    are 70 MB. In the last trip the kernel takes the number of its
+    blocks that hold a live row by scalar prefetch and neither fetches nor
+    computes the others; their partials are unwritten and are dropped
+    unread (an out-of-range segment id). A chunk past ``P`` is not touched.
 
     Measured v5e at 131k x 16 x 128 x 2 (level 12): ~41 ms for the
     scatter strategy's histogram vs ~1 ms kernel + ~4 ms glue here
     (scripts/rf_deep_microbench*.py).
     """
-    from .rf_pallas import subblock_hist, subblock_hist_sel
+    from .rf_pallas import BLOCK_ROWS, subblock_hist, subblock_hist_sel
 
-    if full_bins is not None:
-        n = full_bins.shape[0]
-        F = feats.shape[1]
-    else:
-        n, F = hist_src.shape
+    n = seg.shape[0]
+    F, Fc = n_slots, f_chunk
     S = sw.shape[1]
-    W = F * nb
-    n_sb = n_pad // r_sub
+    chunk = min(n_pad, _LIVE_CHUNK)
+    chunk_sb = chunk // r_sub
+    # the padded order, rounded up to whole chunks (index arithmetic only)
+    n_ceil = -(-n_pad // chunk) * chunk
+    n_sb = n_ceil // r_sub
 
     # stable sort of row ids by node: perm[j] = original row at sorted pos j
     iota = jnp.arange(n, dtype=jnp.int32)
@@ -519,6 +577,8 @@ def _hist_compact(
     pstart = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(plen)]
     )                                                       # (n_nodes+1,)
+    p_live = pstart[n_nodes]                                # P
+    trips = -(-p_live // chunk)
     # node of each sub-block (sub-blocks are node-pure by construction;
     # positions past the data resolve to the n_nodes dump slot)
     sb_pos = jnp.arange(n_sb, dtype=jnp.int32) * r_sub
@@ -528,91 +588,134 @@ def _hist_compact(
     # per-row source index: ONE small-table row gather at sub-block
     # granularity (n_sb rows), broadcast to rows — per-row gathers from
     # the (n_nodes,) tables would cost ~1 ms each at the elementwise
-    # gather wall
+    # gather wall. Index arithmetic only: it stays at full size.
     sbc = jnp.clip(seg_sb, 0, n_nodes - 1)
     tbl = jnp.stack([starts[:-1], pstart[:-1], lens], axis=1)
     tbl_rows = jnp.broadcast_to(
         tbl[sbc][:, None, :], (n_sb, r_sub, 3)
-    ).reshape(n_pad, 3)
-    pos = jnp.arange(n_pad, dtype=jnp.int32)
+    ).reshape(n_ceil, 3)
+    pos = jnp.arange(n_ceil, dtype=jnp.int32)
     off = pos - tbl_rows[:, 1]
-    src = tbl_rows[:, 0] + off
+    src = jnp.clip(tbl_rows[:, 0] + off, 0, n - 1)
     pvalid = (off < tbl_rows[:, 2]) & (
-        jnp.broadcast_to(seg_sb[:, None], (n_sb, r_sub)).reshape(n_pad)
+        jnp.broadcast_to(seg_sb[:, None], (n_sb, r_sub)).reshape(n_ceil)
         < n_nodes
     )
-    src2 = perm[jnp.clip(src, 0, n - 1)]
-    swq = sw[src2] * pvalid[:, None].astype(sw.dtype)       # (n_pad, S)
-    seg_red = jnp.where(seg_sb < n_nodes, seg_sb, n_nodes)
-
-    # cumsum boundary-diff reduction only where EXACT (see
-    # _sorted_block_reduce): integer stats AND total weighted rows small
-    # enough that no per-column global prefix can reach 2^24 (Poisson
-    # bootstrap weights average 1, so n rows bounds the count column up
-    # to tail factors the 2^23 margin absorbs). Width-gated too: the
-    # prefix array is a materialized (n_sb, W) transient, and at the
-    # 1M x 3000 reference shape (W = 16384) the cumsum formulation
-    # measured ~25% SLOWER end-to-end than the segment_sum it replaces
-    # (182 s vs 146 s full fit) — keep it to bench-class widths
-    def _use_cumsum(width):
-        return (not variance) and n <= (1 << 23) and width <= 8192
-
+    # a stat at a time, from (n,) vectors: a gather of (n, S) rows makes
+    # the device pad S to a lane tile first, 256 MB at 500,000 x 2. (Sorting
+    # the stats along with the ids and reading both as one r_sub-wide window
+    # a sub-block was measured and is SLOWER: PERF.md section 6, PR 36.)
+    sw_cols = [sw[:, s] for s in range(S)]
     if full_bins is not None:
-        # fused-selection path: ONE whole-row gather of the uint8 bins
-        # (~93 GB/s — wide contiguous rows) + per-sub-block feature ids;
-        # the kernel selects each node's k columns with an MXU one-hot
-        # dot, replacing the per-row k-column gather that costs ~780 ms
-        # per level at the reference 1M x 3000 shape. Dump sub-blocks
-        # get garbage feature rows but zero weights — they contribute
-        # nothing and reduce into the dropped slot.
-        bq = full_bins[src2]                                # (n_pad, d_pad)
         featsq = feats[sbc]                                 # (n_sb, F)
-        partials = subblock_hist_sel(
-            bq, featsq, swq.T, n_bins=nb, r_sub=r_sub,
-            variance=variance, interpret=interpret,
-        )                                                   # (n_sb, S, F*nb)
-        p2d = partials.reshape(n_sb, S * F * nb)
-        if _use_cumsum(S * F * nb):
-            hist_nodes = _sorted_block_reduce(
-                p2d, pstart, r_sub, n_nodes
-            ).reshape(n_nodes, S, F, nb)
-        else:
-            hist_nodes = jax.ops.segment_sum(
-                p2d, seg_red, num_segments=n_nodes + 1
-            )[:n_nodes].reshape(n_nodes, S, F, nb)
-    else:
-        # int32 bins always (hist_src may arrive uint8 from
-        # take_along_axis): the kernel — and its lowering probe — see
-        # exactly one input dtype
-        binq = hist_src[src2].astype(jnp.int32)             # (n_pad, F)
+    ar_sb = jnp.arange(chunk_sb, dtype=jnp.int32)
 
-        # feature-chunked kernel+reduce: the (n_sb, S, Fc*nb) partials
-        # are the big transient (1.3 GB at the 1M x 3000 reference shape
-        # in one shot) — bound them to ~256 MB; the gathers above happen
-        # ONCE and chunks just slice binq
-        Fc = f_chunk
-        hist_parts = []
-        for c0 in range(0, F, Fc):
-            partials = subblock_hist(
-                binq[:, c0 : c0 + Fc], swq, n_bins=nb, r_sub=r_sub,
-                variance=variance, interpret=interpret,
-            )                                               # (n_sb, S, Fc*nb)
-            p2d = partials.reshape(n_sb, S * Fc * nb)
-            if _use_cumsum(S * Fc * nb):
-                part = _sorted_block_reduce(p2d, pstart, r_sub, n_nodes)
-            else:
-                part = jax.ops.segment_sum(
-                    p2d, seg_red, num_segments=n_nodes + 1
-                )[:n_nodes]
-            hist_parts.append(part.reshape(n_nodes, S, Fc, nb))
-        hist_nodes = (
-            hist_parts[0]
-            if len(hist_parts) == 1
-            else jnp.concatenate(hist_parts, axis=2)
-        )                                                   # (n_nodes, S, F, nb)
+    def level_chunk(c, carry):
+        accs, rows = carry
+        lo = c * chunk
+        lo_sb = c * chunk_sb
+        ok = lax.dynamic_slice(pvalid, (lo,), (chunk,))
+        ids = perm[lax.dynamic_slice(src, (lo,), (chunk,))]
+        swT = jnp.stack([col[ids] for col in sw_cols]) * ok[None, :].astype(
+            sw.dtype
+        )                                                   # (S, chunk)
+        # blocks of this chunk that hold a live row; a sub-block past P
+        # keeps its unwritten partial out of every sum (dropped unread)
+        live_blocks = jnp.clip(
+            -(-(p_live - lo) // BLOCK_ROWS), 0, chunk // BLOCK_ROWS
+        ).reshape(1)
+        seg_c = jnp.where(
+            (lo_sb + ar_sb) * r_sub < p_live,
+            lax.dynamic_slice(seg_sb, (lo_sb,), (chunk_sb,)),
+            n_nodes,
+        )
+        if full_bins is not None:
+            # whole rows; the kernel selects each node's k columns with an
+            # MXU one-hot dot, replacing the per-row k-column gather that
+            # costs ~780 ms per level at the reference 1M x 3000 shape.
+            # Dump sub-blocks of a live block get garbage feature rows but
+            # zero weights — they contribute nothing.
+            parts = [
+                subblock_hist_sel(
+                    full_bins[ids],
+                    lax.dynamic_slice(featsq, (lo_sb, 0), (chunk_sb, F)),
+                    swT, live_blocks, n_bins=nb, r_sub=r_sub,
+                    variance=variance, interpret=interpret,
+                )
+            ]                                               # (chunk_sb, S, F*nb)
+        else:
+            nodes = jnp.broadcast_to(
+                lax.dynamic_slice(sbc, (lo_sb,), (chunk_sb,))[:, None],
+                (chunk_sb, r_sub),
+            ).reshape(chunk)
+            # int32 bins always: the kernel — and its lowering probe — see
+            # exactly one input dtype. Feature-chunked: the kernel's one-hot
+            # is at most 8192 lanes wide
+            binq = row_bins(ids, nodes).astype(jnp.int32)   # (chunk, F)
+            parts = [
+                subblock_hist(
+                    binq[:, c0 : c0 + Fc], swT, live_blocks, n_bins=nb,
+                    r_sub=r_sub, variance=variance, interpret=interpret,
+                    transposed_sw=True,
+                )
+                for c0 in range(0, F, Fc)
+            ]                                               # (chunk_sb, S, Fc*nb)
+        accs = tuple(
+            acc.at[seg_c].add(part.reshape(chunk_sb, -1), mode="drop")
+            for acc, part in zip(accs, parts)
+        )
+        rows = lax.dynamic_update_slice(rows, jnp.where(ok, ids, n), (lo,))
+        return accs, rows
+
+    width = F if full_bins is not None else Fc
+    accs, rows = lax.fori_loop(
+        0, trips, level_chunk,
+        (
+            tuple(
+                jnp.zeros((n_nodes, S * width * nb), sw.dtype)
+                for _ in range(F // width)
+            ),
+            jnp.full((n_ceil,), n, jnp.int32),
+        ),
+    )
+    hist_nodes = jnp.concatenate(
+        [acc.reshape(n_nodes, S, width, nb) for acc in accs], axis=2
+    )                                                       # (n_nodes, S, F, nb)
     parent = hist_nodes[:, :, 0, :].sum(axis=-1)            # (n_nodes, S)
     hist = hist_nodes.transpose(2, 0, 3, 1)                 # (F, n_nodes, nb, S)
-    return hist, parent
+    return hist, parent, _Frontier(rows, sbc, trips, chunk, r_sub)
+
+
+def _route_live(node, frontier, row_bin, do_split, bf, bb, *, offset: int):
+    """Send the live rows of a level to their children: ``node`` with the
+    entries of the frontier's rows whose node split set to the child. The
+    rows' ids are the frontier's, their nodes are known by sub-block, so a
+    chunk costs one element gather (``row_bin(row ids, features)``) and one
+    scatter — over the live prefix, not over n. Rows in a node that became
+    a leaf stay put; dead rows are never touched."""
+    n = node.shape[0]
+    chunk, r_sub = frontier.chunk, frontier.r_sub
+    chunk_sb = chunk // r_sub
+    # per sub-block: its node's split, feature and threshold (n_sb-scale)
+    tbl = jnp.stack(
+        [
+            do_split.astype(jnp.int32), bf, bb,
+            jnp.arange(do_split.shape[0], dtype=jnp.int32),
+        ],
+        axis=1,
+    )[frontier.sb_node]                                     # (n_sb, 4)
+
+    def body(c, node):
+        ids = lax.dynamic_slice(frontier.rows, (c * chunk,), (chunk,))
+        t = jnp.broadcast_to(
+            lax.dynamic_slice(tbl, (c * chunk_sb, 0), (chunk_sb, 4))[:, None],
+            (chunk_sb, r_sub, 4),
+        ).reshape(chunk, 4)
+        rb = row_bin(jnp.minimum(ids, n - 1), t[:, 1])
+        child = 2 * (offset + t[:, 3]) + 1 + (rb > t[:, 2]).astype(jnp.int32)
+        return node.at[jnp.where(t[:, 0] > 0, ids, n)].set(child, mode="drop")
+
+    return lax.fori_loop(0, frontier.trips, body, node)
 
 
 def _best_splits_from_hist(hist, parent, pcount, pimp, realf, nb, cfg):
@@ -844,6 +947,10 @@ def _build_tree(
     leaf = jnp.zeros((M, S), dt)
     gains = jnp.zeros((M,), dt)
     node = jnp.zeros((n,), jnp.int32)
+    # a row the bootstrap drew zero times (or the mask leaves out) is dead
+    # from the root on: nothing the fit returns can depend on it
+    row_w = _count(sw, cfg.impurity)
+    live_rows = []    # per split level: rows the level worked on
 
     # Word-packed bins for the contraction gather (TPU: per-row gathers run
     # at ~1e8 elem/s, making take_along_axis ~16x slower than the dense
@@ -870,9 +977,7 @@ def _build_tree(
     for level in range(cfg.max_depth + 1):
         offset = (1 << level) - 1
         n_nodes = 1 << level
-        local = node - offset
-        in_level = (local >= 0) & (local < n_nodes)
-        seg = jnp.where(in_level, local, n_nodes).astype(jnp.int32)
+        local, in_level, seg = _level_seg(node, level, row_w)
         if level == cfg.max_depth:
             # final level: leaf stats only — the one remaining per-level
             # parent scatter (the compact path below derives parent from
@@ -882,6 +987,7 @@ def _build_tree(
             ]
             leaf = leaf.at[offset : offset + n_nodes].set(parent)
             break
+        live_rows.append((seg < n_nodes).sum(dtype=jnp.int32))
 
         # Per-node feature subsampling (cuML max_features semantics): the
         # k_features highest of a per-(node, feature) uniform draw. The
@@ -919,10 +1025,20 @@ def _build_tree(
             feats = None
             d_hist = d_pad
 
+        def row_bins(rows, nodes, feats=feats):
+            """(m, d_hist) bins of the rows ``rows`` that sit in the nodes
+            ``nodes``: their nodes' sampled columns (the fused-selection
+            kernel selects in-kernel and skips this entirely)."""
+            if not subset:
+                return bins[rows]
+            row_feats = feats[nodes]  # (m, k_pad) real feature ids per row
+            if use_contract:
+                return _contract_gather(packed[rows], row_feats)   # i32
+            return bins[rows[:, None], jnp.clip(row_feats, 0, d_pad - 1)]
+
         def make_hist_src(feats=feats, local=local):
-            """Per-row subset bin extraction — only materialized by the
-            strategies that need it (the fused-selection kernel selects
-            in-kernel and skips this entirely)."""
+            """Per-row subset bin extraction at full size, for the
+            strategies that take every row."""
             if not subset:
                 return bins
             lc0 = jnp.clip(local, 0, n_nodes - 1)
@@ -937,18 +1053,13 @@ def _build_tree(
         r_sub, n_pad_c, Fc = plan.r_sub, plan.n_pad, plan.f_chunk
         use_sel = plan.strategy == "pallas_sel"
         use_compact = use_sel or plan.strategy == "pallas"
-        if use_sel:
-            hist_full, parent = _hist_compact(
-                None, seg, sw, n_nodes=n_nodes, nb=nb, r_sub=r_sub,
-                n_pad=n_pad_c, f_chunk=Fc,
-                variance=(cfg.impurity == "variance"),
-                full_bins=bins, feats=feats,
-            )
-        elif use_compact:
-            hist_full, parent = _hist_compact(
-                make_hist_src(), seg, sw, n_nodes=n_nodes, nb=nb,
-                r_sub=r_sub, n_pad=n_pad_c, f_chunk=Fc,
-                variance=(cfg.impurity == "variance"),
+        if use_compact:
+            hist_full, parent, frontier = _hist_compact(
+                None if use_sel else row_bins, seg, sw, n_nodes=n_nodes,
+                n_slots=d_hist, nb=nb, r_sub=r_sub, n_pad=n_pad_c,
+                f_chunk=Fc, variance=(cfg.impurity == "variance"),
+                full_bins=bins if use_sel else None,
+                feats=feats if use_sel else None,
             )
         else:
             parent = jax.ops.segment_sum(sw, seg, num_segments=n_nodes + 1)[
@@ -1157,20 +1268,43 @@ def _build_tree(
         )
 
         # route rows to children; rows whose node became a leaf stay put
-        lc = jnp.clip(local, 0, n_nodes - 1)
-        row_feat = bf[lc]
-        if use_contract:
-            row_bin = _contract_gather(packed, row_feat[:, None])[:, 0]
-        else:
-            row_bin = jnp.take_along_axis(
-                bins, jnp.clip(row_feat, 0, d_pad - 1)[:, None], axis=1
-            )[:, 0].astype(jnp.int32)
-        go_right = (row_bin > bb[lc]).astype(jnp.int32)
-        child = 2 * node + 1 + go_right
-        moves = in_level & do_split[lc]
-        node = jnp.where(moves, child, node)
+        if use_compact:
+            def row_bin(rows, row_feat):
+                """bins[rows[i], row_feat[i]] as int32."""
+                if use_contract:
+                    return _contract_gather(
+                        packed[rows], row_feat[:, None]
+                    )[:, 0]
+                return bins[rows, jnp.clip(row_feat, 0, d_pad - 1)].astype(
+                    jnp.int32
+                )
 
-    return {"feature": feat, "threshold_bin": thr_bin, "leaf_stats": leaf, "gain": gains}
+            node = _route_live(
+                node, frontier, row_bin, do_split, bf, bb, offset=offset
+            )
+        else:
+            lc = jnp.clip(local, 0, n_nodes - 1)
+            row_feat = bf[lc]
+            if use_contract:
+                row_bin = _contract_gather(packed, row_feat[:, None])[:, 0]
+            else:
+                row_bin = jnp.take_along_axis(
+                    bins, jnp.clip(row_feat, 0, d_pad - 1)[:, None], axis=1
+                )[:, 0].astype(jnp.int32)
+            go_right = (row_bin > bb[lc]).astype(jnp.int32)
+            child = 2 * node + 1 + go_right
+            moves = in_level & do_split[lc]
+            node = jnp.where(moves, child, node)
+
+    return {
+        "feature": feat,
+        "threshold_bin": thr_bin,
+        "leaf_stats": leaf,
+        "gain": gains,
+        "live_rows": jnp.stack(live_rows)
+        if live_rows
+        else jnp.zeros((0,), jnp.int32),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -1359,6 +1493,10 @@ def _grow_trees_batched(
     leaf = jnp.zeros((T, M, S), dt)
     gains = jnp.zeros((T, M), dt)
     node = jnp.zeros((T, n), jnp.int32)
+    # the sequential builder's liveness (_level_seg), unless the caller asked
+    # for every row's final node: then a zero-weight row walks the tree too
+    row_w = None if return_rows else _count(sw, cfg.impurity)
+    live_rows = []
 
     if cfg.contract_gather == "on":
         use_contract = d_pad % 4 == 0
@@ -1375,15 +1513,14 @@ def _grow_trees_batched(
     for level in range(cfg.max_depth + 1):
         offset = (1 << level) - 1
         n_nodes = 1 << level
-        local = node - offset                           # (T, n)
-        in_level = (local >= 0) & (local < n_nodes)
-        seg = jnp.where(in_level, local, n_nodes).astype(jnp.int32)
+        local, in_level, seg = _level_seg(node, level, row_w)  # (T, n)
         if level == cfg.max_depth:
             parent = _allred(
                 _seg_sum_trees(sw, seg, n_nodes + 1)[:, :n_nodes]
             )
             leaf = leaf.at[:, offset : offset + n_nodes].set(parent)
             break
+        live_rows.append((seg < n_nodes).sum(axis=1, dtype=jnp.int32))
 
         subset = cfg.k_features < cfg.n_features
         if subset:
@@ -1738,6 +1875,9 @@ def _grow_trees_batched(
         "threshold_bin": thr_bin,
         "leaf_stats": leaf,
         "gain": gains,
+        "live_rows": jnp.stack(live_rows, axis=1)       # (T, levels)
+        if live_rows
+        else jnp.zeros((T, 0), jnp.int32),
     }
     if return_rows:
         out["node"] = node

@@ -388,6 +388,43 @@ def test_rf_fused_selection_kernel_compiles_at_the_reference_width(one_chip):
     assert _has_kernel(c)
 
 
+def test_rf_build_forest_holds_no_copy_of_a_level(topo, no_compile_cache, monkeypatch):
+    """``build_forest`` at ``rf_dbx``'s shape class (500,000 rows of 3072 uint8
+    bins, 55 of 3000 features a node in 64 slots, 128 bins, 2 classes; two
+    levels deep, a whole tree compiles for minutes): ONE Mosaic call a level,
+    as before a level followed its live rows, now inside the level's loop over
+    chunks of 16,384 node-sorted rows and at that one shape on every level.
+    Which only the compiler's count shows: the program holds no node-sorted
+    copy of a level and no level's partials any more (1.54 + 0.51 GB here,
+    2.34 + 0.78 GB at level 12: the parent's temporaries were 2.07 GB at this
+    depth and 3.17 GB at 13) but a chunk of rows, its partials and the
+    per-node sums — under a fifth of the parent's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.ops import linalg, tree_kernels as tk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the gates
+    monkeypatch.setattr(linalg, "probe_pallas_lowering", lambda cache, key, fn, name: True)
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "mp"))
+    rows = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, P("dp")))
+    n, d, d_pad, depth = 500_000, 3000, 3072, 2
+    cfg = tk.ForestConfig(
+        max_depth=depth, n_bins=128, n_features=d, n_stats=2, impurity="gini", k_features=55, min_samples_leaf=1,
+        min_info_gain=0.0, min_samples_split=2, bootstrap=True, held_bytes=n * d * 4,
+    )
+    assert [tk.level_plan(n, d_pad, lv, cfg).strategy for lv in range(depth)] == ["pallas_sel"] * depth
+    c = tk.build_forest.lower(
+        rows((n, d_pad), jnp.uint8), rows((n,), F32), rows((n, 2), F32), rows((1, 8, 2), jnp.uint32),
+        mesh=mesh, cfg=cfg, gather=False, tree_batch=1,
+    ).compile()
+    txt = c.as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == depth
+    # every call at the chunk's shape: whole rows of one chunk in, its sub-blocks' partials out
+    chunk = tk._LIVE_CHUNK
+    assert txt.count(f"operand_layout_constraints={{s32[1]{{0}}, u8[{chunk},{d_pad}]{{1,0}}") == depth
+    assert c.memory_analysis().temp_size_in_bytes < (400 << 20)
+
+
 def test_rf_sketch_reads_the_reference_frame_in_place(one_chip):
     """The quantile sketch at ``rf_dbx``'s shard (500,000 x 3000 f32, rows
     minor): 1024 runs of 128 consecutive rows, sorted a column on the device.

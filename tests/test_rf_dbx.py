@@ -95,6 +95,16 @@ def test_tree_count_off_the_group_builds_one_program_size(frame):
     groups = [s["args"] for s in spans if s["name"] == "forest.grow_group"]
     assert [g["trees"] for g in groups] == [8, 8] and built == 1
     assert all("strategy" in g and "levels_declined" in g for g in groups)
+    # the group's live rows (PR 36): a level works on the rows of positive
+    # bootstrap weight that sit in one of its nodes — 63% of them at the root,
+    # fewer below, and their share of trees x levels x rows in one number
+    for g in groups:
+        by_level = g["live_rows_by_level"]
+        assert len(by_level) == 4 and 0.55 * 4096 < by_level[0] < 0.70 * 4096
+        assert all(a >= b for a, b in zip(by_level, by_level[1:]))
+        assert abs(g["live_share"] - sum(by_level) / (4 * 4096)) < 1e-9
+    fetches = [s["args"] for s in spans if s["name"] == "forest.fetch_group"]
+    assert [f["parent_id"] for f in fetches] == [g["span_id"] for g in groups]
     assert job["model"]["features"].shape[0] == 11
     numbers = ref.check(config, frame, [job])
     assert all(_ok(config, numbers).values()), numbers
