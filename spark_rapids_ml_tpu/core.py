@@ -983,6 +983,28 @@ class _TpuEstimatorSupervised(_TpuEstimator, HasLabelCol):
         return True
 
 
+def batch_to_device(Xb: Any, put: Callable[[Any], jax.Array] = jnp.asarray) -> jax.Array:
+    """A transform's host batch handed to the runtime (``put``: what the
+    site called before, ``jnp.asarray`` or ``jax.device_put``) inside a
+    ``transform.h2d`` span — the host's own seconds in the put, and the
+    ``bytes`` that cross. A batch that is on the device already (a staged
+    one) passes through bare: its bytes were counted where it crossed."""
+    if not isinstance(Xb, np.ndarray):
+        return put(Xb)
+    with telemetry.span("transform.h2d", bytes=int(Xb.nbytes)):
+        return put(Xb)
+
+
+def output_to_host(v: Any, dtype: Any = None) -> np.ndarray:
+    """A transform program's output as a host array (``np.asarray``: the
+    host blocks until the program has run and the answer is back) inside a
+    ``transform.d2h`` span. A host array passes through bare."""
+    if isinstance(v, np.ndarray):
+        return np.asarray(v, dtype=dtype)
+    with telemetry.span("transform.d2h"):
+        return np.asarray(v, dtype=dtype)
+
+
 class _TpuModel(Params, _TpuParams):
     """Abstract fitted model (reference ``_CumlModel``, ``core.py:1101-1364``)."""
 
@@ -1144,9 +1166,11 @@ class _TpuModel(Params, _TpuParams):
         return 1 << 17  # 131072 rows/batch keeps HBM use bounded
 
     # Models whose transform kernels accept committed device arrays set
-    # this to overlap host->device staging of batch i+1 with batch i's
-    # compute (the async dispatch returns before device work finishes, so
-    # the explicit device_put below it runs during the previous batch).
+    # this: ``_apply_batched`` then puts batch i+1 on the device before it
+    # asks for batch i's outputs, so the put crosses the link while batch i
+    # computes (the async dispatch returns before device work finishes).
+    # Every other model gets a host slice and puts it inside its own
+    # ``transform.apply``: nothing crosses ahead of its batch.
     _transform_device_staging = False
 
     def _apply_batched(
@@ -1163,7 +1187,7 @@ class _TpuModel(Params, _TpuParams):
                 "transform.stage", rows=min(bs, n - lo), batch=batch
             ):
                 Xb = X[lo : lo + bs]
-                return jax.device_put(Xb) if staging else Xb
+                return batch_to_device(Xb, jax.device_put) if staging else Xb
 
         chunks: Dict[str, List[np.ndarray]] = {}
         nxt = stage(0, 0)
@@ -1171,14 +1195,15 @@ class _TpuModel(Params, _TpuParams):
             cur = nxt
             hi = min(lo + bs, n)
             if hi < n:
-                # double-buffer: stage the NEXT batch before materializing
-                # this batch's outputs (np.asarray below blocks on device)
+                # stage the NEXT batch before materializing this batch's
+                # outputs (the fetch below blocks on the device): a put
+                # ahead where the model stages, a host slice where not
                 nxt = stage(hi, batch + 1)
             with telemetry.span("transform.apply", rows=hi - lo, batch=batch):
                 part = fn(cur)
             with telemetry.span("transform.fetch", rows=hi - lo, batch=batch):
                 for k, v in part.items():
-                    chunks.setdefault(k, []).append(np.asarray(v)[: hi - lo])
+                    chunks.setdefault(k, []).append(output_to_host(v)[: hi - lo])
         if n <= bs:
             return {k: v[0] for k, v in chunks.items()}
         with telemetry.span(

@@ -24,7 +24,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..core import FitFunc, FitInputs, _TpuEstimatorSupervised, _TpuModel
+from ..core import (
+    FitFunc,
+    FitInputs,
+    _TpuEstimatorSupervised,
+    _TpuModel,
+    batch_to_device,
+    output_to_host,
+)
 from ..data.dataframe import DataFrame
 from ..params import (
     HasElasticNetParam,
@@ -689,18 +696,18 @@ class LogisticRegressionModel(
                 return pred, prob, raw
 
             def _fn_multi(Xb: np.ndarray) -> Dict[str, np.ndarray]:
-                pred, prob, raw = _predict_multi(jnp.asarray(Xb))
+                pred, prob, raw = _predict_multi(batch_to_device(Xb))
                 return {
-                    pred_col: np.asarray(pred),
-                    prob_col: np.asarray(prob),
-                    raw_col: np.asarray(raw),
+                    pred_col: output_to_host(pred),
+                    prob_col: output_to_host(prob),
+                    raw_col: output_to_host(raw),
                 }
 
             return _fn_multi
 
         def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
             pred, prob, raw = logreg_predict(
-                jnp.asarray(Xb),
+                batch_to_device(Xb),
                 jnp.asarray(coef_np, dtype=Xb.dtype),
                 jnp.asarray(b_np, dtype=Xb.dtype),
                 multinomial=multinomial,

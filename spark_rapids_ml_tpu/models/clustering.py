@@ -24,7 +24,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..core import FitFunc, FitInputs, _TpuEstimator, _TpuModel
+from ..core import (
+    FitFunc,
+    FitInputs,
+    _TpuEstimator,
+    _TpuModel,
+    batch_to_device,
+    output_to_host,
+)
 from ..data.dataframe import DataFrame
 from ..params import (
     HasFeaturesCol,
@@ -585,10 +592,10 @@ class KMeansModel(KMeansClass, _TpuModel, _KMeansParams):
         placed: Dict[Any, jax.Array] = {}  # the centers on the device, per batch dtype
 
         def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
-            Xd = jnp.asarray(Xb)
+            Xd = batch_to_device(Xb)
             if Xd.dtype not in placed:
                 placed[Xd.dtype] = jnp.asarray(centers_np, dtype=Xd.dtype)
-            return {pred_col: np.asarray(_assign_nearest(Xd, placed[Xd.dtype]))}
+            return {pred_col: output_to_host(_assign_nearest(Xd, placed[Xd.dtype]))}
 
         return _fn
 

@@ -32,7 +32,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..core import FitFunc, FitInputs, _TpuEstimator, _TpuModel
+from ..core import (
+    FitFunc,
+    FitInputs,
+    _TpuEstimator,
+    _TpuModel,
+    batch_to_device,
+    output_to_host,
+)
 from ..data.dataframe import DataFrame
 from ..params import (
     HasFeaturesCol,
@@ -428,7 +435,7 @@ class PCAModel(PCAClass, _TpuModel, _PCAParams):
             components = jnp.asarray(self.components_)  # (k, d)
 
             def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
-                return {out_col: np.asarray(_project(jnp.asarray(Xb), components))}
+                return {out_col: output_to_host(_project(batch_to_device(Xb), components))}
 
             return _fn
 

@@ -27,7 +27,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..core import FitFunc, FitInputs, _TpuEstimatorSupervised, _TpuModel
+from ..core import (
+    FitFunc,
+    FitInputs,
+    _TpuEstimatorSupervised,
+    _TpuModel,
+    batch_to_device,
+    output_to_host,
+)
 from ..data.dataframe import DataFrame
 from ..params import (
     HasElasticNetParam,
@@ -473,7 +480,7 @@ class LinearRegressionModel(
                     )
 
             def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
-                return {pred_col: np.asarray(_predict(jnp.asarray(Xb)))}
+                return {pred_col: output_to_host(_predict(batch_to_device(Xb)))}
 
             return _fn
 

@@ -31,7 +31,14 @@ from jax.sharding import NamedSharding
 
 from ..parallel.layout import LAYOUT
 
-from ..core import FitFunc, FitInputs, _TpuEstimatorSupervised, _TpuModel
+from ..core import (
+    FitFunc,
+    FitInputs,
+    _TpuEstimatorSupervised,
+    _TpuModel,
+    batch_to_device,
+    output_to_host,
+)
 from ..data.dataframe import DataFrame
 from ..params import (
     HasFeaturesCol,
@@ -974,9 +981,9 @@ class RandomForestClassificationModel(
                 )
             with st.stage("host_out"):
                 return {
-                    pred_col: np.asarray(pred),
-                    prob_col: np.asarray(prob),
-                    raw_col: np.asarray(raw),
+                    pred_col: output_to_host(pred),
+                    prob_col: output_to_host(prob),
+                    raw_col: output_to_host(raw),
                 }
 
         return _fn
@@ -1002,9 +1009,9 @@ class RandomForestClassificationModel(
                 )
             with st.stage("host_out"):
                 return {
-                    pred_col: np.asarray(pred),
-                    prob_col: np.asarray(prob),
-                    raw_col: np.asarray(raw),
+                    pred_col: output_to_host(pred),
+                    prob_col: output_to_host(prob),
+                    raw_col: output_to_host(raw),
                 }
 
         return _fn
@@ -1018,13 +1025,13 @@ class RandomForestClassificationModel(
 
         def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
             pred, prob, raw = rf_classify(
-                jnp.asarray(Xb), feat, jnp.asarray(thr, Xb.dtype), leafp,
+                batch_to_device(Xb), feat, jnp.asarray(thr, Xb.dtype), leafp,
                 max_depth=depth,
             )
             return {
-                pred_col: np.asarray(pred),
-                prob_col: np.asarray(prob),
-                raw_col: np.asarray(raw),
+                pred_col: output_to_host(pred),
+                prob_col: output_to_host(prob),
+                raw_col: output_to_host(raw),
             }
 
         return _fn
@@ -1138,7 +1145,7 @@ class RandomForestRegressionModel(_RandomForestModel):
                     k1=pf.k1, k2=pf.k2, max_depth=pf.max_depth,
                 )
             with st.stage("host_out"):
-                return {pred_col: np.asarray(pred, dtype=Xb.dtype)}
+                return {pred_col: output_to_host(pred, dtype=Xb.dtype)}
 
         return _fn
 
@@ -1162,7 +1169,7 @@ class RandomForestRegressionModel(_RandomForestModel):
                     max_depth=depth,
                 )
             with st.stage("host_out"):
-                return {pred_col: np.asarray(pred, dtype=Xb.dtype)}
+                return {pred_col: output_to_host(pred, dtype=Xb.dtype)}
 
         return _fn
 
@@ -1175,10 +1182,10 @@ class RandomForestRegressionModel(_RandomForestModel):
 
         def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
             pred = rf_regress(
-                jnp.asarray(Xb), feat, jnp.asarray(thr, Xb.dtype), leafv,
+                batch_to_device(Xb), feat, jnp.asarray(thr, Xb.dtype), leafv,
                 max_depth=depth,
             )
-            return {pred_col: np.asarray(pred)}
+            return {pred_col: output_to_host(pred)}
 
         return _fn
 
@@ -1727,7 +1734,7 @@ class _GBTModel(_GBTClass, _ForestModelBase, _GBTParams):
 
         def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
             leaf = forest_apply(
-                jnp.asarray(Xb), feat, jnp.asarray(thr, Xb.dtype),
+                batch_to_device(Xb), feat, jnp.asarray(thr, Xb.dtype),
                 max_depth=depth,
             )                                            # (T, n)
             s = jax.vmap(lambda v, li: v[li])(vals, leaf).sum(axis=0)

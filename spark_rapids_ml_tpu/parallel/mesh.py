@@ -227,17 +227,28 @@ def pad_rows(
     return x, mask
 
 
-def _enqueue_span(mesh: Mesh, nbytes: int, arrays: int, **attrs: Any) -> Any:
+def _enqueue_span(
+    mesh: Mesh, nbytes: int, arrays: int, host_bytes: Optional[int] = None, **attrs: Any
+) -> Any:
     """Span around handing host arrays to the runtime. Its opening is where a
-    fit's wait for its inputs starts. A single ``device_put`` only enqueues,
-    so the span closes long before the bytes are on the device; the row-block
-    loop of :func:`shard_rows` waits for all but the last blocks in flight,
-    so there the span lasts nearly as long as the transfer does."""
+    fit's wait for its inputs starts. ``bytes`` is what the arrays take on
+    the devices, ``host_bytes`` what the host hands over — what crosses the
+    link: the same unless the devices pad (the row-block loop's rows and
+    columns of zeros are the device's own fill). A single ``device_put``
+    only enqueues, so the span closes long before the bytes are on the
+    device. The row-block loop (:func:`_put_row_blocks`) opens an
+    ``h2d.put`` around every block's ``device_put`` and write dispatch, an
+    ``h2d.wait`` around its one wait (the write of the put
+    ``_PUTS_IN_FLIGHT`` before) and an ``h2d.fold`` around a caller's fold
+    dispatch, and returns with the last ``_PUTS_IN_FLIGHT`` writes still
+    outstanding: the frame has landed when the last run of ``write_program``
+    has ended on the device, not when this span closes."""
     from ..runtime import telemetry
 
     return telemetry.span(
         "h2d.enqueue",
         bytes=nbytes,
+        host_bytes=nbytes if host_bytes is None else host_bytes,
         arrays=arrays,
         devices=int(mesh.devices.size),
         **attrs,
@@ -348,7 +359,14 @@ def _put_row_blocks(
     fold the loop is what it was.
 
     Returns the array, the puts issued and each device's fold state (``None``
-    for a device that got no block, and for every device without a fold)."""
+    for a device that got no block, and for every device without a fold).
+
+    Spans, children of the caller's ``h2d.enqueue``: ``h2d.put`` (the host's
+    own seconds in handing block ``block`` of ``bytes`` to the runtime and
+    dispatching its write), ``h2d.wait`` (the host blocked until the write
+    of ``block`` has run) and, with a fold, ``h2d.fold`` (its dispatch)."""
+    from ..runtime import telemetry
+
     bufs, todo = {}, []
     for dev, idx in sh.addressable_devices_indices_map(shape).items():
         lo, hi, _ = idx[0].indices(shape[0])
@@ -360,16 +378,20 @@ def _put_row_blocks(
         )
     # round-robin over the devices, so that their DMA queues run together
     puts = [p for step in itertools.zip_longest(*todo) for p in step if p is not None]
-    pending = collections.deque()
+    pending = collections.deque()  # (block, the scalar its write leaves)
     states = dict.fromkeys(bufs)
-    for dev, row0, rows in puts:
+    for i, (dev, row0, rows) in enumerate(puts):
         if len(pending) == _PUTS_IN_FLIGHT:
-            pending.popleft().block_until_ready()
-        block = jax.device_put(rows, dev)
-        bufs[dev], written = _write_block(bufs[dev], block, np.int32(row0))
+            waited, written = pending.popleft()
+            with telemetry.span("h2d.wait", block=waited):
+                written.block_until_ready()
+        with telemetry.span("h2d.put", block=i, bytes=int(rows.nbytes)):
+            block = jax.device_put(rows, dev)
+            bufs[dev], written = _write_block(bufs[dev], block, np.int32(row0))
         if fold is not None:
-            states[dev] = fold(states[dev], block, row0, len(rows))
-        pending.append(written)
+            with telemetry.span("h2d.fold", block=i):
+                states[dev] = fold(states[dev], block, row0, len(rows))
+        pending.append((i, written))
     return jax.make_array_from_single_device_arrays(shape, sh, list(bufs.values())), len(puts), states
 
 
@@ -446,9 +468,11 @@ def shard_rows(
         mesh,
         x.dtype.itemsize * int(np.prod(shape)) + mask.nbytes,
         2,
+        host_bytes=x.nbytes + mask.nbytes,
         block_bytes=min(block_rows, n_padded // n_dp) * row_bytes,
         cols=cols,
         pad_cols=pad_cols,
+        write_program=_write_block.__name__,
     ) as span:
         xd, blocks, states = _put_row_blocks(x, shape, sh, block_rows, fold)
         span.set_attr(blocks=blocks)

@@ -9,7 +9,12 @@ process, so one xdist worker (``--dist loadfile``) holds it.
 transform of three batches; (c) ``n_evals`` from the jitted, the batched
 and the host-driven L-BFGS, with the solution held bitwise to the solver
 as it stood before the counter; (d) with no sink every new site gets the
-shared no-op and outputs are bitwise those of the traced run.
+shared no-op and outputs are bitwise those of the traced run; (e) the
+link's spans: a frame that goes up in row blocks has one ``h2d.put`` a
+block, one ``h2d.wait`` a put but the last two and one ``h2d.fold`` a block
+where the caller folds, and a transform has one ``transform.h2d`` and at
+least one ``transform.d2h`` a batch in every model, the puts' bytes those of
+the frame.
 """
 
 import glob
@@ -25,9 +30,13 @@ from spark_rapids_ml_tpu import core
 from spark_rapids_ml_tpu.classification import (
     LogisticRegression,
     LogisticRegressionModel,
+    RandomForestClassifier,
 )
+from spark_rapids_ml_tpu.clustering import KMeans
 from spark_rapids_ml_tpu.data import DataFrame
+from spark_rapids_ml_tpu.feature import PCA
 from spark_rapids_ml_tpu.ops import lbfgs, logreg_pallas
+from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
 from spark_rapids_ml_tpu.regression import LinearRegression
 from spark_rapids_ml_tpu.runtime import telemetry
 
@@ -42,8 +51,10 @@ NEW_SITES = {
     "h2d.enqueue", "solver.launch", "solver.fetch",
     "LogisticRegressionModel.transform.call", "transform.extract",
     "transform.stage", "transform.apply", "transform.fetch",
-    "transform.assemble",
+    "transform.assemble", "transform.h2d", "transform.d2h",
 }
+BLOCK_ROWS, BLOCK_FRAME_ROWS = 1024, 4500  # five puts: 4 x 1024 rows and 404
+BLOCK_SITES = {"h2d.enqueue", "h2d.put", "h2d.wait", "h2d.fold"}
 
 
 def _frame(cols=COLS, rows=ROWS, seed=0):
@@ -75,6 +86,43 @@ def _host_events(xplane):
     return events
 
 
+def _untraced(mp, job):
+    """``job()`` with nothing recording, and every ``span()`` call it made by
+    what the call returned."""
+    seen = []
+    real_span = telemetry.span
+
+    def spy(name, **attrs):
+        s = real_span(name, **attrs)
+        seen.append((name, s))
+        return s
+
+    mp.setattr(telemetry, "span", spy)
+    try:
+        return job(), seen
+    finally:
+        mp.setattr(telemetry, "span", real_span)
+
+
+def _captured(trace_dir, job):
+    """``job()`` under a span sink and a profiler capture: its result, the
+    sink's spans and the capture's host events."""
+    spans = []
+    sink = lambda ev, thread: spans.append(ev)  # noqa: E731
+    telemetry.add_span_sink(sink)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        result = job()
+    finally:
+        jax.profiler.stop_trace()
+        telemetry.remove_span_sink(sink)
+    xplane = glob.glob(trace_dir + "/plugins/profile/*/*.xplane.pb")
+    assert xplane, "the capture wrote no .xplane.pb"
+    return result, spans, _host_events(xplane[0])
+
+
 @pytest.fixture(scope="module")
 def runs(tmp_path_factory):
     """One job with nothing recording, then the same job under a sink and
@@ -87,32 +135,10 @@ def runs(tmp_path_factory):
     try:
         _job(df)
         # (d): every span() call of an untraced job, by what it returned
-        seen = []
-        real_span = telemetry.span
-
-        def spy(name, **attrs):
-            s = real_span(name, **attrs)
-            seen.append((name, s))
-            return s
-
-        mp.setattr(telemetry, "span", spy)
-        plain_model, plain_out = _job(df)
-        mp.setattr(telemetry, "span", real_span)
-
-        spans = []
-        telemetry.add_span_sink(lambda ev, thread: spans.append(ev))
-        trace_dir = str(tmp_path_factory.mktemp("capture"))
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=options)
-        try:
-            model, out = _job(df)
-        finally:
-            jax.profiler.stop_trace()
-        xplane = glob.glob(trace_dir + "/plugins/profile/*/*.xplane.pb")
-        assert xplane, "the capture wrote no .xplane.pb"
+        (plain_model, plain_out), seen = _untraced(mp, lambda: _job(df))
+        (model, out), spans, events = _captured(str(tmp_path_factory.mktemp("capture")), lambda: _job(df))
         yield {
-            "X": X, "y": y, "spans": spans, "events": _host_events(xplane[0]),
+            "X": X, "y": y, "spans": spans, "events": events,
             "model": model, "out": out, "plain_model": plain_model,
             "plain_out": plain_out, "untraced_calls": seen,
         }
@@ -139,8 +165,13 @@ def test_every_span_has_its_profiler_event(runs):
         if name.startswith(telemetry.ANNOTATION_PREFIX):
             assert stats["span_id"] not in twins
             twins[stats["span_id"]] = (name, lo, hi, stats)
-    assert len(runs["spans"]) == 21  # 7 of the fit, 14 of the transform
-    for s in runs["spans"]:
+    # 7 of the fit, 26 of the transform: a transform.h2d and three transform.d2h a batch
+    assert len(runs["spans"]) == 33
+    _assert_twins(runs["spans"], twins)
+
+
+def _assert_twins(spans, twins):
+    for s in spans:
         name, lo, hi, stats = twins[s["args"]["span_id"]]
         assert name == telemetry.ANNOTATION_PREFIX + s["name"]
         assert stats.get("parent_id") == s["args"].get("parent_id")
@@ -149,7 +180,7 @@ def test_every_span_has_its_profiler_event(runs):
         if "parent_id" in stats:
             _, plo, phi, _ = twins[stats["parent_id"]]
             assert plo <= lo and hi <= phi, (name, "not inside its parent")
-    assert len(twins) == len(runs["spans"])
+    assert len(twins) == len(spans)
 
 
 def test_legacy_annotations_once_each_and_unprefixed(runs):
@@ -407,6 +438,18 @@ def test_untraced_job_gets_the_shared_null_at_every_site(runs):
     # three more span() calls in a fit, fifteen in a transform of three batches
     names = [name for name, _ in calls]
     assert names.count("h2d.enqueue") == 2 and names.count("transform.stage") == 3
+    # ... and the link's: a put and three fetches a batch
+    assert names.count("transform.h2d") == 3 and names.count("transform.d2h") == 9
+
+
+def test_untraced_block_loop_gets_the_shared_null_at_every_site(block_runs):
+    for kind, puts in (("logreg", 5), ("pca", 5)):
+        calls = block_runs[kind]["untraced_calls"]
+        assert all(s is telemetry._NULL for _, s in calls), kind
+        names = [name for name, _ in calls]
+        assert names.count("h2d.put") == puts and names.count("h2d.wait") == puts - mesh_mod._PUTS_IN_FLIGHT
+        assert names.count("h2d.fold") == (puts if kind == "pca" else 0)
+    assert BLOCK_SITES <= {name for name, _ in block_runs["pca"]["untraced_calls"]}
 
 
 def test_tracing_leaves_outputs_bitwise(runs):
@@ -419,3 +462,164 @@ def test_tracing_leaves_outputs_bitwise(runs):
         np.testing.assert_array_equal(runs["out"][c], runs["plain_out"][c])
         assert runs["out"][c].shape[0] == ROWS
     assert core._TpuModel._transform_batch_rows(runs["model"]) == 1 << 17
+
+
+# --- (e) the link's spans -----------------------------------------------------
+
+
+def _fit_attrs(model):
+    return {k: np.asarray(v) for k, v in model._get_model_attributes().items() if v is not None}
+
+
+@pytest.fixture(scope="module")
+def block_runs(tmp_path_factory):
+    """A LogisticRegression fit (no fold) and a PCA fit (a fold a block) of a
+    frame that goes up in five row blocks: once with nothing recording, once
+    under a sink and a profiler capture."""
+    telemetry.reset_telemetry()
+    mp = pytest.MonkeyPatch()
+    mp.delenv("TPUML_TRACE", raising=False)
+    mp.setattr(mesh_mod, "_PUT_BLOCK_BYTES", BLOCK_ROWS * COLS * 4)
+    X, y, df = _frame(rows=BLOCK_FRAME_ROWS, seed=5)
+    fits = {
+        "logreg": lambda: LogisticRegression(maxIter=5, regParam=1e-3, num_workers=1).fit(df),
+        "pca": lambda: PCA(k=3, inputCol="features", num_workers=1).fit(df),
+    }
+    out = {"X": X}
+    try:
+        for kind, fit in fits.items():
+            fit()  # warm: neither run below compiles
+            plain, seen = _untraced(mp, fit)
+            model, spans, events = _captured(str(tmp_path_factory.mktemp("capture_" + kind)), fit)
+            out[kind] = {"spans": spans, "events": events, "model": model, "plain_model": plain, "untraced_calls": seen}
+        yield out
+    finally:
+        mp.undo()
+        telemetry.reset_telemetry()
+
+
+@pytest.mark.parametrize("kind", ["logreg", "pca"])
+def test_block_loop_has_a_put_a_block_and_a_wait_a_put_but_the_last_two(block_runs, kind):
+    spans, X = block_runs[kind]["spans"], block_runs["X"]
+    enqueue = _by_name(spans, "h2d.enqueue")[0]  # the frame's; the labels' comes after it
+    args = enqueue["args"]
+    puts, waits, folds = (_by_name(spans, n) for n in ("h2d.put", "h2d.wait", "h2d.fold"))
+    assert args["blocks"] == len(puts) == 5 and args["block_bytes"] == BLOCK_ROWS * COLS * 4
+    assert args["write_program"] == "_write_block"
+    # what crosses: the frame at the host's width and its mask; on the devices no less
+    assert X.nbytes + BLOCK_FRAME_ROWS * 4 <= args["host_bytes"] <= args["bytes"]
+    assert [p["args"]["block"] for p in puts] == list(range(5))
+    assert [p["args"]["bytes"] for p in puts] == [BLOCK_ROWS * COLS * 4] * 4 + [404 * COLS * 4]
+    assert sum(p["args"]["bytes"] for p in puts) == X.nbytes
+    # the wait that was there: for the write of the put two before, from the third put on
+    assert [w["args"]["block"] for w in waits] == list(range(5 - mesh_mod._PUTS_IN_FLIGHT))
+    assert [f["args"]["block"] for f in folds] == (list(range(5)) if kind == "pca" else [])
+    assert ("folded_blocks" in args) == (kind == "pca")
+    children = puts + waits + folds
+    assert all(c["args"]["parent_id"] == args["span_id"] for c in children)
+    order = sorted(children, key=lambda c: c["args"]["span_id"])
+    expect = []
+    for i in range(5):
+        expect += ([("h2d.wait", i - 2)] if i >= 2 else []) + [("h2d.put", i)] + ([("h2d.fold", i)] if kind == "pca" else [])
+    assert [(c["name"], c["args"]["block"]) for c in order] == expect
+    # one wait: nothing recorded inside the enqueue but these
+    inside = [s for s in spans if s["args"].get("parent_id") == args["span_id"]]
+    assert len(inside) == len(children)
+
+
+@pytest.mark.parametrize("kind", ["logreg", "pca"])
+def test_block_loop_spans_have_their_profiler_events(block_runs, kind):
+    twins = {}
+    for name, lo, hi, stats in block_runs[kind]["events"]:
+        if name.startswith(telemetry.ANNOTATION_PREFIX):
+            twins[stats["span_id"]] = (name, lo, hi, stats)
+    assert {telemetry.ANNOTATION_PREFIX + n for n in ("h2d.put", "h2d.wait")} <= {t[0] for t in twins.values()}
+    _assert_twins(block_runs[kind]["spans"], twins)
+
+
+@pytest.mark.parametrize("kind", ["logreg", "pca"])
+def test_block_loop_tracing_leaves_the_fit_bitwise(block_runs, kind):
+    traced, plain = _fit_attrs(block_runs[kind]["model"]), _fit_attrs(block_runs[kind]["plain_model"])
+    assert set(traced) == set(plain) and traced
+    for k in traced:
+        np.testing.assert_array_equal(traced[k], plain[k], err_msg=k)
+
+
+def test_host_bytes_on_the_one_put_block_and_aligned_paths(monkeypatch):
+    telemetry.reset_telemetry()
+    spans = []
+    telemetry.add_span_sink(lambda ev, thread: spans.append(ev))
+    m = mesh_mod.make_mesh(1)
+    X = np.arange(600 * 24, dtype=np.float32).reshape(600, 24)
+    try:
+        xd, md = mesh_mod.shard_rows(X, m)                                   # one put
+        mesh_mod.shard_aligned(np.ones(600, np.float32), m, xd.shape[0])     # the labels' path
+        monkeypatch.setattr(mesh_mod, "_PUT_BLOCK_BYTES", 256 * 24 * 4)
+        xb, mb = mesh_mod.shard_rows(X, m)                                   # three blocks: 256, 256, 88
+        xw, mw = mesh_mod.shard_rows(X, m, cols=32)                          # ... into a wider buffer
+    finally:
+        telemetry.reset_telemetry()
+    one, aligned, blocks, wide = (s["args"] for s in _by_name(spans, "h2d.enqueue"))
+    assert one["host_bytes"] == one["bytes"] == X.nbytes + md.nbytes and "write_program" not in one
+    assert aligned["host_bytes"] == aligned["bytes"] == 600 * 4
+    assert blocks["host_bytes"] == X.nbytes + mb.nbytes == blocks["bytes"] and blocks["blocks"] == 3
+    # the device pads the columns: the host hands over its own width
+    assert wide["host_bytes"] == X.nbytes + mw.nbytes and wide["bytes"] == 600 * 32 * 4 + mw.nbytes
+    assert sum(p["args"]["bytes"] for p in _by_name(spans, "h2d.put")) == 2 * X.nbytes
+    np.testing.assert_array_equal(np.asarray(xb), np.asarray(xd))
+    np.testing.assert_array_equal(np.asarray(xw)[:, :24], X)
+
+
+def _rf():
+    return RandomForestClassifier(numTrees=3, maxDepth=4, maxBins=16, seed=7, num_workers=1)
+
+
+MODELS = {
+    "LogisticRegression": (lambda: LogisticRegression(maxIter=5, regParam=1e-3, num_workers=1), OUTPUTS),
+    "KMeans": (lambda: KMeans(k=4, maxIter=3, seed=1, num_workers=1), ("prediction",)),
+    "PCA": (lambda: PCA(k=3, inputCol="features", outputCol="pca_features", num_workers=1), ("pca_features",)),
+    "LinearRegression": (lambda: LinearRegression(num_workers=1), ("prediction",)),
+    "RandomForestClassifier": (_rf, OUTPUTS),
+}
+
+
+@pytest.mark.parametrize("name", list(MODELS))
+def test_every_batch_has_its_put_and_its_fetch(monkeypatch, name):
+    make, columns = MODELS[name]
+    monkeypatch.delenv("TPUML_TRACE", raising=False)
+    monkeypatch.setenv("TPUML_RF_APPLY", "bins")  # the engine a TPU takes; the CPU's default is legacy
+    X, _, df = _frame(rows=1000, seed=11)
+    telemetry.reset_telemetry()
+    model = make().fit(df)
+    monkeypatch.setattr(type(model), "_transform_batch_rows", lambda self: 400)  # 400, 400, 200
+    plain = model.transform(df)
+    plain = {c: np.asarray(plain.column(c)) for c in columns}
+    spans = []
+    telemetry.add_span_sink(lambda ev, thread: spans.append(ev))
+    try:
+        out = model.transform(df)
+        out = {c: np.asarray(out.column(c)) for c in columns}
+    finally:
+        telemetry.reset_telemetry()
+    by_id = {s["args"]["span_id"]: s for s in spans}
+
+    def batch_of(s):  # the batch of the nearest ancestor that names one
+        while "batch" not in s["args"]:
+            s = by_id[s["args"]["parent_id"]]
+        return s["args"]["batch"], s["name"]
+
+    ups, backs = _by_name(spans, "transform.h2d"), _by_name(spans, "transform.d2h")
+    assert [(batch_of(u)[0], u["args"]["bytes"]) for u in ups] == [
+        (0, 400 * COLS * 4), (1, 400 * COLS * 4), (2, 200 * COLS * 4)]
+    assert sum(u["args"]["bytes"] for u in ups) == X.nbytes
+    staged = name == "RandomForestClassifier"  # the one model that puts batch i+1 ahead of batch i's fetch
+    assert {batch_of(u)[1] for u in ups} == {"transform.stage" if staged else "transform.apply"}
+    per_batch = [sum(1 for b in backs if batch_of(b)[0] == i) for i in range(3)]
+    assert min(per_batch) >= 1 and len(set(per_batch)) == 1
+    assert {batch_of(b)[1] for b in backs} <= {"transform.apply", "transform.fetch"}
+    if staged:
+        (engine,) = {s["args"]["engine"] for s in _by_name(spans, "forest.descent")}
+        assert engine == "bins"
+    for c in columns:
+        np.testing.assert_array_equal(out[c], plain[c])
+        assert out[c].shape[0] == 1000
