@@ -122,7 +122,7 @@ def build_tree(bins, stats, valid, key, cfg, knock):
         leaf = leaf.at[offset:offset + n_nodes].set(parent)
         pcount = tk._count(parent, cfg.impurity)
         pimp = tk._impurity(parent, cfg.impurity)
-        bg, bf, bb = tk._best_splits_from_hist(
+        bg, bf, bb, _ = tk._best_splits_from_hist(
             hist_full, parent, pcount, pimp, feats.T, nb, cfg)
         do_split = jnp.isfinite(bg) & (bg >= 1e-9) & (pcount >= cfg.min_samples_split)
         feat = feat.at[offset:offset + n_nodes].set(jnp.where(do_split, bf, -1))

@@ -87,7 +87,7 @@ def build_tree_variant(bins, stats, valid, key, cfg, *, knock=None):
             bf = jnp.broadcast_to(jnp.int32(0), (n_nodes,))
             bb = jnp.full((n_nodes,), NB // 2, jnp.int32)
         else:
-            g, f, b = tk._best_splits_from_hist(
+            g, f, b, _ = tk._best_splits_from_hist(
                 hist_full, parent, pcount, pimp, feats.T, nb, cfg)
             bg, bf, bb = g, f, b
 

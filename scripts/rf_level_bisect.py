@@ -154,7 +154,7 @@ def main():
     realf = feats.T
 
     def f_gain(c, i, hist_full, parent, pcount, pimp, realf):
-        g, f, b = tk._best_splits_from_hist(
+        g, f, b, _ = tk._best_splits_from_hist(
             jnp.where(c >= jnp.float32(-1e30), hist_full, 0.0),
             parent, pcount, pimp, realf, NB, cfg)
         return g.sum() + f.sum() + b.sum()

@@ -505,12 +505,18 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                                 )
                         live = pieces["live_rows"][-1]  # (n_dp, group, levels)
                         grow.set_attr(
-                            # rows the group's levels worked on, over every
-                            # row on every level of every tree
+                            # rows the group's levels worked on (positive
+                            # weight, in an OPEN node of the level), over
+                            # every row on every level of every tree
                             live_share=float(
                                 live.sum() / max(1, live.size * rows_per_tree)
                             ),
                             live_rows_by_level=live.mean(axis=(0, 1)).tolist(),
+                            # nodes a tree closed when it made them: their
+                            # rows left the levels' work one level sooner
+                            closed_at_birth=float(
+                                pieces["closed_at_birth"][-1].mean()
+                            ),
                         )
 
             # interleave device-major -> tree-major so the slice to n_trees
@@ -546,7 +552,9 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                 # x >= edges[f, b] <=> bin(x) > b, the exact training-side
                 # routing rule, so bin-space transform matches the raw
                 # thresholds bit-for-bit. Absent in pre-round-5 saves —
-                # loaders fall back to the raw-threshold descent.
+                # loaders fall back to the raw-threshold descent. A node that
+                # does not split holds 0 (as ``thresholds`` holds 0.0): every
+                # reader looks at a split node's entry only.
                 "threshold_bins": thr_bin.astype(np.int32),
                 "bin_edges": edges_np.astype(np.float32),
             }

@@ -365,6 +365,24 @@ def _impurity(stats: jax.Array, impurity: str) -> jax.Array:
     raise ValueError(f"unknown impurity {impurity!r}")
 
 
+def _can_split(stats: jax.Array, cfg: "ForestConfig") -> jax.Array:
+    """Whether a node holding ``stats`` may split at all, whatever its
+    histogram: it holds ``min_samples_split`` and, for class counts, more
+    than one class. In exact arithmetic a pure node's impurity and every
+    gain of it are 0, under the 1e-9 floor; a TPU's float32 division is not
+    exact (on a v5e c / c reads 1 - 6e-8 or 1 + 1.2e-7 for a quarter of the
+    counts below 200,000), so there a pure node's gini is +-2.4e-7 and its
+    "gain" cleared the floor: 27% of a forest's splits on the benchmark's
+    data were splits of a pure node on rounding noise, into children of the
+    same one class, down to three levels on (PERF.md section 6, PR 38). Said
+    once, here, for both builders' gain search and for the closing of a
+    child when it is made."""
+    ok = _count(stats, cfg.impurity) >= cfg.min_samples_split
+    if cfg.impurity != "variance":
+        ok = ok & ((stats > 0).sum(axis=-1) > 1)
+    return ok
+
+
 def _chunk_features(
     d_pad: int, n_nodes: int, n_bins: int, n_stats: int, budget: int = _HIST_BUDGET
 ) -> int:
@@ -484,6 +502,9 @@ def _level_seg(node: jax.Array, level: int, weight=None):
     or the mask leaves out adds 0 to every histogram cell, to the parents'
     and to the leaves' statistics, and is no output of the fit — it sorts
     past the live rows and is not gathered, histogrammed, reduced or routed.
+    A row bound for a child that was closed when it was made
+    (``_build_tree``) was never moved there: it sits at its parent's id, in
+    no node of any later level, and is dead here without a word about it.
     A caller that needs EVERY row's final node (``return_rows``) passes no
     weight and keeps such rows live."""
     n_nodes = 1 << level
@@ -686,40 +707,49 @@ def _hist_compact(
     return hist, parent, _Frontier(rows, sbc, trips, chunk, r_sub)
 
 
-def _route_live(node, frontier, row_bin, do_split, bf, bb, *, offset: int):
+def _route_live(node, frontier, row_bin, opens, bf, bb, *, offset: int):
     """Send the live rows of a level to their children: ``node`` with the
-    entries of the frontier's rows whose node split set to the child. The
-    rows' ids are the frontier's, their nodes are known by sub-block, so a
-    chunk costs one element gather (``row_bin(row ids, features)``) and one
-    scatter — over the live prefix, not over n. Rows in a node that became
-    a leaf stay put; dead rows are never touched."""
+    entries of the frontier's rows bound for an OPEN child set to that child
+    (``opens`` (n_nodes, 2): the node split and its left / right child was
+    not closed when made). The rows' ids are the frontier's, their nodes are
+    known by sub-block, so a chunk costs one element gather
+    (``row_bin(row ids, features)``) and one scatter — over the live prefix,
+    not over n. Rows in a node that became a leaf, and rows bound for a
+    closed child, stay put: at their parent's id they are outside every
+    later level's range, so ``_level_seg`` needs no word about them. Dead
+    rows are never touched."""
     n = node.shape[0]
     chunk, r_sub = frontier.chunk, frontier.r_sub
     chunk_sb = chunk // r_sub
-    # per sub-block: its node's split, feature and threshold (n_sb-scale)
+    # per sub-block: whether its node's children are open, the split's
+    # feature and threshold (n_sb-scale)
     tbl = jnp.stack(
         [
-            do_split.astype(jnp.int32), bf, bb,
-            jnp.arange(do_split.shape[0], dtype=jnp.int32),
+            *opens.astype(jnp.int32).T, bf, bb,
+            jnp.arange(bf.shape[0], dtype=jnp.int32),
         ],
         axis=1,
-    )[frontier.sb_node]                                     # (n_sb, 4)
+    )[frontier.sb_node]                                     # (n_sb, 5)
 
     def body(c, node):
         ids = lax.dynamic_slice(frontier.rows, (c * chunk,), (chunk,))
         t = jnp.broadcast_to(
-            lax.dynamic_slice(tbl, (c * chunk_sb, 0), (chunk_sb, 4))[:, None],
-            (chunk_sb, r_sub, 4),
-        ).reshape(chunk, 4)
-        rb = row_bin(jnp.minimum(ids, n - 1), t[:, 1])
-        child = 2 * (offset + t[:, 3]) + 1 + (rb > t[:, 2]).astype(jnp.int32)
-        return node.at[jnp.where(t[:, 0] > 0, ids, n)].set(child, mode="drop")
+            lax.dynamic_slice(tbl, (c * chunk_sb, 0), (chunk_sb, 5))[:, None],
+            (chunk_sb, r_sub, 5),
+        ).reshape(chunk, 5)
+        right = row_bin(jnp.minimum(ids, n - 1), t[:, 2]) > t[:, 3]
+        child = 2 * (offset + t[:, 4]) + 1 + right.astype(jnp.int32)
+        moves = jnp.where(right, t[:, 1], t[:, 0]) > 0
+        return node.at[jnp.where(moves, ids, n)].set(child, mode="drop")
 
     return lax.fori_loop(0, frontier.trips, body, node)
 
 
 def _best_splits_from_hist(hist, parent, pcount, pimp, realf, nb, cfg):
-    """Best (gain, feature, bin) per node from a histogram block.
+    """Best (gain, feature, bin) per node from a histogram block, and the
+    statistics that split sends left: ``cum[f*, node, b*]``, (n_nodes, S) —
+    what the left child will hold (the right one holds ``parent`` less it),
+    a node-scale gather from the sums the search has formed anyway.
 
     ``hist`` is (F, n_nodes, nb, S); ``realf`` (F, n_nodes) maps block
     slots to real feature ids (sentinel = cfg.n_features, masked out).
@@ -753,7 +783,7 @@ def _best_splits_from_hist(hist, parent, pcount, pimp, realf, nb, cfg):
     g = jnp.take_along_axis(m, fi[None, :], axis=0)[0]
     f = jnp.take_along_axis(realf, fi[None, :], axis=0)[0]
     b = jnp.take_along_axis(bbin, fi[None, :], axis=0)[0].astype(jnp.int32)
-    return g, f, b
+    return g, f, b, cum[fi, jnp.arange(fi.shape[0]), b]
 
 
 # ---------------------------------------------------------------------------
@@ -919,6 +949,15 @@ def _build_tree(
     key: jax.Array,
     cfg: ForestConfig,
 ) -> Dict[str, jax.Array]:
+    """One tree, level by level, as heap-ordered tables over ``max_nodes``:
+    ``feature`` (-1 = leaf), ``threshold_bin`` (bin(x) > b goes right; **0 at
+    every node that does not split** — a don't-care no reader looks at: the
+    descents and ``thresholds`` read it where ``feature >= 0`` only),
+    ``leaf_stats`` (the node's weighted statistics, 0 where no row came),
+    ``gain`` (0 at a leaf); and two counts for the caller's span:
+    ``live_rows`` (a split level: rows of positive weight in an OPEN node of
+    it, the rows the level worked on) and ``closed_at_birth`` (nodes closed
+    when they were made: always 0 for variance statistics)."""
     n, d_pad = bins.shape
     S = cfg.n_stats
     nb = cfg.n_bins
@@ -951,6 +990,21 @@ def _build_tree(
     # from the root on: nothing the fit returns can depend on it
     row_w = _count(sw, cfg.impurity)
     live_rows = []    # per split level: rows the level worked on
+    # A child's statistics and fate are handed down by the split that makes
+    # it: its class counts are the winner's left sums (or the parent's less
+    # them), and where they are pure or under min_samples_split the next
+    # level's gain search could not split it whatever its histogram (a pure
+    # node's impurity is 0, so every gain is <= 0 < 1e-9). Such a child is
+    # CLOSED AT BIRTH: its rows stay at the parent's id, out of every later
+    # level's range, and the next level writes the handed-down counts for it.
+    # Weighted class counts are integers in f32 (exact below 2^24 a cell), so
+    # handed-down and own-histogram values are equal to the bit and the
+    # forest is the same forest. Variance statistics are f32 sums in another
+    # grouping: there nothing is handed down and a level runs as it did.
+    hands_down = cfg.impurity != "variance"
+    closed = jnp.zeros((1,), bool)      # this level's nodes, closed when made
+    handed = jnp.zeros((1, S), dt)      # and what their parents' splits gave them
+    closed_at_birth = jnp.zeros((), jnp.int32)
 
     # Word-packed bins for the contraction gather (TPU: per-row gathers run
     # at ~1e8 elem/s, making take_along_axis ~16x slower than the dense
@@ -979,12 +1033,17 @@ def _build_tree(
         n_nodes = 1 << level
         local, in_level, seg = _level_seg(node, level, row_w)
         if level == cfg.max_depth:
-            # final level: leaf stats only — the one remaining per-level
-            # parent scatter (the compact path below derives parent from
-            # its histogram on every split level)
-            parent = jax.ops.segment_sum(sw, seg, num_segments=n_nodes + 1)[
-                :n_nodes
-            ]
+            # final level: leaf stats only. Every node of it was made by a
+            # split (or holds nothing), so where statistics are handed down
+            # they are the leaves; else the one remaining per-level parent
+            # scatter (the compact path below derives parent from its
+            # histogram on every split level)
+            if hands_down and level > 0:
+                parent = handed
+            else:
+                parent = jax.ops.segment_sum(
+                    sw, seg, num_segments=n_nodes + 1
+                )[:n_nodes]
             leaf = leaf.at[offset : offset + n_nodes].set(parent)
             break
         live_rows.append((seg < n_nodes).sum(dtype=jnp.int32))
@@ -1065,6 +1124,9 @@ def _build_tree(
             parent = jax.ops.segment_sum(sw, seg, num_segments=n_nodes + 1)[
                 :n_nodes
             ]
+        if hands_down:
+            # a closed node has no live row and an empty histogram
+            parent = jnp.where(closed[:, None], handed, parent)
         leaf = leaf.at[offset : offset + n_nodes].set(parent)
         pcount = _count(parent, cfg.impurity)
         pimp = _impurity(parent, cfg.impurity)
@@ -1091,8 +1153,9 @@ def _build_tree(
             bg = jnp.full((n_nodes,), -jnp.inf, dt)
             bf = jnp.zeros((n_nodes,), jnp.int32)
             bb = jnp.zeros((n_nodes,), jnp.int32)
+            bl = jnp.zeros((n_nodes, S), dt)
             for c0 in range(0, d_hist, Fc):
-                g, f, b = _best_splits_from_hist(
+                g, f, b, l = _best_splits_from_hist(
                     hist_full[c0 : c0 + Fc], parent, pcount, pimp,
                     realf_full[c0 : c0 + Fc], nb, cfg,
                 )
@@ -1100,6 +1163,7 @@ def _build_tree(
                 bg = jnp.where(upd, g, bg)
                 bf = jnp.where(upd, f, bf)
                 bb = jnp.where(upd, b, bb)
+                bl = jnp.where(upd[:, None], l, bl)
         else:
             use_matmul = plan.strategy == "matmul"
 
@@ -1221,7 +1285,7 @@ def _build_tree(
                            in_level=in_level, local=local, sw=sw,
                            use_matmul=use_matmul, subset=subset,
                            hist_src=hist_src):
-                bg, bf, bb = carry
+                bg, bf, bb, bl = carry
                 binc = lax.dynamic_slice(
                     hist_src, (0, ci * F), (n, F)
                 ).astype(jnp.int32)
@@ -1239,7 +1303,7 @@ def _build_tree(
                         (ci * F + jnp.arange(F, dtype=jnp.int32))[:, None],
                         (F, n_nodes),
                     )
-                g, f, b = _best_splits_from_hist(
+                g, f, b, l = _best_splits_from_hist(
                     hist, parent, pcount, pimp, realf, nb, cfg
                 )
                 upd = g > bg
@@ -1247,27 +1311,47 @@ def _build_tree(
                     jnp.where(upd, g, bg),
                     jnp.where(upd, f, bf),
                     jnp.where(upd, b, bb),
+                    jnp.where(upd[:, None], l, bl),
                 ), None
 
             init = (
                 jnp.full((n_nodes,), -jnp.inf, dt),
                 jnp.zeros((n_nodes,), jnp.int32),
                 jnp.zeros((n_nodes,), jnp.int32),
+                jnp.zeros((n_nodes, S), dt),
             )
-            (bg, bf, bb), _ = lax.scan(chunk_body, init, jnp.arange(n_chunks))
+            (bg, bf, bb, bl), _ = lax.scan(
+                chunk_body, init, jnp.arange(n_chunks)
+            )
 
         do_split = (
             jnp.isfinite(bg)
             & (bg >= max(cfg.min_info_gain, 1e-9))
-            & (pcount >= cfg.min_samples_split)
+            & _can_split(parent, cfg)
         )
         feat = feat.at[offset : offset + n_nodes].set(jnp.where(do_split, bf, -1))
-        thr_bin = thr_bin.at[offset : offset + n_nodes].set(bb)
+        thr_bin = thr_bin.at[offset : offset + n_nodes].set(
+            jnp.where(do_split, bb, 0)
+        )
         gains = gains.at[offset : offset + n_nodes].set(
             jnp.where(do_split, bg, jnp.zeros_like(bg))
         )
 
-        # route rows to children; rows whose node became a leaf stay put
+        # the children this level makes, (n_nodes, 2, ...) left and right
+        made = jnp.broadcast_to(do_split[:, None], (n_nodes, 2))
+        opens = made
+        if hands_down:
+            kids = jnp.where(
+                made[:, :, None], jnp.stack([bl, parent - bl], axis=1), 0
+            )
+            shut = made & ~_can_split(kids, cfg)
+            opens = made & ~shut
+            handed = kids.reshape(2 * n_nodes, S)
+            closed = shut.reshape(2 * n_nodes)
+            closed_at_birth = closed_at_birth + shut.sum(dtype=jnp.int32)
+
+        # route rows to children; rows whose node became a leaf, or whose
+        # child was closed when made, stay put
         if use_compact:
             def row_bin(rows, row_feat):
                 """bins[rows[i], row_feat[i]] as int32."""
@@ -1280,7 +1364,7 @@ def _build_tree(
                 )
 
             node = _route_live(
-                node, frontier, row_bin, do_split, bf, bb, offset=offset
+                node, frontier, row_bin, opens, bf, bb, offset=offset
             )
         else:
             lc = jnp.clip(local, 0, n_nodes - 1)
@@ -1293,7 +1377,7 @@ def _build_tree(
                 )[:, 0].astype(jnp.int32)
             go_right = (row_bin > bb[lc]).astype(jnp.int32)
             child = 2 * node + 1 + go_right
-            moves = in_level & do_split[lc]
+            moves = in_level & opens[lc, go_right]
             node = jnp.where(moves, child, node)
 
     return {
@@ -1304,6 +1388,7 @@ def _build_tree(
         "live_rows": jnp.stack(live_rows)
         if live_rows
         else jnp.zeros((0,), jnp.int32),
+        "closed_at_birth": closed_at_birth,
     }
 
 
@@ -1596,10 +1681,12 @@ def _grow_trees_batched(
         pcount = _count(parent, cfg.impurity)           # (T, n_nodes)
         pimp = _impurity(parent, cfg.impurity)
 
+        # (the winner's left statistics are the sequential builder's to
+        # hand down: this builder closes nothing at birth)
         bsf = jax.vmap(
             lambda h, p, pc, pi, rf_: _best_splits_from_hist(
                 h, p, pc, pi, rf_, nb, cfg
-            )
+            )[:3]
         )
 
         if use_compact:
@@ -1841,12 +1928,14 @@ def _grow_trees_batched(
         do_split = (
             jnp.isfinite(bg)
             & (bg >= max(cfg.min_info_gain, 1e-9))
-            & (pcount >= cfg.min_samples_split)
+            & _can_split(parent, cfg)
         )                                               # (T, n_nodes)
         feat = feat.at[:, offset : offset + n_nodes].set(
             jnp.where(do_split, bf, -1)
         )
-        thr_bin = thr_bin.at[:, offset : offset + n_nodes].set(bb)
+        thr_bin = thr_bin.at[:, offset : offset + n_nodes].set(
+            jnp.where(do_split, bb, 0)
+        )
         gains = gains.at[:, offset : offset + n_nodes].set(
             jnp.where(do_split, bg, jnp.zeros_like(bg))
         )
@@ -1878,6 +1967,7 @@ def _grow_trees_batched(
         "live_rows": jnp.stack(live_rows, axis=1)       # (T, levels)
         if live_rows
         else jnp.zeros((T, 0), jnp.int32),
+        "closed_at_birth": jnp.zeros((T,), jnp.int32),
     }
     if return_rows:
         out["node"] = node

@@ -110,6 +110,33 @@ def test_tree_count_off_the_group_builds_one_program_size(frame):
     assert all(_ok(config, numbers).values()), numbers
 
 
+def test_closed_at_birth_is_on_the_grow_group_span(frame, monkeypatch):
+    """The per-tree builder (the one the benchmark's shape takes: a tree batch
+    of 1) closes a node when it makes it, and says how many on its span: the
+    children of the served splits whose class counts are pure or under
+    min_samples_split = 2, the group's mean a tree. Their counts are handed
+    down, their rows left the levels' work — and every node's count is still
+    the reference's."""
+    monkeypatch.setenv("TPUML_RF_TREE_BATCH", "off")
+    config = _config(300, trees=4, depth=4)
+    spans = []
+    sink = lambda ev, thread: spans.append(ev)  # noqa: E731
+    telemetry.add_span_sink(sink)
+    try:
+        job = closed_loop.Runner(config, MIX, frame, RandomForestClassifier, 1).run_job()
+    finally:
+        telemetry.remove_span_sink(sink)
+    (grow,) = [s["args"] for s in spans if s["name"] == "forest.grow_group"]
+    assert grow["tree_batch"] == 1 and grow["trees"] == 4
+    feat, leaf = job["model"]["features"], job["model"]["leaf_stats"]
+    made = np.repeat(feat[:, :15] >= 0, 2, axis=1)             # a split at heap slot i makes slots 2i+1, 2i+2
+    held = leaf[:, 1:31][made]
+    shut = ((held > 0).sum(axis=1) <= 1) | (held.sum(axis=1) < 2)
+    assert grow["closed_at_birth"] == shut.sum() / 4 > 0
+    numbers = dict(ref.check(config, frame, [job]))
+    assert numbers["count_err"] == 0.0 and numbers["struct_err"] == 0.0, numbers
+
+
 @pytest.mark.parametrize("trees,group", [(1, 1), (8, 8), (16, 8), (50, 5), (20, 5), (53, 8), (12, 6)])
 def test_dispatch_group_is_one_size(trees, group):
     from spark_rapids_ml_tpu.models.tree import _dispatch_group
