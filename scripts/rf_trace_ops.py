@@ -31,8 +31,12 @@ def kind(name: str, hlo: str) -> str:
     rows = lambda x: x >= 400_000 or x == 16384  # noqa: E731  a level's padded rows, or a chunk of them
     if any(k in hlo for k in ("f32[500000,3000]", "f32[131072", "f32[65536,3000]")):
         return "0 not the growth (crossing, sketch, binize)"
-    if dt == "u8" and len(d) == 2 and d[1] >= 1024 and rows(d[0]):
-        return "1 whole-row gather of the bins"
+    if dt in ("u8", "bf16") and len(d) == 2 and d[1] >= 1024 and rows(d[0]):
+        return "1 whole-row gather of the bins (the wide selection: with its bf16 cast, forest.wide_rows)"
+    if dt == "bf16" and d == [16, 16384]:
+        return "5b the statistics' exact three-way bf16 split (forest.wide_rows)"
+    if dt == "f32" and len(d) >= 3 and d[-1] >= 16384:
+        return "3b the wide kernel's per-node sums: zero fill, the three parts added, slots back in order"
     if dt == "f32" and len(d) == 2 and d[1] == 16384:
         if name.startswith("reshape"):
             return "4 partials relaid (reshape)"

@@ -62,6 +62,7 @@ from ..ops.tree_kernels import (
     max_nodes,
     next_pow2,
     plan_levels,
+    plan_reads,
     quantile_edges,
     rf_classify,
     rf_regress,
@@ -458,6 +459,9 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
             strategies, declined = plan_levels(
                 rows_per_tree, d_pad, cfg, stats.dtype
             )
+            hist_cols, hist_calls = plan_reads(
+                rows_per_tree, d_pad, cfg, stats.dtype
+            )
             # per key: list of host arrays shaped (n_dp, group, ...)
             pieces: Dict[str, List[np.ndarray]] = {}
             # the spans every fit carries (PERF.md section 3): the launch
@@ -485,6 +489,10 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                         strategy=strategies,
                         levels_declined=len(declined),
                         **({"declined": str(declined)} if declined else {}),
+                        # what a level's histogram read: bins columns a live
+                        # row, kernel calls a chunk of live rows
+                        hist_cols=hist_cols,
+                        hist_calls=hist_calls,
                     ) as grow:
                         outg = jax.block_until_ready(
                             build_forest(

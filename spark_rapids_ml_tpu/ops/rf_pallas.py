@@ -447,6 +447,230 @@ def rf_hist_sel_ok(
 
 
 # ---------------------------------------------------------------------------
+# wide fused selection: a grid over feature-slot tiles, per-node sums in place
+# ---------------------------------------------------------------------------
+#
+# ``subblock_hist_sel`` holds the one-hot of ALL a node's slots at once: k*nb
+# lanes, capped at 8192, and its lane expansion ``selected @ E`` costs k^2*nb
+# products a row. A regressor's subset (a third of the columns: 1000 of 3000,
+# 131,072 lanes) is past both. This variant tiles the slots over a GRID axis,
+# 128 a step, so every term of it is a tile's: the selection is one exact
+# bf16 product of the block's whole rows with the tile's (d_pad, 128) one-hot,
+# the bin one-hot is a lane REPEAT of the 128 selected columns compared with
+# the lane's bin (bin-major lanes: no expansion product), and the statistics
+# ride a 16-row bf16 operand that holds their exact three-way split.
+
+# rows a grid block: ONE node's (the caller pads a node's run to a multiple)
+WIDE_BLOCK_ROWS = 512
+# feature slots a grid step
+WIDE_SLOTS = 128
+# rows of the statistics' operand: S stats x 3 bf16 parts, sublane-padded
+WIDE_STAT_ROWS = 16
+# bins whose one-hot is held at once: (512, 32*128) bf16 = 4 MB
+_WIDE_BIN_GROUP = 32
+
+
+def split_f32_exact(swT: jax.Array) -> jax.Array:
+    """(S, n) float32 -> (WIDE_STAT_ROWS, n) bfloat16 whose rows ``s``,
+    ``S + s`` and ``2*S + s`` sum to ``swT[s]`` EXACTLY: a float32 has 24
+    mantissa bits, a bfloat16 8, and each part takes the next eight. A product
+    of a part with a 0/1 one-hot is exact in one bf16 MXU pass with float32
+    accumulation, so three rows buy what ``Precision.HIGHEST`` spends six
+    passes on (it splits the one-hot operand too). ``lax.reduce_precision``,
+    not a cast there and back: XLA may drop a convert pair as excess
+    precision, and the remainder would then read 0."""
+    S = swT.shape[0]
+    assert 3 * S <= WIDE_STAT_ROWS, S
+    x = swT.astype(jnp.float32)
+    hi = lax.reduce_precision(x, 8, 7)
+    r1 = x - hi
+    mid = lax.reduce_precision(r1, 8, 7)
+    lo = lax.reduce_precision(r1 - mid, 8, 7)
+    parts = jnp.concatenate([hi, mid, lo], axis=0).astype(jnp.bfloat16)
+    return jnp.pad(parts, ((0, WIDE_STAT_ROWS - 3 * S), (0, 0)))
+
+
+def rf_hist_wide_declined(
+    n_pad: int, d_pad: int, k: int, nb: int, S: int
+) -> str:
+    """The failing terms of the wide fused-selection kernel's gate,
+    comma-joined (empty: admitted)."""
+    R = WIDE_BLOCK_ROWS
+    g = min(_WIDE_BIN_GROUP, nb)
+    vmem = (
+        2 * R * d_pad * 2                          # the block's rows, bf16, x2
+        + d_pad * WIDE_SLOTS * (4 + 4 + 2)         # iota, compare, one-hot
+        + R * g * WIDE_SLOTS * (4 + 4 + 2)         # repeat, compare, one-hot
+        + 4 * WIDE_STAT_ROWS * nb * WIDE_SLOTS * 4  # sums in and out, x2
+    )
+    terms = (
+        ("backend", jax.default_backend() == "tpu" or FORCE_INTERPRET),
+        ("slots%128", k >= WIDE_SLOTS and k % WIDE_SLOTS == 0),
+        ("d_pad%128", d_pad % 128 == 0),
+        # a bin is an exact bfloat16 up to 256; the lane groups divide nb
+        ("bins<=256", nb <= 256 and nb % g == 0),
+        ("stats*3<=16", 1 <= 3 * S <= WIDE_STAT_ROWS),
+        ("rows%block", n_pad % R == 0),
+        ("vmem", vmem <= 80 * 1024 * 1024),
+    )
+    return ",".join(name for name, ok in terms if not ok)
+
+
+_WIDE_LOWERING_OK: dict = {}
+
+
+def rf_hist_wide_ok(n_pad: int, d_pad: int, k: int, nb: int, S: int) -> bool:
+    """Gate (:func:`rf_hist_wide_declined`) and a probed lowering."""
+    ok = not rf_hist_wide_declined(n_pad, d_pad, k, nb, S)
+    if ok and not FORCE_INTERPRET:
+        R = WIDE_BLOCK_ROWS
+
+        def compile_fn():
+            sds = jax.ShapeDtypeStruct
+            subblock_hist_sel_wide.lower(
+                sds((2 * R, d_pad), jnp.bfloat16), sds((2, k), jnp.int32),
+                sds((WIDE_STAT_ROWS, 2 * R), jnp.bfloat16),
+                sds((2,), jnp.int32), sds((1,), jnp.int32),
+                sds((2, WIDE_STAT_ROWS, k * nb), jnp.float32), n_bins=nb,
+            ).compile()
+
+        from .linalg import probe_pallas_lowering
+
+        ok = probe_pallas_lowering(
+            _WIDE_LOWERING_OK, (d_pad, k, nb), compile_fn,
+            "RF wide fused-selection histogram",
+        )
+    return ok
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_bins", "interpret"), donate_argnums=(5,)
+)
+def subblock_hist_sel_wide(
+    bq: jax.Array,        # (n_pad, d_pad) bf16 FULL bins rows, node-sorted
+    feats_b: jax.Array,   # (n_blocks, k) int32 feature ids of a block's node
+    parts: jax.Array,     # (WIDE_STAT_ROWS, n_pad) bf16: split_f32_exact
+    nodes_b: jax.Array,   # (n_blocks,) int32 node of a block, ascending
+    live_blocks: jax.Array,  # (1,) int32 blocks that hold a live row
+    acc: jax.Array,       # (n_nodes, WIDE_STAT_ROWS, k*n_bins) f32 running sums
+    *,
+    n_bins: int,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """``acc`` with the live blocks' histograms added IN PLACE (the operand is
+    donated and aliased to the result): cell ``[g, p, (t*n_bins + b)*128 + j]``
+    gains, over the rows of the blocks of node ``g``, part ``p`` of the
+    statistics of the rows whose feature ``feats[g, t*128 + j]`` lies in bin
+    ``b``. Summing a node's rows ``s``, ``S + s``, ``2*S + s`` gives stat
+    ``s``; the lanes are tile-major, then BIN-major, then the slot in its tile.
+
+    Grid ``(k // 128, n_blocks)``, the slot tile outermost: along a tile the
+    blocks of one node follow each other, so a node's (16, n_bins*128) sums
+    stay in VMEM from its first block (which takes them from ``acc``) to its
+    last. Every block is node-pure (``WIDE_BLOCK_ROWS`` rows; padding rows
+    carry zero statistics). Blocks past ``live_blocks`` are neither fetched
+    nor computed (:func:`_live_index`); a node with no block in this call
+    keeps its sums untouched. Exact: bins and one-hots are bf16 integers, the
+    statistics' parts are bf16 by construction, accumulation is float32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = FORCE_INTERPRET
+    n_pad, d_pad = bq.shape
+    n_blocks, k = feats_b.shape
+    nb, R, T, P = n_bins, WIDE_BLOCK_ROWS, WIDE_SLOTS, WIDE_STAT_ROWS
+    G = min(_WIDE_BIN_GROUP, nb)
+    assert n_pad == n_blocks * R and k % T == 0 and nb % G == 0
+
+    def kern(live_ref, nodes_ref, b_ref, f_ref, p_ref, acc_ref, out_ref):
+        i = pl.program_id(1)
+
+        @pl.when(i < live_ref[0])
+        def _():
+            first = (i == 0) | (
+                nodes_ref[i] != nodes_ref[jnp.maximum(i - 1, 0)]
+            )
+            d_iota = lax.broadcasted_iota(jnp.int32, (d_pad, T), 0)
+            sel = (d_iota == f_ref[0]).astype(jnp.bfloat16)    # (d_pad, T)
+            selected = jnp.dot(
+                b_ref[:], sel, preferred_element_type=jnp.float32
+            )                                                  # (R, T)
+            lane_bin = (
+                lax.broadcasted_iota(jnp.int32, (1, G * T), 1) // T
+            ).astype(jnp.float32)
+            for g in range(nb // G):
+                oh = (
+                    pltpu.repeat(selected, G, axis=1) == lane_bin + float(g * G)
+                ).astype(jnp.bfloat16)                         # (R, G*T)
+                sl = slice(g * G * T, (g + 1) * G * T)
+                base = jnp.where(first, acc_ref[0, :, sl], out_ref[0, :, sl])
+                out_ref[0, :, sl] = base + jnp.dot(
+                    p_ref[:], oh, preferred_element_type=jnp.float32
+                )                                              # (P, G*T)
+
+        # no live block at all: the one block the pipeline still visits (and
+        # writes back) a tile carries its sums through
+        @pl.when((live_ref[0] == 0) & (i == 0))
+        def _():
+            out_ref[:] = acc_ref[:]
+
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k // T, n_blocks),
+            in_specs=[
+                pl.BlockSpec(
+                    (R, d_pad), lambda t, i, live, nodes: (_live_index(i, live), 0),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (1, 1, T), lambda t, i, live, nodes: (_live_index(i, live), 0, t),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (P, R), lambda t, i, live, nodes: (0, _live_index(i, live)),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (1, P, nb * T),
+                    lambda t, i, live, nodes: (nodes[_live_index(i, live)], 0, t),
+                    memory_space=pltpu.VMEM,
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, P, nb * T),
+                lambda t, i, live, nodes: (nodes[_live_index(i, live)], 0, t),
+                memory_space=pltpu.VMEM,
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=100 * 1024 * 1024,
+        ),
+        interpret=interpret,
+        name="rf_hist_sel_pass_wide",
+    )(
+        jnp.asarray(live_blocks, jnp.int32).reshape(1),
+        nodes_b.astype(jnp.int32), bq, feats_b.reshape(n_blocks, 1, k), parts,
+        acc,
+    )
+
+
+def wide_hist_nodes(acc: jax.Array, S: int, k: int, nb: int) -> jax.Array:
+    """The kernel's sums as (n_nodes, S, k, nb): the three parts added, the
+    tile-major / bin-major lanes back in slot order."""
+    n_nodes, T = acc.shape[0], WIDE_SLOTS
+    a = acc[:, : 3 * S].reshape(n_nodes, 3, S, k // T, nb, T)
+    return (a[:, 0] + a[:, 1] + a[:, 2]).transpose(0, 1, 2, 4, 3).reshape(
+        n_nodes, S, k, nb
+    )
+
+
+# ---------------------------------------------------------------------------
 # T-batched wrappers: one kernel call over a whole tree batch
 # ---------------------------------------------------------------------------
 #

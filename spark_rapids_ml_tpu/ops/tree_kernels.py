@@ -172,16 +172,27 @@ def resolve_tree_batch(
     d_hist = next_pow2(cfg.k_features if subset else max(1, cfg.n_features))
     n_nodes_max = 1 << max(0, cfg.max_depth - 1)
     tile = min(_HIST_BUDGET, n_nodes_max * cfg.n_bins * cfg.n_stats * d_hist)
-    per_tree = (
-        4 * n_rows * (cfg.n_stats + 4 + (d_hist if subset else 0))
-        + 16 * tile
+    plan = (
+        level_plan(n_rows, d_pad, max(0, cfg.max_depth - 1), cfg)
+        if d_pad else None
     )
-    if d_pad:
-        deepest = level_plan(n_rows, d_pad, max(0, cfg.max_depth - 1), cfg)
-        if deepest.strategy == "pallas_sel":
-            per_tree += deepest.n_pad * d_pad + (
-                deepest.n_pad // deepest.r_sub
-            ) * cfg.n_stats * d_hist * cfg.n_bins * 4
+    deepest = plan.strategy if plan else ""
+    # the selection tiled over slots gathers no subset: the kernel selects
+    gathered = d_hist if subset and deepest != "pallas_sel_wide" else 0
+    per_tree = 4 * n_rows * (cfg.n_stats + 4 + gathered) + 16 * tile
+    if deepest == "pallas_sel":
+        per_tree += plan.n_pad * d_pad + (
+            plan.n_pad // plan.r_sub
+        ) * cfg.n_stats * d_hist * cfg.n_bins * 4
+    elif deepest == "pallas_sel_wide":
+        # the kernel's running sums, the histogram and its transpose, and a
+        # gain chain over the compact path's 4 * _HIST_BUDGET cells
+        from .rf_pallas import WIDE_STAT_ROWS
+
+        cells = n_nodes_max * d_hist * cfg.n_bins
+        per_tree += 4 * cells * (WIDE_STAT_ROWS + 2 * cfg.n_stats) + 64 * min(
+            4 * _HIST_BUDGET, cells * cfg.n_stats
+        )
     fit = max(1, int(budget // max(1, per_tree)))
     batch = _largest_divisor_leq(t_group, min(want, fit))
     if tune_key is not None:
@@ -540,6 +551,7 @@ def _hist_compact(
     variance: bool,
     full_bins=None,       # (n, d_pad) uint8 + feats => fused-selection
     feats=None,           # (n_nodes, F) int32 per-node feature ids
+    wide: bool = False,   # the fused selection tiled over slots (r_sub = its block)
     interpret=None,
 ):
     """(F, n_nodes, nb, S) histogram, (n_nodes, S) parent stats and the
@@ -574,8 +586,21 @@ def _hist_compact(
     Measured v5e at 131k x 16 x 128 x 2 (level 12): ~41 ms for the
     scatter strategy's histogram vs ~1 ms kernel + ~4 ms glue here
     (scripts/rf_deep_microbench*.py).
+
+    ``wide`` (``level_plan``'s ``pallas_sel_wide``): the same loop, the
+    chunk's whole rows handed to ``subblock_hist_sel_wide`` with the running
+    per-node sums, which the kernel adds to in place — no partials, no
+    scatter-add; a sub-block is one kernel block of one node.
     """
-    from .rf_pallas import BLOCK_ROWS, subblock_hist, subblock_hist_sel
+    from .rf_pallas import (
+        BLOCK_ROWS,
+        WIDE_STAT_ROWS,
+        split_f32_exact,
+        subblock_hist,
+        subblock_hist_sel,
+        subblock_hist_sel_wide,
+        wide_hist_nodes,
+    )
 
     n = seg.shape[0]
     F, Fc = n_slots, f_chunk
@@ -640,68 +665,88 @@ def _hist_compact(
         swT = jnp.stack([col[ids] for col in sw_cols]) * ok[None, :].astype(
             sw.dtype
         )                                                   # (S, chunk)
-        # blocks of this chunk that hold a live row; a sub-block past P
-        # keeps its unwritten partial out of every sum (dropped unread)
+        # blocks of this chunk that hold a live row
         live_blocks = jnp.clip(
             -(-(p_live - lo) // BLOCK_ROWS), 0, chunk // BLOCK_ROWS
         ).reshape(1)
-        seg_c = jnp.where(
-            (lo_sb + ar_sb) * r_sub < p_live,
-            lax.dynamic_slice(seg_sb, (lo_sb,), (chunk_sb,)),
-            n_nodes,
-        )
-        if full_bins is not None:
-            # whole rows; the kernel selects each node's k columns with an
-            # MXU one-hot dot, replacing the per-row k-column gather that
-            # costs ~780 ms per level at the reference 1M x 3000 shape.
-            # Dump sub-blocks of a live block get garbage feature rows but
-            # zero weights — they contribute nothing.
-            parts = [
-                subblock_hist_sel(
-                    full_bins[ids],
+        if wide:
+            with jax.named_scope("forest.wide_rows"):
+                # whole rows as exact bf16 integers (the kernel's selection
+                # is one bf16 product) and the statistics' three-way split
+                rows_bf = full_bins[ids].astype(jnp.bfloat16)
+                parts = split_f32_exact(swT)
+            # the kernel adds a node's blocks to its running sums in place
+            accs = (
+                subblock_hist_sel_wide(
+                    rows_bf,
                     lax.dynamic_slice(featsq, (lo_sb, 0), (chunk_sb, F)),
-                    swT, live_blocks, n_bins=nb, r_sub=r_sub,
-                    variance=variance, interpret=interpret,
-                )
-            ]                                               # (chunk_sb, S, F*nb)
+                    parts, lax.dynamic_slice(sbc, (lo_sb,), (chunk_sb,)),
+                    live_blocks, accs[0], n_bins=nb, interpret=interpret,
+                ),
+            )
         else:
-            nodes = jnp.broadcast_to(
-                lax.dynamic_slice(sbc, (lo_sb,), (chunk_sb,))[:, None],
-                (chunk_sb, r_sub),
-            ).reshape(chunk)
-            # int32 bins always: the kernel — and its lowering probe — see
-            # exactly one input dtype. Feature-chunked: the kernel's one-hot
-            # is at most 8192 lanes wide
-            binq = row_bins(ids, nodes).astype(jnp.int32)   # (chunk, F)
-            parts = [
-                subblock_hist(
-                    binq[:, c0 : c0 + Fc], swT, live_blocks, n_bins=nb,
-                    r_sub=r_sub, variance=variance, interpret=interpret,
-                    transposed_sw=True,
-                )
-                for c0 in range(0, F, Fc)
-            ]                                               # (chunk_sb, S, Fc*nb)
-        accs = tuple(
-            acc.at[seg_c].add(part.reshape(chunk_sb, -1), mode="drop")
-            for acc, part in zip(accs, parts)
-        )
+            if full_bins is not None:
+                # whole rows; the kernel selects each node's k columns with
+                # an MXU one-hot dot, replacing the per-row k-column gather
+                # that costs ~780 ms per level at the reference 1M x 3000
+                # shape. Dump sub-blocks of a live block get garbage feature
+                # rows but zero weights — they contribute nothing.
+                parts = [
+                    subblock_hist_sel(
+                        full_bins[ids],
+                        lax.dynamic_slice(featsq, (lo_sb, 0), (chunk_sb, F)),
+                        swT, live_blocks, n_bins=nb, r_sub=r_sub,
+                        variance=variance, interpret=interpret,
+                    )
+                ]                                           # (chunk_sb, S, F*nb)
+            else:
+                nodes = jnp.broadcast_to(
+                    lax.dynamic_slice(sbc, (lo_sb,), (chunk_sb,))[:, None],
+                    (chunk_sb, r_sub),
+                ).reshape(chunk)
+                # int32 bins always: the kernel — and its lowering probe —
+                # see exactly one input dtype. Feature-chunked: the kernel's
+                # one-hot is at most 8192 lanes wide
+                binq = row_bins(ids, nodes).astype(jnp.int32)   # (chunk, F)
+                parts = [
+                    subblock_hist(
+                        binq[:, c0 : c0 + Fc], swT, live_blocks, n_bins=nb,
+                        r_sub=r_sub, variance=variance, interpret=interpret,
+                        transposed_sw=True,
+                    )
+                    for c0 in range(0, F, Fc)
+                ]                                           # (chunk_sb, S, Fc*nb)
+            # a sub-block past P keeps its unwritten partial out of every
+            # sum (dropped unread)
+            seg_c = jnp.where(
+                (lo_sb + ar_sb) * r_sub < p_live,
+                lax.dynamic_slice(seg_sb, (lo_sb,), (chunk_sb,)),
+                n_nodes,
+            )
+            accs = tuple(
+                acc.at[seg_c].add(part.reshape(chunk_sb, -1), mode="drop")
+                for acc, part in zip(accs, parts)
+            )
         rows = lax.dynamic_update_slice(rows, jnp.where(ok, ids, n), (lo,))
         return accs, rows
 
     width = F if full_bins is not None else Fc
+    if wide:
+        accs0 = (jnp.zeros((n_nodes, WIDE_STAT_ROWS, F * nb), jnp.float32),)
+    else:
+        accs0 = tuple(
+            jnp.zeros((n_nodes, S * width * nb), sw.dtype)
+            for _ in range(F // width)
+        )
     accs, rows = lax.fori_loop(
-        0, trips, level_chunk,
-        (
-            tuple(
-                jnp.zeros((n_nodes, S * width * nb), sw.dtype)
-                for _ in range(F // width)
-            ),
-            jnp.full((n_ceil,), n, jnp.int32),
-        ),
+        0, trips, level_chunk, (accs0, jnp.full((n_ceil,), n, jnp.int32))
     )
-    hist_nodes = jnp.concatenate(
-        [acc.reshape(n_nodes, S, width, nb) for acc in accs], axis=2
-    )                                                       # (n_nodes, S, F, nb)
+    if wide:
+        hist_nodes = wide_hist_nodes(accs[0], S, F, nb).astype(sw.dtype)
+    else:
+        hist_nodes = jnp.concatenate(
+            [acc.reshape(n_nodes, S, width, nb) for acc in accs], axis=2
+        )                                                   # (n_nodes, S, F, nb)
     parent = hist_nodes[:, :, 0, :].sum(axis=-1)            # (n_nodes, S)
     hist = hist_nodes.transpose(2, 0, 3, 1)                 # (F, n_nodes, nb, S)
     return hist, parent, _Frontier(rows, sbc, trips, chunk, r_sub)
@@ -797,8 +842,10 @@ class LevelPlan(NamedTuple):
     r_sub: int       # sub-block rows of the node-sorted copy
     n_pad: int       # its block-aligned padded row count
     f_chunk: int     # feature chunk of the pre-gathered Pallas kernel
-    strategy: str    # "pallas_sel" | "pallas" | "matmul" | "scatter"
+    strategy: str    # "pallas_sel" | "pallas_sel_wide" | "pallas" | "matmul" | "scatter"
     declined: str    # why the shape's own Pallas kernel was not taken ("" where it was)
+    hist_cols: int = 0   # bins columns the histogram reads a live row
+    hist_calls: int = 0  # kernel calls a chunk of live rows (0: no kernel)
 
 
 def level_plan(
@@ -825,10 +872,14 @@ def level_plan(
     """
     from .rf_pallas import (
         BLOCK_ROWS,
+        WIDE_BLOCK_ROWS,
+        WIDE_STAT_ROWS,
         rf_hist_pallas_declined,
         rf_hist_pallas_ok,
         rf_hist_sel_declined,
         rf_hist_sel_ok,
+        rf_hist_wide_declined,
+        rf_hist_wide_ok,
     )
 
     S, nb = cfg.n_stats, cfg.n_bins
@@ -845,13 +896,17 @@ def level_plan(
     n_nodes_max = 1 << max(0, cfg.max_depth - 1)
     pad_nodes = n_nodes_max if (n_nodes_max + 1) * r_sub * 3 <= n else n_nodes
     n_pad_c = -(-(n + (pad_nodes + 1) * r_sub) // BLOCK_ROWS) * BLOCK_ROWS
-    n_sb_c = n_pad_c // r_sub
+    # what a level holds at once is a CHUNK of its live rows and that chunk's
+    # partials (_hist_compact; the batched builder's level-wide copy and
+    # partials are resolve_tree_batch's to count, a batch at a time)
+    chunk = min(n_pad_c, _LIVE_CHUNK)
+    chunk_sb = chunk // r_sub
     # feature chunk: largest power of two satisfying the kernel's one-hot
     # width cap (Fc*nb <= 8192) AND a ~256 MB partials transient budget; must
     # divide d_hist
     Fc = 1 << max(0, min(d_hist, 8192 // nb).bit_length() - 1)
     while Fc > 1 and (
-        d_hist % Fc != 0 or n_sb_c * S * Fc * nb * 4 > (256 << 20)
+        d_hist % Fc != 0 or chunk_sb * S * Fc * nb * 4 > (256 << 20)
     ):
         Fc //= 2
     shape_terms = (
@@ -864,8 +919,8 @@ def level_plan(
     sel_resident = (
         cfg.held_bytes
         + n * d_pad                      # bins (uint8)
-        + n_pad_c * d_pad              # gathered node-sorted copy
-        + n_sb_c * S * d_hist * nb * 4  # partials (f32)
+        + chunk * d_pad                  # a chunk's gathered whole rows
+        + chunk_sb * S * d_hist * nb * 4  # its partials (f32)
         + 2 * n_nodes * S * d_hist * nb * 4  # hist + transpose
     )
     sel_terms = (
@@ -885,7 +940,41 @@ def level_plan(
     if not sel_declined and rf_hist_sel_ok(
         n_pad_c, d_pad, d_hist, nb, S, r_sub, variance=variance
     ):
-        return LevelPlan(r_sub, n_pad_c, Fc, "pallas_sel", "")
+        return LevelPlan(r_sub, n_pad_c, Fc, "pallas_sel", "", d_hist, 1)
+    # the fused selection TILED over feature slots (rf_pallas.
+    # subblock_hist_sel_wide): where the shape is the fused kernel's (a
+    # subset of a wide frame) and only its one-hot width, its VMEM or the
+    # residents that scale with that width decline it. One node a block of
+    # WIDE_BLOCK_ROWS rows; the per-node sums are the kernel's own, in place.
+    wanted_sel = subset and d_pad > _SEL_MIN_DPAD
+    n_pad_w = (
+        -(-(n + (pad_nodes + 1) * WIDE_BLOCK_ROWS) // WIDE_BLOCK_ROWS)
+        * WIDE_BLOCK_ROWS
+    )
+    wide_resident = (
+        cfg.held_bytes
+        + n * d_pad                                  # bins (uint8)
+        + min(n_pad_w, _LIVE_CHUNK) * d_pad * 3      # a chunk's rows, u8 + bf16
+        + n_nodes * WIDE_STAT_ROWS * d_hist * nb * 4   # the running sums
+        + 6 * n_nodes * S * d_hist * nb * 4          # hist and the gain chain
+    )
+    wide_terms = (
+        ("hbm", wide_resident <= _sel_hbm_budget()),
+    )
+    wide_declined = ",".join(
+        t for t in (
+            shape_declined,
+            ",".join(name for name, ok in wide_terms if not ok),
+            rf_hist_wide_declined(n_pad_w, d_pad, d_hist, nb, S),
+        ) if t
+    )
+    if wanted_sel and not wide_declined and rf_hist_wide_ok(
+        n_pad_w, d_pad, d_hist, nb, S
+    ):
+        return LevelPlan(
+            WIDE_BLOCK_ROWS, n_pad_w, Fc, "pallas_sel_wide",
+            f"sel:{sel_declined}", d_hist, 1,
+        )
     compact_declined = ",".join(
         t for t in (
             shape_declined,
@@ -897,10 +986,10 @@ def level_plan(
     ):
         # the pre-gathered kernel where the fused one was the shape's own
         # (a per-row subset gather of ~780 ms a level at 1M x 3000) says so
-        wanted_sel = subset and d_pad > _SEL_MIN_DPAD
         return LevelPlan(
             r_sub, n_pad_c, Fc, "pallas",
-            f"sel:{sel_declined}" if wanted_sel else "",
+            f"sel:{sel_declined};wide:{wide_declined}" if wanted_sel else "",
+            d_hist, d_hist // Fc,
         )
     # strategy per level (static). Subset path: the gathered operand is only
     # k_pad wide, and measured v5e scatter on it is ~2.2 ms/level FLAT in
@@ -922,18 +1011,34 @@ def level_plan(
         )
     return LevelPlan(
         r_sub, n_pad_c, Fc, "matmul" if use_matmul else "scatter",
-        f"sel:{sel_declined};compact:{compact_declined}",
+        f"sel:{sel_declined};compact:{compact_declined}", d_hist, 0,
     )
+
+
+def _level_plans(n: int, d_pad: int, cfg: ForestConfig, dt):
+    return [level_plan(n, d_pad, lv, cfg, dt) for lv in range(cfg.max_depth)]
 
 
 def plan_levels(n: int, d_pad: int, cfg: ForestConfig, dt=jnp.float32):
     """:func:`level_plan` of every split level, for a span: the strategies
     comma-joined in level order, the levels that did not take their Pallas
     kernel as ``{level: declined}``."""
-    plans = [level_plan(n, d_pad, lv, cfg, dt) for lv in range(cfg.max_depth)]
+    plans = _level_plans(n, d_pad, cfg, dt)
     return (
         ",".join(p.strategy for p in plans),
         {lv: p.declined for lv, p in enumerate(plans) if p.declined},
+    )
+
+
+def plan_reads(n: int, d_pad: int, cfg: ForestConfig, dt=jnp.float32):
+    """``(hist_cols, hist_calls)`` for the same span: the most bins columns a
+    level's histogram reads a live row, and the most kernel calls a level
+    makes a chunk of live rows — the two numbers that say a later change
+    flipped the histogram's path."""
+    plans = _level_plans(n, d_pad, cfg, dt)
+    return (
+        max((p.hist_cols for p in plans), default=0),
+        max((p.hist_calls for p in plans), default=0),
     )
 
 
@@ -1110,7 +1215,8 @@ def _build_tree(
 
         plan = level_plan(n, d_pad, level, cfg, dt)
         r_sub, n_pad_c, Fc = plan.r_sub, plan.n_pad, plan.f_chunk
-        use_sel = plan.strategy == "pallas_sel"
+        use_wide = plan.strategy == "pallas_sel_wide"
+        use_sel = use_wide or plan.strategy == "pallas_sel"
         use_compact = use_sel or plan.strategy == "pallas"
         if use_compact:
             hist_full, parent, frontier = _hist_compact(
@@ -1118,7 +1224,7 @@ def _build_tree(
                 n_slots=d_hist, nb=nb, r_sub=r_sub, n_pad=n_pad_c,
                 f_chunk=Fc, variance=(cfg.impurity == "variance"),
                 full_bins=bins if use_sel else None,
-                feats=feats if use_sel else None,
+                feats=feats if use_sel else None, wide=use_wide,
             )
         else:
             parent = jax.ops.segment_sum(sw, seg, num_segments=n_nodes + 1)[
@@ -1659,9 +1765,23 @@ def _grow_trees_batched(
         # so both builders always pick the same strategy per level
         plan = level_plan(n, d_pad, level, cfg, dt)
         r_sub, n_pad_c, Fc = plan.r_sub, plan.n_pad, plan.f_chunk
+        use_wide = plan.strategy == "pallas_sel_wide"
         use_sel = plan.strategy == "pallas_sel"
-        use_compact = use_sel or plan.strategy == "pallas"
-        if use_sel:
+        use_compact = use_wide or use_sel or plan.strategy == "pallas"
+        if use_wide:
+            # a tree at a time through the sequential builder's own level
+            # (its sums are the kernel's, in place: nothing to flatten over
+            # the batch), so the two builders' tables agree by construction
+            hist_full, parent = lax.map(
+                lambda a: _hist_compact(
+                    None, a[0], a[1], n_nodes=n_nodes, n_slots=d_hist, nb=nb,
+                    r_sub=r_sub, n_pad=n_pad_c, f_chunk=Fc,
+                    variance=(cfg.impurity == "variance"),
+                    full_bins=bins, feats=a[2], wide=True,
+                )[:2],
+                (seg, sw, feats),
+            )
+        elif use_sel:
             hist_full, parent = _hist_compact_batched(
                 None, seg, sw, n_nodes=n_nodes, nb=nb, r_sub=r_sub,
                 n_pad=n_pad_c, f_chunk=Fc,

@@ -425,6 +425,61 @@ def test_rf_build_forest_holds_no_copy_of_a_level(topo, no_compile_cache, monkey
     assert c.memory_analysis().temp_size_in_bytes < (400 << 20)
 
 
+def test_rf_wide_selection_kernel_compiles_at_the_regressors_width(one_chip):
+    """``rf_reg_dbx``'s histogram kernel: one chunk's whole rows (16,384 x 3072
+    bins as bf16), 1000 features a node in 1024 slots — a grid of 8 slot tiles
+    x 32 one-node blocks — 128 bins, the three statistics' exact split in 16
+    bf16 rows, the level's per-node sums (32 nodes) added to in place: the
+    fused selection declines this shape on its one-hot width and its VMEM."""
+    from spark_rapids_ml_tpu.ops import rf_pallas as rp
+
+    n, d_pad, k, nb, S, nodes = 16_384, 3072, 1024, 128, 3, 32
+    assert rp.rf_hist_sel_declined(n, d_pad, k, nb, S, 64) == "backend,width<=8192,vmem"
+    assert rp.rf_hist_wide_declined(n, d_pad, k, nb, S) == "backend"      # every other term holds
+    blocks = n // rp.WIDE_BLOCK_ROWS
+    c = rp.subblock_hist_sel_wide.lower(
+        one_chip((n, d_pad), jnp.bfloat16), one_chip((blocks, k), I32), one_chip((rp.WIDE_STAT_ROWS, n), jnp.bfloat16),
+        one_chip((blocks,), I32), one_chip((1,), I32), one_chip((nodes, rp.WIDE_STAT_ROWS, k * nb)),
+        n_bins=nb, interpret=False,
+    ).compile()
+    assert _has_kernel(c)
+    # the sums are updated in place: the result aliases the operand, no second copy
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes == nodes * rp.WIDE_STAT_ROWS * k * nb * 4 and m.temp_size_in_bytes < (1 << 20)
+
+
+def test_rf_regressor_forest_gathers_no_subset(topo, no_compile_cache, monkeypatch):
+    """``build_forest`` at ``rf_reg_dbx``'s shape (500,000 rows of 3072 bins,
+    1000 of 3000 features a node, variance, 128 bins; two levels deep): ONE
+    Mosaic call a level, on a chunk's whole rows — no per-row gather of the
+    1024 sampled columns (``u8[16384,1024]``, 0.2 s a chunk on the chip) and
+    no subset-wide copy of a level; the temporaries are a chunk, the level's
+    sums and the gain search's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.ops import linalg, tree_kernels as tk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(linalg, "probe_pallas_lowering", lambda cache, key, fn, name: True)
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "mp"))
+    rows = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, P("dp")))
+    n, d, d_pad, depth = 500_000, 3000, 3072, 2
+    cfg = tk.ForestConfig(
+        max_depth=depth, n_bins=128, n_features=d, n_stats=3, impurity="variance", k_features=1000, min_samples_leaf=1,
+        min_info_gain=0.0, min_samples_split=2, bootstrap=True, held_bytes=n * d * 4,
+    )
+    assert [tk.level_plan(n, d_pad, lv, cfg).strategy for lv in range(depth)] == ["pallas_sel_wide"] * depth
+    c = tk.build_forest.lower(
+        rows((n, d_pad), jnp.uint8), rows((n,), F32), rows((n, 3), F32), rows((1, 5, 2), jnp.uint32),
+        mesh=mesh, cfg=cfg, gather=False, tree_batch=1,
+    ).compile()
+    txt = c.as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == depth
+    chunk = tk._LIVE_CHUNK
+    assert f"bf16[{chunk},{d_pad}]" in txt and f"u8[{chunk},1024]" not in txt and f"s32[{chunk},1024]" not in txt
+    assert c.memory_analysis().temp_size_in_bytes < (600 << 20)
+
+
 def test_rf_sketch_reads_the_reference_frame_in_place(one_chip):
     """The quantile sketch at ``rf_dbx``'s shard (500,000 x 3000 f32, rows
     minor): 1024 runs of 128 consecutive rows, sorted a column on the device.
