@@ -198,7 +198,8 @@ class FitInputs:
     dtype: Any = jnp.float32
     csize: int = 1                   # per-device row-chunk size (scan kernels)
     n_features_padded: int = 0       # X's column count incl. lane padding
-    X_host: Optional[np.ndarray] = None  # the frame before it is placed (an estimator that places its own: PCA)
+    X_host: Optional[np.ndarray] = None  # the frame before it is placed (an estimator that places its own: PCA, LinearRegression)
+    y_host: Optional[np.ndarray] = None  # its labels, where such an estimator has them
     folded: Optional[Any] = None     # what such an estimator's fold left on the devices, for the later lanes
 
 
@@ -729,23 +730,36 @@ class _TpuEstimator(Params, _TpuParams):
             X_host=X,
         )
 
+    def _places_its_own_frame(self, inputs: FitInputs) -> bool:
+        """Whether the fit function places the frame itself (``X_host``,
+        ``y_host``), folding each row block as it lands
+        (``parallel.mesh.shard_rows(fold=)``): an estimator whose fit is a
+        sum over rows (PCA, LinearRegression) answers True where its fold
+        applies."""
+        return False
+
     def _pre_process_data(self, dataset: DataFrame) -> FitInputs:
         """Put-then-solve: the frame, its mask, labels and weights on the
         devices before the fit function runs — for a solver that needs the
         whole frame at once (every pass of L-BFGS and Lloyd reads every
         row). An estimator whose fit is a sum over rows owns its placement
-        instead (``PCA._pre_process_data``)."""
+        instead (:meth:`_places_its_own_frame`): it gets the host part
+        alone."""
         inputs = self._host_inputs(dataset)
         dtype = inputs.dtype
+        if self._require_label():
+            label_col = self.getOrDefault("labelCol")
+            inputs.y_host = np.asarray(dataset.column(label_col), dtype=dtype)
+        if self._places_its_own_frame(inputs):
+            return inputs
         # the zero columns up to d_padded are written on the device
         inputs.X, inputs.mask = shard_rows(
             inputs.X_host, inputs.mesh, inputs.csize, cols=inputs.n_features_padded
         )
         inputs.X_host = None
-        if self._require_label():
-            label_col = self.getOrDefault("labelCol")
-            y_host = np.asarray(dataset.column(label_col), dtype=dtype)
-            inputs.y = shard_aligned(y_host, inputs.mesh, inputs.X.shape[0])
+        if inputs.y_host is not None:
+            inputs.y = shard_aligned(inputs.y_host, inputs.mesh, inputs.X.shape[0])
+            inputs.y_host = None
         wcol = self._resolved_weight_col()
         if wcol is not None:
             if wcol not in dataset:

@@ -51,20 +51,18 @@ from ..params import (
     _mk,
 )
 from ..ops.linalg import (
+    GramFold,
     cov_from_gram_folds,
     gram_block_rows,
     gram_fold,
-    gram_fold_zeros,
     gram_pallas_declined,
-    gram_tile,
-    host_mean_sample,
     mean_and_cov,
     mean_and_cov_chunked,
     mp_gram_blocks,
     rows_minor,
     topk_eigh,
 )
-from ..parallel.mesh import DP_AXIS, allreduce_sum_host, row_sharding, shard_rows
+from ..parallel.mesh import DP_AXIS, shard_rows
 from ..runtime import telemetry
 
 
@@ -125,7 +123,7 @@ def _pca_fit_kernel(
     (``mp_blocks``; static, resolve with ``mp_gram_blocks`` outside jit; the
     blocked covariance rides out in the result so the caller can measure its
     per-shard bytes) — every other resident fit folds the row blocks under
-    the frame's crossing (:class:`_GramFold`, :func:`_pca_finish`) and never
+    the frame's crossing (``GramFold``, :func:`_pca_finish`) and never
     hands the frame to one program. Also the multi-chip dry run's
     (``__graft_entry__``).
 
@@ -159,74 +157,6 @@ def _pca_finish(G, s, cnt, mean_hat, k: int, mesh, d: int, pallas: bool):
     a ``fitMultiple``'s later lanes run :func:`_pca_from_cov` on them."""
     mean, cov, n = cov_from_gram_folds(G, s, cnt, mean_hat, mesh, d, pallas)
     return _pca_from_cov(mean, cov, n, k), cov, n
-
-
-class _GramFold:
-    """PCA's fold over the row blocks of its frame (``parallel.mesh.RowFold``):
-    each block's shifted Gram, row sum and count are added into its device's
-    running float32 accumulators by one :func:`gram_fold` program, dispatched
-    when the block's put has been issued — so it runs while the next block
-    crosses the link.
-
-    μ̂, which every block is shifted by, has to exist before block 0 is
-    folded: it comes from the HOST array (``host_mean_sample``: runs of rows
-    from all over it, summed over the process world), taken inside the first
-    call, while block 0 is on its way. Which pass folds (the Pallas kernel or
-    XLA's blocked one) is the gate's answer for the FIRST block's shape, asked
-    once a fit: the accumulators have one form, and a short tail block that
-    the device keeps the other way round costs a relayout of that tail alone.
-    """
-
-    def __init__(self, x_host: np.ndarray, csize: int):
-        self.x_host, self.csize = x_host, csize
-        self.d, self.dtype = x_host.shape[1], x_host.dtype
-        self.mean_hat: Optional[np.ndarray] = None
-        self._on_device: Dict[Any, jax.Array] = {}
-        self.attrs: Dict[str, Any] = {}
-
-    def _first_call(self, rows: jax.Array, device) -> None:
-        s, c = allreduce_sum_host(*host_mean_sample(self.x_host))
-        self.mean_hat = (s / max(float(c), 1.0)).astype(self.dtype)
-        n, d = rows.shape
-        declined = gram_pallas_declined(n, d, self.dtype, device)
-        self.pallas = not declined
-        self.block = gram_block_rows(n, d, self.csize, self.dtype.itemsize)
-        self.attrs = {
-            "precision": "highest",
-            "rows_minor": jax.default_backend() == "tpu" and rows_minor(device, n, d, self.dtype),
-            "gram": "pallas" if self.pallas else "xla",
-            "tile": gram_tile(d)[0] if self.pallas else self.block,
-        }
-        if declined:
-            self.attrs["declined"] = declined
-
-    def __call__(self, state, rows: jax.Array, row0: int, valid: int):
-        (device,) = rows.devices()
-        if self.mean_hat is None:
-            self._first_call(rows, device)
-        if device not in self._on_device:
-            self._on_device[device] = jax.device_put(self.mean_hat, device)
-        if state is None:
-            state = gram_fold_zeros(self.d, self.dtype, self.pallas, device)
-        return gram_fold(
-            state, rows, self._on_device[device], np.int32(valid), pallas=self.pallas, block=self.block
-        )
-
-    def sums(self, states: Dict[Any, Any], mesh) -> tuple:
-        """Every device's state as three global arrays, stacked along axis 0
-        and sharded over dp (a device that got no block: zeros)."""
-        sh = row_sharding(mesh)
-        per_device = [
-            states[dev] or gram_fold_zeros(self.d, self.dtype, self.pallas, dev)
-            for dev in sh.addressable_devices_indices_map((mesh.shape[DP_AXIS],))
-        ]
-        n_dp = mesh.shape[DP_AXIS]
-        return tuple(
-            jax.make_array_from_single_device_arrays(
-                (n_dp * parts[0].shape[0],) + parts[0].shape[1:], sh, list(parts)
-            )
-            for parts in zip(*per_device)
-        )
 
 
 @jax.jit
@@ -270,18 +200,18 @@ class PCA(PCAClass, _TpuEstimator, _PCAParams):
         # one placement and one fold; the later lanes reuse the covariance
         return True
 
-    def _pre_process_data(self, dataset: DataFrame) -> FitInputs:
-        """The host part alone (column, dtype, contiguity, mesh, chunk size).
-        The fit is a sum over rows, so the fit function places the frame
-        itself and folds each row block as it lands (:class:`_GramFold`): the
-        frame's crossing lies inside ``fit.dispatch``, with the Gram under it."""
-        return self._host_inputs(dataset)
+    def _places_its_own_frame(self, inputs: FitInputs) -> bool:
+        """Always: the fit is a sum over rows, so the fit function places the
+        frame itself and folds each row block as it lands (``GramFold``):
+        the frame's crossing lies inside ``fit.dispatch``, with the Gram
+        under it."""
+        return True
 
     def _fit_under_the_put(self, inputs: FitInputs, k: int) -> Dict[str, Any]:
         """Place the frame with the Gram folded under its crossing, then
         dispatch the finish program. Leaves the frame and the covariance on
         ``inputs`` for the later lanes of a ``fitMultiple``."""
-        fold = _GramFold(inputs.X_host, inputs.csize)
+        fold = GramFold(inputs.X_host, inputs.mesh, inputs.csize)
         inputs.X, inputs.mask, states = shard_rows(inputs.X_host, inputs.mesh, inputs.csize, fold=fold)
         inputs.X_host = None
         if fold.mean_hat is None:
@@ -293,7 +223,7 @@ class PCA(PCAClass, _TpuEstimator, _PCAParams):
             **fold.attrs,
         ):
             out, cov, n = _pca_finish(
-                *fold.sums(states, inputs.mesh), fold.mean_hat, k=k, mesh=inputs.mesh,
+                *fold.sums(states), fold.mean_hat, k=k, mesh=inputs.mesh,
                 d=fold.d, pallas=fold.pallas,
             )
         inputs.folded = (out["mean"], cov, n)

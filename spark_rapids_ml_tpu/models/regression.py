@@ -9,9 +9,17 @@ Param mapping parity (reference ``regression.py:172-205``):
 
 Solver selection (reference picks cuML class by regularization,
 ``regression.py:502-559``): here l1=0 → closed-form Cholesky on the psum'd
-Gram (the eig/ridge path, incl. Spark's standardized-penalty semantics that
-the reference reproduces via the alpha×M rescale at :530-537); l1>0 → FISTA
-on the precomputed quadratic form (replaces ``CDMG``).
+Gram with no added ridge, and cuML's least-norm ``eig`` where the system is
+singular (the eig/ridge path, incl. Spark's standardized-penalty semantics
+that the reference reproduces via the alpha×M rescale at :530-537); l1>0 →
+FISTA on the precomputed quadratic form (replaces ``CDMG``).
+
+The statistics are a sum over rows: a resident unweighted fit in one process
+places its own frame and folds every row block as it lands, with PCA's
+float32-exact Gram and the label beside it (``ops.linalg.GramFold`` under
+``parallel.mesh.shard_rows(fold=)``); one finish program then solves
+(``ops.linreg_kernels._linreg_finish``). The transform's product is float32
+(``Precision.HIGHEST``): the prediction is the result.
 
 ``fitMultiple`` fits every param map from ONE pass of sufficient statistics
 (reference single-pass loop: ``regression.py:591-608``); ``_combine`` stacks
@@ -20,6 +28,7 @@ models for single-pass CV evaluation (reference ``regression.py:750-773``).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -51,15 +60,24 @@ from ..params import (
     TypeConverters,
     _mk,
 )
-from ..ops.linalg import mp_gram_blocks
+from ..ops.linalg import GramFold, gram_fold, mp_gram_blocks
 from ..ops.linreg_kernels import (
+    _linreg_finish,
     linreg_suffstats,
     linreg_suffstats_chunked,
     solve_elasticnet,
     solve_elasticnet_batched,
+    solve_least_norm,
     solve_normal,
 )
+from ..parallel.mesh import shard_rows
 from ..runtime import telemetry
+
+
+def _penalties(params: Dict[str, Any]) -> Tuple[float, float]:
+    """(λ₁, λ₂) of a param map: ``regParam`` split by ``elasticNetParam``."""
+    alpha, l1_ratio = float(params["alpha"]), float(params["l1_ratio"])
+    return alpha * l1_ratio, alpha * (1.0 - l1_ratio)
 
 
 class LinearRegressionClass:
@@ -183,40 +201,51 @@ class LinearRegression(
     @staticmethod
     def _launch_solver(
         stats: Dict[str, jax.Array], params: Dict[str, Any], dtype: Any
-    ) -> Tuple[str, Tuple[Any, Any, Any]]:
+    ) -> Tuple[str, Tuple[Any, ...]]:
         """Solver dispatch on precomputed sufficient statistics: the jitted
-        solver's name and its (coefficients, intercept, iterations), still
-        on the device."""
-        alpha = float(params["alpha"])
-        l1_ratio = float(params["l1_ratio"])
+        solver's name and its result as :meth:`_fetch_solution` takes it,
+        still on the device."""
+        l1, l2 = _penalties(params)
         standardization = bool(params["standardization"])
-        l1 = alpha * l1_ratio
-        l2 = alpha * (1.0 - l1_ratio)
+        l2 = jnp.asarray(l2, dtype)
         if l1 == 0.0:
-            beta, intercept = solve_normal(
-                stats, jnp.asarray(l2, dtype), standardization=standardization
-            )
-            return solve_normal.__name__, (beta, intercept, 1)
+            solution = solve_normal(stats, l2, standardization=standardization)
+            return solve_normal.__name__, LinearRegression._normal_result(stats, l2, standardization, solution)
         return solve_elasticnet.__name__, solve_elasticnet(
             stats,
             jnp.asarray(l1, dtype),
-            jnp.asarray(l2, dtype),
+            l2,
             standardization=standardization,
             max_iter=int(params["max_iter"]),
             tol=float(params["tol"]),
-        )
+        ) + (None, None)
 
     @staticmethod
-    def _fetch_solution(beta: Any, intercept: Any, it: Any) -> Dict[str, Any]:
+    def _normal_result(stats, l2, standardization: bool, solution) -> Tuple[Any, ...]:
+        """A normal solve's (coefficients, intercept, declined) as
+        :meth:`_fetch_solution` takes them, with the least-norm program on
+        the same statistics standing by for a declined Cholesky."""
+        beta, intercept, declined = solution
+        least_norm = functools.partial(solve_least_norm, stats, l2, standardization=standardization)
+        return beta, intercept, 1, declined, least_norm
+
+    @staticmethod
+    def _fetch_solution(beta: Any, intercept: Any, it: Any, declined: Any, least_norm: Any) -> Dict[str, Any]:
         """The solver's results on the host; the first conversion blocks
-        until the programs before it have run."""
+        until the programs before it have run. Where the Cholesky
+        ``declined`` (None: FISTA ran), ``least_norm()`` dispatches the
+        least-norm eigen-solve and its answer is served."""
         with telemetry.span("solver.fetch") as f_span:
+            solve = "fista" if declined is None else "cholesky"
+            if declined is not None and bool(declined):
+                beta, intercept = least_norm()
+                solve = "eigh"
             result = {
                 "coefficients": np.asarray(beta),
                 "intercept": float(intercept),
                 "n_iter": int(it),
             }
-            f_span.set_attr(n_iter=result["n_iter"])
+            f_span.set_attr(n_iter=result["n_iter"], solve=solve)
         return result
 
     @staticmethod
@@ -227,6 +256,54 @@ class LinearRegression(
         so the two paths cannot diverge."""
         _, out = LinearRegression._launch_solver(stats, params, dtype)
         return LinearRegression._fetch_solution(*out)
+
+    def _places_its_own_frame(self, inputs: FitInputs) -> bool:
+        """A resident unweighted fit in one process whose Gram is not
+        column-blocked: the statistics are a sum over rows, so the fit
+        function places the frame and folds each row block as it lands
+        (:meth:`_fold_and_finish`)."""
+        return (
+            self._resolved_weight_col() is None
+            and jax.process_count() == 1
+            and mp_gram_blocks(inputs.mesh, inputs.n_features_padded) == 1
+        )
+
+    @staticmethod
+    def _fold_and_finish(
+        inputs: FitInputs, params: Dict[str, Any], solve: bool
+    ) -> Tuple[Dict[str, jax.Array], Any]:
+        """The folded statistics for the lane's ``fit_intercept`` and, with
+        ``solve``, its solution as ``_fetch_solution`` takes it: the normal
+        solve inside the ONE finish program, FISTA after it. The first call
+        of a fit places the frame with the Gram and the label's sums folded
+        under its crossing (``GramFold``); a later lane with the other
+        ``fit_intercept`` finishes the same folds again."""
+        first = inputs.folded is None
+        if first:
+            fold = GramFold(inputs.X_host, inputs.mesh, inputs.csize, y_host=inputs.y_host)
+            inputs.X, inputs.mask, states = shard_rows(inputs.X_host, inputs.mesh, inputs.csize, fold=fold)
+            inputs.X_host = inputs.y_host = None
+            if fold.mean_hat is None:
+                raise ValueError("LinearRegression.fit: the dataset has no rows")
+            inputs.folded = (fold, fold.sums(states))
+        fold, sums = inputs.folded
+        programs = ([gram_fold.__name__] if first else []) + [_linreg_finish.__name__]
+        l1, l2 = _penalties(params)
+        l2, standardization = jnp.asarray(l2, inputs.dtype), bool(params["standardization"])
+        with telemetry.span("solver.launch", gram_under_put=first, **fold.attrs) as l_span:
+            stats, out = _linreg_finish(
+                sums, fold.mean_hat, fold.mean_y_hat, l2,
+                mesh=inputs.mesh, d=fold.d, pallas=fold.pallas,
+                fit_intercept=bool(params["fit_intercept"]),
+                standardization=standardization, solve=solve and l1 == 0.0,
+            )
+            if out is not None:
+                out = LinearRegression._normal_result(stats, l2, standardization, out)
+            elif solve:
+                solver, out = LinearRegression._launch_solver(stats, params, inputs.dtype)
+                programs.append(solver)
+            l_span.set_attr(program=",".join(programs))
+        return stats, out
 
     def _chunk_rows(self, n_rows: int, n_dp: int) -> int:
         # route resident fits through the chunked suffstats scan: bounds
@@ -266,7 +343,9 @@ class LinearRegression(
             fit_intercept = bool(ps0["fit_intercept"])
             if fit_intercept not in stats_cache:
                 csize = inputs.csize
-                if self.rows_chunkable(inputs.X.shape[0], inputs.mesh, csize):
+                if inputs.X is None or inputs.folded is not None:
+                    stats_cache[fit_intercept], _ = self._fold_and_finish(inputs, ps0, solve=False)
+                elif self.rows_chunkable(inputs.X.shape[0], inputs.mesh, csize):
                     stats_cache[fit_intercept] = linreg_suffstats_chunked(
                         inputs.X, inputs.mask, inputs.y, inputs.weight,
                         mesh=inputs.mesh, csize=csize,
@@ -315,41 +394,44 @@ class LinearRegression(
     def _get_tpu_fit_func(self, dataset: DataFrame) -> FitFunc:
         stats_cache: Dict[bool, Dict[str, jax.Array]] = {}
 
-        blocked_mp: Dict[bool, int] = {}
-
         def _fit(inputs: FitInputs, params: Dict[str, Any]) -> Dict[str, Any]:
             fit_intercept = bool(params["fit_intercept"])
+            if fit_intercept in stats_cache:
+                # a later lane: the statistics an earlier lane left
+                with telemetry.span("solver.launch", gram_under_put=False) as l_span:
+                    solver, out = self._launch_solver(stats_cache[fit_intercept], params, inputs.dtype)
+                    l_span.set_attr(program=solver)
+                return self._fetch_solution(*out)
+            if inputs.X is None or inputs.folded is not None:
+                stats_cache[fit_intercept], out = self._fold_and_finish(inputs, params, solve=True)
+                return self._fetch_solution(*out)
             with telemetry.span("solver.launch") as l_span:
-                programs = []
-                if fit_intercept not in stats_cache:
-                    # the single data pass — shared by every param map
-                    csize = inputs.csize
-                    mp = mp_gram_blocks(inputs.mesh, inputs.X.shape[1])
-                    if self.rows_chunkable(inputs.X.shape[0], inputs.mesh, csize):
-                        stats_cache[fit_intercept] = linreg_suffstats_chunked(
-                            inputs.X, inputs.mask, inputs.y, inputs.weight,
-                            mesh=inputs.mesh, csize=csize,
-                            fit_intercept=fit_intercept,
-                            weighted=inputs.weight is not None,
-                            mp_blocks=mp > 1,
-                        )
-                        blocked_mp[fit_intercept] = mp
-                        programs.append(linreg_suffstats_chunked.__name__)
-                    else:
-                        stats_cache[fit_intercept] = linreg_suffstats(
-                            inputs.X, inputs.mask, inputs.y, inputs.weight,
-                            fit_intercept=fit_intercept,
-                        )
-                        blocked_mp[fit_intercept] = 1
-                        programs.append(linreg_suffstats.__name__)
+                # the single data pass over the placed frame — shared by
+                # every param map
+                csize = inputs.csize
+                mp = mp_gram_blocks(inputs.mesh, inputs.X.shape[1])
+                if self.rows_chunkable(inputs.X.shape[0], inputs.mesh, csize):
+                    stats_cache[fit_intercept] = linreg_suffstats_chunked(
+                        inputs.X, inputs.mask, inputs.y, inputs.weight,
+                        mesh=inputs.mesh, csize=csize,
+                        fit_intercept=fit_intercept,
+                        weighted=inputs.weight is not None,
+                        mp_blocks=mp > 1,
+                    )
+                    program = linreg_suffstats_chunked.__name__
+                else:
+                    stats_cache[fit_intercept] = linreg_suffstats(
+                        inputs.X, inputs.mask, inputs.y, inputs.weight,
+                        fit_intercept=fit_intercept,
+                    )
+                    mp, program = 1, linreg_suffstats.__name__
                 solver, out = self._launch_solver(
                     stats_cache[fit_intercept], params, inputs.dtype
                 )
                 # the jitted functions this launch dispatched, in order: the
                 # data pass that waits for the frame, then the solve
-                l_span.set_attr(program=",".join(programs + [solver]))
+                l_span.set_attr(program=f"{program},{solver}")
             result = self._fetch_solution(*out)
-            mp = blocked_mp[fit_intercept]
             if mp > 1:
                 G = stats_cache[fit_intercept]["G"]
                 result["_fit_report"] = {
@@ -390,6 +472,15 @@ class LinearRegression(
 
     def _create_model(self, result: Dict[str, Any]) -> "LinearRegressionModel":
         return LinearRegressionModel(**result)
+
+
+@jax.jit
+def _predict(Xb: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """``Xb·w + b``: w (d,) for one model, (d, m) for a CV-combined multi-model.
+    The coefficients are arguments, so every model of one width runs the one
+    program. HIGHEST: the prediction is the result, and at default precision
+    an f32 product on the MXU (the multi-model's) is one bf16 pass."""
+    return jnp.matmul(Xb, w.astype(Xb.dtype), precision=jax.lax.Precision.HIGHEST) + b.astype(Xb.dtype)
 
 
 class LinearRegressionModel(
@@ -464,23 +555,11 @@ class LinearRegressionModel(
         pred_col = self.getOrDefault("predictionCol")
 
         def _build() -> Callable[[np.ndarray], Dict[str, np.ndarray]]:
-            coef_np = np.asarray(self.coefficients)
-            b_np = np.asarray(self.intercept)
-            if coef_np.ndim == 1:
-                @jax.jit
-                def _predict(Xb: jax.Array) -> jax.Array:
-                    w = jnp.asarray(coef_np, dtype=Xb.dtype)
-                    return Xb @ w + jnp.asarray(b_np, dtype=Xb.dtype)
-            else:
-                @jax.jit
-                def _predict(Xb: jax.Array) -> jax.Array:
-                    W = jnp.asarray(coef_np, dtype=Xb.dtype)  # (m, d)
-                    return (
-                        Xb @ W.T + jnp.asarray(b_np, dtype=Xb.dtype)[None, :]
-                    )
+            coef = np.asarray(self.coefficients)
+            w, b = np.ascontiguousarray(coef.T), np.asarray(self.intercept, coef.dtype)
 
             def _fn(Xb: np.ndarray) -> Dict[str, np.ndarray]:
-                return {pred_col: output_to_host(_predict(batch_to_device(Xb)))}
+                return {pred_col: output_to_host(_predict(batch_to_device(Xb), w, b))}
 
             return _fn
 

@@ -22,7 +22,7 @@ from jax import lax, shard_map
 from jax.experimental.layout import Layout
 
 from ..parallel.layout import LAYOUT
-from ..parallel.mesh import DP_AXIS, MP_AXIS
+from ..parallel.mesh import DP_AXIS, MP_AXIS, allreduce_sum_host, padded_rows, row_sharding
 
 
 def masked_mean(X: jax.Array, mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -385,15 +385,21 @@ def host_mean_sample(x: np.ndarray, rows: int = 4096) -> Tuple[np.ndarray, float
     return s, float(runs * width)
 
 
-def _recentre(G, s, n, mean_hat, cols=None):
-    """``(mean, covariance over n-1)`` from the shifted sums ``G = Σ m·(x-μ̂)
+def _recentred(G, s, n, mean_hat, cols=None):
+    """``(mean, Σ m·(x-x̄)(x-x̄)ᵀ)`` from the shifted sums ``G = Σ m·(x-μ̂)
     (x-μ̂)ᵀ``, ``s = Σ m·(x-μ̂)`` and the count: with ``δ = s/n`` the exact
-    mean minus μ̂, ``cov = (G − n·δδᵀ)/(n−1)`` exactly, whatever μ̂ — only the
-    rounding of the correction depends on how small δ is. ``cols`` = (start,
-    width) of the column block that ``G`` holds, where it is not the whole."""
+    mean minus μ̂, ``G − n·δδᵀ`` exactly, whatever μ̂ — only the rounding of
+    the correction depends on how small δ is. ``cols`` = (start, width) of
+    the column block that ``G`` holds, where it is not the whole."""
     delta = s / n
     delta_b = delta if cols is None else lax.dynamic_slice_in_dim(delta, cols[0], cols[1], 0)
-    return mean_hat + delta, (G - n * jnp.outer(delta, delta_b)) / (n - 1.0)
+    return mean_hat + delta, G - n * jnp.outer(delta, delta_b)
+
+
+def _recentre(G, s, n, mean_hat, cols=None):
+    """``(mean, covariance over n-1)`` from the shifted sums: :func:`_recentred`."""
+    mean, Gc = _recentred(G, s, n, mean_hat, cols)
+    return mean, Gc / (n - 1.0)
 
 
 def mean_and_cov_chunked(
@@ -493,21 +499,24 @@ def mean_and_cov_chunked(
     )(X, mask)
 
 
-def gram_fold_zeros(d: int, dtype, pallas: bool, device) -> Tuple[jax.Array, jax.Array, jax.Array]:
+def gram_fold_zeros(d: int, dtype, pallas: bool, device, label: bool = False) -> Tuple[jax.Array, ...]:
     """The state :func:`gram_fold` starts from on ``device``: zero ``(Gram,
     row sum, count)`` in the form its pass leaves them — the Pallas kernel's
-    upper block triangle and lane partials, or XLA's (d, d) and (d,)."""
+    upper block triangle and lane partials, or XLA's (d, d) and (d,) — and,
+    with a ``label``, zero ``(Σ (x-μ̂)(y-μ̂_y), Σ (y-μ̂_y), Σ (y-μ̂_y)²)``."""
     if pallas:
         _, bs, _ = gram_tile(d)
         dp = -(-d // bs) * bs
         shapes = ((dp // bs, bs, dp), (dp, _LANES), (1,))
     else:
         shapes = ((d, d), (d,), (1,))
+    if label:
+        shapes += ((d,), (1,), (1,))
     return tuple(jnp.zeros(shape, dtype, device=device) for shape in shapes)
 
 
 @functools.partial(jax.jit, static_argnames=("pallas", "block"), donate_argnums=0)
-def gram_fold(acc, rows: jax.Array, mean_hat: jax.Array, valid, *, pallas: bool, block: int):
+def gram_fold(acc, rows: jax.Array, mean_hat: jax.Array, valid, label=None, *, pallas: bool, block: int):
     """``acc`` (one device's running ``(Gram, row sum, count)``, donated) plus
     the shifted sums of one row block: ``Σ (x-μ̂)(x-μ̂)ᵀ``, ``Σ (x-μ̂)`` and
     the count over the ``valid`` leading rows of ``rows``. The covariance is
@@ -522,7 +531,14 @@ def gram_fold(acc, rows: jax.Array, mean_hat: jax.Array, valid, *, pallas: bool,
     as it is, and mirrored once, in the finish — XLA's blocked pass (``block``
     rows a product) otherwise. Both at ``Precision.HIGHEST``; the sums over
     blocks are float32 adds of float32 partials, eight an entry at
-    ``pca_dbx``'s frame. ``valid`` is traced: one program a block shape."""
+    ``pca_dbx``'s frame. ``valid`` is traced: one program a block shape.
+
+    ``label`` = ``(y, μ̂_y)``, the block's labels (rows past ``valid`` are
+    masked) and their shift: the Gram as above, and beside it
+    ``Σ (x-μ̂)(y-μ̂_y)``, ``Σ (y-μ̂_y)`` and ``Σ (y-μ̂_y)²`` in ``acc[3:]`` —
+    a least-squares fit's statistics. Their products are float32 ones on the
+    VPU, one more read of the block. Without a label the program is the
+    Gram's alone."""
     m = (lax.iota(jnp.int32, rows.shape[0]) < valid).astype(rows.dtype)
     with jax.named_scope("pca.gram"):
         if pallas:
@@ -530,22 +546,130 @@ def gram_fold(acc, rows: jax.Array, mean_hat: jax.Array, valid, *, pallas: bool,
             cnt = m.sum()
         else:
             G, s, cnt = _shifted_gram_xla(rows, m, mean_hat, block=block)
-    return acc[0] + G, acc[1] + s, acc[2] + cnt
+    out = (acc[0] + G, acc[1] + s, acc[2] + cnt)
+    if label is None:
+        return out
+    y, mean_y_hat = label
+    ys = (y - mean_y_hat) * m
+    xy = ((rows - mean_hat[None, :]) * ys[:, None]).sum(axis=0)
+    return out + (acc[3] + xy, acc[4] + ys.sum(), acc[5] + (ys * ys).sum())
+
+
+class GramFold:
+    """The fold over the row blocks of a frame (``parallel.mesh.RowFold``)
+    that PCA and LinearRegression place their frames with: each block's
+    shifted Gram, row sum and count — with a label, also its shifted label
+    sums — are added into its device's running float32 accumulators by one
+    :func:`gram_fold` program, dispatched when the block's put has been
+    issued, so it runs while the next block crosses the link.
+
+    μ̂ (and μ̂_y), which every block is shifted by, has to exist before block
+    0 is folded: it comes from the HOST arrays (:func:`host_mean_sample`:
+    runs of rows from all over them, summed over the process world), taken
+    inside the first call, while block 0 is on its way. Which pass folds (the
+    Pallas kernel or XLA's blocked one) is the gate's answer for the FIRST
+    block's shape, asked once a fit: the accumulators have one form, and a
+    short tail block that the device keeps the other way round costs a
+    relayout of that tail alone.
+
+    ``y_host`` is the label of every host row, single process only: a
+    block's labels are its rows' slice of it, found from where the block's
+    device shard starts (``mesh``, ``csize``: the padding ``shard_rows``
+    gives the frame), put beside the block."""
+
+    def __init__(self, x_host: np.ndarray, mesh, csize: int, y_host: np.ndarray | None = None):
+        self.x_host, self.mesh, self.csize, self.y_host = x_host, mesh, csize, y_host
+        self.d, self.dtype = x_host.shape[1], x_host.dtype
+        self.mean_hat: np.ndarray | None = None
+        self.mean_y_hat: np.ndarray | None = None
+        self._on_device: dict = {}
+        self.attrs: dict = {}
+
+    def _first_call(self, rows: jax.Array, device) -> None:
+        s, c = allreduce_sum_host(*host_mean_sample(self.x_host))
+        self.mean_hat = (s / max(float(c), 1.0)).astype(self.dtype)
+        if self.y_host is not None:
+            s, c = allreduce_sum_host(*host_mean_sample(self.y_host))
+            self.mean_y_hat = np.asarray(s / max(float(c), 1.0), self.dtype)
+            shape = (padded_rows(len(self.y_host), self.mesh, self.csize),)
+            self.row_start = {
+                dev: idx[0].indices(shape[0])[0]
+                for dev, idx in row_sharding(self.mesh).addressable_devices_indices_map(shape).items()
+            }
+        n, d = rows.shape
+        declined = gram_pallas_declined(n, d, self.dtype, device)
+        self.pallas = not declined
+        self.block = gram_block_rows(n, d, self.csize, self.dtype.itemsize)
+        self.attrs = {
+            "precision": "highest",
+            "rows_minor": jax.default_backend() == "tpu" and rows_minor(device, n, d, self.dtype),
+            "gram": "pallas" if self.pallas else "xla",
+            "tile": gram_tile(d)[0] if self.pallas else self.block,
+        }
+        if declined:
+            self.attrs["declined"] = declined
+
+    def _zeros(self, device):
+        return gram_fold_zeros(self.d, self.dtype, self.pallas, device, label=self.y_host is not None)
+
+    def __call__(self, state, rows: jax.Array, row0: int, valid: int):
+        (device,) = rows.devices()
+        if self.mean_hat is None:
+            self._first_call(rows, device)
+        if device not in self._on_device:
+            shifts = (self.mean_hat,) if self.y_host is None else (self.mean_hat, self.mean_y_hat)
+            self._on_device[device] = tuple(jax.device_put(a, device) for a in shifts)
+        if state is None:
+            state = self._zeros(device)
+        label = None
+        if self.y_host is not None:
+            lo = self.row_start[device] + row0
+            y = np.zeros((rows.shape[0],), self.dtype)
+            y[:valid] = self.y_host[lo:lo + valid]
+            label = (jax.device_put(y, device), self._on_device[device][1])
+        return gram_fold(
+            state, rows, self._on_device[device][0], np.int32(valid), label, pallas=self.pallas, block=self.block
+        )
+
+    def sums(self, states: dict) -> tuple:
+        """Every device's state as global arrays, stacked along axis 0 and
+        sharded over dp (a device that got no block: zeros)."""
+        mesh = self.mesh
+        sh = row_sharding(mesh)
+        per_device = [
+            states[dev] or self._zeros(dev)
+            for dev in sh.addressable_devices_indices_map((mesh.shape[DP_AXIS],))
+        ]
+        n_dp = mesh.shape[DP_AXIS]
+        return tuple(
+            jax.make_array_from_single_device_arrays(
+                (n_dp * parts[0].shape[0],) + parts[0].shape[1:], sh, list(parts)
+            )
+            for parts in zip(*per_device)
+        )
+
+
+def _fold_totals(Gl, sl, cl, d: int, pallas: bool):
+    """Inside a ``shard_map`` over dp: one device's :func:`gram_fold` state
+    summed over dp (a ``psum``, the communication of
+    :func:`mean_and_cov_chunked`) — ``(Gram (d, d), row sum (d,), count)``,
+    the triangle mirrored where the Pallas kernel left one."""
+    G, s, n = lax.psum(Gl, DP_AXIS), lax.psum(sl, DP_AXIS), lax.psum(cl[0], DP_AXIS)
+    if pallas:
+        G, s = _mirror_gram_triangle(G, s, d)
+    return G, s, n
 
 
 def cov_from_gram_folds(
     G: jax.Array, s: jax.Array, cnt: jax.Array, mean_hat: jax.Array, mesh, d: int, pallas: bool
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``(mean, covariance, count)``, replicated, from every device's
-    :func:`gram_fold` state stacked along axis 0 and sharded over dp: one
-    ``psum`` over dp (the communication of :func:`mean_and_cov_chunked`), the
-    triangle's mirror where the Pallas kernel left one, and the rank-one
-    correction. The frame is no operand of it."""
+    :func:`gram_fold` state stacked along axis 0 and sharded over dp:
+    :func:`_fold_totals` and the rank-one correction. The frame is no
+    operand of it."""
 
     def per_device(Gl, sl, cl, mu):
-        G, s, n = lax.psum(Gl, DP_AXIS), lax.psum(sl, DP_AXIS), lax.psum(cl[0], DP_AXIS)
-        if pallas:
-            G, s = _mirror_gram_triangle(G, s, d)
+        G, s, n = _fold_totals(Gl, sl, cl, d, pallas)
         return _recentre(G, s, n, mu) + (n,)
 
     return shard_map(
@@ -555,6 +679,31 @@ def cov_from_gram_folds(
         out_specs=(LAYOUT.replicated(),) * 3,
         check_vma=False,
     )(G, s, cnt, mean_hat)
+
+
+def moments_from_gram_folds(
+    G, s, cnt, xy, sy, yy, mean_hat, mean_y_hat, mesh, d: int, pallas: bool
+) -> Tuple[jax.Array, ...]:
+    """What :func:`cov_from_gram_folds` is to PCA, for least squares: from
+    every device's :func:`gram_fold` state WITH a label, replicated, ``(n,
+    mean of x, mean of y, Σ (x-x̄)(x-x̄)ᵀ, Σ (x-x̄)(y-ȳ), Σ (y-ȳ)²)`` — the
+    same psum, mirror and rank-one correction (:func:`_recentred`), and the
+    label's sums re-centred by the same exact identity."""
+
+    def per_device(Gl, sl, cl, xyl, syl, yyl, mu, mu_y):
+        G, s, n = _fold_totals(Gl, sl, cl, d, pallas)
+        xy, sy, yy = lax.psum(xyl, DP_AXIS), lax.psum(syl[0], DP_AXIS), lax.psum(yyl[0], DP_AXIS)
+        mean, Gc = _recentred(G, s, n, mu)
+        dx, dy = s / n, sy / n
+        return n, mean, mu_y + dy, Gc, xy - n * dx * dy, yy - n * dy * dy
+
+    return shard_map(
+        per_device,
+        mesh=mesh,
+        in_specs=(LAYOUT.rows(),) * 6 + (LAYOUT.replicated(),) * 2,
+        out_specs=(LAYOUT.replicated(),) * 6,
+        check_vma=False,
+    )(G, s, cnt, xy, sy, yy, mean_hat, mean_y_hat)
 
 
 def sign_flip(vectors: jax.Array) -> jax.Array:

@@ -6,12 +6,19 @@ TPU-native replacement for the reference's three cuML solver classes
 scaling, ``CDMG`` coordinate descent for elasticnet).
 
 Design: ONE distributed pass over the dp-sharded design matrix computes the
-weighted centered sufficient statistics (Gram d×d, X'y, y'y, moments) —
-XLA inserts the psum. Every solver then works on the replicated d×d
-system: OLS/ridge are a Cholesky solve, elasticnet is FISTA on the
-quadratic form — O(d²) per iteration with NO further data passes or
-collectives (cuML's CD re-reads the data every iteration; for the
-reference's d≈3000 benchmark shape this is strictly less communication).
+weighted centered sufficient statistics (Gram d×d, X'y, y'y, moments). A
+resident unweighted fit in one process folds them block by block under the
+frame's crossing with PCA's Gram (``ops.linalg.GramFold``, the label beside
+it) and :func:`_linreg_finish` turns the folds into them; every other fit
+takes one pass over the placed frame (:func:`linreg_suffstats`,
+:func:`linreg_suffstats_chunked`). Every product of X is float32
+(``Precision.HIGHEST``): the statistics are the result. Every solver then
+works on the replicated d×d system: OLS/ridge are a Cholesky solve with no
+added ridge (a least-norm eigen-solve where the system is singular),
+elasticnet is FISTA on the quadratic form — O(d²) per iteration with NO
+further data passes or collectives (cuML's CD re-reads the data every
+iteration; for the reference's d≈3000 benchmark shape this is strictly less
+communication).
 
 Spark objective parity: 1/(2n)·Σ wᵢ(yᵢ - x·β - b)² + λ[(1-α)/2‖β‖₂² + α‖β‖₁]
 with the penalty applied to standardized coefficients when
@@ -24,9 +31,15 @@ from __future__ import annotations
 import functools
 from typing import Dict, Optional, Tuple
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .linalg import moments_from_gram_folds
+
+_HIGHEST = lax.Precision.HIGHEST
 
 
 @functools.partial(jax.jit, static_argnames=("fit_intercept",))
@@ -55,8 +68,8 @@ def linreg_suffstats(
     sw = jnp.sqrt(w)
     Xc = (X - mean_x[None, :]) * sw[:, None]
     yc = (y - mean_y) * sw
-    G = Xc.T @ Xc
-    Xy = Xc.T @ yc
+    G = jnp.matmul(Xc.T, Xc, precision=_HIGHEST)
+    Xy = jnp.matmul(Xc.T, yc, precision=_HIGHEST)
     yy = (yc * yc).sum()
     # penalty scaling always uses the true (centered) variance, even when
     # fit_intercept=False leaves G uncentered: diag(G)/n is then E[x²], so
@@ -101,13 +114,11 @@ def linreg_suffstats_chunked(
 
     Requires per-device rows divisible by ``csize``; rows sharded over dp.
 
-    Note on Pallas: a hand-written tiled kernel for this accumulation
-    (HBM→VMEM row tiles, all seven accumulators VMEM-resident, both MXU
-    and VPU Xy variants, 8–16 MB tiles) measured AT PARITY with this scan
-    on v5e at 12M×256 (~97 ms vs ~99 ms, ~385 GB/s both) — unlike the PCA
-    covariance, where the Pallas gram kernel beats XLA ~1.9×. The scan is
-    kept as the single implementation; don't re-add a Pallas path here
-    without profiling past that result.
+    Which fits take it: a weighted fit, one of several processes, or one
+    whose Gram is column-blocked over mp; a resident unweighted fit in one
+    process folds its statistics under the frame's crossing through PCA's
+    Gram instead (``models.regression``). No chip reading of this scan at
+    ``Precision.HIGHEST`` exists; the benchmark's cell runs the fold.
 
     With ``mp_blocks`` (gate via ``ops.linalg.mp_gram_blocks`` — env read
     outside jit) the d×d Gram accumulates as each device's own (d, d/mp)
@@ -170,8 +181,8 @@ def linreg_suffstats_chunked(
                 sy + ((yv - mu_y) * w).sum(),           # Σ w (y-μ̂y)
                 vs + (xdw * xdw).sum(axis=0),           # Σ w (x-μ̂x)²
                 W + w.sum(),
-                G + xs.T @ xb,
-                Xy + xs.T @ ys,
+                G + jnp.matmul(xs.T, xb, precision=_HIGHEST),
+                Xy + jnp.matmul(xs.T, ys, precision=_HIGHEST),
                 yy + (ys * ys).sum(),
             )
 
@@ -238,28 +249,111 @@ def _to_standardized(stats: Dict[str, jax.Array], standardization: bool):
     return G, Xy, std, safe
 
 
+def _least_norm_host(A, b):
+    """The least-norm solution of ``A β = b`` (A symmetric positive
+    semi-definite) by LAPACK's ``eigh`` in float64 on the host, dropping the
+    eigenvalues under ``d·eps·λ_max`` (eps of A's dtype): cuML's ``eig``
+    algorithm, which the reference's ``LinearRegressionMG`` runs."""
+    a = np.asarray(A, np.float64)
+    w, V = np.linalg.eigh(0.5 * (a + a.T))
+    keep = w > a.shape[0] * np.finfo(np.asarray(A).dtype).eps * max(float(w.max()), 0.0)
+    Vk = V[:, keep]
+    return (Vk @ ((Vk.T @ np.asarray(b, np.float64)) / w[keep])).astype(np.asarray(b).dtype)
+
+
+def _normal_system(stats: Dict[str, jax.Array], l2: jax.Array, standardization: bool):
+    """``(A, b, std, safe)``: the normal equations ``A β = b`` with ``A =
+    G/n + λ₂I`` and ``b = Xy/n`` exactly as Spark states them — no ridge is
+    added. A column whose (centred) Gram entry is exactly zero is constant:
+    its row of the system is replaced by ``β_i = 0``, its least-norm value."""
+    n = stats["n"]
+    G, Xy, std, safe = _to_standardized(stats, standardization)
+    zero = jnp.where(jnp.diagonal(G) == 0, 1.0, 0.0).astype(G.dtype)
+    return G / n + jnp.diag(l2 + zero), Xy / n, std, safe
+
+
+def _coefficients(stats, beta, std, safe, standardization: bool):
+    """(coefficients in original scale, intercept) from the standardized solution."""
+    if standardization:
+        beta = jnp.where(std > 0, beta / safe, 0.0)
+    return beta, stats["mean_y"] - jnp.matmul(stats["mean_x"], beta, precision=_HIGHEST)
+
+
+def _normal_solve(
+    stats: Dict[str, jax.Array], l2: jax.Array, standardization: bool
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Closed-form OLS/ridge by Cholesky of :func:`_normal_system` on the
+    device; XLA's factor and triangular solves, their products at HIGHEST.
+    Returns (coefficients, intercept, declined): ``declined`` where the
+    factor is not finite or a pivot² falls under ``d·eps·max(diag)``
+    (exactly collinear columns) — the caller then runs
+    :func:`solve_least_norm` on the same statistics, and the coefficients
+    here are not to be used."""
+    A, b, std, safe = _normal_system(stats, l2, standardization)
+    L = jnp.linalg.cholesky(A)
+    floor = A.shape[0] * jnp.finfo(A.dtype).eps * jnp.diagonal(A).max()
+    declined = ~(jnp.isfinite(L).all() & (jnp.diagonal(L).min() ** 2 > floor))
+    beta = jax.scipy.linalg.cho_solve((L, True), b)
+    return _coefficients(stats, beta, std, safe, standardization) + (declined,)
+
+
 @functools.partial(jax.jit, static_argnames=("standardization",))
 def solve_normal(
     stats: Dict[str, jax.Array], l2: jax.Array, *, standardization: bool
-) -> Tuple[jax.Array, jax.Array]:
-    """Closed-form OLS/ridge: (G/n + λ₂I) β = Xy/n, Cholesky on device.
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`_normal_solve` as its own program, on statistics already made
+    (a ``fitMultiple``'s later lanes, the streaming and weighted fits).
+    Replaces the reference's eig solver path (``regression.py:502-559``)."""
+    return _normal_solve(stats, l2, standardization)
 
-    Replaces the reference's eig solver path (``regression.py:502-559``).
-    Returns (coefficients in original scale, intercept).
-    """
-    n = stats["n"]
-    G, Xy, std, safe = _to_standardized(stats, standardization)
-    d = G.shape[0]
-    A = G / n + l2 * jnp.eye(d, dtype=G.dtype)
-    # dtype-scaled jitter keeps Cholesky PD for exactly-collinear features
-    # (a fixed 1e-10 underflows in f32 against a unit-scale diagonal)
-    jitter = jnp.finfo(G.dtype).eps * jnp.trace(A)
-    A = A + jitter * jnp.eye(d, dtype=G.dtype)
-    beta = jax.scipy.linalg.cho_solve(jax.scipy.linalg.cho_factor(A), Xy / n)
-    if standardization:
-        beta = jnp.where(std > 0, beta / safe, 0.0)
-    intercept = stats["mean_y"] - stats["mean_x"] @ beta
-    return beta, intercept
+
+@functools.partial(jax.jit, static_argnames=("standardization",))
+def solve_least_norm(
+    stats: Dict[str, jax.Array], l2: jax.Array, *, standardization: bool
+) -> Tuple[jax.Array, jax.Array]:
+    """The same system's least-norm solution, where the Cholesky declined:
+    :func:`_least_norm_host`, LAPACK on the host. A program of its own,
+    dispatched only then: a program with a host callback is never written
+    to JAX's persistent compilation cache, so inside the finish it would be
+    compiled again in every process, and a TPU compiles
+    ``jnp.linalg.eigh`` at d = 3000 for minutes (PERF.md section 6)."""
+    A, b, std, safe = _normal_system(stats, l2, standardization)
+    beta = jax.pure_callback(_least_norm_host, jax.ShapeDtypeStruct(b.shape, b.dtype), A, b)
+    return _coefficients(stats, beta, std, safe, standardization)
+
+
+def _stats_from_moments(n, mean_x, mean_y, Gc, Xyc, yyc, fit_intercept: bool) -> Dict[str, jax.Array]:
+    """The solvers' statistics from the centred moments: centred where the
+    fit has an intercept, else uncentred by the exact rank-one identity
+    ``Σ x xᵀ = Σ (x-x̄)(x-x̄)ᵀ + n·x̄x̄ᵀ``; the penalty's variance is the
+    centred one either way (Spark's)."""
+    var = jnp.diagonal(Gc) / n
+    if fit_intercept:
+        return {"n": n, "mean_x": mean_x, "mean_y": mean_y, "G": Gc, "Xy": Xyc, "yy": yyc, "var": var}
+    return {
+        "n": n, "mean_x": jnp.zeros_like(mean_x), "mean_y": jnp.zeros_like(mean_y),
+        "G": Gc + n * jnp.outer(mean_x, mean_x), "Xy": Xyc + n * mean_x * mean_y,
+        "yy": yyc + n * mean_y * mean_y, "var": var,
+    }
+
+
+@functools.partial(
+    jax.jit, static_argnames=("mesh", "d", "pallas", "fit_intercept", "standardization", "solve")
+)
+def _linreg_finish(
+    folds, mean_hat, mean_y_hat, l2, *, mesh, d: int, pallas: bool,
+    fit_intercept: bool, standardization: bool, solve: bool,
+):
+    """What is left of a least-squares fit when the last row block has been
+    folded (``ops.linalg.GramFold`` with a label): the psum over dp, the
+    mirror and the rank-one re-centring (``moments_from_gram_folds``, PCA's
+    steps), the statistics, and with ``solve`` :func:`_normal_solve`. Takes
+    the accumulators, not the frame. Returns ``(statistics, (coefficients,
+    intercept, declined) or None)``: the statistics serve a ``fitMultiple``'s
+    later lanes, the elastic-net solver and :func:`solve_least_norm`."""
+    moments = moments_from_gram_folds(*folds, mean_hat, mean_y_hat, mesh, d, pallas)
+    stats = _stats_from_moments(*moments, fit_intercept)
+    return stats, (_normal_solve(stats, l2, standardization) if solve else None)
 
 
 @functools.partial(jax.jit, static_argnames=("standardization", "max_iter"))
@@ -323,7 +417,7 @@ def solve_elasticnet(
     beta, _, _, it, _ = lax.while_loop(cond, body, state)
     if standardization:
         beta = jnp.where(std > 0, beta / safe, 0.0)
-    intercept = stats["mean_y"] - stats["mean_x"] @ beta
+    intercept = stats["mean_y"] - jnp.matmul(stats["mean_x"], beta, precision=_HIGHEST)
     return beta, intercept, it
 
 
@@ -399,5 +493,5 @@ def solve_elasticnet_batched(
     beta, _, _, it, _ = lax.while_loop(cond, body, state)
     if standardization:
         beta = jnp.where((std > 0)[None, :], beta / safe[None, :], 0.0)
-    intercept = stats["mean_y"] - beta @ stats["mean_x"]
+    intercept = stats["mean_y"] - jnp.matmul(beta, stats["mean_x"], precision=_HIGHEST)
     return beta, intercept, it

@@ -395,6 +395,12 @@ def _put_row_blocks(
     return jax.make_array_from_single_device_arrays(shape, sh, list(bufs.values())), len(puts), states
 
 
+def padded_rows(n: int, mesh: Mesh, row_multiple: int = 1) -> int:
+    """Rows of the global array ``shard_rows`` makes of ``n`` host rows in
+    one process: every device's shard the same, a multiple of ``row_multiple``."""
+    return n + (-n) % (mesh.shape[DP_AXIS] * row_multiple)
+
+
 def shard_rows(
     x: np.ndarray,
     mesh: Mesh,
@@ -449,7 +455,7 @@ def shard_rows(
     n_dp = mesh.shape[DP_AXIS]
     sh = row_sharding(mesh)
     n = x.shape[0]
-    n_padded = n + (-n) % (n_dp * row_multiple)
+    n_padded = padded_rows(n, mesh, row_multiple)
     row_bytes = x.dtype.itemsize * int(np.prod(x.shape[1:]))
     block_rows = _put_block_rows(row_bytes)
     if not pad_cols and n_padded // n_dp <= block_rows:

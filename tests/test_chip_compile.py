@@ -163,6 +163,48 @@ def test_pca_finish_takes_the_accumulators_not_the_frame(topo, no_compile_cache,
     assert ("all-reduce" in c.as_text()) == (chips > 1)
 
 
+def test_linreg_fold_and_finish_compile_at_the_cells_shapes(topo, one_chip, monkeypatch):
+    """``linreg_dbx``'s fit, two programs: the fold of a 65,536 x 3000 block
+    WITH its label — PCA's kernel over the block's transposed view, the
+    label's sums beside it, no relaid copy of the 786 MB block — and the
+    finish at d = 3000 — psum, mirror, re-centring, the Cholesky factor and
+    its triangular solves — every product of which is float32 (HIGHEST),
+    whose operands are the accumulators, not the frame, and which holds no
+    host transfer (the least-norm fall-back's host ``eigh`` is a program of
+    its own: one with a host callback is never written to the persistent
+    compilation cache)."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.ops import linalg
+    from spark_rapids_ml_tpu.ops.linreg_kernels import _linreg_finish
+
+    d, rows = 3000, 65_536
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the gate
+    acc = (one_chip((6, 512, 3072)), one_chip((3072, 128)), one_chip((1,)), one_chip((d,)), one_chip((1,)), one_chip((1,)))
+    label = (one_chip((rows,)), one_chip(()))
+    c = linalg.gram_fold.lower(acc, one_chip((rows, d)), one_chip((d,)), one_chip((), I32), label, pallas=True, block=0).compile()
+    assert _has_kernel(c)
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < (80 << 20)
+    assert mem.alias_size_in_bytes >= 6 * 512 * 3072 * 4
+
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "mp"))
+    rows_of = lambda *shape: jax.ShapeDtypeStruct(shape, F32, sharding=NamedSharding(mesh, P("dp")))
+    rep = lambda *shape: jax.ShapeDtypeStruct(shape, F32, sharding=NamedSharding(mesh, P()))
+    folds = (rows_of(6, 512, 3072), rows_of(3072, 128), rows_of(1), rows_of(d), rows_of(1), rows_of(1))
+    lowered = _linreg_finish.lower(
+        folds, rep(d), rep(), rep(), mesh=mesh, d=d, pallas=True, fit_intercept=True, standardization=True, solve=True
+    )
+    assert "is_host_transfer = true" not in lowered.as_text()
+    c = lowered.compile()
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes < (48 << 20)
+    products = [ln for ln in c.as_text().splitlines() if " convolution(" in ln or " dot(" in ln]
+    assert products and all(re.search(r"operand_precision=\{highest,highest\}", ln) for ln in products)
+
+
 def test_pca_fit_at_a_row_major_shard_takes_xlas_pass(topo, no_compile_cache, monkeypatch):
     """``chip_smoke.py``'s 4,194,304 x 256 lives row-major, where the kernel
     would get a relayout of the whole frame in front of it: the gate says

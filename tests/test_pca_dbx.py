@@ -305,7 +305,6 @@ def test_folded_covariance_against_the_float64_covariance(monkeypatch, pallas):
     """``gram_fold`` over the blocks of ``shard_rows`` and its finish, as the
     estimator strings them, against numpy's float64 covariance — the check of
     ``test_gram_pass_against_the_float64_covariance`` on the sorted frame."""
-    from spark_rapids_ml_tpu.models.feature import _GramFold
     from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
     from spark_rapids_ml_tpu.parallel.mesh import make_mesh, shard_rows
 
@@ -316,11 +315,11 @@ def test_folded_covariance_against_the_float64_covariance(monkeypatch, pallas):
     monkeypatch.setattr(linalg, "FORCE_INTERPRET", pallas)
     jax.clear_caches()  # FORCE_INTERPRET is read at trace time, not cached
     try:
-        fold = _GramFold(X, 563)
+        fold = linalg.GramFold(X, mesh, 563)
         _, _, states = shard_rows(X, mesh, 563, fold=fold)
         assert fold.pallas == pallas and fold.attrs["gram"] == ("pallas" if pallas else "xla")
         finish = jax.jit(lambda G, s, c, mu: linalg.cov_from_gram_folds(G, s, c, mu, mesh, d, pallas))
-        mean, cov, cnt = finish(*fold.sums(states, mesh), fold.mean_hat)
+        mean, cov, cnt = finish(*fold.sums(states), fold.mean_hat)
     finally:
         jax.clear_caches()
     x64 = X.astype(np.float64)

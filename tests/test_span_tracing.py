@@ -290,9 +290,11 @@ def test_linear_regression_gets_the_same_pair():
     dis = _one(spans, "fit.dispatch")
     launch, fetch = _one(spans, "solver.launch"), _one(spans, "solver.fetch")
     assert launch["args"]["parent_id"] == fetch["args"]["parent_id"] == dis["args"]["span_id"]
-    assert launch["args"]["program"].split(",")[-1] == "solve_normal"
-    assert launch["args"]["program"].split(",")[0].startswith("linreg_suffstats")
-    assert fetch["args"]["n_iter"] == 1
+    # the frame is placed with its Gram and label sums folded under the
+    # crossing, and one finish program solves
+    assert launch["args"]["program"] == "gram_fold,_linreg_finish"
+    assert launch["args"]["gram_under_put"] is True and launch["args"]["precision"] == "highest"
+    assert fetch["args"]["n_iter"] == 1 and fetch["args"]["solve"] == "cholesky"
 
 
 # --- (c) the evaluation count -------------------------------------------------
