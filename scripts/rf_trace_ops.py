@@ -24,6 +24,8 @@ from chipbench import trace_reduce as tr  # noqa: E402
 def kind(name: str, hlo: str) -> str:
     if name.startswith("rf_hist"):
         return "2 histogram kernel (rf_hist_sel_pass / rf_hist_pass)"
+    if name.startswith("rf_binize"):
+        return "0 not the growth (crossing, sketch, binize)"
     m = re.search(r"= \(?([a-z0-9]+)\[([0-9,]*)\]", hlo)
     if not m:
         return "9 the rest"

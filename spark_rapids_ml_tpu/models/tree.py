@@ -58,6 +58,7 @@ from ..ops.tree_kernels import (
     resolve_tree_batch,
     ForestConfig,
     binize,
+    binize_plan,
     build_forest,
     max_nodes,
     next_pow2,
@@ -264,8 +265,11 @@ def _quantize_features(inputs: "FitInputs", n_bins: int, d_pad: int, algo: str):
             "impute before fit (binize would route non-finite "
             "values to bin 0)"
         )
-    with telemetry.span("forest.binize", cols=int(d_pad)):
-        bins = binize(inputs.X, jnp.asarray(edges_np), d_pad=d_pad)
+    X = inputs.X
+    devices = X.sharding.device_set
+    plan = binize_plan(X.shape, X.dtype, edges_np.shape[1], d_pad, len(devices), next(iter(devices)))
+    with telemetry.span("forest.binize", cols=int(d_pad), **plan.span_attrs()):
+        bins = binize(X, jnp.asarray(edges_np), d_pad=d_pad, plan=plan)
     return edges_np, bins
 
 
@@ -696,8 +700,6 @@ class _ForestModelBase(_TpuModel):
     def _make_binize_for_apply(self) -> Callable[[np.ndarray], jax.Array]:
         """Per-batch quantizer with the edges table hoisted device-side
         ONCE (a streaming transform calls the returned fn per batch)."""
-        from ..ops.tree_kernels import binize
-
         edges = jnp.asarray(np.asarray(self._model_attributes["bin_edges"]))
         d = edges.shape[0]
         d_pad = -(-d // 4) * 4  # word-packing alignment
@@ -717,8 +719,11 @@ class _ForestModelBase(_TpuModel):
             return _binz
 
         def _binz_batch(Xb):
-            with telemetry.span("forest.binize_batch", rows=int(Xb.shape[0])):
-                return binize(jnp.asarray(Xb), edges, d_pad=d_pad)
+            with telemetry.span("forest.binize_batch", rows=int(Xb.shape[0])) as sp:
+                Xd = jnp.asarray(Xb)
+                plan = binize_plan(Xd.shape, Xd.dtype, edges.shape[1], d_pad)
+                sp.set_attr(**plan.span_attrs())
+                return binize(Xd, edges, d_pad=d_pad, plan=plan)
 
         return _binz_batch
 

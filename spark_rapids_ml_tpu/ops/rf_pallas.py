@@ -781,6 +781,136 @@ def subblock_hist_sel_batched(
 
 
 # ---------------------------------------------------------------------------
+# the quantizer: a frame's values to their bins, read as the device keeps it
+# ---------------------------------------------------------------------------
+
+# a grid step of the quantizer: at most this many rows (lanes of the frame's
+# transpose) by this many columns (a lane tile of row-major bins, four packed
+# uint8 sublane tiles of rows-minor ones)
+BINIZE_ROWS = 2048
+BINIZE_COLS = 128
+_BINIZE_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def binize_tile(n: int) -> tuple[int, int, int]:
+    """``(rows, columns, vmem bytes)`` of a grid step of
+    :func:`rf_binize_pass` over ``n`` rows: the frame's block and the bins'
+    block two buffers each (the pipeline's), the edges' block two."""
+    rows = min(BINIZE_ROWS, -(-n // 128) * 128)
+    need = 2 * BINIZE_COLS * rows * (4 + 1) + 2 * BINIZE_COLS * 128 * 4
+    return rows, BINIZE_COLS, need
+
+
+def rf_binize_declined(
+    n_local: int, d: int, n_edges: int, dtype, devices: int = 1, device=None
+) -> str:
+    """The failing terms of :func:`rf_binize_pass`'s gate, comma-joined
+    (empty: admitted). A TPU; f32 values; at most 127 edges a column (one
+    lane tile holds a column's edges); a frame on one device (the kernel is
+    one device's program: a row-sharded frame keeps XLA's pass, which XLA
+    partitions); a grid step inside VMEM; at least one column tile; and a
+    frame the device keeps with its ROWS minor (``linalg.rows_minor``: a
+    width that is no multiple of 128), which the kernel reads as its
+    transpose, a bitcast — a row-major frame would get a relayout of the
+    whole frame in front of it. A pure function of its arguments and the
+    backend: the forest says on its ``forest.binize`` and
+    ``forest.binize_batch`` spans which pass ran, and why."""
+    terms = [
+        ("dtype", dtype == jnp.float32),
+        ("edges<=127", n_edges <= 127),
+        ("one_device", devices == 1),
+        ("vmem", binize_tile(n_local)[2] <= _BINIZE_VMEM_LIMIT),
+        ("cols>=128", d >= BINIZE_COLS),
+    ]
+    if not FORCE_INTERPRET:
+        from .linalg import rows_minor
+
+        on_tpu = jax.default_backend() == "tpu"
+        terms.insert(0, ("backend", on_tpu))
+        terms.append(("rows_minor", on_tpu and rows_minor(device or jax.devices()[0], n_local, d)))
+    return ",".join(name for name, ok in terms if not ok)
+
+
+def rf_binize_pass(
+    X: jax.Array, edges: jax.Array, *, d_pad: int, rows_minor_out: bool,
+    interpret: bool = False,
+) -> jax.Array:
+    """(n, d) f32 x (d, n_edges) -> (n, d_pad) uint8: ``#{edges <= x}`` per
+    value, 0 in the pad columns — the count of ``tree_kernels.binize``, bit
+    for bit, in one read of the frame.
+
+    The frame is read as its (d, n) transpose, the bytes of a rows-minor
+    frame: a grid step takes a (128 columns, rows) block, so a vreg holds 8
+    columns of 128 rows. A column's edges are sorted into one lane tile of
+    the (columns, 128) table (NaN past the last edge and in the pad
+    columns); the count is then a search of that sorted row — ``pos += s``
+    where ``table[pos + s - 1] <= x``, for s = 64 .. 1 — seven lane gathers
+    (``take_along_axis`` within a vreg), compares and selects a vreg where
+    the compare-count is 127 compares and adds. Sorting moves no count (a
+    count does not see the edges' order); on a sorted row ``e <= x`` is true
+    then false, and a NaN edge or value is never ``<=``, so NaN lands in
+    bin 0 as the count puts it. The bins are written at their default
+    layout: rows minor (``rows_minor_out``) as the (d_pad, n) transpose, a
+    bitcast of the result; row-major through one transpose of a (128, 128)
+    int32 block of counts a lane tile."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = X.shape
+    n_edges = edges.shape[1]
+    rows, cols, _ = binize_tile(n)
+    steps = tuple(1 << i for i in reversed(range(n_edges.bit_length())))
+    col_blocks = pl.cdiv(d_pad, cols)
+    last_x_block = pl.cdiv(d, cols) - 1
+    table = jnp.full((col_blocks * cols, 128), jnp.nan, jnp.float32)
+    table = table.at[:d, :n_edges].set(jnp.sort(edges.astype(jnp.float32), axis=1))
+
+    def kern(e_ref, x_ref, o_ref):
+        groups = [e_ref[g * 8:(g + 1) * 8, :] for g in range(cols // 8)]
+
+        def lane_tile(t, _):
+            lanes = pl.ds(pl.multiple_of(t * 128, 128), 128)
+            counts = []
+            for g, row in enumerate(groups):
+                x = x_ref[g * 8:(g + 1) * 8, lanes]
+                pos = jnp.zeros((8, 128), jnp.int32)
+                for s in steps:
+                    e = jnp.take_along_axis(row, pos + (s - 1), axis=1, mode="promise_in_bounds")
+                    pos = jnp.where(e <= x, pos + s, pos)
+                counts.append(pos)
+            c = jnp.concatenate(counts, axis=0)
+            if rows_minor_out:
+                o_ref[:, lanes] = c.astype(jnp.uint8)
+            else:
+                o_ref[lanes, :] = c.T.astype(jnp.uint8)
+
+        lax.fori_loop(0, rows // 128, lane_tile, None)
+
+    if rows_minor_out:
+        out_shape, out_spec = (d_pad, n), pl.BlockSpec((cols, rows), lambda i, j: (i, j))
+    else:
+        out_shape, out_spec = (n, d_pad), pl.BlockSpec((rows, cols), lambda i, j: (j, i))
+    out = pl.pallas_call(
+        kern,
+        grid=(col_blocks, pl.cdiv(n, rows)),
+        in_specs=[
+            pl.BlockSpec((cols, 128), lambda i, j: (i, 0)),
+            # a pad column block past the frame reads its last block: the
+            # table's NaN rows count it 0 whatever it holds
+            pl.BlockSpec((cols, rows), lambda i, j: (jnp.minimum(i, last_x_block), j)),
+        ],
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.uint8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_BINIZE_VMEM_LIMIT
+        ),
+        name="rf_binize_pass",
+        interpret=interpret,
+    )(table, X.T)
+    return out.T if rows_minor_out else out
+
+
+# ---------------------------------------------------------------------------
 # packed-forest lockstep traversal (inference): hop-2 of the two-hop
 # descent for ALL trees fused into one kernel per row block
 # ---------------------------------------------------------------------------

@@ -310,17 +310,60 @@ def quantile_edges(X: jax.Array, mask: jax.Array, *, n_bins: int):
     return edges.T, finite
 
 
-@functools.partial(jax.jit, static_argnames=("d_pad",))
-def binize(X: jax.Array, edges: jax.Array, *, d_pad: int) -> jax.Array:
+class BinizePlan(NamedTuple):
+    """Which pass :func:`binize` runs at a shape, and why: ``kernel``
+    ``"pallas"`` (``rf_pallas.rf_binize_pass``) or ``"xla"``; ``tile`` the
+    kernel's rows x columns a grid step, or XLA's chunk of columns;
+    ``declined`` the kernel gate's failing terms; ``rows_minor_out`` whether
+    the device keeps the bins with their rows minor (the kernel writes them
+    so)."""
+
+    kernel: str
+    tile: str
+    declined: str
+    rows_minor_out: bool
+
+    def span_attrs(self) -> dict:
+        return {"kernel": self.kernel, "tile": self.tile, "declined": self.declined}
+
+
+def _binize_chunk(n: int, d: int) -> int:
+    """Columns of one compare-count chunk of XLA's pass: the (n, chunk, nb)
+    compare held near 4M elements, in whole 8-column sublane tiles (a chunk
+    of 39 put every later chunk of a rows-minor batch mid-tile)."""
+    return min(d, max(8, (1 << 22) // max(n, 1) // 8 * 8))
+
+
+def binize_plan(shape, dtype, n_edges: int, d_pad: int, devices: int = 1, device=None) -> BinizePlan:
+    """The :class:`BinizePlan` of an ``(n, d)`` frame on ``devices`` devices
+    (the first of them ``device``) at ``d_pad`` bin columns."""
+    from .linalg import rows_minor
+    from .rf_pallas import binize_tile, rf_binize_declined
+
+    n, d = shape
+    declined = rf_binize_declined(n, d, n_edges, dtype, devices, device)
+    if declined:
+        return BinizePlan("xla", str(_binize_chunk(n, d)), declined, False)
+    rows, cols, _ = binize_tile(n)
+    out_minor = rows_minor(device or jax.devices()[0], n, d_pad, jnp.uint8)
+    return BinizePlan("pallas", f"{rows}x{cols}", "", out_minor)
+
+
+@functools.partial(jax.jit, static_argnames=("d_pad", "plan"))
+def binize(X: jax.Array, edges: jax.Array, *, d_pad: int, plan: BinizePlan | None = None) -> jax.Array:
     """Quantize rows to bins: (n, d) x (d, nb-1) -> (n, d_pad) uint8.
 
-    bin = #{edges <= x}, computed as a broadcast compare-count in feature
-    chunks — the searchsorted formulation lowers to a per-element binary
-    search (~n*d*log(nb) serialized gathers, seconds at 131k x 256) while
-    the compare-count is a fused VPU reduction (n*d*nb compare-adds,
-    ~ms). Elementwise along rows, so XLA keeps the dp row sharding.
-    Padding features (d..d_pad) get bin 0 and are masked out of split
-    search.
+    bin = #{edges <= x}. ``plan`` (:func:`binize_plan`; asked of the shape
+    on the default device where None) says which pass counts it. On a TPU,
+    a rows-minor f32 frame takes ``rf_pallas.rf_binize_pass``: one read of
+    the frame as its transpose, a search of each column's sorted edges.
+    Elsewhere XLA's broadcast compare-count in column chunks
+    (:func:`_binize_chunk`) — the searchsorted formulation lowers to a
+    per-element binary search (~n*d*log(nb) serialized gathers, seconds at
+    131k x 256) while the compare-count is a fused VPU reduction (n*d*nb
+    compare-adds, ~ms). Elementwise along rows, so XLA keeps the dp row
+    sharding. Both give the same bins, bit for bit. Padding features
+    (d..d_pad) get bin 0 and are masked out of split search.
 
     Input contract — FINITE values only. NaN compares false against every
     edge, so a NaN lands in bin 0 (the leftmost child everywhere below),
@@ -333,7 +376,15 @@ def binize(X: jax.Array, edges: jax.Array, *, d_pad: int) -> jax.Array:
     per-element isnan pass here on the hot path.
     """
     n, d = X.shape
-    Fc = max(1, min(d, (1 << 22) // max(n, 1)))  # bound the (n,Fc,nb) tile
+    if plan is None:
+        plan = binize_plan(X.shape, X.dtype, edges.shape[1], d_pad)
+    if plan.kernel == "pallas":
+        from .rf_pallas import FORCE_INTERPRET, rf_binize_pass
+
+        return rf_binize_pass(
+            X, edges, d_pad=d_pad, rows_minor_out=plan.rows_minor_out, interpret=FORCE_INTERPRET
+        )
+    Fc = int(plan.tile)
     parts = []
     for c0 in range(0, d, Fc):
         xc = X[:, c0 : c0 + Fc]                       # (n, fc)

@@ -541,6 +541,37 @@ def test_rf_sketch_reads_the_reference_frame_in_place(one_chip):
     assert c.memory_analysis().output_size_in_bytes < 2 * cols * 127 * 4
 
 
+@pytest.mark.parametrize(
+    "rows,d_pad,rows_minor_bins",
+    [(500_000, 3072, False), (131_072, 3000, True), (106_784, 3000, True)],
+    ids=["fit", "full_batch", "tail_batch"],
+)
+def test_rf_binize_compiles_at_the_cells_shapes(topo, one_chip, monkeypatch, rows, d_pad, rows_minor_bins):
+    """The forests' ``binize`` at the shapes ``rf_dbx`` / ``rf_reg_dbx`` run:
+    the fit's 500,000 x 3000 frame to 3072 bin columns, a transform batch of
+    131,072 rows and the tail of 106,784 to 3000. Each frame is rows minor;
+    the gate admits it, and the one program is the Pallas quantizer reading
+    the frame as its transpose (a bitcast) — no f32 operand of the frame's
+    size, so no relaid copy of it — and writing the bins at their default
+    layout (row-major at 3072, rows minor at 3000: a bitcast of the kernel's
+    transposed result). XLA's unrolled compare-count chunks hold 1.85 GB of
+    temporaries at the fit; this program holds none of the frame's size."""
+    from spark_rapids_ml_tpu.ops import linalg, tree_kernels as tk
+
+    cols = 3000
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the gate
+    assert linalg.rows_minor(topo.devices[0], rows, cols)
+    assert linalg.rows_minor(topo.devices[0], rows, d_pad, jnp.uint8) == rows_minor_bins
+    plan = tk.binize_plan((rows, cols), F32, 127, d_pad, 1, topo.devices[0])
+    assert (plan.kernel, plan.declined, plan.tile, plan.rows_minor_out) == ("pallas", "", "2048x128", rows_minor_bins)
+    c = tk.binize.lower(one_chip((rows, cols)), one_chip((cols, 127)), d_pad=d_pad, plan=plan).compile()
+    txt = c.as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1 and "rf_binize_pass" in txt
+    assert f"f32[{cols},{rows}]{{1,0" in txt                 # the frame read as its transpose
+    assert f"f32[{rows},{cols}]{{1,0" not in txt             # and never relaid row-major
+    assert c.memory_analysis().temp_size_in_bytes < (16 << 20)
+
+
 def test_rf_packed_traverse_kernel_compiles(one_chip):
     """Depth 13 (k1=7, k2=6), d=256 (64 packed words), 131,072 rows. The
     kernel unrolls a static loop over every tree and its compile time grows
