@@ -104,11 +104,11 @@ def main():
 
     # 2. full current-code tree build at bench shape (ground truth)
     from spark_rapids_ml_tpu.ops.tree_kernels import (
-        ForestConfig, _build_tree, next_pow2,
+        ForestConfig, _build_tree, _pack_bins, next_pow2,
     )
 
     d = 256
-    bins = jnp.asarray(rng.integers(0, NB, size=(N, d)), jnp.uint8)
+    bins = _pack_bins(jnp.asarray(rng.integers(0, NB, size=(N, d)), jnp.uint8))
     stats = jnp.asarray(
         np.stack([rng.random(N), rng.random(N)], axis=1), jnp.float32
     )

@@ -52,6 +52,7 @@ def main() -> None:
 
     from spark_rapids_ml_tpu.models.tree import _resolve_k_features
     from spark_rapids_ml_tpu.ops.tree_kernels import (
+        _pack_bins,
         ForestConfig,
         build_forest,
         next_pow2,
@@ -110,7 +111,7 @@ def main() -> None:
         )
         y = (blk @ w > 0).astype(jnp.float32)
         b = jnp.searchsorted(edges, blk, side="right").astype(jnp.uint8)
-        b = jnp.pad(b, ((0, 0), (0, d_pad - d)))
+        b = _pack_bins(jnp.pad(b, ((0, 0), (0, d_pad - d))))
         return b, jnp.stack([1.0 - y, y], axis=1)
 
     gen_piece = jax.jit(_piece, out_shardings=(sh, sh))
@@ -119,8 +120,8 @@ def main() -> None:
     def place(ba, piece, i):
         return lax.dynamic_update_slice_in_dim(ba, piece, i * gchunk, 0)
 
-    zeros_u8 = jax.jit(
-        lambda: jnp.zeros((n_pad, d_pad), jnp.uint8), out_shardings=sh
+    zeros_u8 = jax.jit(  # the bins four to an int32 word, as a fit holds them
+        lambda: jnp.zeros((n_pad, d_pad // 4), jnp.int32), out_shardings=sh
     )
     zeros_f32 = jax.jit(
         lambda: jnp.zeros((n_pad, 2), jnp.float32), out_shardings=sh

@@ -33,8 +33,8 @@ def kind(name: str, hlo: str) -> str:
     rows = lambda x: x >= 400_000 or x == 16384  # noqa: E731  a level's padded rows, or a chunk of them
     if any(k in hlo for k in ("f32[500000,3000]", "f32[131072", "f32[65536,3000]")):
         return "0 not the growth (crossing, sketch, binize)"
-    if dt in ("u8", "bf16") and len(d) == 2 and d[1] >= 1024 and rows(d[0]):
-        return "1 whole-row gather of the bins (the wide selection: with its bf16 cast, forest.wide_rows)"
+    if dt in ("u8", "bf16", "s32") and len(d) == 2 and d[1] >= 512 and rows(d[0]):
+        return "1 whole-row gather of the bins (bytes, or words four bins to a word; the wide selection's: forest.wide_rows)"
     if dt == "bf16" and d == [16, 16384]:
         return "5b the statistics' exact three-way bf16 split (forest.wide_rows)"
     if dt == "f32" and len(d) >= 3 and d[-1] >= 16384:
@@ -44,7 +44,8 @@ def kind(name: str, hlo: str) -> str:
             return "4 partials relaid (reshape)"
         if name.startswith(("fusion", "broadcast")):
             return "3 partials' per-node sums (segment_sum / scatter-add, its zero fill)"
-    if dt == "u8" and len(d) == 1 and rows(d[0]):
+    words_operand = re.search(r"s32\[[0-9]{6,},[0-9]+\]", hlo)   # the bins as words
+    if (dt == "u8" or (dt == "s32" and words_operand)) and len(d) == 1 and rows(d[0]):
         return "6 routing: the split feature's bin a row (element gather)"
     if dt in ("pred", "s32") and d == [500000] and name.startswith("fusion"):
         return "7 routing: the tables a row and the move (gathers or a scatter)"

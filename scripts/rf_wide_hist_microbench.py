@@ -10,7 +10,8 @@ three float32 statistics), timed four ways (PERF.md section 6, PRs 39, 40):
 
 * ``kernel``: ``rf_pallas.subblock_hist_sel_wide`` as the level runs it — the
   slot tile outermost (a block fetched once a tile), a tile's selection over
-  its window of columns;
+  its window of columns, the rows as int32 words four bins to a word (the
+  three variants below read bf16 rows);
 * ``parent``: PR 39's kernel — the same grid, every tile's selection one
   product over all 3072 columns;
 * ``block_once_full_width``: the block read once (grid (blocks, tiles), the
@@ -161,6 +162,9 @@ def main() -> int:
     out["rows_gather_u8_ms"] = 1e3 * timed(jax.jit(lambda b, i: b[i]), bins, ids)
     out["split_ms"] = 1e3 * timed(jax.jit(rp.split_f32_exact), swT)
     rows_bf, parts = rows_of(bins, ids), rp.split_f32_exact(swT)
+    from spark_rapids_ml_tpu.ops.tree_kernels import _pack_bins
+
+    rows_words = jax.jit(_pack_bins)(bins)[ids]   # the program's kernel reads the rows as words
 
     def hbm_bytes(k, block_major):
         """Bytes a call moves, from its grid: a block's bf16 rows and its 16
@@ -181,7 +185,7 @@ def main() -> int:
         feats_b = jnp.asarray(feats)[nodes]
         acc = jnp.zeros((n_nodes, rp.WIDE_STAT_ROWS, k * nb), jnp.float32)
         if variant is None:
-            step = lambda a, fb=feats_b: rp.subblock_hist_sel_wide(rows_bf, fb, parts, nodes, live, a, n_bins=nb)  # noqa: E731
+            step = lambda a, fb=feats_b: rp.subblock_hist_sel_wide(rows_words, fb, parts, nodes, live, a, n_bins=nb)  # noqa: E731
         else:
             step = jax.jit(functools.partial(_variant, rows_bf, feats_b, parts, nodes, live, n_bins=nb,
                                              block_major=variant[0], windowed=variant[1]), donate_argnums=0)
@@ -226,7 +230,7 @@ def main() -> int:
         lambda: subblock_hist(binq, swT, None, n_bins=nb, r_sub=64, variance=True, transposed_sw=True)
     )
     print(json.dumps(out), flush=True)
-    del bins, rows_bf
+    del bins, rows_bf, rows_words
 
     if args.fit:
         from chipbench.data import gen_data

@@ -59,6 +59,7 @@ from ..ops.tree_kernels import (
     ForestConfig,
     binize,
     binize_plan,
+    bins_layout,
     build_forest,
     max_nodes,
     next_pow2,
@@ -245,7 +246,8 @@ def _quantize_features(inputs: "FitInputs", n_bins: int, d_pad: int, algo: str):
     (``ops.tree_kernels.quantile_edges``: runs of consecutive rows spread
     over the whole frame, so a sorted dataset still gives even bins, and
     mask-aware so padding rows never enter it); only the edges and one flag
-    cross to the host."""
+    cross to the host. The bins come four to an int32 word
+    (``ops.tree_kernels._pack_bins``): the one copy every level reads."""
     with telemetry.span(
         "forest.sketch", rows=int(min(inputs.n_rows, inputs.X.shape[0]))
     ) as sp:
@@ -267,20 +269,25 @@ def _quantize_features(inputs: "FitInputs", n_bins: int, d_pad: int, algo: str):
         )
     X = inputs.X
     devices = X.sharding.device_set
-    plan = binize_plan(X.shape, X.dtype, edges_np.shape[1], d_pad, len(devices), next(iter(devices)))
-    with telemetry.span("forest.binize", cols=int(d_pad), **plan.span_attrs()):
+    plan = binize_plan(
+        X.shape, X.dtype, edges_np.shape[1], d_pad, len(devices), next(iter(devices)), packed=True
+    )
+    with telemetry.span("forest.binize", cols=int(d_pad), **plan.span_attrs()) as sp:
         bins = binize(X, jnp.asarray(edges_np), d_pad=d_pad, plan=plan)
+        sp.set_attr(bins_layout=bins_layout(bins))
     return edges_np, bins
 
 
 def _bins_width(d: int, subset: bool) -> int:
-    """Columns of the binned matrix. The fused-selection histogram kernel
-    (a per-node feature subset at more than 1024 columns) reads whole rows
-    of it at every level, so there the width is the lane multiple (3000 ->
-    3072: a quarter less to read, gather and keep than 4096); every other
-    strategy chunks the columns by powers of two and keeps the power of two."""
-    lanes = -(-d // 128) * 128
-    return lanes if subset and lanes > 1024 else next_pow2(d)
+    """Columns of the binned matrix, a multiple of 4 at every width: a fit
+    keeps its bins four to an int32 word. The fused-selection histogram
+    kernel (a per-node feature subset at more than 1024 columns) reads whole
+    rows of it at every level, so there the width is the multiple of 512
+    whose words fill whole lane tiles (3000 -> 3072: a quarter less to read,
+    gather and keep than 4096); every other strategy chunks the columns by
+    powers of two and keeps the power of two (4 at the least)."""
+    tiles = -(-d // 512) * 512
+    return tiles if subset and d > 1024 else max(4, next_pow2(d))
 
 
 def _dispatch_group(t_local: int) -> int:
@@ -390,7 +397,8 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
             )
             d_pad = _bins_width(d, k_features < d)
 
-            # 1) quantize features (device quantile sketch -> device binize)
+            # 1) quantize features (device quantile sketch -> device binize),
+            # the bins four to a word
             edges_np, bins = _quantize_features(
                 inputs, n_bins, d_pad, "RandomForest"
             )
@@ -494,9 +502,11 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                         levels_declined=len(declined),
                         **({"declined": str(declined)} if declined else {}),
                         # what a level's histogram read: bins columns a live
-                        # row, kernel calls a chunk of live rows
+                        # row, kernel calls a chunk of live rows, and how the
+                        # bins are held (four to an int32 word, or why not)
                         hist_cols=hist_cols,
                         hist_calls=hist_calls,
+                        bins_layout=bins_layout(bins),
                     ) as grow:
                         outg = jax.block_until_ready(
                             build_forest(
@@ -1449,7 +1459,7 @@ class _GBTEstimator(_GBTClass, _TpuEstimatorSupervised, _GBTParams):
                 self.logger.warning("maxBins=%d clamped to 256", n_bins)
                 n_bins = 256
             d = inputs.n_features
-            d_pad = next_pow2(d)
+            d_pad = _bins_width(d, False)
             seed = int(params.get("random_state") or 0)
 
             edges_np, bins = _quantize_features(inputs, n_bins, d_pad, "GBT")

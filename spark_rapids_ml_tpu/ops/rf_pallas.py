@@ -257,7 +257,8 @@ def subblock_hist(
     static_argnames=("n_bins", "r_sub", "variance", "interpret"),
 )
 def subblock_hist_sel(
-    bq: jax.Array,      # (n_pad, d_pad) uint8 FULL bins, node-sorted
+    bq: jax.Array,      # (n_pad, d_pad / 4) int32 FULL bins rows, four to a
+                        # word (tree_kernels._pack_bins), node-sorted
     featsq: jax.Array,  # (n_sb, k) int32 selected feature ids per sub-block
     swT: jax.Array,     # (S, n_pad) f32 stats*weight (0 on padding rows)
     live_blocks: jax.Array | None = None,  # (1,) int32; None = every block
@@ -278,26 +279,29 @@ def subblock_hist_sel(
     Node-contiguous rows turn that gather into dense MXU work: every
     ``r_sub``-aligned sub-block is node-pure, so its k selected columns
     are ONE static set — a (d_pad, k) one-hot built from the sub-block's
-    feature-id row and contracted against the raw uint8 rows:
+    feature-id row and contracted against the rows' bins, a byte plane of
+    the words at a time (plane ``p`` holds columns ``p*W .. p*W + W - 1``):
 
-        selected = rows(r_sub, d_pad) @ sel(d_pad, k)     (MXU)
+        selected = sum_p plane_p(r_sub, W) @ sel_p(W, k)  (MXU)
         bl       = selected @ E(k, k*nb)                  (lane expand)
         oh       = (bl == lane % nb)                      (bin one-hot)
         out_j    = swT_j(S, r_sub) @ oh                   (stat reduce)
 
-    The full-bins operand arrives by ONE row gather of whole rows
-    (~93 GB/s measured — wide contiguous rows, not element access).
-    Sentinel feature ids (== n_features) hit a zero-padded or absent
-    column and produce bin 0, the same invariant the gather paths keep.
-    Exact for classification: u8 bins and one-hots are bf16-exact, f32
-    accumulation; variance stats force Precision.HIGHEST.
+    The full-bins operand arrives by ONE row gather of whole rows of
+    words (a row is ``W = d_pad / 4`` words: a quarter of the tiles of its
+    bytes). Sentinel feature ids (== n_features) hit a zero-padded or
+    absent column and produce bin 0, the same invariant the gather paths
+    keep. Exact for classification: bins and one-hots are bf16-exact, a
+    slot's column lies in one plane, f32 accumulation; variance stats
+    force Precision.HIGHEST.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
         interpret = FORCE_INTERPRET
-    n_pad, d_pad = bq.shape
+    n_pad, words = bq.shape
+    d_pad = 4 * words
     n_sb, k = featsq.shape
     S = swT.shape[0]
     nb = n_bins
@@ -323,10 +327,6 @@ def subblock_hist_sel(
         )
 
     def body(b_ref, f_ref, s_ref, out_ref):
-        # Mosaic has no direct u8->f32 cast; hop through int32
-        rows_all = (
-            b_ref[:].astype(jnp.int32).astype(jnp.float32)
-        )                                                  # (R, d_pad)
         lane_bin = (
             lax.broadcasted_iota(jnp.int32, (1, W), 1) % nb
         ).astype(jnp.float32)
@@ -335,15 +335,19 @@ def subblock_hist_sel(
         fi = lax.broadcasted_iota(jnp.int32, (k_lanes, W), 0)
         li = lax.broadcasted_iota(jnp.int32, (k_lanes, W), 1)
         E = (li // nb == fi).astype(jnp.float32)
-        d_iota = lax.broadcasted_iota(jnp.int32, (d_pad, k_lanes), 0)
+        w_iota = lax.broadcasted_iota(jnp.int32, (words, k_lanes), 0)
         for j in range(L):
-            rows = rows_all[j * r_sub : (j + 1) * r_sub]   # (r_sub, d_pad)
+            wj = b_ref[j * r_sub : (j + 1) * r_sub, :]     # (r_sub, words)
             f_row = f_ref[j : j + 1, :]                    # (1, k_lanes)
-            sel = (d_iota == f_row).astype(jnp.float32)    # (d_pad, k_lanes)
-            selected = jnp.dot(
-                rows, sel, precision=prec,
-                preferred_element_type=jnp.float32,
-            )                                              # (r_sub, k_lanes)
+            selected = None
+            for p in range(4):
+                plane = ((wj >> (8 * p)) & 0xFF).astype(jnp.float32)
+                sel = (w_iota + p * words == f_row).astype(jnp.float32)
+                part = jnp.dot(
+                    plane, sel, precision=prec,
+                    preferred_element_type=jnp.float32,
+                )                                          # (r_sub, k_lanes)
+                selected = part if selected is None else selected + part
             bl = jnp.dot(
                 selected, E, precision=prec,
                 preferred_element_type=jnp.float32,
@@ -362,7 +366,7 @@ def subblock_hist_sel(
             grid=(n_blocks,),
             in_specs=[
                 pl.BlockSpec(
-                    (R, d_pad), lambda i, live: (_live_index(i, live), 0),
+                    (R, words), lambda i, live: (_live_index(i, live), 0),
                     memory_space=pltpu.VMEM,
                 ),
                 pl.BlockSpec(
@@ -407,7 +411,7 @@ def rf_hist_sel_declined(
         # the (L, k_lanes) feature-id block needs >= 8 sublanes
         ("sub-blocks>=8", R // max(r_sub, 1) >= 8),
         ("d_pad%128", d_pad % 128 == 0),
-        # (R, d_pad) f32 rows + (r_sub, W) transients + sel, x2 buffers
+        # the block's bins as f32 + (r_sub, W) transients + sel, x2 buffers
         (
             "vmem",
             (R * d_pad * 4 + r_sub * k * nb * 4 + d_pad * k * 4) * 2
@@ -431,7 +435,7 @@ def rf_hist_sel_ok(
         key = (d_pad, k, nb, S, r_sub, variance)
 
         def compile_fn():
-            bq = jax.ShapeDtypeStruct((2 * R, d_pad), jnp.uint8)
+            bq = jax.ShapeDtypeStruct((2 * R, d_pad // 4), jnp.int32)
             fq = jax.ShapeDtypeStruct((2 * (R // r_sub), k), jnp.int32)
             sT = jax.ShapeDtypeStruct((S, 2 * R), jnp.float32)
             subblock_hist_sel.lower(
@@ -499,7 +503,7 @@ def rf_hist_wide_declined(
     R = WIDE_BLOCK_ROWS
     g = min(_WIDE_BIN_GROUP, nb)
     vmem = (
-        2 * R * d_pad * 2                          # the block's rows, bf16, x2
+        2 * R * d_pad                              # the block's rows of words, x2
         + 128 * WIDE_SLOTS * (4 + 4 + 2)           # a window tile's iota, compare, one-hot
         + 2 * R * WIDE_SLOTS * 4                   # the selected columns and a tile's product
         + R * g * WIDE_SLOTS * (4 + 4 + 2)         # repeat, compare, one-hot
@@ -508,7 +512,8 @@ def rf_hist_wide_declined(
     terms = (
         ("backend", jax.default_backend() == "tpu" or FORCE_INTERPRET),
         ("slots%128", k >= WIDE_SLOTS and k % WIDE_SLOTS == 0),
-        ("d_pad%128", d_pad % 128 == 0),
+        # a column tile is a byte plane of a whole lane tile of words
+        ("words%128", d_pad % 512 == 0),
         # a bin is an exact bfloat16 up to 256; the lane groups divide nb
         ("bins<=256", nb <= 256 and nb % g == 0),
         ("stats*3<=16", 1 <= 3 * S <= WIDE_STAT_ROWS),
@@ -530,7 +535,7 @@ def rf_hist_wide_ok(n_pad: int, d_pad: int, k: int, nb: int, S: int) -> bool:
         def compile_fn():
             sds = jax.ShapeDtypeStruct
             subblock_hist_sel_wide.lower(
-                sds((2 * R, d_pad), jnp.bfloat16), sds((2, k), jnp.int32),
+                sds((2 * R, d_pad // 4), jnp.int32), sds((2, k), jnp.int32),
                 sds((WIDE_STAT_ROWS, 2 * R), jnp.bfloat16),
                 sds((2,), jnp.int32), sds((1,), jnp.int32),
                 sds((2, WIDE_STAT_ROWS, k * nb), jnp.float32), n_bins=nb,
@@ -563,7 +568,8 @@ def wide_windows(feats: jax.Array, d_pad: int) -> tuple[jax.Array, jax.Array]:
     jax.jit, static_argnames=("n_bins", "interpret"), donate_argnums=(5,)
 )
 def subblock_hist_sel_wide(
-    bq: jax.Array,        # (n_pad, d_pad) bf16 FULL bins rows, node-sorted
+    bq: jax.Array,        # (n_pad, d_pad / 4) int32 FULL bins rows, four to a
+                          # word (tree_kernels._pack_bins), node-sorted
     feats_b: jax.Array,   # (n_blocks, k) int32 feature ids of a block's node
     parts: jax.Array,     # (WIDE_STAT_ROWS, n_pad) bf16: split_f32_exact
     nodes_b: jax.Array,   # (n_blocks,) int32 node of a block, ascending
@@ -587,7 +593,11 @@ def subblock_hist_sel_wide(
     (:func:`wide_windows`, by scalar prefetch): a loop over those 128-column
     tiles, each an exact bf16 product with the tile's one-hot of them — a
     node's ids ascending make the window about four tiles wide, where the
-    whole width is ``d_pad // 128``. Every block is node-pure
+    whole width is ``d_pad // 128``. Column tile ``c`` is byte plane
+    ``c // (W / 128)`` of word tile ``c % (W / 128)`` (the words' planar
+    layout), so a tile's bins are one lane tile of words shifted and cast in
+    VMEM: the rows cross HBM as words, a quarter of the tiles of their
+    bytes and half the bytes of bf16. Every block is node-pure
     (``WIDE_BLOCK_ROWS`` rows; padding rows carry zero statistics). Blocks
     past ``live_blocks`` are neither fetched nor computed (:func:`_live_index`);
     a node with no block in this call keeps its sums untouched. Exact: bins
@@ -598,12 +608,14 @@ def subblock_hist_sel_wide(
 
     if interpret is None:
         interpret = FORCE_INTERPRET
-    n_pad, d_pad = bq.shape
+    n_pad, words = bq.shape
+    d_pad = 4 * words
+    word_tiles = words // 128
     n_blocks, k = feats_b.shape
     nb, R, T, P = n_bins, WIDE_BLOCK_ROWS, WIDE_SLOTS, WIDE_STAT_ROWS
     G = min(_WIDE_BIN_GROUP, nb)
     n_tiles = k // T
-    assert n_pad == n_blocks * R and k % T == 0 and nb % G == 0
+    assert n_pad == n_blocks * R and k % T == 0 and nb % G == 0 and words % 128 == 0
     lo, cnt = wide_windows(feats_b, d_pad)              # (n_blocks, n_tiles)
     # flat (SMEM pads a 2-D table's rows): [(block * n_tiles + tile) * 2 + 0/1]
     win = jnp.stack([lo, cnt], axis=-1).reshape(-1).astype(jnp.int32)
@@ -619,11 +631,12 @@ def subblock_hist_sel_wide(
             f_row = f_ref[0]                                   # (1, T)
 
             def select(c, selected):
-                col = pl.multiple_of(c * 128, 128)
-                ids = lax.broadcasted_iota(jnp.int32, (128, T), 0) + col
+                ids = lax.broadcasted_iota(jnp.int32, (128, T), 0) + c * 128
                 sel = (ids == f_row).astype(jnp.bfloat16)      # (128, T)
+                w = b_ref[:, pl.ds(pl.multiple_of((c % word_tiles) * 128, 128), 128)]
+                bins = ((w >> (8 * (c // word_tiles))) & 0xFF).astype(jnp.float32)
                 return selected + jnp.dot(
-                    b_ref[:, pl.ds(col, 128)], sel,
+                    bins.astype(jnp.bfloat16), sel,
                     preferred_element_type=jnp.float32,
                 )
 
@@ -658,7 +671,7 @@ def subblock_hist_sel_wide(
             grid=(n_tiles, n_blocks),
             in_specs=[
                 pl.BlockSpec(
-                    (R, d_pad),
+                    (R, words),
                     lambda t, i, live, nodes, win: (_live_index(i, live), 0),
                     memory_space=pltpu.VMEM,
                 ),
@@ -757,7 +770,7 @@ def subblock_hist_batched(
 
 
 def subblock_hist_sel_batched(
-    bq: jax.Array,      # (T, n_pad, d_pad) uint8 FULL bins, node-sorted
+    bq: jax.Array,      # (T, n_pad, d_pad / 4) int32 FULL bins words, node-sorted
     featsq: jax.Array,  # (T, n_sb, k) int32 selected feature ids
     swT: jax.Array,     # (T, S, n_pad) f32 stats*weight
     *,
@@ -767,12 +780,12 @@ def subblock_hist_sel_batched(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused-selection variant: (T, n_pad//r_sub, S, k*n_bins)."""
-    T, n_pad, d_pad = bq.shape
+    T, n_pad, words = bq.shape
     n_sb, k = featsq.shape[-2:]
     S = swT.shape[-2]
     assert n_pad % BLOCK_ROWS == 0, n_pad
     out = subblock_hist_sel(
-        bq.reshape(T * n_pad, d_pad),
+        bq.reshape(T * n_pad, words),
         featsq.reshape(T * n_sb, k),
         swT.transpose(1, 0, 2).reshape(S, T * n_pad),
         n_bins=n_bins, r_sub=r_sub, variance=variance, interpret=interpret,
@@ -786,23 +799,25 @@ def subblock_hist_sel_batched(
 
 # a grid step of the quantizer: at most this many rows (lanes of the frame's
 # transpose) by this many columns (a lane tile of row-major bins, four packed
-# uint8 sublane tiles of rows-minor ones)
+# uint8 sublane tiles of rows-minor ones), or this many words of packed bins
 BINIZE_ROWS = 2048
 BINIZE_COLS = 128
 _BINIZE_VMEM_LIMIT = 32 * 1024 * 1024
 
 
-def binize_tile(n: int) -> tuple[int, int, int]:
+def binize_tile(n: int, words: int = 0) -> tuple[int, int, int]:
     """``(rows, columns, vmem bytes)`` of a grid step of
     :func:`rf_binize_pass` over ``n`` rows: the frame's block and the bins'
-    block two buffers each (the pipeline's), the edges' block two."""
+    block two buffers each (the pipeline's), the edges' block two. With
+    ``words`` (the bins four to an int32 word, ``words`` a row) a step reads
+    the columns of one byte plane of as many words as it writes."""
     rows = min(BINIZE_ROWS, -(-n // 128) * 128)
-    need = 2 * BINIZE_COLS * rows * (4 + 1) + 2 * BINIZE_COLS * 128 * 4
-    return rows, BINIZE_COLS, need
+    cols = min(BINIZE_COLS, words) if words else BINIZE_COLS
+    return rows, cols, 2 * cols * rows * (4 + (4 if words else 1)) + 2 * cols * 128 * 4
 
 
 def rf_binize_declined(
-    n_local: int, d: int, n_edges: int, dtype, devices: int = 1, device=None
+    n_local: int, d: int, n_edges: int, dtype, devices: int = 1, device=None, words: int = 0
 ) -> str:
     """The failing terms of :func:`rf_binize_pass`'s gate, comma-joined
     (empty: admitted). A TPU; f32 values; at most 127 edges a column (one
@@ -812,16 +827,20 @@ def rf_binize_declined(
     frame the device keeps with its ROWS minor (``linalg.rows_minor``: a
     width that is no multiple of 128), which the kernel reads as its
     transpose, a bitcast — a row-major frame would get a relayout of the
-    whole frame in front of it. A pure function of its arguments and the
-    backend: the forest says on its ``forest.binize`` and
+    whole frame in front of it. With ``words`` (packed bins, ``words`` a
+    row): a byte plane's block of the frame is whole sublane tiles, and a
+    step's words are a lane tile or all of them. A pure function of its
+    arguments and the backend: the forest says on its ``forest.binize`` and
     ``forest.binize_batch`` spans which pass ran, and why."""
     terms = [
         ("dtype", dtype == jnp.float32),
         ("edges<=127", n_edges <= 127),
         ("one_device", devices == 1),
-        ("vmem", binize_tile(n_local)[2] <= _BINIZE_VMEM_LIMIT),
+        ("vmem", binize_tile(n_local, words)[2] <= _BINIZE_VMEM_LIMIT),
         ("cols>=128", d >= BINIZE_COLS),
     ]
+    if words:
+        terms.append(("words", words % BINIZE_COLS == 0 or (words < BINIZE_COLS and words % 8 == 0)))
     if not FORCE_INTERPRET:
         from .linalg import rows_minor
 
@@ -833,11 +852,13 @@ def rf_binize_declined(
 
 def rf_binize_pass(
     X: jax.Array, edges: jax.Array, *, d_pad: int, rows_minor_out: bool,
-    interpret: bool = False,
+    packed: bool = False, interpret: bool = False,
 ) -> jax.Array:
     """(n, d) f32 x (d, n_edges) -> (n, d_pad) uint8: ``#{edges <= x}`` per
     value, 0 in the pad columns — the count of ``tree_kernels.binize``, bit
-    for bit, in one read of the frame.
+    for bit, in one read of the frame. ``packed``: the same bins four to an
+    int32 word, (n, d_pad / 4), in ``tree_kernels._pack_bins``' planar
+    layout (byte ``p`` of word ``w`` holds column ``p * d_pad / 4 + w``).
 
     The frame is read as its (d, n) transpose, the bytes of a rows-minor
     frame: a grid step takes a (128 columns, rows) block, so a vreg holds 8
@@ -849,23 +870,33 @@ def rf_binize_pass(
     the compare-count is 127 compares and adds. Sorting moves no count (a
     count does not see the edges' order); on a sorted row ``e <= x`` is true
     then false, and a NaN edge or value is never ``<=``, so NaN lands in
-    bin 0 as the count puts it. The bins are written at their default
-    layout: rows minor (``rows_minor_out``) as the (d_pad, n) transpose, a
-    bitcast of the result; row-major through one transpose of a (128, 128)
-    int32 block of counts a lane tile."""
+    bin 0 as the count puts it. Packed, the grid's innermost axis runs over
+    the four byte planes of a block of words, the block staying in VMEM: a
+    step counts one plane's columns, as a step of the byte bins does, and
+    ORs them into the words shifted to their byte (a count is at most 127:
+    no word is negative). The bins are written at their default layout:
+    rows minor (``rows_minor_out``) as the (d_pad, n) or (d_pad / 4, n)
+    transpose, a bitcast of the result; row-major through one transpose of
+    a (128, 128) int32 block of counts a lane tile."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, d = X.shape
     n_edges = edges.shape[1]
-    rows, cols, _ = binize_tile(n)
+    words = d_pad // 4 if packed else 0
+    rows, cols, _ = binize_tile(n, words)
+    planes = 4 if packed else 1
+    # blocks from one byte plane's columns to the next's
+    plane_blocks = words // cols if packed else 0
     steps = tuple(1 << i for i in reversed(range(n_edges.bit_length())))
-    col_blocks = pl.cdiv(d_pad, cols)
+    out_width = words if packed else d_pad
     last_x_block = pl.cdiv(d, cols) - 1
-    table = jnp.full((col_blocks * cols, 128), jnp.nan, jnp.float32)
+    table = jnp.full((pl.cdiv(d_pad, cols) * cols, 128), jnp.nan, jnp.float32)
     table = table.at[:d, :n_edges].set(jnp.sort(edges.astype(jnp.float32), axis=1))
+    out_dtype = jnp.int32 if packed else jnp.uint8
 
     def kern(e_ref, x_ref, o_ref):
+        plane = pl.program_id(2)
         groups = [e_ref[g * 8:(g + 1) * 8, :] for g in range(cols // 8)]
 
         def lane_tile(t, _):
@@ -879,30 +910,32 @@ def rf_binize_pass(
                     pos = jnp.where(e <= x, pos + s, pos)
                 counts.append(pos)
             c = jnp.concatenate(counts, axis=0)
-            if rows_minor_out:
-                o_ref[:, lanes] = c.astype(jnp.uint8)
-            else:
-                o_ref[lanes, :] = c.T.astype(jnp.uint8)
+            at = (slice(None), lanes) if rows_minor_out else (lanes, slice(None))
+            c = c if rows_minor_out else c.T
+            if packed:
+                # the plane's byte, OR'd into the words the earlier planes wrote
+                c = jnp.where(plane == 0, 0, o_ref[at]) | (c << (8 * plane))
+            o_ref[at] = c.astype(out_dtype)
 
         lax.fori_loop(0, rows // 128, lane_tile, None)
 
     if rows_minor_out:
-        out_shape, out_spec = (d_pad, n), pl.BlockSpec((cols, rows), lambda i, j: (i, j))
+        out_shape, out_spec = (out_width, n), pl.BlockSpec((cols, rows), lambda i, j, p: (i, j))
     else:
-        out_shape, out_spec = (n, d_pad), pl.BlockSpec((rows, cols), lambda i, j: (j, i))
+        out_shape, out_spec = (n, out_width), pl.BlockSpec((rows, cols), lambda i, j, p: (j, i))
     out = pl.pallas_call(
         kern,
-        grid=(col_blocks, pl.cdiv(n, rows)),
+        grid=(pl.cdiv(out_width, cols), pl.cdiv(n, rows), planes),
         in_specs=[
-            pl.BlockSpec((cols, 128), lambda i, j: (i, 0)),
+            pl.BlockSpec((cols, 128), lambda i, j, p: (i + p * plane_blocks, 0)),
             # a pad column block past the frame reads its last block: the
             # table's NaN rows count it 0 whatever it holds
-            pl.BlockSpec((cols, rows), lambda i, j: (jnp.minimum(i, last_x_block), j)),
+            pl.BlockSpec((cols, rows), lambda i, j, p: (jnp.minimum(i + p * plane_blocks, last_x_block), j)),
         ],
         out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_BINIZE_VMEM_LIMIT
+            dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=_BINIZE_VMEM_LIMIT
         ),
         name="rf_binize_pass",
         interpret=interpret,
@@ -965,7 +998,8 @@ def packed_traverse_ok(t_pad: int, k1: int, k2: int, words: int) -> bool:
     jax.jit, static_argnames=("k1", "k2", "d_pad", "interpret")
 )
 def packed_traverse(
-    packed: jax.Array,   # (n, Wp) int32 word-packed row bins, n % B == 0
+    packed: jax.Array,   # (n, d_pad/4) int32 row bins four to a word
+                         # (tree_kernels._pack_bins), n % B == 0
     i1: jax.Array,       # (n, T_pad) int32 hop-1 heap indices
     feat2: jax.Array,    # (T_pad * 2^k1, 64) int32 hop-2 feature tables
     thr2: jax.Array,     # (T_pad * 2^k1, 64) int32 hop-2 thresholds
@@ -1037,8 +1071,8 @@ def packed_traverse(
                 ridx = jnp.concatenate(
                     [ridx, jnp.zeros((B, Wp - LANES), jnp.int32)], axis=1
                 )
-            w = jnp.take_along_axis(pbins, ridx >> 2, axis=1)
-            xv = (w >> ((ridx & 3) * 8)) & 0xFF            # (B, Wp)
+            w = jnp.take_along_axis(pbins, ridx % words, axis=1)
+            xv = (w >> ((ridx // words) * 8)) & 0xFF       # (B, Wp)
             xv = lax.slice_in_dim(xv, 0, LANES, axis=1)    # (B, 64)
             is_split = rfeat >= 0.0
             bits = ((xv.astype(jnp.float32) > rthr) & is_split).astype(

@@ -6,7 +6,8 @@ trains a local ``cuml.RandomForest*`` per worker; the builder itself lives in
 libcuml). A translation is impossible and undesirable — instead this is an
 XGBoost-style **histogram** builder designed for XLA:
 
-* features are quantized once to ``n_bins`` buckets (uint8), so every split
+* features are quantized once to ``n_bins`` buckets (a byte each; a fit
+  keeps them four to an int32 word, ``_pack_bins``), so every split
   decision becomes dense integer work with static shapes;
 * trees grow **level-wise**: one ``segment_sum`` per feature-chunk builds the
   (node, feature, bin, stat) histogram, a cumulative-sum scan turns it into
@@ -313,18 +314,27 @@ def quantile_edges(X: jax.Array, mask: jax.Array, *, n_bins: int):
 class BinizePlan(NamedTuple):
     """Which pass :func:`binize` runs at a shape, and why: ``kernel``
     ``"pallas"`` (``rf_pallas.rf_binize_pass``) or ``"xla"``; ``tile`` the
-    kernel's rows x columns a grid step, or XLA's chunk of columns;
+    kernel's rows x columns a grid step reads, or XLA's chunk of columns;
     ``declined`` the kernel gate's failing terms; ``rows_minor_out`` whether
     the device keeps the bins with their rows minor (the kernel writes them
-    so)."""
+    so); ``packed`` whether the bins come four to an int32 word
+    (:func:`_pack_bins`: a fit's) or a byte each (a transform batch's)."""
 
     kernel: str
     tile: str
     declined: str
     rows_minor_out: bool
+    packed: bool = False
 
     def span_attrs(self) -> dict:
         return {"kernel": self.kernel, "tile": self.tile, "declined": self.declined}
+
+
+def bins_layout(bins: jax.Array) -> str:
+    """How a fit holds its bins, for its spans: ``packed32`` (four to an
+    int32 word, :func:`_pack_bins`), else their dtype — the reason they are
+    not."""
+    return "packed32" if bins.dtype == jnp.int32 else f"dtype={bins.dtype}"
 
 
 def _binize_chunk(n: int, d: int) -> int:
@@ -334,24 +344,32 @@ def _binize_chunk(n: int, d: int) -> int:
     return min(d, max(8, (1 << 22) // max(n, 1) // 8 * 8))
 
 
-def binize_plan(shape, dtype, n_edges: int, d_pad: int, devices: int = 1, device=None) -> BinizePlan:
+def binize_plan(
+    shape, dtype, n_edges: int, d_pad: int, devices: int = 1, device=None, packed: bool = False
+) -> BinizePlan:
     """The :class:`BinizePlan` of an ``(n, d)`` frame on ``devices`` devices
-    (the first of them ``device``) at ``d_pad`` bin columns."""
+    (the first of them ``device``) at ``d_pad`` bin columns, a byte each or
+    (``packed``) four to an int32 word."""
     from .linalg import rows_minor
     from .rf_pallas import binize_tile, rf_binize_declined
 
     n, d = shape
-    declined = rf_binize_declined(n, d, n_edges, dtype, devices, device)
+    words = d_pad // 4 if packed else 0
+    declined = rf_binize_declined(n, d, n_edges, dtype, devices, device, words)
     if declined:
-        return BinizePlan("xla", str(_binize_chunk(n, d)), declined, False)
-    rows, cols, _ = binize_tile(n)
-    out_minor = rows_minor(device or jax.devices()[0], n, d_pad, jnp.uint8)
-    return BinizePlan("pallas", f"{rows}x{cols}", "", out_minor)
+        return BinizePlan("xla", str(_binize_chunk(n, d)), declined, False, packed)
+    rows, cols, _ = binize_tile(n, words)
+    out_minor = rows_minor(
+        device or jax.devices()[0], n, words or d_pad, jnp.int32 if packed else jnp.uint8
+    )
+    return BinizePlan("pallas", f"{rows}x{cols}", "", out_minor, packed)
 
 
 @functools.partial(jax.jit, static_argnames=("d_pad", "plan"))
 def binize(X: jax.Array, edges: jax.Array, *, d_pad: int, plan: BinizePlan | None = None) -> jax.Array:
-    """Quantize rows to bins: (n, d) x (d, nb-1) -> (n, d_pad) uint8.
+    """Quantize rows to bins: (n, d) x (d, nb-1) -> (n, d_pad) uint8, or
+    where ``plan.packed`` (a fit's) the same bins four to an int32 word,
+    (n, d_pad / 4) in :func:`_pack_bins`' layout, written so by the pass.
 
     bin = #{edges <= x}. ``plan`` (:func:`binize_plan`; asked of the shape
     on the default device where None) says which pass counts it. On a TPU,
@@ -382,7 +400,8 @@ def binize(X: jax.Array, edges: jax.Array, *, d_pad: int, plan: BinizePlan | Non
         from .rf_pallas import FORCE_INTERPRET, rf_binize_pass
 
         return rf_binize_pass(
-            X, edges, d_pad=d_pad, rows_minor_out=plan.rows_minor_out, interpret=FORCE_INTERPRET
+            X, edges, d_pad=d_pad, rows_minor_out=plan.rows_minor_out, packed=plan.packed,
+            interpret=FORCE_INTERPRET,
         )
     Fc = int(plan.tile)
     parts = []
@@ -396,7 +415,7 @@ def binize(X: jax.Array, edges: jax.Array, *, d_pad: int, plan: BinizePlan | Non
     bins = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
     if d_pad > d:
         bins = jnp.pad(bins, ((0, 0), (0, d_pad - d)))
-    return bins
+    return _pack_bins(bins) if plan.packed else bins
 
 
 # ---------------------------------------------------------------------------
@@ -462,14 +481,54 @@ def _chunk_features(
 
 
 def _pack_bins(bins: jax.Array) -> jax.Array:
-    """(n, d) uint8 bins -> (n, d/4) int32, 4 bins per word (d % 4 == 0)."""
+    """(n, d) uint8 bins -> (n, d/4) int32 words, four bins to a word
+    (d % 4 == 0). PLANAR: byte ``p`` of word ``w`` holds column
+    ``p * (d/4) + w``, so byte plane ``p`` is the columns ``[p*d/4,
+    (p+1)*d/4)`` in order — a lane tile of a plane is a lane tile of
+    columns, and a kernel reads 128 columns as 128 words and a shift
+    (``rf_pallas``). THE layout of a fit's bins from ``binize`` to the last
+    level, and of every word-packed bins here."""
+    w = bins.shape[1] // 4
     b32 = bins.astype(jnp.int32)
     return (
-        b32[:, 0::4]
-        | (b32[:, 1::4] << 8)
-        | (b32[:, 2::4] << 16)
-        | (b32[:, 3::4] << 24)
+        b32[:, :w]
+        | (b32[:, w : 2 * w] << 8)
+        | (b32[:, 2 * w : 3 * w] << 16)
+        | (b32[:, 3 * w :] << 24)
     )
+
+
+def _unpack_bins(packed: jax.Array, c0=0, width: int | None = None) -> jax.Array:
+    """Columns ``[c0, c0 + width)`` of packed bins (all of them by default)
+    as (n, width) int32. ``c0`` may be traced; it is a multiple of
+    ``width``, which divides the words a row (a slice of one byte plane) or
+    is a multiple of them (whole planes)."""
+    words = packed.shape[1]
+    width = 4 * words if width is None else width
+    if width <= words:
+        assert words % width == 0, (words, width)
+        w = lax.dynamic_slice_in_dim(packed, c0 % words, width, axis=1)
+        return (w >> (8 * (c0 // words))) & 0xFF
+    assert width % words == 0, (words, width)
+    return jnp.concatenate(
+        [(packed >> (8 * (c0 // words + p))) & 0xFF for p in range(width // words)],
+        axis=1,
+    )
+
+
+def _bins_at(packed: jax.Array, rows, cols: jax.Array) -> jax.Array:
+    """``bins[rows, cols]`` of packed bins as int32: one element gather of a
+    word and a shift. ``rows`` broadcasts against ``cols``; None takes row
+    ``r`` for ``cols[r]`` (``take_along_axis``). A column id past the width
+    (a padded subset's sentinel) is clipped to the last column, as the
+    byte gathers clipped it."""
+    words = packed.shape[1]
+    c = jnp.clip(cols, 0, 4 * words - 1)
+    if rows is None:
+        w = jnp.take_along_axis(packed, c % words, axis=1)
+    else:
+        w = packed[rows, c % words]
+    return (w >> (8 * (c // words))) & 0xFF
 
 
 def _contract_gather(packed: jax.Array, idx: jax.Array) -> jax.Array:
@@ -479,21 +538,22 @@ def _contract_gather(packed: jax.Array, idx: jax.Array) -> jax.Array:
     per-node sampled columns the single dominant cost of an RF level
     (measured 25.5 ms of a ~33 ms level at 131k x 256, k=16). Expressed as
     a word-packed one-hot contraction the same extraction streams on the
-    VPU at ~1.6 ms: compare idx>>2 against the d/4 word lanes, reduce, and
-    shift the byte out. Feature-count sentinels yield bin 0 (see the
-    sentinel invariant note below this function), and the gain search
-    masks those slots exactly like the old clipped-gather path."""
+    VPU at ~1.6 ms: compare the column's word (``idx % W``) against the W
+    word lanes, reduce, and shift its byte (``idx // W``) out. Feature-count
+    sentinels yield bin 0 (see the sentinel invariant note below this
+    function), and the gain search masks those slots exactly like the old
+    clipped-gather path."""
     words = packed.shape[1]
     ar_w = jnp.arange(words, dtype=jnp.int32)
-    sel = (idx[:, :, None] >> 2) == ar_w[None, None, :]
+    sel = (idx % words)[:, :, None] == ar_w[None, None, :]
     w = jnp.where(sel, packed[:, None, :], 0).sum(-1)  # (n, k)
-    return (w >> ((idx & 3) * 8)) & 0xFF
+    return jnp.where(idx < 4 * words, (w >> ((idx // words) * 8)) & 0xFF, 0)
 
 
 # Sentinel invariant for _contract_gather: a feature-count sentinel
-# (idx == n_features) either matches NO word (n_features == d_pad) and
-# yields 0, or lands in a zero-filled padding column (n_features < d_pad;
-# binize pads bins with 0) and yields bin 0 — the same value the old
+# (idx == n_features) either lies past every column (n_features == d_pad)
+# and yields 0, or lands in a zero-filled padding column (n_features <
+# d_pad; binize pads bins with 0) and yields bin 0 — the same value the old
 # clipped take_along_axis produced. Both cases rely on binize's zero fill
 # of columns >= n_features, and the gain search additionally masks every
 # sentinel slot via realf < n_features.
@@ -603,7 +663,7 @@ def _hist_compact(
                           # block-aligned padded row count it validated
     f_chunk: int,         # feature-chunk width (gate-validated, divides F)
     variance: bool,
-    full_bins=None,       # (n, d_pad) uint8 + feats => fused-selection
+    full_bins=None,       # (n, d_pad/4) int32 packed + feats => fused-selection
     feats=None,           # (n_nodes, F) int32 per-node feature ids
     wide: bool = False,   # the fused selection tiled over slots (r_sub = its block)
     interpret=None,
@@ -626,8 +686,10 @@ def _hist_compact(
     device scalar. Every shape stays static; what follows ``P`` is a trip
     count. The level is ONE loop over chunks of ``_LIVE_CHUNK`` sorted
     positions, ``ceil(P / chunk)`` trips: a trip gathers its rows' ids and
-    weights and the rows themselves (whole uint8 rows for the fused
-    selection — ~100 GB/s, wide contiguous rows), hands that chunk to the
+    weights and the rows themselves (whole rows of packed words for the
+    fused selection, which the kernel unpacks in VMEM: a row of 3072 bins
+    is 6 lane tiles of words where its bytes were 24 sub-word tiles, and
+    the gather's rate goes by the tile), hands that chunk to the
     kernel, and adds the chunk's partials to the per-node sums in sub-block
     order, as ``segment_sum`` adds them (the batched builder's grouping of
     the f32 sums). No node-sorted copy of a level is held (1.5-2.3 GB at
@@ -642,7 +704,7 @@ def _hist_compact(
     (scripts/rf_deep_microbench*.py).
 
     ``wide`` (``level_plan``'s ``pallas_sel_wide``): the same loop, the
-    chunk's whole rows handed to ``subblock_hist_sel_wide`` with the running
+    chunk's whole rows of words handed to ``subblock_hist_sel_wide`` with the running
     per-node sums, which the kernel adds to in place — no partials, no
     scatter-add; a sub-block is one kernel block of one node. The kernel
     takes each node's ids ascending, so a slot tile's selection reads a
@@ -719,7 +781,7 @@ def _hist_compact(
         featsq = ids_up[sbc]                                # (n_sb, F)
         blocks = plen // r_sub
         sel_tiles = jnp.stack([
-            (blocks * wide_windows(ids_up, full_bins.shape[1])[1].sum(axis=1)).sum(),
+            (blocks * wide_windows(ids_up, 4 * full_bins.shape[1])[1].sum(axis=1)).sum(),
             blocks.sum() * (F // WIDE_SLOTS),
         ]).astype(jnp.int32)
     elif full_bins is not None:
@@ -727,7 +789,7 @@ def _hist_compact(
     ar_sb = jnp.arange(chunk_sb, dtype=jnp.int32)
 
     def level_chunk(c, carry):
-        accs, rows = carry
+        accs, live_ids = carry
         lo = c * chunk
         lo_sb = c * chunk_sb
         ok = lax.dynamic_slice(pvalid, (lo,), (chunk,))
@@ -741,14 +803,14 @@ def _hist_compact(
         ).reshape(1)
         if wide:
             with jax.named_scope("forest.wide_rows"):
-                # whole rows as exact bf16 integers (the kernel's selection
-                # is one bf16 product) and the statistics' three-way split
-                rows_bf = full_bins[ids].astype(jnp.bfloat16)
+                # whole rows of words (the kernel unpacks a column tile of
+                # bins at a time) and the statistics' three-way split
+                rows = full_bins[ids]
                 parts = split_f32_exact(swT)
             # the kernel adds a node's blocks to its running sums in place
             accs = (
                 subblock_hist_sel_wide(
-                    rows_bf,
+                    rows,
                     lax.dynamic_slice(featsq, (lo_sb, 0), (chunk_sb, F)),
                     parts, lax.dynamic_slice(sbc, (lo_sb,), (chunk_sb,)),
                     live_blocks, accs[0], n_bins=nb, interpret=interpret,
@@ -756,8 +818,8 @@ def _hist_compact(
             )
         else:
             if full_bins is not None:
-                # whole rows; the kernel selects each node's k columns with
-                # an MXU one-hot dot, replacing the per-row k-column gather
+                # whole rows of words; the kernel selects each node's k
+                # columns with an MXU one-hot dot, replacing the per-row k-column gather
                 # that costs ~780 ms per level at the reference 1M x 3000
                 # shape. Dump sub-blocks of a live block get garbage feature
                 # rows but zero weights — they contribute nothing.
@@ -797,8 +859,8 @@ def _hist_compact(
                 acc.at[seg_c].add(part.reshape(chunk_sb, -1), mode="drop")
                 for acc, part in zip(accs, parts)
             )
-        rows = lax.dynamic_update_slice(rows, jnp.where(ok, ids, n), (lo,))
-        return accs, rows
+        live_ids = lax.dynamic_update_slice(live_ids, jnp.where(ok, ids, n), (lo,))
+        return accs, live_ids
 
     width = F if full_bins is not None else Fc
     if wide:
@@ -940,7 +1002,8 @@ def level_plan(
     an HBM budget: the probe compiles a tiny instance and cannot see HBM
     pressure, and a runtime OOM here has no fallback. Residents counted:
     what the caller holds (``cfg.held_bytes``), bins + the row-gathered copy
-    (both n-scale uint8), partials, and two histogram tiles.
+    (both a byte a bin: four to an int32 word), partials, and two histogram
+    tiles.
     """
     from .rf_pallas import (
         BLOCK_ROWS,
@@ -990,7 +1053,7 @@ def level_plan(
     shape_declined = ",".join(name for name, ok in shape_terms if not ok)
     sel_resident = (
         cfg.held_bytes
-        + n * d_pad                      # bins (uint8)
+        + n * d_pad                      # bins (a byte each, packed)
         + chunk * d_pad                  # a chunk's gathered whole rows
         + chunk_sb * S * d_hist * nb * 4  # its partials (f32)
         + 2 * n_nodes * S * d_hist * nb * 4  # hist + transpose
@@ -1025,8 +1088,8 @@ def level_plan(
     )
     wide_resident = (
         cfg.held_bytes
-        + n * d_pad                                  # bins (uint8)
-        + min(n_pad_w, _LIVE_CHUNK) * d_pad * 3      # a chunk's rows, u8 + bf16
+        + n * d_pad                                  # bins (a byte each, packed)
+        + min(n_pad_w, _LIVE_CHUNK) * d_pad          # a chunk's rows of words
         + n_nodes * WIDE_STAT_ROWS * d_hist * nb * 4   # the running sums
         + 6 * n_nodes * S * d_hist * nb * 4          # hist and the gain chain
     )
@@ -1120,7 +1183,7 @@ def plan_reads(n: int, d_pad: int, cfg: ForestConfig, dt=jnp.float32):
 
 
 def _build_tree(
-    bins: jax.Array,    # (n, d_pad) uint8
+    packed: jax.Array,  # (n, d_pad/4) int32: the bins four to a word (_pack_bins)
     stats: jax.Array,   # (n, S) float
     valid: jax.Array,   # (n,) float row mask
     key: jax.Array,
@@ -1137,7 +1200,8 @@ def _build_tree(
     when they were made: always 0 for variance statistics) and
     ``sel_tiles`` (the wide selection's column tiles read and slot tiles
     run, over its levels: ``_Frontier.sel_tiles``)."""
-    n, d_pad = bins.shape
+    n, words = packed.shape
+    d_pad = 4 * words
     S = cfg.n_stats
     nb = cfg.n_bins
     M = max_nodes(cfg.max_depth)
@@ -1186,25 +1250,22 @@ def _build_tree(
     handed = jnp.zeros((1, S), dt)      # and what their parents' splits gave them
     closed_at_birth = jnp.zeros((), jnp.int32)
 
-    # Word-packed bins for the contraction gather (TPU: per-row gathers run
-    # at ~1e8 elem/s, making take_along_axis ~16x slower than the dense
+    # The contraction gather over the words (TPU: per-row gathers run at
+    # ~1e8 elem/s, making take_along_axis ~16x slower than the dense
     # formulation at d_pad=256; CPU keeps take_along_axis). The contraction
     # does d_pad/4 word-ops per extracted element (~8.6e10 word-ops/s
     # measured), so its advantage erodes linearly with width — "auto" caps
     # it at d_pad<=1024 (4x the measured shape), past which the predicted
-    # win thins and the un-fused intermediate risk grows. Packed once per
-    # tree, outside the level loop.
+    # win thins and the un-fused intermediate risk grows.
     if cfg.contract_gather == "on":
-        use_contract = d_pad % 4 == 0
+        use_contract = True
     elif cfg.contract_gather == "off":
         use_contract = False
     else:
         use_contract = (
             jax.default_backend() == "tpu"
-            and d_pad % 4 == 0
             and d_pad <= 1024
         )
-    packed = _pack_bins(bins) if use_contract else None
 
     # levels are a static python loop: each level has its own (static) node
     # count and feature-chunk size, so XLA compiles tight fixed-shape kernels
@@ -1269,24 +1330,23 @@ def _build_tree(
             ``nodes``: their nodes' sampled columns (the fused-selection
             kernel selects in-kernel and skips this entirely)."""
             if not subset:
-                return bins[rows]
+                return _unpack_bins(packed[rows])
             row_feats = feats[nodes]  # (m, k_pad) real feature ids per row
             if use_contract:
                 return _contract_gather(packed[rows], row_feats)   # i32
-            return bins[rows[:, None], jnp.clip(row_feats, 0, d_pad - 1)]
+            return _bins_at(packed, rows[:, None], row_feats)
 
         def make_hist_src(feats=feats, local=local):
             """Per-row subset bin extraction at full size, for the
-            strategies that take every row."""
+            strategies that take every row (the words themselves without
+            a subset: a feature chunk unpacks its columns)."""
             if not subset:
-                return bins
+                return packed
             lc0 = jnp.clip(local, 0, n_nodes - 1)
             row_feats = feats[lc0]  # (n, k_pad) real feature ids per row
             if use_contract:
                 return _contract_gather(packed, row_feats)  # (n, k_pad) i32
-            return jnp.take_along_axis(
-                bins, jnp.clip(row_feats, 0, d_pad - 1), axis=1
-            )  # (n, k_pad) uint8
+            return _bins_at(packed, None, row_feats)  # (n, k_pad) i32
 
         plan = level_plan(n, d_pad, level, cfg, dt)
         r_sub, n_pad_c, Fc = plan.r_sub, plan.n_pad, plan.f_chunk
@@ -1298,7 +1358,7 @@ def _build_tree(
                 None if use_sel else row_bins, seg, sw, n_nodes=n_nodes,
                 n_slots=d_hist, nb=nb, r_sub=r_sub, n_pad=n_pad_c,
                 f_chunk=Fc, variance=(cfg.impurity == "variance"),
-                full_bins=bins if use_sel else None,
+                full_bins=packed if use_sel else None,
                 feats=feats if use_sel else None, wide=use_wide,
             )
             sel_tiles = sel_tiles + frontier.sel_tiles
@@ -1468,9 +1528,10 @@ def _build_tree(
                            use_matmul=use_matmul, subset=subset,
                            hist_src=hist_src):
                 bg, bf, bb, bl = carry
-                binc = lax.dynamic_slice(
-                    hist_src, (0, ci * F), (n, F)
-                ).astype(jnp.int32)
+                if subset:
+                    binc = lax.dynamic_slice(hist_src, (0, ci * F), (n, F))
+                else:
+                    binc = _unpack_bins(hist_src, ci * F, F)
                 make = _hist_matmul if use_matmul else _hist_scatter
                 hist = make(
                     binc, n_nodes=n_nodes, in_level=in_level, local=local, sw=sw
@@ -1541,9 +1602,7 @@ def _build_tree(
                     return _contract_gather(
                         packed[rows], row_feat[:, None]
                     )[:, 0]
-                return bins[rows, jnp.clip(row_feat, 0, d_pad - 1)].astype(
-                    jnp.int32
-                )
+                return _bins_at(packed, rows, row_feat)
 
             node = _route_live(
                 node, frontier, row_bin, opens, bf, bb, offset=offset
@@ -1554,9 +1613,7 @@ def _build_tree(
             if use_contract:
                 row_bin = _contract_gather(packed, row_feat[:, None])[:, 0]
             else:
-                row_bin = jnp.take_along_axis(
-                    bins, jnp.clip(row_feat, 0, d_pad - 1)[:, None], axis=1
-                )[:, 0].astype(jnp.int32)
+                row_bin = _bins_at(packed, None, row_feat[:, None])[:, 0]
             go_right = (row_bin > bb[lc]).astype(jnp.int32)
             child = 2 * node + 1 + go_right
             moves = in_level & opens[lc, go_right]
@@ -1598,7 +1655,8 @@ def _seg_sum_trees(vals, seg, num):
 
 
 def _hist_compact_batched(
-    hist_src,             # (T, n, F) int bins, or None with full_bins
+    hist_src,             # (T, n, F) int bins, (n, d_pad/4) int32 packed words
+                          # shared by the batch, or None with full_bins
     seg: jax.Array,       # (T, n) int32 level-local node id; n_nodes = dead
     sw: jax.Array,        # (T, n, S) f32 stats*weight
     *,
@@ -1608,7 +1666,7 @@ def _hist_compact_batched(
     n_pad: int,
     f_chunk: int,
     variance: bool,
-    full_bins=None,       # (n, d_pad) uint8 SHARED rows + feats => fused-sel
+    full_bins=None,       # (n, d_pad/4) int32 SHARED packed rows + feats => fused-sel
     feats=None,           # (T, n_nodes, F) int32 per-node feature ids
     interpret=None,
 ):
@@ -1627,6 +1685,8 @@ def _hist_compact_batched(
     if full_bins is not None:
         n = full_bins.shape[0]
         F = feats.shape[-1]
+    elif hist_src.ndim == 2:    # shared packed words (no subset)
+        n, F = hist_src.shape[0], 4 * hist_src.shape[1]
     else:
         n, F = hist_src.shape[-2], hist_src.shape[-1]
     S = sw.shape[-1]
@@ -1679,7 +1739,7 @@ def _hist_compact_batched(
         return _seg_sum_trees(p2d, seg_red, n_nodes + 1)[:, :n_nodes]
 
     if full_bins is not None:
-        bq = jax.vmap(lambda s2: full_bins[s2])(src2)       # (T, n_pad, d_pad)
+        bq = jax.vmap(lambda s2: full_bins[s2])(src2)       # (T, n_pad, d_pad/4)
         featsq = jax.vmap(lambda f, c: f[c])(feats, sbc)    # (T, n_sb, F)
         partials = subblock_hist_sel_batched(
             bq, featsq, swq.transpose(0, 2, 1), n_bins=nb, r_sub=r_sub,
@@ -1690,7 +1750,7 @@ def _hist_compact_batched(
         ).reshape(T, n_nodes, S, F, nb)
     else:
         if hist_src.ndim == 2:      # shared full bins (no subset)
-            binq = jax.vmap(lambda s2: hist_src[s2])(src2).astype(jnp.int32)
+            binq = jax.vmap(lambda s2: _unpack_bins(hist_src[s2]))(src2)
         else:                       # per-tree subset-gathered bins
             binq = jax.vmap(lambda h, s2: h[s2])(hist_src, src2).astype(
                 jnp.int32
@@ -1717,7 +1777,7 @@ def _hist_compact_batched(
 
 
 def _grow_trees_batched(
-    bins: jax.Array,    # (n, d_pad) uint8, shared across the tree batch
+    packed: jax.Array,  # (n, d_pad/4) int32 bins four to a word, shared across the tree batch
     sw: jax.Array,      # (T, n, S) float stats*weight per tree
     kf: jax.Array,      # (T, 2) per-tree feature-subset keys
     cfg: ForestConfig,
@@ -1746,7 +1806,8 @@ def _grow_trees_batched(
     the boosting loop reads leaf assignments from it without a second
     descent.
     """
-    n, d_pad = bins.shape
+    n, words = packed.shape
+    d_pad = 4 * words
     T = sw.shape[0]
     S = cfg.n_stats
     nb = cfg.n_bins
@@ -1768,16 +1829,14 @@ def _grow_trees_batched(
     sel_tiles = jnp.zeros((T, 2), jnp.int32)
 
     if cfg.contract_gather == "on":
-        use_contract = d_pad % 4 == 0
+        use_contract = True
     elif cfg.contract_gather == "off":
         use_contract = False
     else:
         use_contract = (
             jax.default_backend() == "tpu"
-            and d_pad % 4 == 0
             and d_pad <= 1024
         )
-    packed = _pack_bins(bins) if use_contract else None
 
     for level in range(cfg.max_depth + 1):
         offset = (1 << level) - 1
@@ -1825,7 +1884,7 @@ def _grow_trees_batched(
 
         def make_hist_src(feats=feats, local=local):
             if not subset:
-                return bins                             # (n, d_pad) shared
+                return packed                           # (n, d_pad/4) shared
             lc0 = jnp.clip(local, 0, n_nodes - 1)       # (T, n)
             row_feats = jax.vmap(lambda f, l: f[l])(feats, lc0)
             if use_contract:
@@ -1833,10 +1892,8 @@ def _grow_trees_batched(
                     lambda rf_: _contract_gather(packed, rf_)
                 )(row_feats)                            # (T, n, k_pad) i32
             return jax.vmap(
-                lambda rf_: jnp.take_along_axis(
-                    bins, jnp.clip(rf_, 0, d_pad - 1), axis=1
-                )
-            )(row_feats)                                # (T, n, k_pad) u8
+                lambda rf_: _bins_at(packed, None, rf_)
+            )(row_feats)                                # (T, n, k_pad) i32
 
         # the SAME per-tree plan as _build_tree — resolve_tree_batch's
         # budget is what accounts for the xT transients, NOT these gates,
@@ -1855,7 +1912,7 @@ def _grow_trees_batched(
                     None, a[0], a[1], n_nodes=n_nodes, n_slots=d_hist, nb=nb,
                     r_sub=r_sub, n_pad=n_pad_c, f_chunk=Fc,
                     variance=(cfg.impurity == "variance"),
-                    full_bins=bins, feats=a[2], wide=True,
+                    full_bins=packed, feats=a[2], wide=True,
                 )
                 return hist, parent, frontier.sel_tiles
 
@@ -1866,7 +1923,7 @@ def _grow_trees_batched(
                 None, seg, sw, n_nodes=n_nodes, nb=nb, r_sub=r_sub,
                 n_pad=n_pad_c, f_chunk=Fc,
                 variance=(cfg.impurity == "variance"),
-                full_bins=bins, feats=feats,
+                full_bins=packed, feats=feats,
             )
         elif use_compact:
             hist_full, parent = _hist_compact_batched(
@@ -2089,9 +2146,7 @@ def _grow_trees_batched(
                         hist_src, (0, 0, ci * F), (T, n, F)
                     ).astype(jnp.int32)
                 else:
-                    binc = lax.dynamic_slice(
-                        hist_src, (0, ci * F), (n, F)
-                    ).astype(jnp.int32)
+                    binc = _unpack_bins(hist_src, ci * F, F)
                 make = _hist_matmul_b if use_matmul else _hist_scatter_b
                 hist = make(
                     binc, n_nodes=n_nodes, in_level=in_level,
@@ -2148,9 +2203,7 @@ def _grow_trees_batched(
             )(row_feat)
         else:
             row_bin = jax.vmap(
-                lambda rf_: jnp.take_along_axis(
-                    bins, jnp.clip(rf_, 0, d_pad - 1)[:, None], axis=1
-                )[:, 0].astype(jnp.int32)
+                lambda rf_: _bins_at(packed, None, rf_[:, None])[:, 0]
             )(row_feat)
         go_right = (row_bin > jnp.take_along_axis(bb, lc, axis=1)).astype(
             jnp.int32
@@ -2176,7 +2229,7 @@ def _grow_trees_batched(
 
 
 def _build_trees_batched(
-    bins: jax.Array,    # (n, d_pad) uint8
+    bins: jax.Array,    # (n, d_pad/4) int32 bins four to a word
     stats: jax.Array,   # (n, S) float
     valid: jax.Array,   # (n,) float row mask
     keys: jax.Array,    # (T, 2) uint32
@@ -2214,7 +2267,7 @@ def _build_trees_batched(
     jax.jit, static_argnames=("mesh", "cfg", "gather", "tree_batch")
 )
 def build_forest(
-    bins: jax.Array,   # (N_pad, d_pad) uint8, dp-sharded
+    bins: jax.Array,   # (N_pad, d_pad/4) int32 bins four to a word, dp-sharded
     mask: jax.Array,   # (N_pad,) float, dp-sharded
     stats: jax.Array,  # (N_pad, S) float, dp-sharded
     keys: jax.Array,   # (n_dp, trees_per_device, 2) uint32, dp-sharded
@@ -2232,7 +2285,7 @@ def build_forest(
     sees only its worker's row partition (the per-worker local cuRF fit,
     ``tree.py:269-402``), which costs tree quality as worker count grows.
     ``gather=True`` is the TPU-first improvement the reference cannot
-    afford over NCCL: one ICI ``all_gather`` of the uint8 binned matrix
+    afford over NCCL: one ICI ``all_gather`` of the binned matrix
     (n x d bytes — 33 MB at 131k x 256, ~3 GB at the 1M x 3000 reference
     shape) gives every tree the FULL dataset, making quality independent
     of worker count while growth stays collective-free."""

@@ -415,24 +415,25 @@ def test_rf_subblock_hist_kernel_compiles(one_chip):
 
 
 def test_rf_fused_selection_kernel_compiles_at_the_reference_width(one_chip):
-    """``rf_dbx``'s histogram kernel: whole rows of 3072 uint8 bins (3000
-    columns at the lane multiple, not 4096), 64 feature slots (55 sampled),
-    128 bins, 2 stats, sub-blocks of 64 rows — two grid blocks, as the
-    lowering probe compiles it."""
+    """``rf_dbx``'s histogram kernel: whole rows of 3072 bins (3000 columns
+    at the multiple of 512, not 4096) held four to an int32 word, 768 words
+    a row, unpacked in VMEM; 64 feature slots (55 sampled), 128 bins, 2
+    stats, sub-blocks of 64 rows — two grid blocks, as the lowering probe
+    compiles it."""
     from spark_rapids_ml_tpu.ops.rf_pallas import BLOCK_ROWS, rf_hist_sel_declined, subblock_hist_sel
 
     n, d_pad, k, r_sub, S = 2 * BLOCK_ROWS, 3072, 64, 64, 2
     assert rf_hist_sel_declined(n, d_pad, k, 128, S, r_sub) == "backend"   # every other term holds
     c = subblock_hist_sel.lower(
-        one_chip((n, d_pad), jnp.uint8), one_chip((n // r_sub, k), I32), one_chip((S, n)),
+        one_chip((n, d_pad // 4), I32), one_chip((n // r_sub, k), I32), one_chip((S, n)),
         n_bins=128, r_sub=r_sub, variance=False, interpret=False,
     ).compile()
     assert _has_kernel(c)
 
 
 def test_rf_build_forest_holds_no_copy_of_a_level(topo, no_compile_cache, monkeypatch):
-    """``build_forest`` at ``rf_dbx``'s shape class (500,000 rows of 3072 uint8
-    bins, 55 of 3000 features a node in 64 slots, 128 bins, 2 classes; two
+    """``build_forest`` at ``rf_dbx``'s shape class (500,000 rows of 3072 bins
+    four to an int32 word, 55 of 3000 features a node in 64 slots, 128 bins, 2 classes; two
     levels deep, a whole tree compiles for minutes): ONE Mosaic call a level,
     as before a level followed its live rows, now inside the level's loop over
     chunks of 16,384 node-sorted rows and at that one shape on every level.
@@ -456,20 +457,21 @@ def test_rf_build_forest_holds_no_copy_of_a_level(topo, no_compile_cache, monkey
     )
     assert [tk.level_plan(n, d_pad, lv, cfg).strategy for lv in range(depth)] == ["pallas_sel"] * depth
     c = tk.build_forest.lower(
-        rows((n, d_pad), jnp.uint8), rows((n,), F32), rows((n, 2), F32), rows((1, 8, 2), jnp.uint32),
+        rows((n, d_pad // 4), I32), rows((n,), F32), rows((n, 2), F32), rows((1, 8, 2), jnp.uint32),
         mesh=mesh, cfg=cfg, gather=False, tree_batch=1,
     ).compile()
     txt = c.as_text()
     assert txt.count('custom_call_target="tpu_custom_call"') == depth
-    # every call at the chunk's shape: whole rows of one chunk in, its sub-blocks' partials out
+    # every call at the chunk's shape: whole rows of words of one chunk in, its sub-blocks' partials out
     chunk = tk._LIVE_CHUNK
-    assert txt.count(f"operand_layout_constraints={{s32[1]{{0}}, u8[{chunk},{d_pad}]{{1,0}}") == depth
+    assert txt.count(f"operand_layout_constraints={{s32[1]{{0}}, s32[{chunk},{d_pad // 4}]{{1,0}}") == depth
+    assert f"u8[{chunk},{d_pad}]" not in txt
     assert c.memory_analysis().temp_size_in_bytes < (400 << 20)
 
 
 def test_rf_wide_selection_kernel_compiles_at_the_regressors_width(one_chip):
     """``rf_reg_dbx``'s histogram kernel: one chunk's whole rows (16,384 x 3072
-    bins as bf16), 1000 features a node in 1024 slots — a grid of 8 slot tiles
+    bins as 768 int32 words a row, a column tile unpacked in VMEM), 1000 features a node in 1024 slots — a grid of 8 slot tiles
     x 32 one-node blocks, a tile's selection a loop over its window of column
     tiles (scalar prefetch) — 128 bins, the three statistics' exact split in
     16 bf16 rows, the level's per-node sums (32 nodes) added to in place: the
@@ -483,8 +485,9 @@ def test_rf_wide_selection_kernel_compiles_at_the_regressors_width(one_chip):
     assert rp.rf_hist_wide_declined(n, d_pad, k, nb, S) == "backend"      # every other term holds
     assert rp.rf_hist_wide_declined(n, d_pad, 2 * k, nb, S) == rp.rf_hist_wide_declined(n, d_pad, k, 256, S) == "backend"
     blocks = n // rp.WIDE_BLOCK_ROWS
+    assert rp.rf_hist_wide_declined(n, 1152, k, nb, S) == "backend,words%128"
     c = rp.subblock_hist_sel_wide.lower(
-        one_chip((n, d_pad), jnp.bfloat16), one_chip((blocks, k), I32), one_chip((rp.WIDE_STAT_ROWS, n), jnp.bfloat16),
+        one_chip((n, d_pad // 4), I32), one_chip((blocks, k), I32), one_chip((rp.WIDE_STAT_ROWS, n), jnp.bfloat16),
         one_chip((blocks,), I32), one_chip((1,), I32), one_chip((nodes, rp.WIDE_STAT_ROWS, k * nb)),
         n_bins=nb, interpret=False,
     ).compile()
@@ -495,12 +498,13 @@ def test_rf_wide_selection_kernel_compiles_at_the_regressors_width(one_chip):
 
 
 def test_rf_regressor_forest_gathers_no_subset(topo, no_compile_cache, monkeypatch):
-    """``build_forest`` at ``rf_reg_dbx``'s shape (500,000 rows of 3072 bins,
-    1000 of 3000 features a node, variance, 128 bins; two levels deep): ONE
-    Mosaic call a level, on a chunk's whole rows — no per-row gather of the
-    1024 sampled columns (``u8[16384,1024]``, 0.2 s a chunk on the chip) and
-    no subset-wide copy of a level; the temporaries are a chunk, the level's
-    sums and the gain search's."""
+    """``build_forest`` at ``rf_reg_dbx``'s shape (500,000 rows of 3072 bins
+    four to an int32 word, 1000 of 3000 features a node, variance, 128 bins;
+    two levels deep): ONE Mosaic call a level, on a chunk's whole rows of
+    words — no bf16 copy of a chunk (``bf16[16384,3072]``, 100 MB), no
+    per-row gather of the 1024 sampled columns (``u8[16384,1024]``, 0.2 s a
+    chunk on the chip) and no subset-wide copy of a level; the temporaries
+    are a chunk, the level's sums and the gain search's."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from spark_rapids_ml_tpu.ops import linalg, tree_kernels as tk
@@ -516,13 +520,14 @@ def test_rf_regressor_forest_gathers_no_subset(topo, no_compile_cache, monkeypat
     )
     assert [tk.level_plan(n, d_pad, lv, cfg).strategy for lv in range(depth)] == ["pallas_sel_wide"] * depth
     c = tk.build_forest.lower(
-        rows((n, d_pad), jnp.uint8), rows((n,), F32), rows((n, 3), F32), rows((1, 5, 2), jnp.uint32),
+        rows((n, d_pad // 4), I32), rows((n,), F32), rows((n, 3), F32), rows((1, 5, 2), jnp.uint32),
         mesh=mesh, cfg=cfg, gather=False, tree_batch=1,
     ).compile()
     txt = c.as_text()
     assert txt.count('custom_call_target="tpu_custom_call"') == depth
     chunk = tk._LIVE_CHUNK
-    assert f"bf16[{chunk},{d_pad}]" in txt and f"u8[{chunk},1024]" not in txt and f"s32[{chunk},1024]" not in txt
+    assert f"s32[{chunk},{d_pad // 4}]" in txt and f"bf16[{chunk},{d_pad}]" not in txt
+    assert f"u8[{chunk},1024]" not in txt and f"s32[{chunk},1024]" not in txt
     assert c.memory_analysis().temp_size_in_bytes < (600 << 20)
 
 
@@ -542,33 +547,40 @@ def test_rf_sketch_reads_the_reference_frame_in_place(one_chip):
 
 
 @pytest.mark.parametrize(
-    "rows,d_pad,rows_minor_bins",
-    [(500_000, 3072, False), (131_072, 3000, True), (106_784, 3000, True)],
-    ids=["fit", "full_batch", "tail_batch"],
+    "rows,d_pad,packed,rows_minor_bins",
+    [(500_000, 3072, True, False), (131_072, 3000, False, True), (106_784, 3000, False, True), (1500, 256, True, True)],
+    ids=["fit", "full_batch", "tail_batch", "narrow_fit"],
 )
-def test_rf_binize_compiles_at_the_cells_shapes(topo, one_chip, monkeypatch, rows, d_pad, rows_minor_bins):
+def test_rf_binize_compiles_at_the_cells_shapes(topo, one_chip, monkeypatch, rows, d_pad, packed, rows_minor_bins):
     """The forests' ``binize`` at the shapes ``rf_dbx`` / ``rf_reg_dbx`` run:
-    the fit's 500,000 x 3000 frame to 3072 bin columns, a transform batch of
-    131,072 rows and the tail of 106,784 to 3000. Each frame is rows minor;
-    the gate admits it, and the one program is the Pallas quantizer reading
-    the frame as its transpose (a bitcast) — no f32 operand of the frame's
-    size, so no relaid copy of it — and writing the bins at their default
-    layout (row-major at 3072, rows minor at 3000: a bitcast of the kernel's
-    transposed result). XLA's unrolled compare-count chunks hold 1.85 GB of
-    temporaries at the fit; this program holds none of the frame's size."""
+    the fit's 500,000 x 3000 frame to 3072 bin columns four to an int32 word
+    (768 words a row), a transform batch of 131,072 rows and the tail of
+    106,784 to 3000 bytes a row; and a narrow fit (1,500 x 200 to 256
+    columns, 64 words a row). Each frame is rows minor; the gate admits it,
+    and the one program is the Pallas quantizer reading the frame as its
+    transpose (a bitcast) — no f32 operand of the frame's size, so no relaid
+    copy of it — and writing the bins at their default layout (row-major
+    words at 768, rows minor at 3000 bytes and 64 words: a bitcast of the
+    kernel's transposed result) with no pack pass behind it. XLA's unrolled
+    compare-count chunks hold 1.85 GB of temporaries at the fit; this
+    program holds none of the frame's size."""
     from spark_rapids_ml_tpu.ops import linalg, tree_kernels as tk
 
-    cols = 3000
+    cols = 3000 if rows > 1500 else 200
+    width, dt = (d_pad // 4, I32) if packed else (d_pad, jnp.uint8)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the gate
     assert linalg.rows_minor(topo.devices[0], rows, cols)
-    assert linalg.rows_minor(topo.devices[0], rows, d_pad, jnp.uint8) == rows_minor_bins
-    plan = tk.binize_plan((rows, cols), F32, 127, d_pad, 1, topo.devices[0])
-    assert (plan.kernel, plan.declined, plan.tile, plan.rows_minor_out) == ("pallas", "", "2048x128", rows_minor_bins)
+    assert linalg.rows_minor(topo.devices[0], rows, width, dt) == rows_minor_bins
+    plan = tk.binize_plan((rows, cols), F32, 127, d_pad, 1, topo.devices[0], packed=packed)
+    tile = "1536x64" if d_pad == 256 else "2048x128"
+    assert (plan.kernel, plan.declined, plan.tile, plan.rows_minor_out) == ("pallas", "", tile, rows_minor_bins)
     c = tk.binize.lower(one_chip((rows, cols)), one_chip((cols, 127)), d_pad=d_pad, plan=plan).compile()
+    assert c.out_info.shape == (rows, width) and c.out_info.dtype == dt
     txt = c.as_text()
     assert txt.count('custom_call_target="tpu_custom_call"') == 1 and "rf_binize_pass" in txt
     assert f"f32[{cols},{rows}]{{1,0" in txt                 # the frame read as its transpose
     assert f"f32[{rows},{cols}]{{1,0" not in txt             # and never relaid row-major
+    assert f"u8[{rows},{d_pad}]" not in txt or not packed    # and no byte bins packed after it
     assert c.memory_analysis().temp_size_in_bytes < (16 << 20)
 
 

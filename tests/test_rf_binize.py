@@ -104,7 +104,9 @@ def test_binize_spans_say_which_pass_ran(kernel, monkeypatch):
     batch) carry ``kernel``, ``tile`` and ``declined``: on the CPU the gate
     declines on the backend and XLA's chunk is whole sublane tiles; with the
     gate answered as a TPU's (the kernel in interpret mode) every span says
-    ``pallas`` and the grid step."""
+    ``pallas`` and the grid step — a fit's step counts one byte plane of the
+    64 words a row of its 256 bin columns packs into, a batch's step 128
+    columns of bytes."""
     if kernel == "pallas":
         monkeypatch.setattr(rfp, "FORCE_INTERPRET", True)
     monkeypatch.setenv("TPUML_RF_APPLY", "bins")
@@ -130,6 +132,6 @@ def test_binize_spans_say_which_pass_ran(kernel, monkeypatch):
     for args in fit + batches:
         assert args["kernel"] == kernel
         if kernel == "pallas":
-            assert args["declined"] == "" and args["tile"] == "1536x128"
+            assert args["declined"] == "" and args["tile"] == ("1536x64" if args in fit else "1536x128")
         else:
             assert args["declined"] == "backend,rows_minor" and args["tile"] == str(tk._binize_chunk(n, d))

@@ -39,7 +39,7 @@ def _data(labels: str, seed=0, n=600):
         yr = np.where(y > 0, 1.0, -1.0)
     else:
         yr = rng.normal(size=n)
-    bins = tk.binize(jnp.asarray(X), jnp.asarray(tk.make_bin_edges(X, NB)), d_pad=D)
+    bins = tk._pack_bins(tk.binize(jnp.asarray(X), jnp.asarray(tk.make_bin_edges(X, NB)), d_pad=D))
     yr = jnp.asarray(yr, jnp.float32)
     counts = jax.nn.one_hot(jnp.asarray(y), 2, dtype=jnp.float32)
     return bins, {
@@ -105,7 +105,7 @@ def _weights(valid, key, cfg):
 
 def _step_down(tree, bins, node, moving):
     """One level down the served splits: the rows that move, and where to."""
-    feat, thr, b = np.asarray(tree["feature"]), np.asarray(tree["threshold_bin"]), np.asarray(bins)
+    feat, thr, b = np.asarray(tree["feature"]), np.asarray(tree["threshold_bin"]), np.asarray(tk._unpack_bins(bins))
     f = feat[node]
     moving = moving & (f >= 0)
     right = b[np.arange(len(node)), np.clip(f, 0, None)] > thr[node]
@@ -258,7 +258,7 @@ def test_level_follows_its_live_prefix(monkeypatch, sel, live):
     hist, parent, frontier = tk._hist_compact(
         None if sel else (lambda r, nd: jb[r[:, None], jf[nd]]), jnp.asarray(seg), jnp.asarray(sw),
         n_nodes=n_nodes, n_slots=F, nb=NB, r_sub=r_sub, n_pad=n_pad, f_chunk=F, variance=False,
-        full_bins=jb if sel else None, feats=jf if sel else None, interpret=True,
+        full_bins=tk._pack_bins(jb) if sel else None, feats=jf if sel else None, interpret=True,
     )
     assert frontier.chunk == 512
     p_live = sum(-(-int((seg == g).sum()) // r_sub) * r_sub for g in range(n_nodes))
@@ -293,7 +293,7 @@ def test_kernels_skip_blocks_past_the_live_count(live):
     swT = jnp.asarray(rng.integers(0, 3, (S, n)), jnp.float32)
     count = jnp.asarray([live], jnp.int32)
     kept = live * (R // r_sub)
-    bq = jnp.asarray(rng.integers(0, NB, (n, D)), jnp.uint8)
+    bq = tk._pack_bins(jnp.asarray(rng.integers(0, NB, (n, D)), jnp.uint8))
     fq = jnp.asarray(rng.integers(0, D, (n // r_sub, k)), jnp.int32)
     sel = lambda *a: rfp.subblock_hist_sel(bq, fq, swT, *a, n_bins=NB, r_sub=r_sub, interpret=True)
     np.testing.assert_array_equal(np.asarray(sel(count))[:kept], np.asarray(sel())[:kept])

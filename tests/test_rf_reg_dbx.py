@@ -185,7 +185,7 @@ def test_a_classifiers_forest_is_the_parents(monkeypatch):
     y = rng.integers(0, 2, n)
     X = rng.normal(size=(n, d)).astype(np.float32)
     X[:, :40] += np.where(y[:, None] > 0, 0.35, -0.35).astype(np.float32)
-    bins = tk.binize(jnp.asarray(X), jnp.asarray(tk.make_bin_edges(X, nb)), d_pad=d_pad)
+    bins = tk._pack_bins(tk.binize(jnp.asarray(X), jnp.asarray(tk.make_bin_edges(X, nb)), d_pad=d_pad))
     stats = jax.nn.one_hot(jnp.asarray(y), 2, dtype=jnp.float32)
     monkeypatch.setattr(tk, "_LIVE_CHUNK", 1024)
     key = jax.random.PRNGKey(9)
@@ -213,12 +213,12 @@ def test_a_classifiers_forest_is_the_parents(monkeypatch):
 
 def _wide_case():
     """Three calls on one level's running sums, 4 nodes of 256 slots over
-    1152 columns, 32 bins, 512-row blocks: call 1 holds nodes 0, 0, 1, 2;
+    1536 columns (1100 of them the frame's), 32 bins, 512-row blocks: call 1 holds nodes 0, 0, 1, 2;
     call 2 nodes 2, 2, 3 and a dead block (3 live), so node 2 spans both
     calls; call 3 no live block at all. Each node's draw of ids; node 1's
     last 40 slots are the sentinel 1100, and node 3 drew 0 and 1099 first."""
     rng = np.random.default_rng(21)
-    d, d_pad, k, nb = 1100, 1152, 256, 32
+    d, d_pad, k, nb = 1100, 1536, 256, 32
     draw = np.stack([rng.permutation(d)[:k] for _ in range(4)]).astype(np.int32)
     draw[1, -40:] = d
     draw[3, :2] = [0, d - 1]
@@ -240,14 +240,15 @@ def test_the_wide_kernels_sums_against_float64(order):
     tile's window about the whole width); ``ascending_but_node_3``: node 3's
     drawn ids, whose first tile spans 0 ... 1099, beside sorted ones."""
     draw, bins, sw, calls, nb = _wide_case()
-    k = draw.shape[1]
+    k, d_pad = draw.shape[1], bins.shape[1]
     fed = np.sort(draw, axis=1) if order != "drawn" else draw.copy()
     if order == "ascending_but_node_3":
         fed[3] = draw[3]
     acc = jnp.zeros((4, rfp.WIDE_STAT_ROWS, k * nb), jnp.float32)
     for nodes, rows, live in calls:
         acc = rfp.subblock_hist_sel_wide(
-            jnp.asarray(bins[rows], jnp.bfloat16), jnp.asarray(fed[nodes]), rfp.split_f32_exact(jnp.asarray(sw[:, rows])),
+            tk._pack_bins(jnp.asarray(bins[rows], jnp.uint8)), jnp.asarray(fed[nodes]),
+            rfp.split_f32_exact(jnp.asarray(sw[:, rows])),
             jnp.asarray(nodes, jnp.int32), jnp.asarray([live], jnp.int32), acc, n_bins=nb, interpret=True,
         )
     got = np.asarray(rfp.wide_hist_nodes(acc, 3, k, nb, draw=None if order == "drawn" else jnp.asarray(draw)), np.float64)
@@ -258,11 +259,11 @@ def test_the_wide_kernels_sums_against_float64(order):
         for b in range(live):
             r = np.arange(rows.start + 512 * b, rows.start + 512 * (b + 1))
             for j, f in enumerate(draw[nodes[b]]):
-                col = bins[r, min(f, 1151)].astype(np.int64)
+                col = bins[r, min(f, d_pad - 1)].astype(np.int64)
                 for s in range(3):
                     want[nodes[b], s, j] += np.bincount(col, weights=sw[s, r].astype(np.float64), minlength=nb)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6 * np.abs(want).max())
-    lo, cnt = (np.asarray(a) for a in rfp.wide_windows(jnp.asarray(fed), 1152))
+    lo, cnt = (np.asarray(a) for a in rfp.wide_windows(jnp.asarray(fed), d_pad))
     if order == "drawn":
         assert cnt.min() >= 8                       # a drawn tile's window: about the whole width
     else:
@@ -295,12 +296,12 @@ def test_a_regressors_forest_is_the_parents(monkeypatch, builder):
     draw's order before the gain search, which breaks ties as before."""
     recorded = np.load(os.path.join(ROOT, "tests", "data", "rf_regressor_tables_parent_pr40.npz"))
     rng = np.random.default_rng(11)
-    n, d, nb, d_pad = 2048, 1100, 32, 1152
+    n, d, nb, d_pad = 2048, 1100, 32, 1536
     X = rng.normal(size=(n, d)).astype(np.float32)
     X[:, 550:] = X[:, :550]
     coef = np.array([3.0, -2.0, 1.5, 1.0, -1.0, 0.5], np.float32)
     y = jnp.asarray((X[:, :6] @ coef + 0.3 * rng.normal(size=n)).astype(np.float32) * 100)
-    bins = tk.binize(jnp.asarray(X), jnp.asarray(tk.make_bin_edges(X, nb)), d_pad=d_pad)
+    bins = tk._pack_bins(tk.binize(jnp.asarray(X), jnp.asarray(tk.make_bin_edges(X, nb)), d_pad=d_pad))
     stats = jnp.stack([jnp.ones(n), y, y * y], axis=1)
     cfg = tk.ForestConfig(
         max_depth=5, n_bins=nb, n_features=d, n_stats=3, impurity="variance", k_features=367, min_samples_leaf=1,

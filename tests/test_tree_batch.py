@@ -21,7 +21,7 @@ def _data(seed=0, n=600, d=16, nb=32):
     rng = np.random.default_rng(seed)
     X = rng.normal(size=(n, d)).astype(np.float32)
     edges = tk.make_bin_edges(X, nb)
-    bins = tk.binize(jnp.asarray(X), jnp.asarray(edges), d_pad=tk.next_pow2(d))
+    bins = tk._pack_bins(tk.binize(jnp.asarray(X), jnp.asarray(edges), d_pad=tk.next_pow2(d)))
     valid = jnp.ones((n,), jnp.float32)
     y = (X[:, 0] + 0.5 * X[:, 3] > 0).astype(np.int32)
     cls_stats = jax.nn.one_hot(jnp.asarray(y), 2, dtype=jnp.float32)
@@ -272,7 +272,7 @@ def test_return_rows_leaf_assignment():
     node = np.asarray(out["node"])                       # (2, n)
     feat = np.asarray(out["feature"])
     thrb = np.asarray(out["threshold_bin"])
-    bins_np = np.asarray(bins)
+    bins_np = np.asarray(tk._unpack_bins(bins))
     for t in range(2):
         cur = np.zeros(bins_np.shape[0], np.int64)
         for _ in range(cfg.max_depth):
